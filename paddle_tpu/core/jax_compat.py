@@ -1,66 +1,32 @@
-"""Version-fragile jax API surface, centralized.
+"""The single import site for jax names that have moved between releases,
+and the one platform probe.
 
-`shard_map` has moved twice: `jax.experimental.shard_map.shard_map`
-(<= 0.4.x), then promoted to `jax.shard_map` (>= 0.6), with the
-`check_rep` kwarg renamed to `check_vma` along the way. A bare
-`from jax import shard_map` therefore breaks every importing module on
-the 0.4.x line (10 test files failed collection on 0.4.37). All
-paddle_tpu code imports `shard_map` from HERE; tools/check_jax_compat.py
-fails CI when a bare import sneaks back in.
+`shard_map`, `axis_size` and the Pallas TPU compiler params have each
+lived under more than one name. paddle_tpu supports exactly the
+installed jax (0.9.x), so these are plain aliases — but every module
+imports them from HERE, so the next rename is a one-file change, and
+the analyzer's `jax-compat` pass (tools/analyze/passes/jax_compat.py)
+fails CI when a bare spelling sneaks back in.
 
-Pallas TPU compiler params renamed too: `pltpu.TPUCompilerParams`
-(0.4.x) became `pltpu.CompilerParams` (newer lines). Kernels build
-theirs through `tpu_compiler_params(...)` here.
+`on_tpu()` is the one answer to "is the default backend a TPU": the
+Pallas auto-dispatch gates, the serving engine's kernel choice, the
+autotune sweep gate and the MFU gauge all ask it. It does not catch:
+a backend that fails to initialise raises here, where the cause is
+visible, instead of reading as "not a TPU" and silently taking the
+CPU path.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax import shard_map
+from jax.experimental.pallas import tpu as _pltpu
 
-__all__ = ["shard_map", "axis_size", "tpu_compiler_params"]
+__all__ = ["shard_map", "axis_size", "tpu_compiler_params", "on_tpu"]
+
+axis_size = jax.lax.axis_size
+tpu_compiler_params = _pltpu.CompilerParams
 
 
 def on_tpu() -> bool:
-    """True when the default jax backend is a real TPU — the shared
-    auto-dispatch gate for the Pallas kernel modules (kernels/
-    flash_attention, blockwise_ce, fused_norm); one probe, one
-    behavior."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def tpu_compiler_params(**kwargs):
-    """`pltpu.CompilerParams(**kwargs)` under whichever name the
-    installed jax line exports (`TPUCompilerParams` on 0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-try:                                   # jax >= 0.6: promoted to top level
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if hasattr(jax.lax, "axis_size"):      # added ~0.5
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """Size of a named mesh axis inside shard_map: psum of 1 folds
-        to the constant at compile time on the 0.4.x line."""
-        return jax.lax.psum(1, axis_name)
-
-_HAS_VMA = "check_vma" in inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma=None, **kw):
-    """`jax.shard_map` with the replication-check kwarg translated for
-    whichever jax line is installed (`check_vma` new / `check_rep` old)."""
-    if check_vma is not None:
-        kw["check_vma" if _HAS_VMA else "check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """True when jax's default backend is a TPU."""
+    return jax.devices()[0].platform == "tpu"

@@ -84,6 +84,22 @@ def prune_candidates(candidates, tuner_cfg, history=()):
     return kept, pruned
 
 
+def _peak_flops(tuner_cfg):
+    """Per-chip peak FLOP/s the cost model prices compute against:
+    `tuner_cfg["peak_flops"]`, else the local chip's entry in the one
+    peaks table. No default: a plan for an undescribed chip is priced
+    against nothing."""
+    if tuner_cfg.get("peak_flops"):
+        return float(tuner_cfg["peak_flops"])
+    from paddle_tpu.device.peaks import detect_peaks
+    peaks = detect_peaks()
+    if peaks is None:
+        raise ValueError(
+            'tuner_cfg needs "peak_flops" (per-chip FLOP/s of the target '
+            "chip) when no TPU is attached to read it from")
+    return peaks.bf16_flops
+
+
 def _cost(cfg, tuner_cfg):
     """Roofline step-time proxy: compute time on MXU + collective time on
     ICI (reference: cost_model.py; ours prices XLA collectives instead of
@@ -93,7 +109,7 @@ def _cost(cfg, tuner_cfg):
     seq = int(tuner_cfg.get("seq_length", 2048))
     n = int(tuner_cfg["num_devices"])
     flops = 6.0 * p * gbs * seq            # fwd+bwd matmul flops
-    peak = float(tuner_cfg.get("peak_flops", 459e12)) * n
+    peak = _peak_flops(tuner_cfg) * n
     t_compute = flops / peak
     # TP all-reduces: 2 per layer fwd+bwd over activations
     h = int(tuner_cfg.get("hidden_size", 4096))

@@ -229,11 +229,11 @@ def ring_attention_local(q, k, v, axis_name, causal=True, sm_scale=None,
     interpret=True) block-aligned shapes take the flash-kernel ring;
     others keep the jnp online-softmax merge."""
     import os
-    from paddle_tpu.kernels.flash_attention import _on_tpu
+    from paddle_tpu.core import jax_compat
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if use_flash is None:
-        use_flash = ((_on_tpu() or interpret)
+        use_flash = ((jax_compat.on_tpu() or interpret)
                      and os.environ.get("PADDLE_TPU_RING_FLASH",
                                         "1") != "0"
                      and _ring_flash_shapes_ok(q, k))

@@ -101,15 +101,7 @@ def _jax_distributed_active():
     """True when jax.distributed.initialize already ran in this process
     (e.g. the launcher did it before handing control to the script) —
     a second initialize raises."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    # older jax: fall back to the private global state
-    try:
-        from jax._src import distributed as _jd
-        return _jd.global_state.client is not None
-    except Exception:       # noqa: BLE001 — internal layout moved
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def is_initialized():

@@ -115,12 +115,7 @@ class DevicePrefetcher:
 
     @staticmethod
     def _needs_global_assembly(sh):
-        try:
-            return jax.process_count() > 1 and \
-                not sh.is_fully_addressable and \
-                hasattr(jax, "make_array_from_process_local_data")
-        except Exception:
-            return False
+        return jax.process_count() > 1 and not sh.is_fully_addressable
 
     def _place(self, tree, acc, key=None):
         if isinstance(tree, dict):

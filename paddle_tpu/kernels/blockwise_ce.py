@@ -52,9 +52,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.core.jax_compat import on_tpu as _on_tpu
+from paddle_tpu.core import jax_compat
 from paddle_tpu.core.jax_compat import tpu_compiler_params
+from paddle_tpu.kernels import sharding as _sharding
 
 __all__ = ["blockwise_ce_loss", "ce_shape_problems", "check_ce_shapes",
            "logits_bytes_saved", "dense_logits_bytes"]
@@ -408,6 +410,33 @@ def _ce_dw_kernel(x_ref, w_ref, lab_ref, lse_ref, sc_ref, dw_ref,
         dw_ref[...] = acc_scr[...].astype(dw_ref.dtype)
 
 
+def _compiler_params(chunk, d, bv, itemsize):
+    """Mosaic params for the three CE kernels, with the scoped-VMEM
+    limit stated: at chunk 512 x hidden 2048 the double-buffered x
+    chunk, W block and output block plus the f32 accumulator already
+    pass the compiler's 16 MiB default (libtpu refused the dx kernel at
+    16.78M), so the limit is computed from the blocks the kernel
+    actually holds — pipelined inputs/outputs twice, the f32 scratch,
+    and the (chunk, bv) f32 score/probability temporaries — plus a
+    quarter of headroom."""
+    big = max(chunk * d, d * bv)
+    need = (2 * (chunk * d + d * bv + big) * itemsize   # x, W, out blocks
+            + big * 4                                   # f32 accumulator
+            + 6 * chunk * bv * 4)                       # score temporaries
+    return tpu_compiler_params(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=int(need * 1.25))
+
+
+def _row_spec(chunk, index_map):
+    """BlockSpec for one (1, chunk) f32 row of a per-chunk vector
+    (labels, lse, picked, scale). The arrays ride as (nc, 1, chunk) with
+    the chunk axis squeezed, so the block's last two dims EQUAL the
+    array's: a (1, chunk) block of an (nc, chunk) array breaks Mosaic's
+    8-sublane block rule."""
+    return pl.BlockSpec((None, 1, chunk), index_map)
+
+
 def _fwd_pallas(x, w, labels, chunk, vocab_block, ignore_index,
                 interpret):
     n, d = x.shape
@@ -422,6 +451,7 @@ def _fwd_pallas(x, w, labels, chunk, vocab_block, ignore_index,
     # labels ride into the kernel as f32 rows (exact below 2^24): all
     # in-kernel compares stay f32 2D — no int relayouts for Mosaic
     labf = lab2.astype(jnp.float32)
+    row = _row_spec(chunk, lambda i, j: (i, 0, 0))
 
     lse, picked = pl.pallas_call(
         functools.partial(_ce_fwd_kernel, block_v=bv, v_valid=v, nv=nv),
@@ -429,19 +459,18 @@ def _fwd_pallas(x, w, labels, chunk, vocab_block, ignore_index,
         in_specs=[
             pl.BlockSpec((chunk, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, 0)),
+            row,
         ],
-        out_specs=[pl.BlockSpec((1, chunk), lambda i, j: (i, 0)),
-                   pl.BlockSpec((1, chunk), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nc, chunk), jnp.float32),
-                   jax.ShapeDtypeStruct((nc, chunk), jnp.float32)],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((nc, 1, chunk), jnp.float32),
+                   jax.ShapeDtypeStruct((nc, 1, chunk), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((chunk, 8), jnp.float32),
                         pltpu.VMEM((chunk, 8), jnp.float32),
                         pltpu.VMEM((chunk, 8), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(chunk, d, bv, x.dtype.itemsize),
         interpret=interpret,
-    )(xp.reshape(nc * chunk, d), wp, labf)
+    )(xp.reshape(nc * chunk, d), wp, labf[:, None, :])
+    lse, picked = lse[:, 0, :], picked[:, 0, :]
     valid = lab2 != ignore_index
     count = jnp.maximum(jnp.sum(valid.astype(jnp.float32)), 1.0)
     loss = jnp.sum(jnp.where(valid, lse - picked, 0.0)) / count
@@ -463,6 +492,8 @@ def _bwd_pallas(x, w, labels, lses, count, g, chunk, vocab_block,
     scale = jnp.where(lab2 != ignore_index, g / count, 0.0).astype(
         jnp.float32)
     x2 = xp.reshape(nc * chunk, d)
+    rows = [a[:, None, :] for a in (labf, lses, scale)]
+    row = _row_spec(chunk, lambda i, j: (i, 0, 0))
 
     dx = pl.pallas_call(
         functools.partial(_ce_dx_kernel, block_v=bv, v_valid=v, nv=nv),
@@ -470,35 +501,30 @@ def _bwd_pallas(x, w, labels, lses, count, g, chunk, vocab_block,
         in_specs=[
             pl.BlockSpec((chunk, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, 0)),
+            row, row, row,
         ],
         out_specs=pl.BlockSpec((chunk, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((chunk, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(chunk, d, bv, x.dtype.itemsize),
         interpret=interpret,
-    )(x2, wp, labf, lses, scale)
+    )(x2, wp, *rows)
 
+    row = _row_spec(chunk, lambda jv, ir: (ir, 0, 0))
     dw = pl.pallas_call(
         functools.partial(_ce_dw_kernel, block_v=bv, v_valid=v, nr=nc),
         grid=(nv, nc),
         in_specs=[
             pl.BlockSpec((chunk, d), lambda jv, ir: (ir, 0)),
             pl.BlockSpec((d, bv), lambda jv, ir: (0, jv)),
-            pl.BlockSpec((1, chunk), lambda jv, ir: (ir, 0)),
-            pl.BlockSpec((1, chunk), lambda jv, ir: (ir, 0)),
-            pl.BlockSpec((1, chunk), lambda jv, ir: (ir, 0)),
+            row, row, row,
         ],
         out_specs=pl.BlockSpec((d, bv), lambda jv, ir: (0, jv)),
         out_shape=jax.ShapeDtypeStruct((d, v_pad), w.dtype),
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(chunk, d, bv, x.dtype.itemsize),
         interpret=interpret,
-    )(x2, wp, labf, lses, scale)
+    )(x2, wp, *rows)
     return dx[:n], dw[:, :v]
 
 
@@ -576,15 +602,33 @@ def blockwise_ce_loss(x, w, labels, *, chunk, vocab_block=0,
     n, d = x.shape
     v = w.shape[1]
     if kernel == "pallas":
-        on_tpu = _on_tpu()
-        interpret = interpret or not on_tpu
+        interpret = interpret or not jax_compat.on_tpu()
         check_ce_shapes(n, d, v, chunk, vocab_block, interpret)
         use_pallas = True
     elif kernel == "jnp":
         use_pallas = False
     else:
-        use_pallas = (_on_tpu() and not ce_shape_problems(
+        use_pallas = (jax_compat.on_tpu() and not ce_shape_problems(
             n, d, v, chunk, vocab_block, interpret))
-    return _bce(x, w, jnp.asarray(labels).astype(jnp.int32),
-                int(chunk), int(vocab_block), int(ignore_index),
-                use_pallas, bool(interpret))
+    labels = jnp.asarray(labels).astype(jnp.int32)
+
+    def local(xl, wl, ll):
+        return _bce(xl, wl, ll, int(chunk), int(vocab_block),
+                    int(ignore_index), use_pallas, bool(interpret))
+
+    mesh = _sharding.kernel_mesh() if use_pallas else None
+    if mesh is None:
+        return local(x, w, labels)
+    # rows are independent: each shard takes its slice of them against
+    # the whole W and returns (loss sum, valid count); the mean is
+    # taken over the sums (kernels/sharding.py)
+    rows = _sharding.row_axes(mesh, n)
+
+    def shard(xl, wl, ll):
+        cnt = jnp.sum(ll != ignore_index).astype(jnp.float32)
+        return (local(xl, wl, ll) * cnt)[None], cnt[None]
+
+    sums, counts = _sharding.per_shard(
+        shard, mesh, (P(rows, None), P(None, None), P(rows)),
+        (P(rows), P(rows)))(x, w, labels)
+    return jnp.sum(sums) / jnp.maximum(jnp.sum(counts), 1.0)

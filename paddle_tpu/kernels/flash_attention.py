@@ -48,6 +48,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from paddle_tpu.core import jax_compat
+from paddle_tpu.kernels import sharding as _sharding
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634  # kernels exponentiate in base 2: exp(x) = exp2(x*log2e)
@@ -1100,12 +1104,9 @@ def _chunked_attention(q, k, v, causal, sm_scale, block_q=512, block_k=512):
 # custom_vjp glue
 # ---------------------------------------------------------------------------
 
-from paddle_tpu.core.jax_compat import on_tpu as _on_tpu  # noqa: E402
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, sm_scale, seg_len):
-    if _on_tpu():
+    if jax_compat.on_tpu():
         return _flash_fwd_pallas(q, k, v, causal, sm_scale,
                                  save_lse=False, seg_len=seg_len)[0]
     assert seg_len is None  # the GQA fold is only taken on the TPU path
@@ -1113,7 +1114,7 @@ def _flash(q, k, v, causal, sm_scale, seg_len):
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, seg_len):
-    if _on_tpu():
+    if jax_compat.on_tpu():
         out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
                                      seg_len=seg_len)
         return out, (q, k, v, out, lse)
@@ -1145,16 +1146,33 @@ def flash_attention_bhsd(q, k, v, causal=False, sm_scale=None):
     out per-kv-head directly (no XLA group-reduction). Requires the
     segment length S to align with the q block sizes; otherwise falls
     back to jnp.repeat of k/v.
+
+    Under a device mesh the Pallas path runs per shard (batch over
+    dp/fsdp, heads over mp — kernels/sharding.py): XLA cannot partition
+    the Mosaic call itself.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    local = functools.partial(_flash_local, causal=causal,
+                              sm_scale=sm_scale)
+    mesh = _sharding.kernel_mesh() if jax_compat.on_tpu() else None
+    if mesh is None:
+        return local(q, k, v)
+    spec = P(_sharding.batch_axes(mesh, q.shape[0]),
+             _sharding.head_axis(mesh, q.shape[1], k.shape[1]), None, None)
+    return _sharding.per_shard(local, mesh, (spec, spec, spec),
+                               spec)(q, k, v)
+
+
+def _flash_local(q, k, v, causal, sm_scale):
     hq, hk = q.shape[1], k.shape[1]
     if hk != hq:
         rep = hq // hk
         b, _, s, d = q.shape
         bq_f = min(_BLOCK_Q, rep * s)
         bq_b = min(_BLOCK_Q_BWD, rep * s)
-        if _on_tpu() and hq % hk == 0 and s % bq_f == 0 and s % bq_b == 0:
+        if (jax_compat.on_tpu() and hq % hk == 0 and s % bq_f == 0
+                and s % bq_b == 0):
             qf = q.reshape(b, hk, rep * s, d)
             out = _flash(qf, k, v, causal, sm_scale, s)
             return out.reshape(b, hq, s, d)

@@ -36,9 +36,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.core.jax_compat import on_tpu as _on_tpu
+from paddle_tpu.core import jax_compat
 from paddle_tpu.core.jax_compat import tpu_compiler_params
+from paddle_tpu.kernels import sharding as _sharding
 
 __all__ = ["rms_norm_residual", "rope_apply",
            "norm_shape_problems", "check_norm_shapes",
@@ -226,15 +228,19 @@ def _rmsn_bwd_pallas(h2, w, rstd_t, gy2, gh2, interpret):
         kernel,
         grid=(n_pad // bn,),
         in_specs=in_specs,
+        # the per-block dW partial rides as (blocks, 1, d) with the block
+        # axis squeezed: a (1, d) block of a (blocks, d) array breaks
+        # Mosaic's 8-sublane block rule
         out_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                   pl.BlockSpec((1, d), lambda i: (i, 0))],
+                   pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((n_pad, d), h2.dtype),
-                   jax.ShapeDtypeStruct((n_pad // bn, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((n_pad // bn, 1, d),
+                                        jnp.float32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
-    return dh[:n], jnp.sum(dwp, axis=0).astype(w.dtype)
+    return dh[:n], jnp.sum(dwp, axis=(0, 1)).astype(w.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -324,21 +330,35 @@ def rms_norm_residual(x, weight, residual=None, epsilon=1e-6,
         raise ValueError(f"residual shape {residual.shape} != x shape "
                          f"{x.shape}")
     if kernel == "pallas":
-        interpret = interpret or not _on_tpu()
+        interpret = interpret or not jax_compat.on_tpu()
         check_norm_shapes(d, interpret)
         use_pallas = True
     elif kernel == "jnp":
         use_pallas = False
     else:
-        use_pallas = _on_tpu() and not norm_shape_problems(d, interpret)
+        use_pallas = (jax_compat.on_tpu()
+                      and not norm_shape_problems(d, interpret))
     lead = x.shape[:-1]
     x2 = x.reshape(-1, d)
     eps = float(epsilon)
+    mesh = _sharding.kernel_mesh() if use_pallas else None
+    # under a mesh the rows split over its shards (kernels/sharding.py);
+    # the weight rides whole, its gradient summed by the shard_map
+    rows = P(_sharding.row_axes(mesh, x2.shape[0]), None) \
+        if mesh is not None else None
     if residual is None:
-        y = _rmsn_plain(x2, weight, eps, use_pallas, bool(interpret))
-        return y.reshape(lead + (d,)), x
-    r2 = residual.reshape(-1, d)
-    y, h = _rmsn_res(x2, r2, weight, eps, use_pallas, bool(interpret))
+        def plain(xl, wl):
+            return _rmsn_plain(xl, wl, eps, use_pallas, bool(interpret))
+        if mesh is not None:
+            plain = _sharding.per_shard(plain, mesh, (rows, P(None)), rows)
+        return plain(x2, weight).reshape(lead + (d,)), x
+
+    def fused(xl, rl, wl):
+        return _rmsn_res(xl, rl, wl, eps, use_pallas, bool(interpret))
+    if mesh is not None:
+        fused = _sharding.per_shard(fused, mesh, (rows, rows, P(None)),
+                                    (rows, rows))
+    y, h = fused(x2, residual.reshape(-1, d), weight)
     return y.reshape(lead + (d,)), h.reshape(lead + (d,))
 
 
@@ -453,13 +473,14 @@ def rope_apply(x, positions=None, theta=10000.0, kernel=None,
     if d % 2 != 0:
         raise ValueError(f"head_dim must be even (got {d})")
     if kernel == "pallas":
-        interpret = interpret or not _on_tpu()
+        interpret = interpret or not jax_compat.on_tpu()
         check_rope_shapes(d, interpret)
         use_pallas = True
     elif kernel == "jnp":
         use_pallas = False
     else:
-        use_pallas = _on_tpu() and not rope_shape_problems(d, interpret)
+        use_pallas = (jax_compat.on_tpu()
+                      and not rope_shape_problems(d, interpret))
     if positions is None:
         pos = jnp.tile(jnp.arange(s, dtype=jnp.int32), b)
     else:
@@ -470,5 +491,15 @@ def rope_apply(x, positions=None, theta=10000.0, kernel=None,
             pos = pos.reshape(-1)
     cos_f, sin_f = _cos_sin_rows(pos, d, float(theta), jnp.float32)
     x3 = x.reshape(b * s, h, d)
-    out = _rope(x3, cos_f, sin_f, use_pallas, bool(interpret))
-    return out.reshape(b, s, h, d)
+
+    def local(xl, cl, sl):
+        return _rope(xl, cl, sl, use_pallas, bool(interpret))
+    mesh = _sharding.kernel_mesh() if use_pallas else None
+    if mesh is not None:
+        # batch-major rows over the batch axes, heads over mp
+        # (kernels/sharding.py)
+        rows = _sharding.batch_axes(mesh, b)
+        xs = P(rows, _sharding.head_axis(mesh, h), None)
+        local = _sharding.per_shard(
+            local, mesh, (xs, P(rows, None), P(rows, None)), xs)
+    return local(x3, cos_f, sin_f).reshape(b, s, h, d)

@@ -6,7 +6,8 @@ module is that math as a runtime reporter: `TrainingTelemetry` turns
 (tokens, step wall time) into tokens/sec and an MFU estimate using the
 SAME flops-per-token helper bench.py uses (models/llama.py
 `flops_per_token`, including the 8/6 recompute replay factor) and the
-same per-chip peak-FLOPs table, publishing gauges/histograms into the
+one per-chip peaks table (device/peaks.py), publishing
+gauges/histograms into the
 shared metrics registry. `parallel/trainer.py` drives it when
 observability is enabled; the cost when disabled is one attribute
 check in Trainer.step.
@@ -30,43 +31,16 @@ import collections
 
 from paddle_tpu.observability import metrics as _metrics
 
-__all__ = ["PEAK_FLOPS", "peak_flops_for_kind", "detect_peak_flops",
-           "flops_per_token_for", "TrainingTelemetry"]
-
-# bf16 peak FLOP/s per chip by device kind (public TPU specs) — kept in
-# lockstep with bench.py's _PEAK table; tests cross-check the two.
-PEAK_FLOPS = {
-    "TPU v4": 275e12,
-    "TPU v5": 459e12,        # v5p
-    "TPU v5 lite": 197e12,   # v5e
-    "TPU v5e": 197e12,
-    "TPU v6 lite": 918e12,   # v6e / Trillium
-    "TPU v6e": 918e12,
-}
-
-
-def peak_flops_for_kind(kind: str) -> float:
-    """Longest-key-first match (bench.py learned this the hard way:
-    'TPU v5 lite' must win over 'TPU v5'). Unknown kinds assume v5p,
-    the north-star part."""
-    kind = kind or ""
-    for k in sorted(PEAK_FLOPS, key=len, reverse=True):
-        if kind.startswith(k) or k in kind:
-            return PEAK_FLOPS[k]
-    return 459e12
+__all__ = ["detect_peak_flops", "flops_per_token_for",
+           "TrainingTelemetry"]
 
 
 def detect_peak_flops():
-    """Peak FLOP/s of device 0, or None off-TPU (MFU reads 0 there —
-    a CPU-emulation 'MFU' would be noise)."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return None
-        return peak_flops_for_kind(getattr(dev, "device_kind", ""))
-    except Exception:
-        return None
+    """bf16 peak FLOP/s of device 0 from the one peaks table, or None
+    off-TPU (MFU reads 0 there). An unknown TPU kind raises."""
+    from paddle_tpu.device.peaks import detect_peaks
+    peaks = detect_peaks()
+    return None if peaks is None else peaks.bf16_flops
 
 
 def flops_per_token_for(model, seq_len: int) -> float:

@@ -17,6 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.core import jax_compat
 from paddle_tpu.core.dispatch import dispatch, OpDef
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.nn.layer.layers import Layer
@@ -358,7 +359,7 @@ def _weight_only_matmul(xv, qwv, eff_scale):
     traffic); otherwise the XLA fallback (which materializes the bf16
     weight — correct, but no bandwidth win)."""
     K, N = qwv.shape
-    if (jax.default_backend() == "tpu" and eff_scale.ndim == 1
+    if (jax_compat.on_tpu() and eff_scale.ndim == 1
             and xv.dtype in (jnp.bfloat16, jnp.float32)):
         from paddle_tpu.kernels.quant_matmul import (
             pick_block_m, weight_only_int8_matmul)

@@ -17,7 +17,8 @@ from paddle_tpu.models.llama import tiny_llama_config
 
 def test_plan_mesh_ranks_candidates():
     model = LlamaForCausalLM(tiny_llama_config())
-    axes, ranked = plan_mesh(model, 8, {"global_batch_size": 8})
+    axes, ranked = plan_mesh(model, 8, {"global_batch_size": 8,
+                                        "peak_flops": 197e12})
     assert int(np.prod(list(axes.values()))) == 8
     assert len(ranked) > 3
     # for a 200k-param toy model pure model-parallel over 8 must not win
@@ -65,7 +66,8 @@ def test_engine_full_auto_fit_and_cost():
     model = LlamaForCausalLM(cfg)
     o = opt.AdamW(learning_rate=1e-3, parameters=model.parameters())
     eng = Engine(model=model, optimizer=o).prepare(
-        tuner_cfg={"global_batch_size": 8, "pp_degree": [1]})
+        tuner_cfg={"global_batch_size": 8, "pp_degree": [1],
+                   "peak_flops": 197e12})
     rng = np.random.RandomState(0)
     ids = rng.randint(0, cfg.vocab_size, (8, 32)).astype(np.int32)
     data = [{"input_ids": ids, "labels": ids}] * 6
@@ -73,7 +75,7 @@ def test_engine_full_auto_fit_and_cost():
     assert len(losses) == 6 and losses[-1] < losses[0]
     ev = eng.evaluate(data, steps=1)
     assert np.isfinite(ev)
-    c = eng.cost({"global_batch_size": 8})
+    c = eng.cost({"global_batch_size": 8, "peak_flops": 197e12})
     assert c["step_time_s"] > 0 and c["memory_bytes_per_chip"] > 0
 
 

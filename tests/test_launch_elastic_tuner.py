@@ -79,7 +79,8 @@ def test_auto_tuner_candidates_and_prune():
 
 def test_auto_tuner_tune_picks_best():
     cfg = {"num_devices": 8, "global_batch_size": 8, "num_layers": 8,
-           "model_params": 1e8, "hidden_size": 256, "seq_length": 128}
+           "model_params": 1e8, "hidden_size": 256, "seq_length": 128,
+           "peak_flops": 197e12}
     tuner = AutoTuner(cfg)
     assert tuner.candidates, "search space must not be empty"
 
@@ -91,9 +92,19 @@ def test_auto_tuner_tune_picks_best():
     assert best["mp_degree"] == 1 and best["pp_degree"] == 1
 
 
-def test_auto_tuner_max_trials_keeps_queue():
+def test_auto_tuner_needs_a_described_chip():
+    """No default peak: off-TPU the cost model refuses to price a plan
+    for a chip nobody described (it used to assume the v5p)."""
     cfg = {"num_devices": 8, "global_batch_size": 8, "num_layers": 8,
            "model_params": 1e8, "hidden_size": 256, "seq_length": 128}
+    with pytest.raises(ValueError, match="peak_flops"):
+        AutoTuner(cfg)
+
+
+def test_auto_tuner_max_trials_keeps_queue():
+    cfg = {"num_devices": 8, "global_batch_size": 8, "num_layers": 8,
+           "model_params": 1e8, "hidden_size": 256, "seq_length": 128,
+           "peak_flops": 197e12}
     tuner = AutoTuner(cfg)
     n0 = len(tuner.candidates)
     tuner.tune(lambda c: 1.0, max_trials=2)

@@ -233,17 +233,41 @@ def _bench():
     return mod
 
 
-def test_peak_flops_table_matches_bench():
+def test_one_peaks_table_and_unknown_kind_raises(monkeypatch):
+    """bench.py and the telemetry gauge read the ONE table
+    (device/peaks.py), keyed exactly by device_kind, every row with its
+    source; a device that is not in it is an error, never a default."""
+    from paddle_tpu.core import jax_compat
+    from paddle_tpu.device import peaks
     bench = _bench()
-    assert T.PEAK_FLOPS == bench._PEAK
+    assert not hasattr(bench, "_PEAK") and not hasattr(T, "PEAK_FLOPS")
 
     class Dev:
+        platform = "tpu"
+
         def __init__(self, kind):
             self.device_kind = kind
-    for kind in ("TPU v5 lite", "TPU v5p", "TPU v4", "TPU v6e",
-                 "weird device", ""):
-        assert T.peak_flops_for_kind(kind) == bench._peak_flops(
-            Dev(kind)), kind
+
+    v5e = peaks.peaks_for_kind("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bytes_per_s) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    assert all(p.source for p in peaks.PEAKS.values())
+    assert bench._peak_flops(Dev("TPU v5 lite")) == 197e12
+    for kind in ("weird device", "", "TPU v5 lite pod", "TPU v5p"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks.peaks_for_kind(kind)
+        with pytest.raises(ValueError, match="no published peaks"):
+            bench._peak_flops(Dev(kind))
+
+    # the in-program gauge: absent off-TPU, an error on an unknown TPU
+    assert T.detect_peak_flops() is None
+    import jax
+    monkeypatch.setattr(jax_compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev("TPU v5 lite")])
+    assert T.detect_peak_flops() == 197e12
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev("weird device")])
+    with pytest.raises(ValueError, match="no published peaks"):
+        T.detect_peak_flops()
 
 
 def test_mfu_formula_matches_bench():

@@ -1,0 +1,242 @@
+"""Cross-lower the hot path for the TPU platform, on the CPU box.
+
+`jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))` runs the
+Pallas -> Mosaic lowering (block-shape rules, layouts the lowering
+checks, "Mosaic kernels cannot be automatically partitioned") without a
+chip, with the one platform probe (`core/jax_compat.on_tpu`) forced
+true so the auto-dispatch takes the branches it takes on the chip. It
+found in minutes, and without chip budget, that blockwise CE and the
+fused RMSNorm backward did not lower at all and that the mesh-partitioned
+train step refused every Pallas call.
+
+Where libtpu can DESCRIBE a v5e without one being attached
+(`jax.experimental.topologies`), the kernel rows go one step further and
+AOT-compile for it — the real Mosaic compiler, which is what refused
+blockwise CE's dx kernel over the 16 MiB scoped-VMEM default. Where it
+cannot, those rows cross-lower only.
+
+None of this can replace the chip run: nothing executes, so wrong
+numbers, runtime faults and whatever the attached chip's own compiler
+pass differs in stay invisible. `python chip_smoke.py` on the chip is
+the proof; this is the cheap guard in front of it. Shapes are
+chip_smoke.py's: TinyLlama widths, depth cut to one layer for the
+whole-step rows.
+"""
+import sys
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
+
+from paddle_tpu.core import jax_compat  # noqa: E402
+
+FULL = chip_smoke.FULL
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    monkeypatch.setattr(jax_compat, "on_tpu", lambda: True)
+
+
+def _lower_tpu(jitted, *args):
+    return jitted.trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def _calls(lowered):
+    return chip_smoke._custom_calls(lowered.as_text())
+
+
+class _ShapeOnlyRng:
+    """Stands in for numpy's Generator: the kernel cases are lowered
+    from shapes alone, so every draw is zeros of the asked shape."""
+
+    def standard_normal(self, size, dtype=np.float32):
+        return np.zeros(size, dtype)
+
+    def integers(self, lo, hi=None, size=None):
+        return np.full(size, lo, np.int64)
+
+    def uniform(self, lo, hi, size):
+        return np.full(size, lo, np.float64)
+
+    def random(self, size):
+        return np.ones(size, np.float64)
+
+
+_CASES = chip_smoke.kernel_cases(FULL)
+
+
+@pytest.fixture(scope="module")
+def described_v5e():
+    """Sharding on a v5e chip that libtpu describes but nobody attached,
+    or None where libtpu will not (then the kernel rows only lower)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        # libtpu asks the environment what machine it is on
+        mp.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+        mp.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:1x1",
+                chips_per_host_bounds=(1, 1, 1))
+        except Exception as e:      # noqa: BLE001 — no libtpu, no row
+            print(f"libtpu cannot describe a v5e here ({e}); "
+                  f"kernel rows cross-lower only")
+            yield None
+            return
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("name,build", _CASES, ids=[n for n, _ in _CASES])
+def test_kernel_lowers_and_compiles_for_tpu(as_tpu, described_v5e, name,
+                                            build):
+    fn, args, _ = build(FULL, jnp.dtype("bfloat16"), False,
+                        _ShapeOnlyRng())
+    shapes = [None if a is None else jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=described_v5e) for a in args]
+    if described_v5e is None:
+        assert _calls(_lower_tpu(jax.jit(fn), *shapes)) >= 1
+        return
+    lowered = jax.jit(fn).lower(*shapes)
+    assert _calls(lowered) >= 1
+    lowered.compile()       # Mosaic: VMEM limits, layouts
+
+
+def _trainer(mesh=None, **cfg_kw):
+    import paddle_tpu
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.parallel import Trainer, TrainStepConfig
+    from paddle_tpu.parallel.plan import llama_sharding_plan
+    cfg = chip_smoke.llama_config(FULL, 1, use_flash_attention=True,
+                                  **cfg_kw)
+    paddle_tpu.seed(0)
+    model = LlamaForCausalLM(cfg)
+    optimizer = opt.AdamW(learning_rate=1e-4,
+                          parameters=model.parameters())
+    plan = None if mesh is None else llama_sharding_plan(mesh.dim_names)
+    return Trainer(model, optimizer, mesh=mesh, plan=plan,
+                   config=TrainStepConfig(compute_dtype="bfloat16"))
+
+
+def _lower_step(trainer):
+    ids = np.zeros((FULL.batch, FULL.context), np.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    trainer._step_fn = trainer._build_step(None)
+    args = (trainer.params, trainer.opt_state,
+            jnp.asarray(1e-4, jnp.float32), batch)
+    with trainer._mesh_ctx():
+        return _lower_tpu(trainer._step_fn, *args)
+
+
+@pytest.mark.parametrize("cfg_kw,calls", [
+    (dict(loss_chunk=0), 2),                   # flash fwd + fused bwd
+    (dict(loss_chunk=0, recompute=True), 3),   # + the remat forward
+    (dict(loss_chunk=512), 5),                 # + CE fwd, dx, dW
+], ids=["dense_loss", "recompute", "loss_chunk_512"])
+def test_train_step_lowers_for_tpu(as_tpu, cfg_kw, calls):
+    assert _calls(_lower_step(_trainer(**cfg_kw))) == calls
+
+
+def test_mesh_train_step_lowers_per_shard(as_tpu):
+    """Under fsdp 2 x mp 2 every Pallas entry runs inside a shard_map
+    (kernels/sharding.py): XLA refuses to partition a Mosaic call. The
+    flash operand must be the per-shard folded q — half the batch, half
+    the kv heads — not the global array."""
+    from paddle_tpu.distributed.mesh import init_mesh
+    text = _lower_step(_trainer(mesh=init_mesh({"fsdp": 2, "mp": 2}),
+                                loss_chunk=512)).as_text()
+    assert chip_smoke._custom_calls(text) == 5
+    rep = FULL.heads // FULL.kv_heads
+    assert (f"tensor<{FULL.batch // 2}x{FULL.kv_heads // 2}"
+            f"x{rep * FULL.context}x{FULL.head_dim}xbf16>") in text
+    # blockwise CE: the 8,192 rows split four ways
+    assert f"tensor<{FULL.batch * FULL.context // 4}x{FULL.hidden}" \
+        f"xbf16>" in text
+
+
+@pytest.mark.parametrize("kv_dtype,page", [(None, 16), ("int8", 32)])
+def test_engine_programs_lower_for_tpu(as_tpu, kv_dtype, page):
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.models import LlamaForCausalLM
+    layers = 2
+    paddle_tpu.seed(0)
+    model = LlamaForCausalLM(chip_smoke.llama_config(FULL, layers))
+    model = paddle_tpu.amp.decorate(models=model, level="O2",
+                                    dtype="bfloat16")
+    b, mp = FULL.slots, 40
+    eng = PagedKVEngine(model, max_slots=b, page_size=page,
+                        num_pages=b * mp + 1, max_pages_per_slot=mp,
+                        kernel=None, kv_dtype=kv_dtype)
+    assert eng.decode_kernel == "pallas"
+    pools = [a for kv in eng.pools for a in kv]
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+
+    tick = eng._tick_fn(False)
+    lowered = _lower_tpu(tick.func, *tick.args, z(b), z(b), z(b, dt=bool),
+                         z(b), z(b, mp), z(b), key, pools)
+    assert _calls(lowered) == layers      # one decode kernel a layer
+    # the weights are the program's argument, never HLO literals
+    assert len(lowered.as_text()) < 5e6
+
+    prefill = eng._prefill_fn(512, b)
+    lowered = _lower_tpu(prefill.func, *prefill.args, z(b, 512), z(b),
+                         z(b), z(b, mp), pools)
+    # prefill attends through the jnp gather path: no flash kernel yet
+    # (ROADMAP open item) — pin it so the day it changes is noticed
+    assert _calls(lowered) == 0
+
+
+@pytest.mark.parametrize("entry", ["ce", "norm", "rope"])
+def test_sharded_entries_match_unsharded(entry):
+    """The per-shard wrappers (kernels/sharding.py) against the same
+    entry off the mesh, Pallas in interpret mode on the 8-CPU-device
+    mesh: values and gradients agree."""
+    from jax.sharding import Mesh
+    from paddle_tpu.kernels.blockwise_ce import blockwise_ce_loss
+    from paddle_tpu.kernels.fused_norm import (rms_norm_residual,
+                                               rope_apply)
+    rng = np.random.default_rng(0)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("fsdp", "mp"))
+    n, d, v = 64, 32, 96
+    x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    if entry == "ce":
+        w = jnp.asarray(rng.standard_normal((d, v)) * 0.1, jnp.float32)
+        labels = rng.integers(0, v, n)
+        labels[:5] = -100
+        fn = lambda x_, w_: blockwise_ce_loss(          # noqa: E731
+            x_, w_, jnp.asarray(labels), chunk=8, kernel="pallas")
+        args = (x, w)
+    elif entry == "norm":
+        w = jnp.asarray(rng.standard_normal(d), jnp.float32)
+        r = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+        fn = lambda x_, w_, r_: sum(jnp.sum(o * o) for o in  # noqa: E731
+                                    rms_norm_residual(x_, w_, r_,
+                                                      kernel="pallas"))
+        args = (x, w, r)
+    else:
+        x4 = x.reshape(4, 4, 4, 32)
+        fn = lambda x_: jnp.sum(jnp.sin(rope_apply(     # noqa: E731
+            x_, kernel="pallas")))
+        args = (x4,)
+    grad = jax.jit(jax.value_and_grad(fn, argnums=tuple(
+        range(len(args)))))
+    want = grad(*args)
+    with mesh:
+        lowered = grad.lower(*args)
+        got = lowered.compile()(*args)
+    assert "shard_map" in lowered.as_text() \
+        or "sdy.manual_computation" in lowered.as_text()
+    for g, w_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
+                                   rtol=1e-5, atol=1e-5)

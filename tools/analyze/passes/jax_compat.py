@@ -1,9 +1,10 @@
 """Pass `jax-compat` — version-fragile jax spellings (line-based).
 
-Port of tools/check_jax_compat.py: `from jax import shard_map` /
-`jax.shard_map(...)` / `jax.lax.axis_size(...)` only exist on jax>=0.6
-and broke collection on 0.4.37; the sanctioned spellings live in
-paddle_tpu/core/jax_compat.py. Line-based (works on files the AST
+Port of tools/check_jax_compat.py: `shard_map`, `axis_size` and the
+Pallas TPU compiler params have each moved between jax releases, so
+paddle_tpu imports them from ONE site, paddle_tpu/core/jax_compat.py
+(plain aliases for the installed jax), and this pass keeps bare
+spellings from creeping back. Line-based (works on files the AST
 passes skip), with the comment/string stripper that keeps a stray
 triple-quote in a COMMENT from hiding the rest of the file.
 
@@ -18,8 +19,8 @@ import re
 from tools.analyze.core import Finding, build_index
 
 PASS_ID = "jax-compat"
-DESCRIPTION = ("version-fragile jax imports (shard_map/axis_size) that "
-               "break on jax 0.4.x — use paddle_tpu.core.jax_compat")
+DESCRIPTION = ("version-fragile jax imports (shard_map/axis_size) — "
+               "import them from paddle_tpu.core.jax_compat")
 
 # (pattern, why). Docstrings/comments are excluded by the stripper;
 # prose mentions inside docstrings are tolerated (they can't break an
@@ -27,16 +28,16 @@ DESCRIPTION = ("version-fragile jax imports (shard_map/axis_size) that "
 FRAGILE = [
     (re.compile(r"^\s*from\s+jax\s+import\s+(?:\([^)]*\bshard_map\b"
                 r"|.*\bshard_map\b)"),
-     "`from jax import shard_map` needs jax>=0.6; import it from "
-     "paddle_tpu.core.jax_compat instead"),
+     "`from jax import shard_map` has moved between releases; import "
+     "it from paddle_tpu.core.jax_compat instead"),
     (re.compile(r"\bjax\.shard_map\s*\("),
-     "`jax.shard_map(...)` needs jax>=0.6; use "
+     "`jax.shard_map(...)` has moved between releases; use "
      "paddle_tpu.core.jax_compat.shard_map"),
     (re.compile(r"^\s*from\s+jax\.experimental\.shard_map\s+import"),
-     "import shard_map via paddle_tpu.core.jax_compat (handles the "
-     "check_rep->check_vma rename), not jax.experimental directly"),
+     "import shard_map via paddle_tpu.core.jax_compat, not "
+     "jax.experimental directly"),
     (re.compile(r"\bjax\.lax\.axis_size\s*\("),
-     "`jax.lax.axis_size` does not exist on jax 0.4.x; use "
+     "`jax.lax.axis_size` has moved between releases; use "
      "paddle_tpu.core.jax_compat.axis_size"),
 ]
 
