@@ -105,6 +105,12 @@ __all__ = ["PagedState", "paged_attention_update", "decode_kernel_scope",
            "PagedKVEngine"]
 
 
+# the phases of a scheduler tick, in order: each is the span
+# `engine.tick.<phase>` and one column of `PagedKVEngine.tick_log`
+TICK_PHASES = ("retire", "admit", "alloc", "upload", "launch", "readback",
+               "accept")
+
+
 class PagedState(NamedTuple):
     """Per-call paged-cache coordinates, threaded through model forward
     as `cache_index` (a NamedTuple is a jax pytree, so it traces).
@@ -299,22 +305,28 @@ def paged_attention_update(q, k, v, cache, state: PagedState):
             "scales (see PagedKVEngine(kv_dtype='int8'))")
     b, s, hq, d = q.shape
 
-    kp, vp, k_scale, v_scale = _scatter_kv(kp, vp, k, v, state,
-                                           k_scale, v_scale)
+    # the scopes are metadata of the compiled ops: a device trace can
+    # tell the pool writes (and the copies XLA makes for them) from the
+    # attention proper
+    with jax.named_scope("kv_write"):
+        kp, vp, k_scale, v_scale = _scatter_kv(kp, vp, k, v, state,
+                                               k_scale, v_scale)
 
     kind, interpret = getattr(_decode_cfg, "cfg", None) or ("jnp", False)
-    if kind == "pallas" and s == 1:
-        from paddle_tpu.kernels.paged_attention import \
-            paged_decode_attention
-        # the query position is lens (this token's k/v just landed
-        # there); the kernel masks cols <= lens and skips pages past it
-        out = paged_decode_attention(
-            q[:, 0], kp, vp, _val(state.block_tables),
-            _val(state.lens), k_scale=k_scale, v_scale=v_scale,
-            interpret=interpret)
-        out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
-    else:
-        out = _attend_pages(q, kp, vp, state, k_scale, v_scale)
+    with jax.named_scope("paged_attn"):
+        if kind == "pallas" and s == 1:
+            from paddle_tpu.kernels.paged_attention import \
+                paged_decode_attention
+            # the query position is lens (this token's k/v just landed
+            # there); the kernel masks cols <= lens and skips pages
+            # past it
+            out = paged_decode_attention(
+                q[:, 0], kp, vp, _val(state.block_tables),
+                _val(state.lens), k_scale=k_scale, v_scale=v_scale,
+                interpret=interpret)
+            out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
+        else:
+            out = _attend_pages(q, kp, vp, state, k_scale, v_scale)
     if quantized:
         return Tensor(out), (Tensor(kp), Tensor(vp),
                              Tensor(k_scale), Tensor(v_scale))
@@ -735,9 +747,19 @@ class PagedKVEngine:
                       "admitted": 0, "finished": 0, "cancelled": 0,
                       "expired": 0, "overloaded": 0,
                       "prefill_s": 0.0, "tick_s": 0.0,
+                      # always on, by the tick's own clock (_note_tick):
+                      # tick_wall_s = tick_host_s + readback_s + the
+                      # prefill_s spent inside ticks
+                      "tick_wall_s": 0.0, "tick_host_s": 0.0,
+                      "readback_s": 0.0, "tick_max_s": 0.0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefix_hit_tokens": 0, "prefix_pages_shared": 0,
                       "prefix_evictions": 0}
+        # one row a tick, the newest 256: (seq, start by perf_counter,
+        # the TICK_PHASES' seconds in order, live slots, prefills
+        # admitted) — what says which phase a slow tick spent it in
+        # when no profiler was running
+        self.tick_log: collections.deque = collections.deque(maxlen=256)
         # serving integration: PredictorServer must not serialize
         # concurrent streams through its executable lock — the engine's
         # ticker thread is the only chip user
@@ -776,6 +798,8 @@ class PagedKVEngine:
         registry.set_gauge("engine.cancelled", s["cancelled"])
         registry.set_gauge("engine.expired", s["expired"])
         registry.set_gauge("engine.overloaded", s["overloaded"])
+        registry.set_gauge("engine.tick_max_seconds", s["tick_max_s"])
+        registry.set_gauge("engine.tick_host_seconds", s["tick_host_s"])
         # _pending is swapped by the ticker under _lock; an unguarded
         # len() here races the swap (found by the guarded-field
         # analyzer pass — the same shape as the PR 12 quota bypass)
@@ -1112,6 +1136,21 @@ class PagedKVEngine:
             ts["shed"] += 1
         if observability.ENABLED:
             observability.inc("tenant.shed", tenant=tkey, reason=reason)
+
+    def cancel_all(self):
+        """Cancel every request the engine holds, queued or in a slot,
+        and return how many. The scheduler retires each at its next
+        tick, so every stream ends in order (its queue gets the closing
+        None) and no socket is reset. Safe from any thread. A request
+        the scheduler is admitting at this very moment is in neither
+        place: a caller that must end them all calls again until
+        `has_work()` is false."""
+        with self._lock:
+            held = list(self._pending)
+        held += [s.req for s in list(self._slots) if s is not None]
+        for req in held:
+            req.cancel()
+        return len(held)
 
     def has_work(self):
         # _inflight counts submit -> retire/drop, so the transient
@@ -1754,47 +1793,48 @@ class PagedKVEngine:
         and a storm of long prompts pays ceil(max_len/chunk) program
         calls total instead of one full chunk loop per request.
         Exhausted rows ride later rounds with n_valid=0 (writes drop)."""
-        import time as _time
-        t0 = _time.perf_counter()
-        for _idx, req in grp:
-            if req.obs is not None:
-                req.obs.record("prefill_start", rid=req.rid)
         chunk = self.prefill_chunk
         bw = 1 if len(grp) == 1 else self.max_slots
-        fn = self._prefill_chunk_fn(chunk, bw)
-        done = np.zeros(bw, np.int32)              # consumed per row
-        for r, (idx, _req) in enumerate(grp):
-            # warm rows (prefix-cache hit) start past the shared pages
-            done[r] = self._slots[idx].shared * self.page_size
-        plens = [int(req.prompt.size) for _, req in grp]
-        final_logits = [None] * len(grp)
-        while any(done[r] < plens[r] for r in range(len(grp))):
-            ids = np.zeros((bw, chunk), np.int32)
-            lens = np.zeros(bw, np.int32)
-            nv = np.zeros(bw, np.int32)
-            bt = np.zeros((bw, self.max_pages_per_slot), np.int32)
-            for r, (idx, req) in enumerate(grp):
-                take = min(chunk, plens[r] - int(done[r]))
-                if take <= 0:
-                    continue
-                ids[r, :take] = req.prompt[done[r]:done[r] + take]
-                lens[r] = done[r]
-                nv[r] = take
-                bt[r] = self._bt[idx]
-            last, flat = fn(jnp.asarray(ids), jnp.asarray(lens),
-                            jnp.asarray(nv), jnp.asarray(bt),
-                            [a for kv in self.pools for a in kv])
-            self.pools = self._unflat_pools(flat)
-            last_np = np.asarray(last)
-            for r in range(len(grp)):
-                if nv[r] > 0 and done[r] + nv[r] >= plens[r]:
-                    final_logits[r] = last_np[r]
-                done[r] += nv[r]
-        self.stats["prefills"] += len(grp)
-        self.stats["prefill_s"] += _time.perf_counter() - t0
-        for _idx, req in grp:
-            if req.obs is not None:
-                req.obs.record("prefill_end", rid=req.rid)
+        with observability.span("engine.prefill", bucket=chunk,
+                                rows=len(grp), group=bw):
+            t0 = time.perf_counter()
+            for _idx, req in grp:
+                if req.obs is not None:
+                    req.obs.record("prefill_start", rid=req.rid)
+            fn = self._prefill_chunk_fn(chunk, bw)
+            done = np.zeros(bw, np.int32)              # consumed per row
+            for r, (idx, _req) in enumerate(grp):
+                # warm rows (prefix-cache hit) start past the shared pages
+                done[r] = self._slots[idx].shared * self.page_size
+            plens = [int(req.prompt.size) for _, req in grp]
+            final_logits = [None] * len(grp)
+            while any(done[r] < plens[r] for r in range(len(grp))):
+                ids = np.zeros((bw, chunk), np.int32)
+                lens = np.zeros(bw, np.int32)
+                nv = np.zeros(bw, np.int32)
+                bt = np.zeros((bw, self.max_pages_per_slot), np.int32)
+                for r, (idx, req) in enumerate(grp):
+                    take = min(chunk, plens[r] - int(done[r]))
+                    if take <= 0:
+                        continue
+                    ids[r, :take] = req.prompt[done[r]:done[r] + take]
+                    lens[r] = done[r]
+                    nv[r] = take
+                    bt[r] = self._bt[idx]
+                last, flat = fn(jnp.asarray(ids), jnp.asarray(lens),
+                                jnp.asarray(nv), jnp.asarray(bt),
+                                [a for kv in self.pools for a in kv])
+                self.pools = self._unflat_pools(flat)
+                last_np = np.asarray(last)
+                for r in range(len(grp)):
+                    if nv[r] > 0 and done[r] + nv[r] >= plens[r]:
+                        final_logits[r] = last_np[r]
+                    done[r] += nv[r]
+            self.stats["prefills"] += len(grp)
+            self.stats["prefill_s"] += time.perf_counter() - t0
+            for _idx, req in grp:
+                if req.obs is not None:
+                    req.obs.record("prefill_end", rid=req.rid)
         for r, (idx, req) in enumerate(grp):
             slot = self._slots[idx]
             slot.lens = plens[r]
@@ -1833,47 +1873,48 @@ class PagedKVEngine:
         writes drop) for admission storms — so the compile count stays
         at two per bucket while a storm pays one prefill latency
         total."""
-        import time as _time
-        t0 = _time.perf_counter()
-        for _idx, req in grp:
-            if req.obs is not None:
-                req.obs.record("prefill_start", rid=req.rid)
         bw = 1 if len(grp) == 1 else self.max_slots
-        fn = self._prefill_fn(ppad, bw)
-        ids = np.zeros((bw, ppad), np.int32)
-        lens = np.zeros(bw, np.int32)
-        nv = np.zeros(bw, np.int32)
-        bt = np.zeros((bw, self.max_pages_per_slot), np.int32)
-        for row, (idx, req) in enumerate(grp):
-            # warm slots (prefix-cache hit) prefill ONLY the uncached
-            # tail: lens starts past the shared pages, and the tail
-            # attends over their KV through the block table
-            off = self._slots[idx].shared * self.page_size
-            tail = req.prompt[off:]
-            ids[row, :tail.size] = tail
-            lens[row] = off
-            nv[row] = tail.size
-            bt[row] = self._bt[idx]
-        last_logits, flat = fn(
-            jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(nv),
-            jnp.asarray(bt), [a for kv in self.pools for a in kv])
-        self.pools = self._unflat_pools(flat)
-        if self.draft_model is not None:
-            # the draft's pools share the same block tables, so shared
-            # pages already hold the PREFIX's draft KV too (same
-            # tokens, written when the entry was cached) — the draft
-            # prefill also runs only the tail
-            dfn = self._draft_prefill_fn(ppad, bw)
-            dflat = dfn(jnp.asarray(ids), jnp.asarray(lens),
-                        jnp.asarray(nv), jnp.asarray(bt),
-                        [a for kv in self.draft_pools for a in kv])
-            self.draft_pools = self._unflat_pools(dflat)
-        logits_np = np.asarray(last_logits)              # (bw, vocab)
-        self.stats["prefills"] += len(grp)
-        self.stats["prefill_s"] += _time.perf_counter() - t0
-        for _idx, req in grp:
-            if req.obs is not None:
-                req.obs.record("prefill_end", rid=req.rid)
+        with observability.span("engine.prefill", bucket=ppad,
+                                rows=len(grp), group=bw):
+            t0 = time.perf_counter()
+            for _idx, req in grp:
+                if req.obs is not None:
+                    req.obs.record("prefill_start", rid=req.rid)
+            fn = self._prefill_fn(ppad, bw)
+            ids = np.zeros((bw, ppad), np.int32)
+            lens = np.zeros(bw, np.int32)
+            nv = np.zeros(bw, np.int32)
+            bt = np.zeros((bw, self.max_pages_per_slot), np.int32)
+            for row, (idx, req) in enumerate(grp):
+                # warm slots (prefix-cache hit) prefill ONLY the uncached
+                # tail: lens starts past the shared pages, and the tail
+                # attends over their KV through the block table
+                off = self._slots[idx].shared * self.page_size
+                tail = req.prompt[off:]
+                ids[row, :tail.size] = tail
+                lens[row] = off
+                nv[row] = tail.size
+                bt[row] = self._bt[idx]
+            last_logits, flat = fn(
+                jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(nv),
+                jnp.asarray(bt), [a for kv in self.pools for a in kv])
+            self.pools = self._unflat_pools(flat)
+            if self.draft_model is not None:
+                # the draft's pools share the same block tables, so shared
+                # pages already hold the PREFIX's draft KV too (same
+                # tokens, written when the entry was cached) — the draft
+                # prefill also runs only the tail
+                dfn = self._draft_prefill_fn(ppad, bw)
+                dflat = dfn(jnp.asarray(ids), jnp.asarray(lens),
+                            jnp.asarray(nv), jnp.asarray(bt),
+                            [a for kv in self.draft_pools for a in kv])
+                self.draft_pools = self._unflat_pools(dflat)
+            logits_np = np.asarray(last_logits)              # (bw, vocab)
+            self.stats["prefills"] += len(grp)
+            self.stats["prefill_s"] += time.perf_counter() - t0
+            for _idx, req in grp:
+                if req.obs is not None:
+                    req.obs.record("prefill_end", rid=req.rid)
         for row, (idx, req) in enumerate(grp):
             slot = self._slots[idx]
             slot.lens = int(req.prompt.size)
@@ -1995,105 +2036,167 @@ class PagedKVEngine:
             self._in_step = False
 
     def _step_tick(self):
+        """One tick under its spans (observability/trace.py SPANS) and
+        its own clock: `engine.tick` holds the TICK_PHASES in order,
+        `marks` the perf_counter reading at each boundary. The
+        counters and `tick_log` are always on (`_note_tick`); the
+        spans show in any profiler capture."""
         from paddle_tpu.distributed import chaos
         if chaos.ENABLED:
             # a slow scheduler tick (congested chip, straggler host):
             # stretches TTFT and ITL — the request-tracing tests' lever
             chaos.maybe_delay("engine.tick.delay")
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot.req.cancelled.is_set():
-                self.stats["cancelled"] += 1
-                self._retire(i)
-        if self.suspend_after_s is not None:
-            self._suspend_sweep()
-        self._admit()
-        live = [i for i, s in enumerate(self._slots) if s is not None]
-        if not live:
-            return False
-        if self.tenancy is not None:
-            self._note_slot_ticks(live)
-        if self.draft_model is not None:
-            return self._step_spec(live)
-        n = self.steps_per_tick
-        for i in live:
-            slot = self._slots[i]
-            budget_tokens = slot.req.prompt.size + slot.req.max_new_tokens
-            need = min(slot.lens + n, budget_tokens)
-            self._alloc_pages(i, -(-need // self.page_size))
-        a = self._slot_arrays(live)
-        tok, lens, active = a["tok"], a["lens"], a["active"]
-        limit, eos = a["limit"], a["eos"]
-        temp, topk, topp, wants = (a["temp"], a["topk"], a["topp"],
-                                   a["wants"])
-        import time as _time
-        t0 = _time.perf_counter()
-        any_sample = bool(wants.any())
-        fn = self._tick_fn(any_sample)
-        key = jax.random.fold_in(self._key, self._tick_count)
-        args = [jnp.asarray(tok), jnp.asarray(lens), jnp.asarray(active),
-                jnp.asarray(limit), jnp.asarray(self._bt),
-                jnp.asarray(eos),
-                jax.random.key_data(key)]
-        if any_sample:
-            args += [jnp.asarray(temp), jnp.asarray(topk),
-                     jnp.asarray(topp), jnp.asarray(wants)]
-        toks_out, lens_f, flat = fn(*args,
-                                    [a for kv in self.pools for a in kv])
-        self.pools = self._unflat_pools(flat)
-        toks_np = np.asarray(toks_out)          # (b, n)
-        lens_np = np.asarray(lens_f)
+        if not any(self._slots):
+            with self._lock:
+                idle = not self._pending and not self._import_staged
+            if idle:
+                # an idle poll is no tick: no span, no tick_log row
+                if self.suspend_after_s is not None:
+                    self._suspend_sweep()
+                return False
+        clock = time.perf_counter
+        pre_s, pre_n = self.stats["prefill_s"], self.stats["prefills"]
+        with observability.span("engine.tick", seq=self._step_seq):
+            marks = [clock()]
+            with observability.span("engine.tick.retire"):
+                for i, slot in enumerate(self._slots):
+                    if slot is not None and slot.req.cancelled.is_set():
+                        self.stats["cancelled"] += 1
+                        self._retire(i)
+                if self.suspend_after_s is not None:
+                    self._suspend_sweep()
+            marks.append(clock())
+            with observability.span("engine.tick.admit"):
+                self._admit()
+            marks.append(clock())
+            live = [i for i, s in enumerate(self._slots) if s is not None]
+            if live and self.tenancy is not None:
+                self._note_slot_ticks(live)
+            if live and self.draft_model is not None:
+                self._step_spec(live, marks)
+            elif live:
+                # the decode tick, phase by phase. The program is called
+                # from this frame, as it always was: with a helper method
+                # and a closure between here and the jitted call the
+                # program's first call (trace and lowering) took 13 s
+                # against 6 s on the v5e host, a quarter more set-up for
+                # a server (measured, cause not found: PERF.md, PR 25)
+                n = self.steps_per_tick
+                with observability.span("engine.tick.alloc"):
+                    for i in live:
+                        slot = self._slots[i]
+                        budget_tokens = (slot.req.prompt.size
+                                         + slot.req.max_new_tokens)
+                        need = min(slot.lens + n, budget_tokens)
+                        self._alloc_pages(i, -(-need // self.page_size))
+                    a = self._slot_arrays(live)
+                marks.append(clock())
+                with observability.span("engine.tick.upload"):
+                    any_sample = bool(a["wants"].any())
+                    fn = self._tick_fn(any_sample)
+                    key = jax.random.fold_in(self._key, self._tick_count)
+                    args = [jnp.asarray(a["tok"]), jnp.asarray(a["lens"]),
+                            jnp.asarray(a["active"]),
+                            jnp.asarray(a["limit"]), jnp.asarray(self._bt),
+                            jnp.asarray(a["eos"]),
+                            jax.random.key_data(key)]
+                    if any_sample:
+                        args += [jnp.asarray(a["temp"]),
+                                 jnp.asarray(a["topk"]),
+                                 jnp.asarray(a["topp"]),
+                                 jnp.asarray(a["wants"])]
+                marks.append(clock())
+                with observability.span("engine.tick.launch"):
+                    toks_out, lens_f, flat = fn(
+                        *args, [x for kv in self.pools for x in kv])
+                    self.pools = self._unflat_pools(flat)
+                marks.append(clock())
+                with observability.span("engine.tick.readback"):
+                    toks_np = np.asarray(toks_out)          # (b, n)
+                    lens_np = np.asarray(lens_f)
+                marks.append(clock())
+                self._ticked(marks)
+                counts = np.minimum(a["limit"], n)
+                with observability.span("engine.tick.accept"):
+                    self._accept_tick(live, toks_np, counts, a["eos"],
+                                      lens_np)
+                marks.append(clock())
+        self._note_tick(marks, len(live), pre_s, pre_n)
+        return bool(live)
+
+    def _ticked(self, marks):
+        """Count one decode tick whose read back has just ended."""
         self._tick_count += 1
         self.stats["ticks"] += 1
-        self.stats["tick_s"] += _time.perf_counter() - t0
+        self.stats["tick_s"] += marks[-1] - marks[3]    # upload .. readback
         if observability.ENABLED:
             observability.inc("inference.decode.kernel",
                               path=self.decode_kernel)
-        counts = np.minimum(limit, n)
-        self._accept_tick(live, toks_np, counts, eos, lens_np)
-        return True
 
-    def _step_spec(self, live):
+    def _step_spec(self, live, marks):
         """Speculative tick: greedy AND sampled slots ride it together
-        (per-slot regimes in-graph; _spec_tick_fn doc)."""
-        import time as _time
+        (per-slot regimes in-graph; _spec_tick_fn doc). The same phases
+        as the plain tick's, their clock marks appended to `marks`."""
+        clock = time.perf_counter
         g = self.spec_tokens
-        for i in live:
-            slot = self._slots[i]
-            budget = slot.req.prompt.size + slot.req.max_new_tokens
-            need = min(slot.lens + g + 1, budget)
-            self._alloc_pages(i, -(-need // self.page_size))
-        a = self._slot_arrays(live)
-        t0 = _time.perf_counter()
-        fn = self._spec_tick_fn(bool(a["wants"].any()))
-        key = jax.random.fold_in(self._key, self._tick_count)
-        out, n_emit, lens_f, tflat, dflat = fn(
-            jnp.asarray(a["tok"]), jnp.asarray(a["lens"]),
-            jnp.asarray(a["active"]), jnp.asarray(self._bt),
-            jax.random.key_data(key), jnp.asarray(a["temp"]),
-            jnp.asarray(a["topk"]), jnp.asarray(a["topp"]),
-            jnp.asarray(a["wants"]),
-            [x for kv in self.pools for x in kv],
-            [x for kv in self.draft_pools for x in kv])
-        self.pools = self._unflat_pools(tflat)
-        self.draft_pools = self._unflat_pools(dflat)
-        out_np = np.asarray(out)
-        emit_np = np.asarray(n_emit)
-        lens_np = np.asarray(lens_f)
-        self._tick_count += 1
-        self.stats["ticks"] += 1
+        with observability.span("engine.tick.alloc"):
+            for i in live:
+                slot = self._slots[i]
+                budget = slot.req.prompt.size + slot.req.max_new_tokens
+                need = min(slot.lens + g + 1, budget)
+                self._alloc_pages(i, -(-need // self.page_size))
+            a = self._slot_arrays(live)
+        marks.append(clock())
+        with observability.span("engine.tick.upload"):
+            fn = self._spec_tick_fn(bool(a["wants"].any()))
+            key = jax.random.fold_in(self._key, self._tick_count)
+            args = [jnp.asarray(a["tok"]), jnp.asarray(a["lens"]),
+                    jnp.asarray(a["active"]), jnp.asarray(self._bt),
+                    jax.random.key_data(key), jnp.asarray(a["temp"]),
+                    jnp.asarray(a["topk"]), jnp.asarray(a["topp"]),
+                    jnp.asarray(a["wants"])]
+        marks.append(clock())
+        with observability.span("engine.tick.launch"):
+            out, n_emit, lens_f, tflat, dflat = fn(
+                *args, [x for kv in self.pools for x in kv],
+                [x for kv in self.draft_pools for x in kv])
+            self.pools = self._unflat_pools(tflat)
+            self.draft_pools = self._unflat_pools(dflat)
+        marks.append(clock())
+        with observability.span("engine.tick.readback"):
+            out_np = np.asarray(out)
+            emit_np = np.asarray(n_emit)
+            lens_np = np.asarray(lens_f)
+        marks.append(clock())
+        self._ticked(marks)
         self.stats["spec_ticks"] = self.stats.get("spec_ticks", 0) + 1
         self.stats["spec_proposed"] = (self.stats.get("spec_proposed", 0)
                                        + g * len(live))
         self.stats["spec_accepted"] = (
             self.stats.get("spec_accepted", 0)
             + int(sum(emit_np[i] - 1 for i in live)))
-        self.stats["tick_s"] += _time.perf_counter() - t0
-        if observability.ENABLED:
-            observability.inc("inference.decode.kernel",
-                              path=self.decode_kernel)
         counts = np.minimum(emit_np, a["limit"])
-        self._accept_tick(live, out_np, counts, a["eos"], lens_np)
-        return True
+        with observability.span("engine.tick.accept"):
+            self._accept_tick(live, out_np, counts, a["eos"], lens_np)
+        marks.append(clock())
+
+    def _note_tick(self, marks, live, pre_s, pre_n):
+        """The always-on record of one tick from its clock marks: the
+        counters and one `tick_log` row. A tick that found no live
+        slot after admission has the first two phases only; the rest
+        read 0."""
+        phases = [b - a for a, b in zip(marks, marks[1:])]
+        phases += [0.0] * (len(TICK_PHASES) - len(phases))
+        s = self.stats
+        wall, readback = marks[-1] - marks[0], phases[5]
+        s["tick_wall_s"] += wall
+        s["readback_s"] += readback
+        # what the host spent while the device could not be working
+        # for this engine: the wall less the waits on the device
+        s["tick_host_s"] += wall - readback - (s["prefill_s"] - pre_s)
+        s["tick_max_s"] = max(s["tick_max_s"], wall)
+        self.tick_log.append((self._step_seq, marks[0], *phases, live,
+                              s["prefills"] - pre_n))
 
     def run_until_idle(self):
         """Synchronously drain all pending + active requests (tests,
@@ -2161,7 +2264,8 @@ class PagedKVEngine:
                     idle = 0.0
                 else:
                     idle = min(0.05, idle + 0.005)
-                    time.sleep(idle)
+                    with observability.span("engine.idle"):
+                        time.sleep(idle)
             except Exception as e:      # noqa: BLE001 — fail all waiters
                 with self._lock:
                     doomed = self._pending
@@ -2548,20 +2652,21 @@ class PagedKVEngine:
                     position_ids=Tensor(lens[:, None]),
                     cache_index=state)
                 last = _val(logits)[:, -1]
-                greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                if any_sample:
-                    sk = jax.random.fold_in(
-                        jax.random.wrap_key_data(key_data), step_i)
-                    noise = jax.random.gumbel(sk, last.shape,
-                                              jnp.float32)
-                    proc = _process_logits_rowwise(last, temp, topk,
-                                                   topp)
-                    sampled = jnp.argmax(proc + noise,
-                                         axis=-1).astype(jnp.int32)
-                    nxt = jnp.where(wants, sampled, greedy)
-                else:
-                    nxt = greedy
-                nxt = jnp.where(live, nxt, 0)
+                with jax.named_scope("sample"):
+                    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                    if any_sample:
+                        sk = jax.random.fold_in(
+                            jax.random.wrap_key_data(key_data), step_i)
+                        noise = jax.random.gumbel(sk, last.shape,
+                                                  jnp.float32)
+                        proc = _process_logits_rowwise(last, temp, topk,
+                                                       topp)
+                        sampled = jnp.argmax(proc + noise,
+                                             axis=-1).astype(jnp.int32)
+                        nxt = jnp.where(wants, sampled, greedy)
+                    else:
+                        nxt = greedy
+                    nxt = jnp.where(live, nxt, 0)
                 new_lens = lens + live.astype(jnp.int32)
                 new_cnt = cnt + live.astype(jnp.int32)
                 hit_eos = live & (eos >= 0) & (nxt == eos)

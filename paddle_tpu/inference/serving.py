@@ -632,11 +632,18 @@ class PredictorServer:
                 self.send_header("Transfer-Encoding", "chunked")
                 self.end_headers()
 
+                ctx = getattr(self, "_obs_ctx", None)
+                attrs = {} if ctx is None else {"rid": ctx.request_id}
+
                 def chunk(obj):
-                    data = (json.dumps(obj) + "\n").encode()
-                    self.wfile.write(b"%x\r\n" % len(data) + data
-                                     + b"\r\n")
-                    self.wfile.flush()
+                    # a handler thread writing while the ticker waits
+                    # for the interpreter lock shows in a capture as
+                    # this span over the ticker's gap
+                    with observability.span("http.write", **attrs):
+                        data = (json.dumps(obj) + "\n").encode()
+                        self.wfile.write(b"%x\r\n" % len(data) + data
+                                         + b"\r\n")
+                        self.wfile.flush()
                 exc = None
                 try:
                     try:

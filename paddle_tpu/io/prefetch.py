@@ -29,6 +29,11 @@ Lifecycle contract:
   - the queue is bounded (`depth`): a stalled consumer backpressures
     the worker instead of buffering the epoch onto the device.
 
+Always on: the plain attribute `wait_s` (seconds the consumer's
+`next()` blocked on the queue, beside `batches_prefetched`) and the
+spans `input.wait` (consumer) / `input.h2d` (worker), which a profiler
+capture holds (observability/trace.py SPANS).
+
 Failure injection + observability (both zero-cost when disabled):
   - chaos site `io.prefetch.delay` — a slow host input pipeline;
   - `io.prefetch.queue_depth` gauge, `io.h2d.seconds` histogram
@@ -79,6 +84,7 @@ class DevicePrefetcher:
         self._stop = threading.Event()
         self._finished = False
         self.batches_prefetched = 0
+        self.wait_s = 0.0       # the consumer's time blocked in next()
         # the thread holds only a WEAKREF to self (plus the stop event
         # and the queue, which carry no back-reference): a prefetcher
         # abandoned without close() stays collectable, __del__ runs
@@ -139,16 +145,17 @@ class DevicePrefetcher:
         if chaos.ENABLED:
             chaos.maybe_delay("io.prefetch.delay")
         acc: list = []
-        if observability.ENABLED:
-            t0 = time.perf_counter()
-            placed = self._place(batch, acc)
-            for a in acc:             # measure true H2D, not dispatch
-                jax.block_until_ready(a)
-            observability.observe("io.h2d.seconds",
-                                  time.perf_counter() - t0)
-            observability.inc("io.prefetch.batches")
-        else:
-            placed = self._place(batch, acc)
+        with observability.span("input.h2d"):
+            if observability.ENABLED:
+                t0 = time.perf_counter()
+                placed = self._place(batch, acc)
+                for a in acc:         # measure true H2D, not dispatch
+                    jax.block_until_ready(a)
+                observability.observe("io.h2d.seconds",
+                                      time.perf_counter() - t0)
+                observability.inc("io.prefetch.batches")
+            else:
+                placed = self._place(batch, acc)
         self.batches_prefetched += 1
         return _ITEM, placed
 
@@ -159,14 +166,20 @@ class DevicePrefetcher:
     def __next__(self):
         if self._finished:
             raise StopIteration
-        while True:
-            try:
-                tag, payload = self._q.get(timeout=0.1)
-                break
-            except _queue.Empty:
-                if self._stop.is_set() and not self._thread.is_alive():
-                    self._finished = True
-                    raise StopIteration from None
+        t0 = time.perf_counter()
+        try:
+            with observability.span("input.wait"):
+                while True:
+                    try:
+                        tag, payload = self._q.get(timeout=0.1)
+                        break
+                    except _queue.Empty:
+                        if self._stop.is_set() \
+                                and not self._thread.is_alive():
+                            self._finished = True
+                            raise StopIteration from None
+        finally:
+            self.wait_s += time.perf_counter() - t0
         if observability.ENABLED:
             observability.set_gauge("io.prefetch.queue_depth",
                                     self._q.qsize())

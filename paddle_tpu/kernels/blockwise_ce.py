@@ -469,6 +469,7 @@ def _fwd_pallas(x, w, labels, chunk, vocab_block, ignore_index,
                         pltpu.VMEM((chunk, 8), jnp.float32)],
         compiler_params=_compiler_params(chunk, d, bv, x.dtype.itemsize),
         interpret=interpret,
+        name="blockwise_ce_fwd",
     )(xp.reshape(nc * chunk, d), wp, labf[:, None, :])
     lse, picked = lse[:, 0, :], picked[:, 0, :]
     valid = lab2 != ignore_index
@@ -508,6 +509,7 @@ def _bwd_pallas(x, w, labels, lses, count, g, chunk, vocab_block,
         scratch_shapes=[pltpu.VMEM((chunk, d), jnp.float32)],
         compiler_params=_compiler_params(chunk, d, bv, x.dtype.itemsize),
         interpret=interpret,
+        name="blockwise_ce_dx",
     )(x2, wp, *rows)
 
     row = _row_spec(chunk, lambda jv, ir: (ir, 0, 0))
@@ -524,6 +526,7 @@ def _bwd_pallas(x, w, labels, lses, count, g, chunk, vocab_block,
         scratch_shapes=[pltpu.VMEM((d, bv), jnp.float32)],
         compiler_params=_compiler_params(chunk, d, bv, x.dtype.itemsize),
         interpret=interpret,
+        name="blockwise_ce_dw",
     )(x2, wp, *rows)
     return dx[:n], dw[:, :v]
 

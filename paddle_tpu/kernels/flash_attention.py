@@ -538,6 +538,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(q, kt, v)
     out = outs[0]
     lse = outs[1] if save_lse else None
@@ -960,6 +961,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
             scratch_shapes=[pltpu.VMEM((sk_p, d), jnp.float32),
                             pltpu.VMEM((sk_p, d), jnp.float32)],
             interpret=interpret,
+            name="flash_bwd_fused",
         )(q, k, v, g, o, lse)
         return (dq[:, :, :sq, :], dk[:, :, :sk, :], dv[:, :, :sk, :])
 
@@ -991,6 +993,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
             out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             interpret=interpret,
+            name="flash_bwd_dq_stream",
         )(q, k, v, g, lse, delta)
     else:
         qspec = pl.BlockSpec((1, 1, bq, d),
@@ -1008,6 +1011,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
             out_specs=qspec,
             out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
             interpret=interpret,
+            name="flash_bwd_dq",
         )(q, k, v, g, lse, delta)
 
     nq_total = sq_p // bq
@@ -1029,6 +1033,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, g, lse, delta)
 
     return (dq[:, :, :sq, :], dk[:, :, :sk, :], dv[:, :, :sk, :])

@@ -197,6 +197,7 @@ def _rmsn_fwd_pallas(x2, res2, w, eps, interpret):
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_rmsnorm_fwd",
     )(*args)
     return y[:n], h[:n], rstd_t
 
@@ -239,6 +240,7 @@ def _rmsn_bwd_pallas(h2, w, rstd_t, gy2, gh2, interpret):
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_rmsnorm_bwd",
     )(*args)
     return dh[:n], jnp.sum(dwp, axis=(0, 1)).astype(w.dtype)
 
@@ -408,6 +410,7 @@ def _rope_pallas(x3, cos_f, sin_f, interpret):
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fused_rope",
     )(x3, cos_f, sin_f)
     return out[:n]
 

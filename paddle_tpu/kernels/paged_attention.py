@@ -278,5 +278,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention_decode",
     )(*scalar_args, qf, k_pool, v_pool)
     return out[:, :, :g, :].reshape(b, hq, d)

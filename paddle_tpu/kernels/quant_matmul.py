@@ -100,5 +100,6 @@ def weight_only_int8_matmul(x, qw, scale, block_m=None, block_n=512,
             bytes_accessed=M * K * 2 + K * N + M * N * 2,
             transcendentals=0),
         interpret=interpret,
+        name="quant_matmul",
     )(x2, qw, scale.reshape(1, N))
     return out.reshape(lead + (N,))

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu
@@ -206,34 +207,54 @@ class LlamaAttention(nn.Layer):
 
     def forward(self, hidden_states, position_ids=None, attn_mask=None,
                 cache=None, cache_index=None):
+        # named scopes (qkv, rope, core, out_proj under the layer's
+        # attn) are metadata of the compiled ops: a device trace can
+        # then say which block an op belongs to
         cfg = self.config
         b, s = hidden_states.shape[0], hidden_states.shape[1]
-        if cfg.fuse_attention_qkv:
-            kv_out = cfg.num_key_value_heads * cfg.head_dim
-            qkv = _maybe_overlap_linear(self.qkv_proj, hidden_states,
-                                        "qkv_proj", cfg)
-            q, k, v = T.split(qkv, [cfg.hidden_size, kv_out, kv_out],
-                              axis=-1)
-        else:
-            q = _maybe_overlap_linear(self.q_proj, hidden_states,
-                                      "q_proj", cfg)
-            k = _maybe_overlap_linear(self.k_proj, hidden_states,
-                                      "k_proj", cfg)
-            v = _maybe_overlap_linear(self.v_proj, hidden_states,
-                                      "v_proj", cfg)
-        q = T.reshape(q, [b, s, cfg.num_attention_heads, cfg.head_dim])
-        k = T.reshape(k, [b, s, cfg.num_key_value_heads, cfg.head_dim])
-        v = T.reshape(v, [b, s, cfg.num_key_value_heads, cfg.head_dim])
-        if cfg.fused_rope:
-            # fused train-path apply (kernels/fused_norm.py): identical
-            # rotation, one pass, inverse-rotation backward
-            from paddle_tpu.incubate.nn.functional import fused_rope_apply
-            q, k = fused_rope_apply(q, k, position_ids=position_ids,
-                                    rotary_emb_base=cfg.rope_theta)
-        else:
-            q, k, _ = fused_rotary_position_embedding(
-                q, k, None, position_ids=position_ids,
-                rotary_emb_base=cfg.rope_theta)
+        with jax.named_scope("qkv"):
+            if cfg.fuse_attention_qkv:
+                kv_out = cfg.num_key_value_heads * cfg.head_dim
+                qkv = _maybe_overlap_linear(self.qkv_proj, hidden_states,
+                                            "qkv_proj", cfg)
+                q, k, v = T.split(qkv, [cfg.hidden_size, kv_out, kv_out],
+                                  axis=-1)
+            else:
+                q = _maybe_overlap_linear(self.q_proj, hidden_states,
+                                          "q_proj", cfg)
+                k = _maybe_overlap_linear(self.k_proj, hidden_states,
+                                          "k_proj", cfg)
+                v = _maybe_overlap_linear(self.v_proj, hidden_states,
+                                          "v_proj", cfg)
+            q = T.reshape(q, [b, s, cfg.num_attention_heads, cfg.head_dim])
+            k = T.reshape(k, [b, s, cfg.num_key_value_heads, cfg.head_dim])
+            v = T.reshape(v, [b, s, cfg.num_key_value_heads, cfg.head_dim])
+        with jax.named_scope("rope"):
+            if cfg.fused_rope:
+                # fused train-path apply (kernels/fused_norm.py):
+                # identical rotation, one pass, inverse-rotation backward
+                from paddle_tpu.incubate.nn.functional import \
+                    fused_rope_apply
+                q, k = fused_rope_apply(q, k, position_ids=position_ids,
+                                        rotary_emb_base=cfg.rope_theta)
+            else:
+                q, k, _ = fused_rotary_position_embedding(
+                    q, k, None, position_ids=position_ids,
+                    rotary_emb_base=cfg.rope_theta)
+        with jax.named_scope("core"):
+            out, new_cache = self._core(q, k, v, attn_mask, cache,
+                                        cache_index)
+        with jax.named_scope("out_proj"):
+            if cache is not None:
+                return self.o_proj(out), new_cache
+            return _maybe_overlap_linear(self.o_proj, out, "o_proj", cfg)
+
+    def _core(self, q, k, v, attn_mask, cache, cache_index):
+        """Attention proper over position-encoded q, k, v: the paged or
+        the incremental cache path, else flash / SDPA. Returns (out
+        (b, s, hidden), new cache or None)."""
+        cfg = self.config
+        b, s = q.shape[0], q.shape[1]
         if cache is not None:
             from paddle_tpu.inference.paged import (PagedState,
                                                     paged_attention_update)
@@ -242,9 +263,7 @@ class LlamaAttention(nn.Layer):
                 # page-pool pair, cache_index carries the block tables +
                 # per-slot lengths (inference/paged.py; reference serving
                 # path: block_multi_head_attention_kernel.cu)
-                out, new_cache = paged_attention_update(
-                    q, k, v, cache, cache_index)
-                return self.o_proj(out), new_cache
+                return paged_attention_update(q, k, v, cache, cache_index)
             # incremental decode (models/generation.py): write this
             # step's k/v into the fixed-size buffer at cache_index,
             # then attend over the whole buffer under a position mask
@@ -275,15 +294,13 @@ class LlamaAttention(nn.Layer):
                         T.full_like(fmask, float("-inf"))) + attn_mask
             out = F.scaled_dot_product_attention(
                 q, k_buf, v_buf, attn_mask=mask)
-            out = T.reshape(out, [b, s, cfg.hidden_size])
-            return self.o_proj(out), (k_buf, v_buf)
+            return T.reshape(out, [b, s, cfg.hidden_size]), (k_buf, v_buf)
         if cfg.use_flash_attention and attn_mask is None:
             out, _ = F.flash_attention(q, k, v, causal=True)
         else:
             out = F.scaled_dot_product_attention(
                 q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None)
-        out = T.reshape(out, [b, s, cfg.hidden_size])
-        return _maybe_overlap_linear(self.o_proj, out, "o_proj", cfg)
+        return T.reshape(out, [b, s, cfg.hidden_size]), None
 
 
 class LlamaMLP(nn.Layer):
@@ -336,31 +353,36 @@ class LlamaDecoderLayer(nn.Layer):
         fused = self.config.fused_norm
         eps = self.config.rms_norm_eps
         residual = hidden_states
-        if fused:
-            # fused train-path norms (kernels/fused_norm.py): norm1 as
-            # one custom_vjp op; norm2 fuses the attention residual add
-            # into the same pass (one read of attn_out, h written once)
-            h, _ = F.rms_norm_fused(hidden_states,
-                                    self.input_layernorm.weight, eps)
-        else:
-            h = self.input_layernorm(hidden_states)
+        with jax.named_scope("norm"):
+            if fused:
+                # fused train-path norms (kernels/fused_norm.py): norm1
+                # as one custom_vjp op; norm2 fuses the attention
+                # residual add into the same pass (one read of
+                # attn_out, h written once)
+                h, _ = F.rms_norm_fused(hidden_states,
+                                        self.input_layernorm.weight, eps)
+            else:
+                h = self.input_layernorm(hidden_states)
         new_cache = None
-        if cache is not None:
-            h, new_cache = self.self_attn(
-                h, position_ids=position_ids, attn_mask=attn_mask,
-                cache=cache, cache_index=cache_index)
-        else:
-            h = self.self_attn(h, position_ids=position_ids,
-                               attn_mask=attn_mask)
-        if fused:
-            h2, residual = F.rms_norm_fused(
-                h, self.post_attention_layernorm.weight, eps,
-                residual=residual)
-        else:
-            h = residual + h
-            residual = h
-            h2 = self.post_attention_layernorm(h)
-        h2 = self.mlp(h2)
+        with jax.named_scope("attn"):
+            if cache is not None:
+                h, new_cache = self.self_attn(
+                    h, position_ids=position_ids, attn_mask=attn_mask,
+                    cache=cache, cache_index=cache_index)
+            else:
+                h = self.self_attn(h, position_ids=position_ids,
+                                   attn_mask=attn_mask)
+        with jax.named_scope("norm"):
+            if fused:
+                h2, residual = F.rms_norm_fused(
+                    h, self.post_attention_layernorm.weight, eps,
+                    residual=residual)
+            else:
+                h = residual + h
+                residual = h
+                h2 = self.post_attention_layernorm(h)
+        with jax.named_scope("mlp"):
+            h2 = self.mlp(h2)
         out = residual + h2
         return out if cache is None else (out, new_cache)
 
@@ -379,16 +401,18 @@ class LlamaModel(nn.Layer):
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def _final_norm(self, h):
-        if self.config.fused_norm:
-            out, _ = F.rms_norm_fused(h, self.norm.weight,
-                                      self.config.rms_norm_eps)
-            return out
-        return self.norm(h)
+        with jax.named_scope("norm"):
+            if self.config.fused_norm:
+                out, _ = F.rms_norm_fused(h, self.norm.weight,
+                                          self.config.rms_norm_eps)
+                return out
+            return self.norm(h)
 
     def forward(self, input_ids, position_ids=None, attn_mask=None,
                 caches=None, cache_index=None):
         from paddle_tpu.distributed.recompute import recompute
-        h = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
         if caches is not None:
             new_caches = []
             for layer, cache in zip(self.layers, caches):
@@ -424,10 +448,11 @@ class LlamaForCausalLM(nn.Layer):
                 bias_attr=False)
 
     def logits(self, hidden):
-        if self.lm_head is None:
-            w = self.model.embed_tokens.weight
-            return T.matmul(hidden, T.transpose(w, [1, 0]))
-        return self.lm_head(hidden)
+        with jax.named_scope("lm_head_loss"):
+            if self.lm_head is None:
+                w = self.model.embed_tokens.weight
+                return T.matmul(hidden, T.transpose(w, [1, 0]))
+            return self.lm_head(hidden)
 
     def forward(self, input_ids, labels=None, position_ids=None,
                 attn_mask=None, caches=None, cache_index=None):
@@ -447,14 +472,16 @@ class LlamaForCausalLM(nn.Layer):
             # exists to return, hence (loss, None)
             w = (self.model.embed_tokens.weight if self.lm_head is None
                  else self.lm_head.weight)
-            loss = next_token_loss_blockwise(
-                h, w, labels, self.config,
-                transpose_w=self.lm_head is None)
+            with jax.named_scope("lm_head_loss"):
+                loss = next_token_loss_blockwise(
+                    h, w, labels, self.config,
+                    transpose_w=self.lm_head is None)
             return loss, None
         logits = self.logits(h)
         if labels is None:
             return logits
-        loss = next_token_loss(logits, labels, self.config.vocab_size)
+        with jax.named_scope("lm_head_loss"):
+            loss = next_token_loss(logits, labels, self.config.vocab_size)
         return loss, logits
 
     def generate(self, input_ids, max_new_tokens=32, **kwargs):

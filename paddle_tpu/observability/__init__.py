@@ -11,9 +11,9 @@ Three pieces, one switch:
                   Histogram with a closed name catalogue (METRICS),
                   JSON snapshot + Prometheus text exposition (served
                   at GET /metrics by inference/serving.PredictorServer)
-    trace.py      span(name, **attrs) -> bounded ring buffer ->
-                  chrome-trace JSON, mergeable with the profiler's
-                  HostTracer events
+    trace.py      span(name, **attrs) -> a profiler annotation always,
+                  and a bounded ring buffer -> chrome-trace JSON when
+                  enabled; the closed span catalogue (SPANS)
     telemetry.py  per-step training reporter: tokens/sec/chip + MFU
                   (the bench.py math, in-framework), lagged loss,
                   driven by parallel/trainer.py
@@ -23,9 +23,12 @@ Three pieces, one switch:
                   at GET /debug/fleet), and the crash flight recorder
                   (atomic diagnostic bundles, tools/obs_dump.py)
 
-Contract with the hot path — the same one distributed/chaos.py set:
-when observability is disabled (the default), every instrumentation
-point is a single module-attribute load + falsy branch:
+Contract with the hot path.
+
+Counters (`inc` / `observe` / `set_gauge`) are gated — the contract
+distributed/chaos.py set. When observability is disabled (the
+default), an instrumentation point is a single module-attribute load +
+falsy branch:
 
     if observability.ENABLED:
         observability.inc("store.rpc.retries")
@@ -37,11 +40,26 @@ counters are the exception: they are always on because they REPLACE
 the /stats bookkeeping PredictorServer already paid for (per-server
 registries, not this module's global one).
 
-Metric names at instrumentation sites must be string literals from
-the metrics.METRICS catalogue; tools/check_metric_names.py (tier-1
-wired) fails the build otherwise.
+Spans are NOT gated: `span()` and `step_span()` always annotate.
 
-Importing this package never touches jax.
+    with observability.span("engine.tick.admit"):
+        self._admit()
+
+Disabled, the call returns the bare `jax.profiler.TraceAnnotation` (a
+TraceMe: entering it is one atomic load when no capture runs; no ring,
+no lock, no `Span`), and the shared no-op only in a process that has
+not loaded jax. Enabled, it returns a `trace.Span`, which enters the
+same annotation and also records into the ring. So a profiler capture
+holds the program's spans on the device ops' clock with no switch to
+flip; ENABLED decides only whether the ring (whose clock is
+`time.perf_counter()`, not the profiler's) keeps them too. Spans sit
+at tick and step granularity, never per token or per op.
+
+Metric names at instrumentation sites must be string literals from
+the metrics.METRICS catalogue, span names from trace.SPANS;
+tools/check_metric_names.py (tier-1 wired) fails the build otherwise.
+
+Importing this package never imports jax.
 """
 from __future__ import annotations
 
@@ -58,7 +76,7 @@ from paddle_tpu.observability.requests import RequestContext
 
 __all__ = [
     "ENABLED", "enable", "disable", "scoped", "inc", "observe",
-    "set_gauge", "span", "METRICS", "MetricsRegistry", "REGISTRY",
+    "set_gauge", "span", "step_span", "METRICS", "MetricsRegistry", "REGISTRY",
     "Span", "export_chrome_trace", "metrics", "trace", "requests",
     "RequestContext", "fleet",
 ]
@@ -137,11 +155,21 @@ _NOOP_SPAN = _NoopSpan()
 
 
 def span(name, **attrs):
-    """Timed scope -> the trace ring. Cheap when disabled: returns a
-    shared no-op context manager without allocating."""
+    """Timed scope: always a profiler annotation, and a ring record
+    when enabled (module doc). Disabled, what comes back is the bare
+    annotation — or the shared no-op where jax is not loaded."""
     if not ENABLED:
-        return _NOOP_SPAN
+        return trace.annotation(name, attrs) or _NOOP_SPAN
     return Span(name, attrs)
+
+
+def step_span(name, step_num):
+    """`span` for one step of a loop: a `StepTraceAnnotation`, which
+    the profiler's per-step analysis keys on `step_num`."""
+    ann = trace.step_annotation(name, step_num)
+    if not ENABLED:
+        return ann or _NOOP_SPAN
+    return Span(name, {"step_num": step_num}, ann)
 
 
 # -- env bootstrap (read once at import) ------------------------------------

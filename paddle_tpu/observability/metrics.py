@@ -493,6 +493,12 @@ METRICS = {
     "engine.expired": ("gauge", "requests expired before admission"),
     "engine.overloaded": ("gauge", "submits shed with EngineOverloaded"),
     "engine.pending": ("gauge", "requests queued for admission"),
+    "engine.tick_max_seconds": ("gauge", "the longest scheduler tick, "
+                                "wall seconds (engine.tick_log says "
+                                "which phase of a recent one)"),
+    "engine.tick_host_seconds": ("gauge", "tick wall seconds spent "
+                                 "outside the waits on the device "
+                                 "(readback, prefill), cumulative"),
 }
 
 

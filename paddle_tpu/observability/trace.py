@@ -1,32 +1,143 @@
-"""Lightweight span tracing into a bounded ring buffer.
+"""Span tracing: a profiler annotation always, a ring record when enabled.
 
-`observability.span(name, **attrs)` (the gated entry point — see
-observability/__init__.py) wraps a host-side scope; completed spans
-land in a process-wide ring buffer (oldest evicted first, so a
-long-running job's memory is bounded) and export as chrome-trace JSON
-that loads in chrome://tracing / perfetto. `export_chrome_trace`
-merges the native profiler's HostTracer events on request so one
-timeline shows both the coarse runtime spans recorded here (steps,
-checkpoint saves, RPC retries) and the fine per-op scopes from
-paddle_tpu/_native — and, side by side in perfetto, the XLA device
-trace `jax.profiler` writes under its logdir.
+`observability.span(name, **attrs)` (the entry point — see
+observability/__init__.py) wraps a host-side scope. Two things can
+hold it:
+
+- the profiler. Wherever jax is loaded a span enters a
+  `jax.profiler.TraceAnnotation(name, **attrs)`, so ANY capture
+  (`jax.profiler.start_trace`, the benchmark's `--trace 1`, an
+  operator's on-demand capture) holds the program's spans on the same
+  clock as the device ops, whether observability is enabled or not.
+  With no capture running an annotation is one atomic load.
+- the ring. With observability ENABLED the span is also a `Span`:
+  completed spans land in a process-wide ring buffer (oldest evicted
+  first, so a long-running job's memory is bounded) and export as
+  chrome-trace JSON that loads in chrome://tracing / perfetto.
+  `export_chrome_trace` merges the native profiler's HostTracer events
+  on request. The ring's remaining users are the slow-request
+  exemplars of observability/requests.py (`record_span`, rebuilt from
+  a request's timeline after the fact), the overlap phase spans of
+  parallel/overlap.py and Trainer.measure_phase_seconds, and the
+  flight recorder's trace.json (fleet.py).
+
+The ring's clock is `time.perf_counter()`; the profiler's is its own.
+Nothing the ring holds can be laid over a device trace: read the
+annotations in the capture for that.
+
+SPANS is the closed catalogue of span names at call sites, like
+metrics.METRICS: name -> (layer, what it covers, the per-layer metric
+designed to read it; benchmarks/spans.py holds the readers, PERF.md
+says which are registered). tools/check_metric_names.py holds call
+sites to it.
 
 Spans nest naturally: chrome-trace "X" (complete) events reconstruct
 the stack from ts/dur containment per thread; `depth` is also recorded
 explicitly in args for programmatic consumers.
 
-Stdlib-only; importing this module never touches jax.
+Stdlib-only; importing this module never imports jax (it uses the
+profiler of a jax that something else has already loaded).
 """
 from __future__ import annotations
 
 import collections
 import json
 import os
+import sys
 import threading
 import time
 
-__all__ = ["Span", "record_span", "set_ring_capacity", "ring_capacity",
+__all__ = ["Span", "SPANS", "annotation", "step_annotation",
+           "record_span", "set_ring_capacity", "ring_capacity",
            "spans", "clear", "export_chrome_trace", "chrome_events"]
+
+# name -> (layer, what the span covers, the per-layer metric designed to
+# read it).
+# The thread is the one that owns the work: the ticker for engine.*,
+# the training loop for train.* and input.wait, the prefetch worker for
+# input.h2d, a handler thread for http.write.
+SPANS = {
+    "input.wait": (
+        "input", "DevicePrefetcher.__next__ blocked on its queue",
+        "input.prefetch_wait_share (counter DevicePrefetcher.wait_s)"),
+    "input.h2d": (
+        "input", "the prefetch worker placing one batch on the device",
+        "none yet; io.h2d.seconds times the same work when enabled"),
+    "train.step": (
+        "step", "the whole of Trainer.step; a step span whose step_num "
+        "is the optimizer's step count before the step",
+        "step.host_dispatch_ms_p50"),
+    "train.step.place": (
+        "step", "the mesh device_put loop over the batch's leaves",
+        "none yet; a child of train.step"),
+    "train.step.dispatch": (
+        "step", "the call of the jitted step (trace and compile on a "
+        "first call, else the enqueue)",
+        "none yet; a child of train.step"),
+    "engine.tick": (
+        "scheduler", "one scheduler tick that found work, attr seq; "
+        "its children follow in this order",
+        "sched.tick_host_ms_p50 (counters tick_wall_s, tick_host_s)"),
+    "engine.tick.retire": (
+        "scheduler", "the cancel sweep and the session suspend sweep",
+        "serve.idle_in_accept_share"),
+    "engine.tick.admit": (
+        "scheduler", "_admit(): queue swap, prefix lookups, page "
+        "reservations, and the prefills (engine.prefill) inside it",
+        "serve.idle_in_launch_share"),
+    "engine.tick.alloc": (
+        "scheduler", "pages for this tick's tokens and the per-slot "
+        "host arrays", "serve.idle_in_launch_share"),
+    "engine.tick.upload": (
+        "scheduler", "the tick program's lookup and its arguments "
+        "made device arrays", "serve.idle_in_launch_share"),
+    "engine.tick.launch": (
+        "scheduler", "the call of the tick program (the enqueue)",
+        "serve.idle_in_launch_share"),
+    "engine.tick.readback": (
+        "scheduler", "np.asarray of the tick's tokens and lengths: "
+        "the wait for the device", "counter readback_s"),
+    "engine.tick.accept": (
+        "scheduler", "_accept_tick: tokens to the requests' queues, "
+        "retirements", "serve.idle_in_accept_share"),
+    "engine.prefill": (
+        "scheduler", "one prefill program call and its read back, "
+        "attrs bucket, rows, group", "counter prefill_s"),
+    "engine.idle": (
+        "scheduler", "the ticker's sleep after a step() with no work",
+        "serve.idle_between_ticks_share"),
+    "http.write": (
+        "HTTP", "one chunk of a streamed reply written and flushed, "
+        "attr rid where the request is traced",
+        "none yet; laid beside the ticker's idle gaps"),
+}
+
+_annotations = None     # (TraceAnnotation, StepTraceAnnotation) of jax
+
+
+def _profiler_classes():
+    """jax's annotation classes if jax is loaded, else None. Never
+    imports jax: a process that has not loaded it has no profiler to
+    write to."""
+    global _annotations
+    if _annotations is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        if prof is None:
+            return None
+        _annotations = (prof.TraceAnnotation, prof.StepTraceAnnotation)
+    return _annotations
+
+
+def annotation(name, attrs):
+    """The profiler annotation of a span, or None where jax is not
+    loaded."""
+    classes = _profiler_classes()
+    return classes[0](name, **attrs) if classes else None
+
+
+def step_annotation(name, step_num):
+    classes = _profiler_classes()
+    return classes[1](name, step_num=step_num) if classes else None
 
 _DEFAULT_CAPACITY = 4096
 
@@ -52,21 +163,28 @@ def clear():
 
 
 class Span:
-    """One timed scope. Use through observability.span(...) so the
-    disabled path stays a single attribute check; constructing a Span
-    directly always records."""
+    """One timed scope: a ring record and, where jax is loaded, a
+    profiler annotation around the same code. Use through
+    observability.span(...), which skips the ring when observability
+    is disabled; constructing a Span directly always records."""
 
-    __slots__ = ("name", "attrs", "t0", "dur_us", "depth", "tid")
+    __slots__ = ("name", "attrs", "t0", "dur_us", "depth", "tid",
+                 "_annotation")
 
-    def __init__(self, name, attrs=None):
+    def __init__(self, name, attrs=None, ann=None):
         self.name = name
         self.attrs = attrs or {}
         self.t0 = 0.0
         self.dur_us = 0.0
         self.depth = 0
         self.tid = 0
+        self._annotation = ann
 
     def __enter__(self):
+        if self._annotation is None:
+            self._annotation = annotation(self.name, self.attrs)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         depth = getattr(_tls, "depth", 0)
         _tls.depth = depth + 1
         self.depth = depth
@@ -76,6 +194,8 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_us = (time.perf_counter() - self.t0) * 1e6
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         _tls.depth = self.depth
         if exc_type is not None:
             self.attrs = {**self.attrs, "error": exc_type.__name__}

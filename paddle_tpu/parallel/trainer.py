@@ -446,8 +446,9 @@ class Trainer:
                     for k, v in st.items()}
                 for n, st in opt_state.items()}
         train_p = {n: params[n] for n in self.param_names}
-        new_p, new_s = self.optimizer.apply_gradients_arrays(
-            train_p, grads, opt_state, lr)
+        with jax.named_scope("optimizer"):
+            new_p, new_s = self.optimizer.apply_gradients_arrays(
+                train_p, grads, opt_state, lr)
         if self.config.health_probe:
             # ONE global reduction: the squared grad norm propagates
             # any NaN/Inf, so isfinite(gnorm2) is the all-grads-finite
@@ -533,7 +534,16 @@ class Trainer:
         """One optimizer step on `batch` (dict of np/jax arrays or Tensors).
         Returns the scalar loss as a lazy Tensor: steps dispatch
         asynchronously and only reading the value (float()/numpy()) blocks,
-        so a caller that never reads it keeps the device queue full."""
+        so a caller that never reads it keeps the device queue full.
+
+        The whole step is the step span `train.step` (step_num: the
+        optimizer's step count before it), which any profiler capture
+        holds; `train.step.place` and `.dispatch` are its children."""
+        with observability.step_span("train.step",
+                                     self.optimizer._step_count):
+            return self._step(batch)
+
+    def _step(self, batch: dict) -> Tensor:
         # numpy leaves stay numpy here: on the mesh path device_put
         # below does ONE direct host->sharded transfer (jnp.asarray
         # first paid an extra staging copy to the default device), and
@@ -551,15 +561,17 @@ class Trainer:
             self._tel_last_t = self._tel_prev = None
         if self.mesh is not None:
             put = {}
-            for k, v in batch.items():
-                sh = self._batch_sharding(k, v.ndim)
-                if getattr(v, "sharding", None) == sh:
-                    # already placed (the data_iter prefetch path): the
-                    # hot path stays free of device_put — no H2D, no
-                    # host->device sync on the dispatch thread
-                    put[k] = v
-                else:
-                    put[k] = jax.device_put(v, sh)
+            with observability.span("train.step.place"):
+                for k, v in batch.items():
+                    sh = self._batch_sharding(k, v.ndim)
+                    if getattr(v, "sharding", None) == sh:
+                        # already placed (the data_iter prefetch path):
+                        # the hot path stays free of device_put — no
+                        # H2D, no host->device sync on the dispatch
+                        # thread
+                        put[k] = v
+                    else:
+                        put[k] = jax.device_put(v, sh)
             batch = put
         if self._step_fn is None:
             self._step_fn = self._build_step(None)
@@ -602,7 +614,7 @@ class Trainer:
         # enter the mesh context for the (first-call) trace so
         # sharding-aware custom vjps (e.g. the embedding grad reshard in
         # nn/functional/common.py) can read the axis names
-        with self._mesh_ctx():
+        with self._mesh_ctx(), observability.span("train.step.dispatch"):
             out = self._step_fn(*args)
         if observability.ENABLED and n0 is not None \
                 and self._trace_count() > n0:

@@ -194,9 +194,12 @@ def test_span_records_error_and_disabled_span_is_free():
             raise RuntimeError("x")
     assert trace.spans()[-1].attrs["error"] == "RuntimeError"
     obs.disable()
-    # disabled: the same shared no-op context manager, nothing recorded
+    # disabled: no Span and nothing in the ring; what comes back is
+    # the bare profiler annotation (jax is loaded here), which costs
+    # one atomic load while no capture runs
     trace.clear()
-    assert obs.span("a") is obs.span("b")
+    assert not isinstance(obs.span("a"), trace.Span)
+    assert hasattr(obs.span("a"), "__enter__")
     with obs.span("nope"):
         pass
     assert trace.spans() == []

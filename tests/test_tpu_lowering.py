@@ -22,8 +22,11 @@ the proof; this is the cheap guard in front of it. Shapes are
 chip_smoke.py's: TinyLlama widths, depth cut to one layer for the
 whole-step rows.
 """
+import ast
+import glob
 import sys
 import os
+import re
 
 import numpy as np
 import pytest
@@ -51,6 +54,12 @@ def _lower_tpu(jitted, *args):
 
 def _calls(lowered):
     return chip_smoke._custom_calls(lowered.as_text())
+
+
+def _kernel_names(lowered):
+    """The `name=` of every Pallas call in the lowered text: what a
+    device trace shows as the custom call's instruction name."""
+    return set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
 
 
 class _ShapeOnlyRng:
@@ -110,6 +119,54 @@ def test_kernel_lowers_and_compiles_for_tpu(as_tpu, described_v5e, name,
     lowered.compile()       # Mosaic: VMEM limits, layouts
 
 
+_NAMES = {
+    "flash_fwd_bwd_whole_kv": {"flash_fwd", "flash_bwd_fused"},
+    "flash_fwd_bwd_streamed_kv": {"flash_fwd", "flash_bwd_dq_stream",
+                                  "flash_bwd_dkv"},
+    "paged_decode_bf16_page16": {"paged_attention_decode"},
+    "paged_decode_int8_page32": {"paged_attention_decode"},
+    "blockwise_ce_whole_vocab": {"blockwise_ce_fwd", "blockwise_ce_dx",
+                                 "blockwise_ce_dw"},
+    "blockwise_ce_vocab_block": {"blockwise_ce_fwd", "blockwise_ce_dx",
+                                 "blockwise_ce_dw"},
+    "rms_norm_residual": {"fused_rmsnorm_fwd", "fused_rmsnorm_bwd"},
+    "rms_norm_no_residual": {"fused_rmsnorm_fwd", "fused_rmsnorm_bwd"},
+    "rope_apply": {"fused_rope"},
+    "weight_only_int8_matmul": {"quant_matmul"},
+}
+
+
+@pytest.mark.parametrize("name,build", _CASES, ids=[n for n, _ in _CASES])
+def test_kernel_carries_its_name_in_the_lowered_text(as_tpu, name, build):
+    """A trace reader finds a kernel by the name its call site gives
+    it, not by operand shapes the next configuration changes."""
+    fn, args, _ = build(FULL, jnp.dtype("bfloat16"), False,
+                        _ShapeOnlyRng())
+    shapes = [None if a is None else jax.ShapeDtypeStruct(a.shape, a.dtype)
+              for a in args]
+    assert _kernel_names(_lower_tpu(jax.jit(fn), *shapes)) == _NAMES[name]
+
+
+def test_every_pallas_call_site_passes_a_literal_name():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = []
+    for path in sorted(glob.glob(os.path.join(
+            root, "paddle_tpu", "kernels", "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) \
+                    and isinstance(node.func, ast.Attribute) \
+                    and node.func.attr == "pallas_call":
+                kw = {k.arg: k.value for k in node.keywords}
+                assert isinstance(kw.get("name"), ast.Constant), \
+                    f"{path}:{node.lineno}: pallas_call without a " \
+                    f"literal name="
+                names.append(kw["name"].value)
+    assert len(names) == len(set(names)) == 13
+    assert set().union(*_NAMES.values()) | {"flash_bwd_dq"} == set(names)
+
+
 def _trainer(mesh=None, **cfg_kw):
     import paddle_tpu
     import paddle_tpu.optimizer as opt
@@ -143,7 +200,18 @@ def _lower_step(trainer):
     (dict(loss_chunk=512), 5),                 # + CE fwd, dx, dW
 ], ids=["dense_loss", "recompute", "loss_chunk_512"])
 def test_train_step_lowers_for_tpu(as_tpu, cfg_kw, calls):
-    assert _calls(_lower_step(_trainer(**cfg_kw))) == calls
+    lowered = _lower_step(_trainer(**cfg_kw))
+    assert _calls(lowered) == calls
+    want = {"flash_fwd", "flash_bwd_fused"}
+    if cfg_kw.get("loss_chunk"):
+        want |= {"blockwise_ce_fwd", "blockwise_ce_dx", "blockwise_ce_dw"}
+    assert _kernel_names(lowered) == want
+    # the blocks' scopes ride the ops' locations, the backward's as
+    # `transpose(jvp(attn))/...`
+    text = lowered.as_text(debug_info=True)
+    for scope in ("embed", "attn", "qkv", "rope", "core", "out_proj",
+                  "mlp", "norm", "lm_head_loss", "optimizer"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
 
 
 def test_mesh_train_step_lowers_per_shard(as_tpu):
@@ -186,6 +254,10 @@ def test_engine_programs_lower_for_tpu(as_tpu, kv_dtype, page):
     lowered = _lower_tpu(tick.func, *tick.args, z(b), z(b), z(b, dt=bool),
                          z(b), z(b, mp), z(b), key, pools)
     assert _calls(lowered) == layers      # one decode kernel a layer
+    assert _kernel_names(lowered) == {"paged_attention_decode"}
+    text = lowered.as_text(debug_info=True)
+    for scope in ("kv_write", "paged_attn", "sample"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
     # the weights are the program's argument, never HLO literals
     assert len(lowered.as_text()) < 5e6
 

@@ -32,7 +32,7 @@ DESCRIPTION = ("host syncs (.item/float/np.asarray/device_get/print) "
 
 # qualnames treated as hot even without a visible jit wrapper: the
 # trainer's per-step dispatch body (PR 4's zero-device_put contract)
-KNOWN_HOT = {"Trainer.step"}
+KNOWN_HOT = {"Trainer.step", "Trainer._step"}
 
 _NUMPY_MATERIALIZERS = {"asarray", "array"}
 _CAST_BUILTINS = {"float", "int", "bool"}

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Fail CI when a metric instrumentation site is off-catalogue.
+"""Fail CI when a metric or span instrumentation site is off-catalogue.
 
 THIN SHIM: the scanner now lives in the unified static-analysis
 framework as the `metric-names` pass
@@ -24,7 +24,7 @@ if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
 from tools.analyze.passes.metric_names import (  # noqa: E402,F401
-    ACQUIRERS, ALLOWED, INSTRUMENTS, scan)
+    ACQUIRERS, ALLOWED, INSTRUMENTS, SPAN_CALLS, scan, scan_spans)
 
 
 def main(argv):
@@ -43,8 +43,10 @@ def main(argv):
         # non-gated path (exporters) or be mid-migration
         print("check_metric_names: warning, catalogue entries with no "
               f"literal call site: {stale}")
+    _v, spans_seen, spans = scan_spans(root)
     print(f"check_metric_names: clean ({len(seen)} literal name(s) "
-          f"across the package, {len(catalogue)} catalogued)")
+          f"across the package, {len(catalogue)} catalogued; "
+          f"{len(spans_seen)} span name(s) of {len(spans)})")
     return 0
 
 
