@@ -647,6 +647,14 @@ class PagedKVEngine:
                     self.decode_kernel = "jnp"
             self.draft_pools = make_pools(dn_kv, dhd,
                                           dcfg.num_hidden_layers)
+        # the kernel's own account of how it engages at this geometry
+        # (heads and pages a grid step, grid, VMEM bytes): a count from
+        # shapes, so it reads the same with and without a chip
+        self.decode_plan = None
+        if self.decode_kernel == "pallas":
+            self.decode_plan = _pk.decode_plan(
+                cfg.num_attention_heads, n_kv, hd, self.page_size,
+                self.max_pages_per_slot, pool_dtype, slots=self.max_slots)
         self._free = list(range(self.num_pages - 1, 0, -1))  # 0 = trash
         # pages promised to admitted slots but not yet popped from the
         # free list; admission headroom = len(_free) - _reserved_unalloc
@@ -800,6 +808,9 @@ class PagedKVEngine:
         registry.set_gauge("engine.overloaded", s["overloaded"])
         registry.set_gauge("engine.tick_max_seconds", s["tick_max_s"])
         registry.set_gauge("engine.tick_host_seconds", s["tick_host_s"])
+        registry.set_gauge("engine.decode_grid_steps",
+                           self.decode_plan.grid_steps
+                           if self.decode_plan else 0)
         # _pending is swapped by the ticker under _lock; an unguarded
         # len() here races the swap (found by the guarded-field
         # analyzer pass — the same shape as the PR 12 quota bypass)

@@ -10,24 +10,43 @@ vLLM-PagedAttention-style replacement (Kwon et al., SOSP'23; same
 capability as the reference's block_multi_head_attention_kernel.cu
 decode branch):
 
-- the page pools (num_pages, hk, page_size, d) stay in HBM; the grid is
-  (slot, kv_head, page) and the k/v BlockSpec index_map reads the
-  BLOCK TABLE (a scalar-prefetch operand, SMEM-resident before the body
-  runs) to DMA exactly the pages the slot owns — no dense gather, no
-  copy of anyone else's pages;
+- the page pools (num_pages, hk, page_size, d) stay in HBM
+  (`memory_space=HBM`); the grid is (slot, head block, page block) and
+  one step takes EVERY kv head of the head block over a block of pages
+  (128 keys): the body reads the block table (a scalar-prefetch
+  operand, SMEM-resident before the body runs) and copies exactly the
+  pages the slot owns, one `make_async_copy` a page for all heads at
+  once, into one of two VMEM windows while the step before computes on
+  the other — across slots too, so only a call's first window is
+  waited for. `decode_plan` derives heads a step, pages a step and the
+  VMEM bytes from the shapes under one fixed budget; there is no knob;
+- a page is read as rows of whole 128-lane tiles (`_packing`): where
+  d < 128, `fold` = 128 / d tokens lie side by side in a row, and where
+  a head's rows do not fill the pool dtype's sublane tile, `pack` heads
+  share one. Mosaic slices a ref only along whole tiles, so this is
+  what lets a copy address one page; it also leaves no padding in VMEM
+  and gives the MXU 128 lanes to contract over. The q tile carries one
+  row per (packed head, folded token, query head): the query in that
+  token's lanes, zeros elsewhere, so a single dot batched over head
+  groups scores every row against its own tokens, and a mask drops the
+  columns of the other packed heads;
 - GQA is handled by the same head-fold trick as flash_attention.py:
-  the g = hq//hk query heads sharing a kv head ride ONE (g, d) q tile,
-  so k/v pages are streamed once per kv head instead of materializing
-  jnp.repeat'ed copies;
+  the g = hq//hk query heads sharing a kv head ride the rows of ONE q
+  tile, so k/v pages are streamed once per kv head instead of
+  materializing jnp.repeat'ed copies;
 - softmax is the online accumulator from the flash kernels (base-2
-  exponentials, log2e folded into the q scale once), carried in VMEM
-  scratch across the page axis; pages past the slot's length are
-  skipped via pl.when AND their DMA is elided by clamping the index
-  map to the last needed page (the _ki_clamp trick);
-- int8 KV pools dequantize INSIDE the K-loop: scores/values are
-  computed from the int8 page block and scaled by the per-page-per-head
-  f32 scale AFTER the dot (scalar multiply), so the bf16/f32 pool is
-  never materialized in HBM — the quant_matmul.py lesson applied to KV.
+  exponentials, log2e folded into the q scale once), one per row of
+  the q tile, carried across the page-block axis in the resident
+  output blocks (numerator, running max, running sum); the caller
+  merges the `fold` rows of a query head, which is exact. Pages past
+  the slot's length are never copied, and a block wholly past it is
+  one bare skipped step;
+- int8 KV pools dequantize INSIDE the K-loop: int8 -> f32 in register,
+  the dot, and the per-page-per-head f32 scale applied to the score
+  columns after it (K) and to the probabilities' columns before theirs
+  (V: a block holds several pages, so the scale is per column), so the
+  bf16/f32 pool is never materialized in HBM — the quant_matmul.py
+  lesson applied to KV.
 
 Masking contract: query position per slot is `lens[i]` (the new token's
 k/v is already scattered at that position), so column c is visible iff
@@ -41,6 +60,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +70,7 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.core.jax_compat import tpu_compiler_params
 
 __all__ = ["paged_decode_attention", "decode_shape_problems",
-           "check_decode_shapes"]
+           "check_decode_shapes", "decode_plan", "DecodePlan"]
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
@@ -68,6 +88,14 @@ def _prec(dtype):
 _MIN_SUBLANE = {1: 32, 2: 16, 4: 8}
 
 
+def _sublane(dtype):
+    return _MIN_SUBLANE.get(jnp.dtype(dtype).itemsize, 8)
+
+
+def _round_up(x, n):
+    return -(-x // n) * n
+
+
 def decode_shape_problems(hq, hk, d, page_size, interpret=False,
                           kv_dtype=None):
     """Reasons this (hq, hk, d, page_size) geometry cannot take the
@@ -81,11 +109,11 @@ def decode_shape_problems(hq, hk, d, page_size, interpret=False,
         problems.append(f"q heads must be a multiple of kv heads "
                         f"(hq={hq}, hk={hk})")
     if not interpret:
-        # compiled Mosaic wants tileable (page_size, d) k/v blocks;
+        # compiled Mosaic copies a page as whole (sublane, 128) tiles;
         # interpret mode (CPU tier-1) has no tiling constraint
         dt = jnp.dtype(kv_dtype if kv_dtype is not None
                        else jnp.float32)
-        sub = _MIN_SUBLANE.get(dt.itemsize, 8)
+        sub = _sublane(dt)
         if d % 8 != 0:
             problems.append(f"head_dim % 8 == 0 required on TPU "
                             f"(got d={d})")
@@ -93,6 +121,17 @@ def decode_shape_problems(hq, hk, d, page_size, interpret=False,
             problems.append(f"page_size % {sub} == 0 required on TPU "
                             f"for {dt.name} pools (got "
                             f"page_size={page_size})")
+        fold, pack = _packing(max(hk, 1), d, page_size, dt)
+        if fold * d % 128 != 0:
+            problems.append(f"head_dim must be a multiple of 128, or "
+                            f"divide it with page_size % (128 / "
+                            f"head_dim) == 0, on TPU (got d={d}, "
+                            f"page_size={page_size})")
+        elif pack * (page_size // fold) % sub != 0:
+            problems.append(f"kv heads must pack whole {dt.name} "
+                            f"sublane tiles of {sub} rows on TPU (got "
+                            f"hk={hk} heads of {page_size // fold} "
+                            f"rows a page)")
     return problems
 
 
@@ -110,84 +149,252 @@ def check_decode_shapes(hq, hk, d, page_size, interpret=False,
             + '; use kernel="jnp" for the gather/softmax fallback')
 
 
-def _decode_kernel(bt_ref, lens_ref, kscale_ref, vscale_ref,
-                   q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   page_size, sm_scale, quantized):
-    """Grid (b, hk, max_pages). Scalar-prefetch refs: block tables
-    (b, mp) i32, lens (b,) i32, and — quantized pools only — the
-    PER-SLOT gathered f32 scales (b, mp, hk) in SMEM (gathered from
-    the (num_pages, hk) planes outside the kernel so SMEM use scales
-    with the batch, not the pool). k_ref/v_ref are ONE page block
-    (1, 1, page_size, d), DMA'd by the index_map through the block
-    table."""
-    bi = pl.program_id(0)
-    hi = pl.program_id(1)
-    j = pl.program_id(2)
+# one step's score columns: a lane tile of tokens, so the online-softmax
+# state is touched once per 128 keys and not once per page
+_BLOCK_TOKENS = 128
+# what a step's buffers may take of VMEM: half the 16 MiB a v5e kernel
+# is scoped to by default, so Mosaic's own spills and the pipeline's
+# bookkeeping have the other half
+_VMEM_BUDGET = 8 * 2 ** 20
+
+
+class DecodePlan(NamedTuple):
+    """How `paged_decode_attention` cuts one call into grid steps, and
+    how it reads a page: as rows of whole 128-lane tiles."""
+    heads: int          # kv heads a step
+    pages: int          # pages of a slot a step
+    grid: tuple         # (slots, head blocks, page blocks)
+    vmem_bytes: int     # of the step's buffers, tile padding counted
+    fold: int           # tokens side by side in one row (d < 128)
+    pack: int           # kv heads sharing one sublane tile of rows
+
+    @property
+    def grid_steps(self):
+        return math.prod(self.grid)
+
+
+def _packing(hk, d, page_size, kv_dtype):
+    """(fold, pack): a head's page is page_size * d contiguous
+    elements; read as rows of `fold` tokens (fold * d = 128 lanes where
+    d < 128) it has page_size / fold rows, and `pack` heads together
+    fill whole sublane tiles of the pool's dtype. Mosaic slices a ref
+    only along whole tiles, so this is the shape the copies move."""
+    fold = 128 // d if d < 128 and 128 % d == 0 \
+        and page_size % (128 // d) == 0 else 1
+    sub = _sublane(kv_dtype)
+    pack = sub // math.gcd(sub, page_size // fold)
+    return fold, (pack if hk % pack == 0 else 1)
+
+
+def _tile_bytes(rows, cols, dtype):
+    """VMEM bytes of a (rows, cols) array: rows padded to the dtype's
+    sublane tile, cols to 128 lanes."""
+    return _round_up(rows, _sublane(dtype)) * _round_up(cols, 128) \
+        * jnp.dtype(dtype).itemsize
+
+
+def _q_rows(g, fold, pack):
+    """Rows of the q tile: one per (packed head, folded token, query
+    head of the group), padded to whole f32 sublane tiles."""
+    return _round_up(g * fold * pack, 8)
+
+
+def _step_vmem_bytes(heads, pages, g, d, page_size, kv_dtype, fold, pack):
+    """Everything one grid step holds in VMEM for `heads` kv heads of
+    `pages` pages: the double-buffered K and V windows, the pipelined q
+    block, the resident output blocks (accumulator, running max and
+    sum), and the score-sized (int8: also the dequantized window-sized)
+    temporaries of the body."""
+    f32 = jnp.float32
+    gp, lanes = _q_rows(g, fold, pack), fold * d
+    rows = pages * pack * page_size // fold
+    kv = 2 * 2 * _tile_bytes(rows, lanes, kv_dtype)
+    q_out = 2 * 2 * _tile_bytes(gp, lanes, f32) \
+        + 2 * 2 * _tile_bytes(gp, 128, f32)
+    temps = 2 * _tile_bytes(gp, rows, f32)
+    if jnp.dtype(kv_dtype) == jnp.int8:
+        temps += 2 * _tile_bytes(rows, lanes, f32)
+    return heads // pack * (kv + q_out + temps)
+
+
+def decode_plan(hq, hk, d, page_size, max_pages, kv_dtype, slots=1):
+    """Heads a step, pages a step, grid and VMEM bytes of the decode
+    kernel for this geometry — from the shapes alone. A step takes
+    `_BLOCK_TOKENS` keys of every kv head; where that does not fit
+    `_VMEM_BUDGET` it takes a divisor of the heads, then fewer pages."""
+    g = hq // hk
+    fold, pack = _packing(hk, d, page_size, kv_dtype)
+    pages = max(1, min(_BLOCK_TOKENS // page_size, max_pages))
+    while True:
+        for heads in range(hk, 0, -pack):
+            if hk % heads:
+                continue
+            need = _step_vmem_bytes(heads, pages, g, d, page_size,
+                                    kv_dtype, fold, pack)
+            if need <= _VMEM_BUDGET:
+                break
+        if need <= _VMEM_BUDGET or pages == 1:
+            break
+        pages //= 2
+    return DecodePlan(heads, pages,
+                      (slots, hk // heads, -(-max_pages // pages)), need,
+                      fold, pack)
+
+
+def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized):
+    """Grid (b, head blocks, page blocks), run in order. Scalar
+    prefetch: block tables (b, mp) i32, lens (b,) i32 and, for int8
+    pools, the per-slot gathered f32 scales (b, mp, hk) in SMEM
+    (gathered from the (num_pages, hk) planes outside the kernel, so
+    SMEM use follows the batch and not the pool).
+
+    The pools stay in HBM, seen as (num_pages, hk / pack, rows, lanes):
+    `pack` heads' rows of `fold` tokens each (`_packing`). The body
+    copies the `pages` pages of this step's block, every head of the
+    head block at once, into one of two VMEM windows (groups, pages *
+    rows, lanes) while the step before computes on the other. q is the
+    (1, groups, gp, lanes) block of one slot: row (a, r, i) holds query
+    head i of packed head a in the lanes of folded token r and zeros
+    elsewhere, so one dot over all lanes scores it against the tokens
+    t = r (mod fold); columns of the other packed heads are masked.
+    Each row keeps its own online softmax in the resident output blocks
+    (acc, m, l); the caller merges the rows of one query head."""
+    if quantized:
+        ks_ref, vs_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, acc_ref, m_ref, l_ref, kbuf, vbuf, sem, cur_ref \
+        = refs
+    bi, hi, j = (pl.program_id(a) for a in range(3))
+    nb, nh, _ = (pl.num_programs(a) for a in range(3))
+    mp = bt_ref.shape[1]
+    groups, gp = q_ref.shape[1], q_ref.shape[2]
+    pages, fold, pack = plan.pages, plan.fold, plan.pack
+    rp = page_size // fold               # rows of one head of one page
+    rows = pack * rp                     # rows of one page of a group
+    prec = _prec(q_ref.dtype)
+
+    def last_page(b):
+        return jnp.clip(jax.lax.div(lens_ref[b], page_size), 0, mp - 1)
+
+    def window(b, h, blk, slot, do):
+        """`do` ("start" or "wait") the copies that bring pages [blk *
+        pages, ...) of slot b's head block h into window `slot`: only
+        the pages the length reaches, so the same count is started and
+        waited for."""
+        first = blk * pages
+
+        def page(p, carry):
+            at = bt_ref[b, first + p]
+            row = pl.multiple_of(p * rows, rows)
+            for n, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                src = hbm.at[at] if nh == 1 else \
+                    hbm.at[at, pl.ds(h * groups, groups)]
+                getattr(pltpu.make_async_copy(
+                    src, buf.at[slot, :, pl.ds(row, rows)],
+                    sem.at[n, slot]), do)()
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.clip(last_page(b) + 1 - first, 0, pages), page, 0)
+
+    pos = lens_ref[bi]                   # query position of this slot
+    last = last_page(bi)
+    last_blk = jax.lax.div(last, pages)  # block 0 is always computed
+
+    @pl.when((bi == 0) & (hi == 0) & (j == 0))
+    def _first():
+        # a window's pages past the length are never copied and their
+        # probabilities are exactly 0, but 0 * (what VMEM held before
+        # the call) may be NaN: from here on it holds zeros or pool data
+        vbuf[...] = jnp.zeros_like(vbuf)
+        cur_ref[0] = 0
+        window(bi, hi, 0, 0, "start")
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = lens_ref[bi]                   # query position of this slot
-    last = pos // page_size              # last page the window touches
-    gp, d = q_ref.shape[2], q_ref.shape[3]
-    prec = _prec(q_ref.dtype)
-
-    @pl.when(j <= last)
+    @pl.when(j <= last_blk)
     def _compute():
-        # log2e folded into the (gp, d) q tile once; exponentials below
-        # are exp2 (flash_attention.py convention)
-        q = q_ref[0, 0] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
-        kj = k_ref[0, 0]                              # (ps, d)
-        vj = v_ref[0, 0]
+        cur = cur_ref[0]
+        # next window: this slot's next block, or after its last one
+        # block 0 of the next (slot, head block) — the grid runs in
+        # order, so that step finds its window already on the way
+        unit = bi * nh + hi + 1
+        ends = j == last_blk
+        nxt_b = jnp.where(ends, jnp.minimum(jax.lax.div(unit, nh),
+                                            nb - 1), bi)
+        nxt_h = jnp.where(ends, jax.lax.rem(unit, nh), hi)
+        nxt_blk = jnp.where(ends, 0, j + 1)
+
+        @pl.when(jnp.logical_not(ends) | (unit < nb * nh))
+        def _prefetch():
+            window(nxt_b, nxt_h, nxt_blk, 1 - cur, "start")
+
+        window(bi, hi, j, cur, "wait")
+        cur_ref[0] = 1 - cur
+
+        q = q_ref[0]                                  # (groups, gp, lanes)
+        kj = kbuf[cur]                                # (groups, t, lanes)
+        vj = vbuf[cur]
         if quantized:
-            # fuse-the-convert: int8 -> f32 in REGISTER, dot, then one
-            # scalar multiply per page block (the per-page-per-head
-            # scale) — the dequantized page never exists in HBM
+            # fuse-the-convert: int8 -> f32 in REGISTER, dot, then the
+            # per-page-per-head scale on the score columns — the
+            # dequantized window never exists in HBM
             kj = kj.astype(jnp.float32)
             vj = vj.astype(jnp.float32)
             q = q.astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, kj, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=prec) * kscale_ref[bi, j, hi]  # (gp, ps)
-        else:
-            s = jax.lax.dot_general(
-                q, kj, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=prec)                          # (gp, ps)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-            + j * page_size
-        s = jnp.where(col <= pos, s, _NEG_INF)
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
+        s = jax.lax.dot_general(
+            q, kj, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=prec)                           # (groups, gp, t)
+        # which token and which packed head a column is, which a row
+        # wants: the same for every group
+        t = pages * rows
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, gp, t), 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, gp, t), 2)
+        div, rem = jax.lax.div, jax.lax.rem  # of non-negative ints
+        token = (j * pages + div(col, rows)) * page_size \
+            + rem(col, rp) * fold + rem(div(row, g), fold)
+        seen = (token <= pos) \
+            & (rem(div(col, rp), pack) == div(row, g * fold))
+
+        def by_column(sc_ref):
+            """(groups, 1, t): the scale of each column's page and
+            head, built from SMEM scalars; past the length the last
+            page's, never one of a page the slot does not own."""
+            unit = div(col[:, :1], rp)                # page, packed head
+            out = []
+            for gi in range(groups):
+                sc = jnp.zeros((1, 1, t), jnp.float32)
+                for u in range(pages * pack):
+                    pg = jnp.minimum(j * pages + u // pack, last)
+                    head = (hi * groups + gi) * pack + u % pack
+                    sc = jnp.where(unit == u, sc_ref[bi, pg, head], sc)
+                out.append(sc)
+            return jnp.concatenate(out, axis=0)
+
+        if quantized:
+            s = s * by_column(ks_ref)
+        s = jnp.where(seen, s, _NEG_INF)
+        m = m_ref[0, :, :, :1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True,
-                                    dtype=jnp.float32)
-        pv = jax.lax.dot_general(
-            p.astype(vj.dtype), vj, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
+        l_new = l_ref[0, :, :, :1] * alpha \
+            + jnp.sum(p, axis=-1, keepdims=True, dtype=jnp.float32)
         if quantized:
-            pv = pv * vscale_ref[bi, j, hi]
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _store():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[:, :1], 1e-30))
-
-
-def _decode_kernel_noquant(bt_ref, lens_ref, *rest, **kw):
-    """Unquantized pools carry no scale operands: splice None refs into
-    _decode_kernel's scale slots."""
-    return _decode_kernel(bt_ref, lens_ref, None, None, *rest,
-                          quantized=False, **kw)
+            # the V scale is per column of p, so it rides p into the
+            # dot (f32 x f32, as the K scale rides s out of one)
+            p = p * by_column(vs_ref)
+        pv = jax.lax.dot_general(
+            p.astype(vj.dtype), vj, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=prec)                           # (groups, gp, lanes)
+        acc_ref[0] = acc_ref[0] * alpha + pv
+        m_ref[0] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[0] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
@@ -201,7 +408,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
         k ~= k_pool * k_scale[page, head, None, None].
     block_tables: (b, max_pages) int32 — physical page of each logical
         page per slot (engine convention: 0 = never-written trash page
-        for unallocated entries; those columns are masked anyway).
+        for unallocated entries; pages past the length are not read).
     lens: (b,) int32 — this query's position (its k/v must already be
         scattered there); columns c <= lens[i] are attended.
 
@@ -209,45 +416,55 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
     (call it, or gate on `decode_shape_problems`, before forcing this
     path — same contract as ring_attention_local(use_flash=True)).
     """
-    b, hq, d = q.shape
-    num_pages, hk, page_size, _ = k_pool.shape
-    mp = block_tables.shape[1]
+    _, hq, d = q.shape
+    _, hk, page_size, _ = k_pool.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    quantized = k_pool.dtype == jnp.int8
-    if quantized and (k_scale is None or v_scale is None):
+    if k_pool.dtype == jnp.int8 and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools require k_scale and v_scale "
                          "(num_pages, hk) f32")
     check_decode_shapes(hq, hk, d, page_size, interpret,
                         kv_dtype=k_pool.dtype)
+    return _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
+                   sm_scale=sm_scale, interpret=interpret)
 
-    g = hq // hk
-    # fold query heads sharing a kv head into the q tile's rows, padded
-    # to a full sublane tile so the compiled kernel never sees a g < 8
-    # second-minor dim (padded rows are zeros; their output is sliced
-    # off — they cost nothing real at these sizes)
-    gp = max(8, -(-g // 8) * 8) if not interpret else g
-    qf = q.reshape(b, hk, g, d)
-    if gp != g:
-        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+
+# jitted on its own: a model calls this once a layer, and a caller's
+# trace then holds ONE traced and lowered kernel that every layer calls,
+# not one a layer (a server's start is mostly tracing its tick)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale, *,
+            sm_scale, interpret):
+    b, hq, d = q.shape
+    num_pages, hk, page_size, _ = k_pool.shape
+    mp = block_tables.shape[1]
+    quantized = k_pool.dtype == jnp.int8
+    plan = decode_plan(hq, hk, d, page_size, mp, k_pool.dtype, slots=b)
+    fold, pack = plan.fold, plan.pack
+    g, lanes = hq // hk, fold * d
+    groups = plan.heads // pack
+    n = pack * fold * g                  # rows that hold a query
+    # padded to whole sublane tiles so the compiled kernel never sees a
+    # ragged second-minor dim (padded rows are zeros and are sliced off)
+    gp = n if interpret else _q_rows(g, fold, pack)
+
+    # row (a, r, i) of a group's q tile: query head i of packed head a,
+    # in the lanes of folded token r. log2e rides the softmax scale into
+    # q once, here: exponentials in the body are exp2
+    # (flash_attention.py convention)
+    qs = (q * jnp.asarray(sm_scale * _LOG2E, q.dtype)).reshape(
+        b, hk // pack, pack, 1, g, 1, d)
+    qf = (qs * jnp.eye(fold, dtype=q.dtype).reshape(fold, 1, fold, 1)
+          ).reshape(b, hk // pack, n, lanes)
+    if gp != n:
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, gp - n), (0, 0)))
+
+    def rows_of(pool):
+        return pool.reshape(num_pages, hk // pack,
+                            pack * page_size // fold, lanes)
 
     bt = block_tables.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
-
-    def clamp(j, bt_sp, lens_sp, bi):
-        # revisit the last needed page above the window: a repeated
-        # block index elides the DMA (flash _ki_clamp trick), and the
-        # clamped entry is always an ALLOCATED page of this slot
-        return bt_sp[bi, jnp.minimum(j, lens_sp[bi] // page_size)]
-
-    kv_spec = pl.BlockSpec(
-        (1, 1, page_size, d),
-        lambda bi, hi, j, bt_sp, lens_sp, *_sc: (
-            clamp(j, bt_sp, lens_sp, bi), hi, 0, 0))
-    q_spec = pl.BlockSpec(
-        (1, 1, gp, d),
-        lambda bi, hi, j, *_sp: (bi, hi, 0, 0))
-
     scalar_args = [bt, lens]
     if quantized:
         # gather scales per SLOT here (tiny: (b, mp, hk)) so the SMEM
@@ -255,29 +472,49 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
         # planes would outgrow SMEM at production page counts
         scalar_args += [k_scale[bt].astype(jnp.float32),
                         v_scale[bt].astype(jnp.float32)]
-        kernel = functools.partial(_decode_kernel, page_size=page_size,
-                                   sm_scale=sm_scale, quantized=True)
-    else:
-        kernel = functools.partial(_decode_kernel_noquant,
-                                   page_size=page_size,
-                                   sm_scale=sm_scale)
 
+    def block(width):
+        return pl.BlockSpec((1, groups, gp, width),
+                            lambda bi, hi, j, *_sp: (bi, hi, 0, 0))
+
+    def out(width):
+        return jax.ShapeDtypeStruct((b, hk // pack, gp, width),
+                                    jnp.float32)
+
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    window = (2, groups, plan.pages * pack * page_size // fold, lanes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
-        grid=(b, hk, mp),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((gp, 8), jnp.float32),
-                        pltpu.VMEM((gp, 8), jnp.float32),
-                        pltpu.VMEM((gp, d), jnp.float32)],
+        grid=plan.grid,
+        in_specs=[block(lanes), pool_spec, pool_spec],
+        out_specs=[block(lanes), block(128), block(128)],
+        scratch_shapes=[pltpu.VMEM(window, k_pool.dtype),
+                        pltpu.VMEM(window, v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
-    out = pl.pallas_call(
-        kernel,
+    acc, m, l = pl.pallas_call(
+        functools.partial(_decode_kernel, page_size=page_size, plan=plan,
+                          g=g, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, gp, d), jnp.float32),
+        out_shape=[out(lanes), out(128), out(128)],
+        # in order: a step starts the copies the next one waits for
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
         name="paged_attention_decode",
-    )(*scalar_args, qf, k_pool, v_pool)
-    return out[:, :, :g, :].reshape(b, hq, d)
+    )(*scalar_args, qf, rows_of(k_pool), rows_of(v_pool))
+
+    # merge the `fold` partial softmaxes of each query head: row
+    # (a, r, i) has its numerator in the lanes of token r
+    def per_row(x):
+        return x[:, :, :n, 0].reshape(b, hk, fold, g)
+    acc = acc[:, :, :n].reshape(b, hk, fold, g, fold, d)
+    acc = jnp.stack([acc[:, :, r, :, r] for r in range(fold)], axis=2)
+    m, l = per_row(m), per_row(l)
+    w = jnp.exp2(m - jnp.max(m, axis=2, keepdims=True))
+    o = jnp.sum(acc * w[..., None], axis=2) \
+        / jnp.maximum(jnp.sum(l * w, axis=2), 1e-30)[..., None]
+    # no column is visible to a negative position
+    o = jnp.where((lens >= 0)[:, None, None, None], o, 0.0)
+    return o.reshape(b, hq, d)

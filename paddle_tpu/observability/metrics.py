@@ -499,6 +499,9 @@ METRICS = {
     "engine.tick_host_seconds": ("gauge", "tick wall seconds spent "
                                  "outside the waits on the device "
                                  "(readback, prefill), cumulative"),
+    "engine.decode_grid_steps": ("gauge", "grid steps of one paged-"
+                                 "decode kernel call (engine."
+                                 "decode_plan; 0 on the jnp path)"),
 }
 
 

@@ -479,6 +479,7 @@ def test_serving_generate_forwards_session():
         assert "conv-7" in eng._sessions
     finally:
         server.stop()
+        eng.stop()      # /generate started the engine's ticker
 
 
 class _Tok:

@@ -22,7 +22,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.kernels.paged_attention import (check_decode_shapes,
+from paddle_tpu.kernels.paged_attention import (_VMEM_BUDGET,
+                                                check_decode_shapes,
+                                                decode_plan,
                                                 decode_shape_problems,
                                                 paged_decode_attention)
 
@@ -63,6 +65,13 @@ def _oracle(q, kd, vd, bt, lens):
     return out
 
 
+def _quant(pool):
+    s = np.abs(pool).max(axis=(2, 3)) / 127.0            # (npages, hk)
+    qp = np.clip(np.round(pool / np.maximum(s[:, :, None, None], 1e-30)),
+                 -127, 127).astype(np.int8)
+    return qp, s.astype(np.float32)
+
+
 def test_kernel_matches_dense_oracle_f32():
     q, kp, vp, bt, lens = _setup()
     out = np.asarray(paged_decode_attention(
@@ -86,15 +95,8 @@ def test_kernel_no_gqa_and_len_zero():
 
 def test_kernel_int8_dequant_in_kloop():
     q, kp, vp, bt, lens = _setup(seed=3)
-
-    def quant(pool):
-        s = np.abs(pool).max(axis=(2, 3)) / 127.0    # (npages, hk)
-        qp = np.clip(np.round(pool / np.maximum(
-            s[:, :, None, None], 1e-30)), -127, 127).astype(np.int8)
-        return qp, s.astype(np.float32)
-
-    kq, ks = quant(kp)
-    vq, vs = quant(vp)
+    kq, ks = _quant(kp)
+    vq, vs = _quant(vp)
     out = np.asarray(paged_decode_attention(
         jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
         jnp.asarray(bt), jnp.asarray(lens),
@@ -167,3 +169,184 @@ def test_kernel_under_jit_and_scan():
     ref = _oracle(q, kp, vp, bt, lens)
     for t in range(2):
         np.testing.assert_allclose(outs[t], ref, rtol=2e-5, atol=2e-5)
+
+
+# -- every kv head of 128 keys a grid step (PR 26) ---------------------------
+
+def _paged(b, hq, hk, d, ps, mp, lens, dtype="float32", seed=0):
+    """Pools whose slots own the pages their length reaches and point
+    every later block-table entry at page 0, the trash page, which is
+    poisoned: a kernel that attends (or multiplies by 0) what lies past
+    the length shows it. Returns the kernel's arguments and the oracle's
+    (rounded through `dtype` and dequantized)."""
+    rng = np.random.default_rng(seed)
+    npages = b * mp + 1
+    kp = rng.normal(size=(npages, hk, ps, d)).astype(np.float32)
+    vp = rng.normal(size=(npages, hk, ps, d)).astype(np.float32)
+    q = rng.normal(size=(b, hq, d)).astype(np.float32)
+    lens = np.asarray(lens, np.int32)
+    bt = 1 + np.arange(b * mp, dtype=np.int32).reshape(b, mp)
+    for i in range(b):
+        bt[i, lens[i] // ps + 1:] = 0
+    scales = {}
+    if dtype == "int8":
+        kq, ks = _quant(kp)
+        vq, vs = _quant(vp)
+        kd = kq.astype(np.float32) * ks[:, :, None, None]
+        vd = vq.astype(np.float32) * vs[:, :, None, None]
+        kq[0], vq[0] = 127, -127
+        ks[0], vs[0] = np.nan, np.inf
+        args = [jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq)]
+        scales = dict(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+        qd = q
+    else:
+        cast = lambda x: jnp.asarray(x).astype(dtype)       # noqa: E731
+        back = lambda x: np.array(x.astype(jnp.float32))    # noqa: E731
+        kp[0], vp[0] = np.nan, 1e30
+        args = [cast(q), cast(kp), cast(vp)]
+        qd, kd, vd = (back(a) for a in args)
+    kd[0] = vd[0] = 0.0         # the oracle slices past pages off anyway
+    args += [jnp.asarray(bt), jnp.asarray(lens)]
+    return args, scales, (qd, kd, vd, bt, lens)
+
+
+_TOL = {"float32": 2e-5, "int8": 1e-4, "bfloat16": 2e-2}
+
+_GEOMETRIES = {
+    # the serve cell's heads: full multi-head, d 64, page 16
+    "cell_f32": dict(b=3, hq=32, hk=32, d=64, ps=16, mp=13,
+                     lens=[5, 100, 207]),
+    "cell_bf16": dict(b=3, hq=32, hk=32, d=64, ps=16, mp=13,
+                      lens=[77, 128, 191], dtype="bfloat16"),
+    # Mistral's: 4 query heads a kv head, d 128
+    "gqa4_d128": dict(b=2, hq=16, hk=4, d=128, ps=16, mp=9,
+                      lens=[143, 17]),
+    "gqa4_d128_bf16": dict(b=2, hq=16, hk=4, d=128, ps=16, mp=9,
+                           lens=[130, 64], dtype="bfloat16"),
+    "int8_page32": dict(b=3, hq=8, hk=4, d=64, ps=32, mp=5,
+                        lens=[0, 70, 159], dtype="int8"),
+    "int8_page32_d128": dict(b=2, hq=8, hk=2, d=128, ps=32, mp=7,
+                             lens=[33, 223], dtype="int8"),
+    # page boundaries on both sides, and a full table
+    "page_edges": dict(b=5, hq=4, hk=4, d=64, ps=16, mp=8,
+                       lens=[0, 15, 16, 31, 127]),
+    # a table the pages-a-step do not divide
+    "pages_5": dict(b=2, hq=4, hk=2, d=64, ps=32, mp=5, lens=[159, 40]),
+    "pages_7": dict(b=2, hq=4, hk=2, d=64, ps=32, mp=7, lens=[223, 128]),
+    "pages_11": dict(b=2, hq=4, hk=2, d=64, ps=16, mp=11, lens=[175, 129]),
+    "pages_13_int8": dict(b=2, hq=4, hk=2, d=64, ps=32, mp=13,
+                          lens=[415, 384], dtype="int8"),
+    # more heads than one step's VMEM budget holds: a head-block axis
+    "head_blocks": dict(b=2, hq=64, hk=64, d=128, ps=16, mp=9,
+                        lens=[143, 3]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_GEOMETRIES))
+def test_kernel_geometries_match_dense_oracle(name):
+    geo = dict(_GEOMETRIES[name])
+    dtype = geo.setdefault("dtype", "float32")
+    args, scales, ref = _paged(**geo)
+    out = np.asarray(paged_decode_attention(*args, **scales,
+                                            interpret=True))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, _oracle(*ref), rtol=_TOL[dtype],
+                               atol=_TOL[dtype])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_kernel_blocks_under_jit_and_scan(dtype):
+    # two blocks of pages a slot, as the engine's tick runs it
+    args, scales, ref = _paged(b=2, hq=8, hk=4, d=64, ps=32, mp=6,
+                               lens=[130, 31], dtype=dtype)
+
+    @jax.jit
+    def run(*a):
+        def step(carry, _):
+            return carry, paged_decode_attention(*a, **scales,
+                                                 interpret=True)
+        return jax.lax.scan(step, 0, jnp.arange(2))[1]
+
+    outs = np.asarray(run(*args))
+    want = _oracle(*ref)
+    for t in range(2):
+        np.testing.assert_allclose(outs[t], want, rtol=_TOL[dtype],
+                                   atol=_TOL[dtype])
+
+
+def test_negative_position_attends_nothing():
+    args, scales, _ = _paged(b=2, hq=4, hk=2, d=64, ps=16, mp=4,
+                             lens=[20, 3])
+    args[-1] = jnp.asarray([20, -1], jnp.int32)
+    out = np.asarray(paged_decode_attention(*args, interpret=True))
+    assert np.isfinite(out).all() and not out[1].any() and out[0].any()
+
+
+_PLANS = {
+    # (hq, hk, d, page, pages a slot, pool dtype, slots)
+    "cell": (32, 32, 64, 16, 48, "bfloat16", 16),
+    "mistral": (32, 8, 128, 16, 64, "bfloat16", 16),
+    "int8": (32, 8, 128, 32, 40, "int8", 16),
+    "cell_int8": (32, 32, 64, 32, 24, "int8", 16),
+    "wide_f32": (64, 64, 128, 16, 64, "float32", 8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PLANS))
+def test_decode_plan_from_shapes(name):
+    hq, hk, d, ps, mp, dt, slots = _PLANS[name]
+    plan = decode_plan(hq, hk, d, ps, mp, dt, slots=slots)
+    assert 0 < plan.vmem_bytes <= _VMEM_BUDGET
+    assert hk % plan.heads == 0 and 1 <= plan.pages <= mp
+    assert plan.grid == (slots, hk // plan.heads, -(-mp // plan.pages))
+    assert plan.grid_steps == slots * (hk // plan.heads) \
+        * -(-mp // plan.pages)
+    # a step is a lane tile of keys where the pages allow it
+    assert plan.pages * ps == 128 or plan.heads < hk
+    # a page is moved as whole tiles: 128 lanes, the dtype's sublanes
+    sub = {"float32": 8, "bfloat16": 16, "int8": 32}[dt]
+    assert plan.fold * d % 128 == 0
+    assert plan.pack * (ps // plan.fold) % sub == 0
+    assert plan.heads % plan.pack == 0
+    if name == "cell":
+        # 24,576 steps of one 16 x 64 tile before (slots x heads x pages)
+        assert plan.heads == 32 and plan.grid_steps <= 768
+        assert plan.grid_steps == 96
+        # two tokens a 128-lane row, two heads a bf16 sublane tile
+        assert (plan.fold, plan.pack) == (2, 2)
+    if name == "mistral":
+        assert (plan.heads, plan.fold, plan.pack) == (8, 1, 1)
+    if name == "wide_f32":
+        assert plan.heads < hk          # the budget splits the heads
+
+
+def test_decode_plan_reads_no_knob(monkeypatch):
+    """Block sizes come from the shapes: the same plan whatever the
+    environment says, and no argument selects one."""
+    import inspect
+    want = decode_plan(32, 32, 64, 16, 48, "bfloat16", slots=16)
+    for var in ("PADDLE_TPU_AUTOTUNE", "PADDLE_TPU_DECODE_PAGES",
+                "PADDLE_TPU_DECODE_HEADS"):
+        monkeypatch.setenv(var, "1")
+    assert decode_plan(32, 32, 64, 16, 48, "bfloat16", slots=16) == want
+    assert set(inspect.signature(paged_decode_attention).parameters) == {
+        "q", "k_pool", "v_pool", "block_tables", "lens", "k_scale",
+        "v_scale", "sm_scale", "interpret"}
+
+
+def test_shape_contract_names_what_cannot_be_tiled():
+    """On the chip a page moves as whole (sublane, 128) tiles: a head
+    width that neither divides nor is a multiple of 128, and kv heads
+    that cannot pack a sublane tile between them, are named."""
+    with pytest.raises(ValueError, match=r"multiple of 128"):
+        check_decode_shapes(8, 8, 96, 16, interpret=False,
+                            kv_dtype="bfloat16")
+    # d 64 at page 16 in bf16: 8 rows a head, so heads pack in pairs
+    assert not decode_shape_problems(8, 8, 64, 16, interpret=False,
+                                     kv_dtype="bfloat16")
+    with pytest.raises(ValueError, match=r"hk=3 heads of 8 rows"):
+        check_decode_shapes(3, 3, 64, 16, interpret=False,
+                            kv_dtype="bfloat16")
+    # interpret mode has no tiles
+    assert not decode_shape_problems(3, 3, 96, 16, interpret=True,
+                                     kv_dtype="bfloat16")

@@ -909,6 +909,39 @@ def test_decode_kernel_tick_counter():
             path="jnp") == 0
 
 
+@pytest.mark.parametrize("kernel,kv_dtype", [
+    ("pallas", None), ("pallas", "int8"), ("jnp", None)])
+def test_engine_reports_its_decode_plan(kernel, kv_dtype):
+    """engine.decode_plan is the kernel's own account of one call at
+    this geometry (heads and pages a grid step, grid, VMEM bytes), the
+    gauge engine.decode_grid_steps its step count; both are counts from
+    shapes, so a CPU box reports them."""
+    from paddle_tpu.kernels.paged_attention import decode_plan
+    from paddle_tpu.observability.metrics import MetricsRegistry
+    model = _model()
+    cfg = model.config
+    eng = PagedKVEngine(model, max_slots=3, page_size=4, num_pages=32,
+                        max_pages_per_slot=7, steps_per_tick=2,
+                        kernel=kernel, kv_dtype=kv_dtype)
+    reg = MetricsRegistry()
+    eng.export_metrics(reg)
+    if kernel == "jnp":
+        assert eng.decode_plan is None
+        assert reg.gauge("engine.decode_grid_steps").value() == 0
+        return
+    hk = cfg.num_key_value_heads or cfg.num_attention_heads
+    plan = eng.decode_plan
+    assert plan == decode_plan(
+        cfg.num_attention_heads, hk,
+        cfg.hidden_size // cfg.num_attention_heads, 4, 7,
+        eng.pools[0][0].dtype, slots=3)
+    assert plan.grid[0] == 3 and hk % plan.heads == 0
+    assert plan.grid_steps == plan.grid[0] * plan.grid[1] * plan.grid[2]
+    # fewer, larger steps than one (head, page) tile each
+    assert plan.grid_steps < 3 * hk * 7
+    assert reg.gauge("engine.decode_grid_steps").value() == plan.grid_steps
+
+
 @pytest.mark.quick
 def test_engine_export_metrics():
     """export_metrics publishes the stats dict as catalogued gauges

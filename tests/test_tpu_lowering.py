@@ -23,6 +23,7 @@ chip_smoke.py's: TinyLlama widths, depth cut to one layer for the
 whole-step rows.
 """
 import ast
+import dataclasses
 import glob
 import sys
 import os
@@ -117,6 +118,58 @@ def test_kernel_lowers_and_compiles_for_tpu(as_tpu, described_v5e, name,
     lowered = jax.jit(fn).lower(*shapes)
     assert _calls(lowered) >= 1
     lowered.compile()       # Mosaic: VMEM limits, layouts
+
+
+# the paged-decode kernel at the geometries it serves, beside chip_smoke's
+# TinyLlama heads: the serve cell's (SmolLM2-1.7B: 16 slots, 32 / 32 heads
+# of 64, 48 pages of 16, and the same heads on int8 pages of 32) and
+# Mistral-7B's (8 kv heads of 128, 4 query heads each). Mosaic's VMEM
+# and tiling limits are met here, before chip time is spent
+_SERVE_CELL = dataclasses.replace(FULL, heads=32, kv_heads=32,
+                                  decode_slots=16, decode_tokens=768)
+_MISTRAL = dataclasses.replace(FULL, hidden=4096, heads=32, kv_heads=8,
+                               decode_slots=16, decode_tokens=1024)
+# 64 kv heads of 128: more than one step's VMEM budget, so a head-block axis
+_WIDE = dataclasses.replace(FULL, hidden=8192, heads=64, kv_heads=64,
+                            decode_slots=4, decode_tokens=256)
+_PAGED_GEOMETRIES = [
+    ("wide_bf16_head_blocks", _WIDE, False),
+    ("serve_cell_bf16_page16", _SERVE_CELL, False),
+    ("serve_cell_int8_page32", _SERVE_CELL, True),
+    ("mistral_bf16_page16", _MISTRAL, False),
+    ("mistral_int8_page32", _MISTRAL, True),
+]
+
+
+@pytest.mark.parametrize("name,size,int8", _PAGED_GEOMETRIES,
+                         ids=[g[0] for g in _PAGED_GEOMETRIES])
+def test_paged_decode_compiles_at_serving_geometries(as_tpu, described_v5e,
+                                                     name, size, int8):
+    from paddle_tpu.kernels.paged_attention import decode_plan
+    fn, args, _ = chip_smoke._paged_case(size, jnp.dtype("bfloat16"), False,
+                                         _ShapeOnlyRng(), int8=int8)
+    shapes = [None if a is None else jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=described_v5e) for a in args]
+    q, pool, bt = args[0], args[1], args[3]
+    plan = decode_plan(q.shape[1], pool.shape[1], q.shape[2], pool.shape[2],
+                       bt.shape[1], pool.dtype, slots=q.shape[0])
+    if size is _SERVE_CELL and not int8:
+        assert bt.shape == (16, 48) and plan.grid_steps <= 768
+    if size is _WIDE:
+        assert plan.grid[1] > 1
+    if described_v5e is None:
+        lowered = _lower_tpu(jax.jit(fn), *shapes)
+    else:
+        lowered = jax.jit(fn).lower(*shapes)
+    assert _calls(lowered) == 1
+    assert _kernel_names(lowered) == {"paged_attention_decode"}
+    # what the benchmark's patterns find the tick by: the block table is
+    # the custom call's first operand, two-dimensional
+    call = re.search(r"tpu_custom_call.*", lowered.as_text()).group(0)
+    assert re.search(rf"\(tensor<{bt.shape[0]}x{bt.shape[1]}xi32>", call), \
+        call[:300]
+    if described_v5e is not None:
+        lowered.compile()       # Mosaic: VMEM limits, tiling, layouts
 
 
 _NAMES = {
@@ -231,21 +284,28 @@ def test_mesh_train_step_lowers_per_shard(as_tpu):
         f"xbf16>" in text
 
 
-@pytest.mark.parametrize("kv_dtype,page", [(None, 16), ("int8", 32)])
-def test_engine_programs_lower_for_tpu(as_tpu, kv_dtype, page):
+@pytest.mark.parametrize("size,b,mp,kv_dtype,page", [
+    (FULL, FULL.slots, 40, None, 16),
+    (FULL, FULL.slots, 40, "int8", 32),
+    (_SERVE_CELL, 16, 48, None, 16),    # the serve cell's engine
+], ids=["smoke_bf16", "smoke_int8", "serve_cell_bf16"])
+def test_engine_programs_lower_for_tpu(as_tpu, size, b, mp, kv_dtype, page):
     import paddle_tpu
     from paddle_tpu.inference import PagedKVEngine
     from paddle_tpu.models import LlamaForCausalLM
     layers = 2
     paddle_tpu.seed(0)
-    model = LlamaForCausalLM(chip_smoke.llama_config(FULL, layers))
+    model = LlamaForCausalLM(chip_smoke.llama_config(size, layers))
     model = paddle_tpu.amp.decorate(models=model, level="O2",
                                     dtype="bfloat16")
-    b, mp = FULL.slots, 40
     eng = PagedKVEngine(model, max_slots=b, page_size=page,
                         num_pages=b * mp + 1, max_pages_per_slot=mp,
                         kernel=None, kv_dtype=kv_dtype)
     assert eng.decode_kernel == "pallas"
+    assert eng.decode_plan.grid[0] == b
+    if size is _SERVE_CELL:
+        # 16 x 32 x 48 steps of one (16, 64) tile before this plan
+        assert eng.decode_plan.grid_steps <= 768
     pools = [a for kv in eng.pools for a in kv]
     z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
     key = np.asarray(jax.random.key_data(jax.random.key(0)))
@@ -253,7 +313,11 @@ def test_engine_programs_lower_for_tpu(as_tpu, kv_dtype, page):
     tick = eng._tick_fn(False)
     lowered = _lower_tpu(tick.func, *tick.args, z(b), z(b), z(b, dt=bool),
                          z(b), z(b, mp), z(b), key, pools)
-    assert _calls(lowered) == layers      # one decode kernel a layer
+    # one decode kernel a layer: the kernel is traced and lowered once,
+    # into a function of its own that every layer calls (XLA inlines it,
+    # so the compiled tick holds one custom call a layer)
+    assert _calls(lowered) == 1
+    assert len(re.findall(r"call @_decode\(", lowered.as_text())) == layers
     assert _kernel_names(lowered) == {"paged_attention_decode"}
     text = lowered.as_text(debug_info=True)
     for scope in ("kv_write", "paged_attn", "sample"):
