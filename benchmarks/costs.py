@@ -11,6 +11,18 @@ Conventions, the same for every configuration:
   not the scores flash's backward rebuilds, not the logits blockwise CE's
   backward rebuilds;
 - attention is causal: half of the S x S scores.
+
+A family that this file's dense-decoder arithmetic does not fit brings its own
+in its builder (`builders/<family>.py`: `costs`, an object with
+`train_flops_per_token(cfg, seq)` and / or a dict `KERNEL_COSTS` of
+`name -> fn(cfg, sizes, window) -> (flops, bytes)`); `mfu` and `least_seconds`
+take it as `own`, ask it first and fall back to this file. The conventions
+above bind a builder's functions too, and two more: everything comes from the
+configuration's sizes and the traffic, never from the program; a cut that
+stands for one chip's share of a larger layout (some of the experts, a slice
+of the vocabulary) counts what this chip computes. A builder supplies
+operations and bytes, never a peak or a time: `PEAKS` and `_least_s` are here
+alone.
 """
 from __future__ import annotations
 
@@ -77,9 +89,11 @@ def train_flops_per_token(cfg, seq):
     return 6.0 * matmul_params(cfg) + attention_flops_per_token(cfg, seq)
 
 
-def mfu(cfg, seq, tokens_per_s_per_chip, device_kind):
-    """Model FLOP/s utilization of one chip, in percent."""
-    return (100.0 * train_flops_per_token(cfg, seq) * tokens_per_s_per_chip
+def mfu(cfg, seq, tokens_per_s_per_chip, device_kind, own=None):
+    """Model FLOP/s utilization of one chip, in percent; the FLOPs a token
+    are `own`'s (a builder's `costs`) where it counts them."""
+    per_token = getattr(own, "train_flops_per_token", train_flops_per_token)
+    return (100.0 * per_token(cfg, seq) * tokens_per_s_per_chip
             / peaks(device_kind)["bf16_flops"])
 
 
@@ -133,7 +147,9 @@ KERNEL_COSTS = {"flash_step": flash_step, "ce_step": ce_step,
                 "decode_step": decode_step}
 
 
-def least_seconds(cost, cfg, sizes, window, device_kind):
-    """(seconds, 'compute' | 'memory') the chip could not beat."""
-    flops, bytes_ = KERNEL_COSTS[cost](cfg, sizes, window)
+def least_seconds(cost, cfg, sizes, window, device_kind, own=None):
+    """(seconds, 'compute' | 'memory') the chip could not beat; the cost
+    is `own`'s (a builder's `costs`) where its `KERNEL_COSTS` names it."""
+    fn = getattr(own, "KERNEL_COSTS", {}).get(cost) or KERNEL_COSTS[cost]
+    flops, bytes_ = fn(cfg, sizes, window)
     return _least_s(flops, bytes_, device_kind)

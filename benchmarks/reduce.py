@@ -6,8 +6,9 @@ into plain lists (`planes -> lines -> (name, start_ns, duration_ns)`), and
 lines. Ops may nest (a `while` holds the ops of its body), so every share
 is taken over *self* time: an op's duration less what its children cover.
 
-The readers at the bottom are the whole vocabulary of `metrics/*.json`.
-Each takes the run's context and the arguments its file gives, and returns
+The readers at the bottom, with the four of `spans.py` over the program's
+own spans and scopes (`run.READERS` joins them), are the whole vocabulary of
+`metrics/*.json`. Each takes the run's context and the arguments its file gives, and returns
 a number or None; None leaves the metric out of the result line. A pattern
 is a regular expression over the event text with layouts (`{1,0:T(8,128)}`)
 stripped and the cell's sizes substituted for `{B}`, `{S}`, ... first.
@@ -195,10 +196,6 @@ class Trace:
         self.start = min(s for s, _ in spans) if spans else 0.0
         self.end = max(e for _, e in spans) if spans else 0.0
 
-    @classmethod
-    def from_file(cls, path):
-        return cls(load_xplane(path))
-
     @property
     def window_s(self):
         return (self.end - self.start) / 1e9
@@ -293,7 +290,8 @@ def op_kind(text):
 
 # -- readers ------------------------------------------------------------
 # ctx: {"trace": Trace | None, "sizes": {...}, "window": {...},
-#       "config": {...}, "device_kind": str}
+#       "config": {...}, "device_kind": str, "builder": the cell's builder
+#       module, "scopes": [(scope path, self ns)] | None}
 
 def _pat(ctx, pattern):
     return substitute(pattern, ctx["sizes"]) if pattern else None
@@ -345,17 +343,27 @@ def module_share(ctx, name=None, holds=None, lacks=None):
     return tr.mean_over_devices(one)
 
 
+def _own_costs(ctx):
+    """The yardstick the cell's builder brings for its family (`costs`: its
+    `train_flops_per_token` and `KERNEL_COSTS`), if it brings one; what it
+    leaves out is `costs.py`'s. Operations and bytes only: the peaks are
+    `costs.py`'s alone."""
+    return getattr(ctx.get("builder"), "costs", None)
+
+
 def roofline(ctx, cost, module, pattern=None, steps_per_module=1):
-    """Least time by shapes (`costs.py`) over measured time, %: the median
-    over the selected programs, so that one cut at the trace's edge does
-    not move it. Measured is the matching ops' self time inside the
-    program or, with no pattern, the program's own duration."""
+    """Least time by shapes (the builder's `costs` or `costs.py`) over
+    measured time, %: the median over the selected programs, so that one
+    cut at the trace's edge does not move it. Measured is the matching ops'
+    self time inside the program or, with no pattern, the program's own
+    duration."""
     tr = ctx["trace"]
     if tr is None or ctx["device_kind"] is None:
         return None
     from benchmarks import costs
     least, bound = costs.least_seconds(
-        cost, ctx["config"], ctx["sizes"], ctx["window"], ctx["device_kind"])
+        cost, ctx["config"], ctx["sizes"], ctx["window"], ctx["device_kind"],
+        own=_own_costs(ctx))
     steps = ctx["sizes"][steps_per_module] \
         if isinstance(steps_per_module, str) else steps_per_module
     pattern = _pat(ctx, pattern)
@@ -426,13 +434,13 @@ def stall_share(ctx):
 
 
 def mfu(ctx):
-    """`costs.py` FLOPs a token x the window's tokens/s/chip over the
-    chip's bf16 peak, %."""
+    """FLOPs a token (the builder's `costs` or `costs.py`) x the window's
+    tokens/s/chip over the chip's bf16 peak, %."""
     from benchmarks import costs
     if ctx["device_kind"] is None:
         return None
     return costs.mfu(ctx["config"], ctx["sizes"]["S"], ctx["window"]["rate"],
-                     ctx["device_kind"])
+                     ctx["device_kind"], own=_own_costs(ctx))
 
 
 READERS = {f.__name__: f for f in (

@@ -5,8 +5,9 @@
 
 The cell's configuration, traffic mix and per-layer metrics are data files
 found by the names BENCHMARK.json gives (README.md). The last line of stdout
-is the result: `correct`, `attempted`, `failed`, `metrics`, `device` and,
-traced, `breakdown`. Every other print is on an earlier line. Without a TPU,
+is the result: `correct`, `attempted`, `failed`, `metrics`, `device`, traced
+also `breakdown`, and last `compared` (what `correct` held against which
+limit). Every other print is on an earlier line. Without a TPU,
 or with fewer chips than the cell asks for, the run ends non-zero and prints
 no result; `--rehearse` (never passed by the driver) shrinks the cell by
 `rehearse.json` and runs it on the CPU to prove the control flow, and its
@@ -30,6 +31,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+from benchmarks import reduce, spans    # noqa: E402
+
+# every reader a metric file can name
+READERS = {**reduce.READERS, **spans.READERS}
 
 
 def log(*parts):
@@ -113,15 +119,18 @@ class Tracer:
             jax.profiler.stop_trace()
 
     def load(self):
-        from benchmarks import reduce
+        """(the trace, each device op's scope path and self time)."""
         try:
-            return reduce.Trace.from_file(reduce.find_xplane(self.dir))
+            planes, scoped = spans.load_xplane(reduce.find_xplane(self.dir))
+            return reduce.Trace(planes), spans.op_scopes(scoped)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
 
 def load_cell(name, rehearse):
-    """The cell's entry and the three kinds of files it names."""
+    """The cell's entry and the three kinds of files it names. A per-layer
+    metric is read in the cell when its file's `kinds` hold the traffic's
+    kind and its entry under `per_layer` lists the cell."""
     bench = load_json(ROOT, "BENCHMARK.json")
     entry = next((w for w in bench["workloads"] if w["name"] == name), None)
     if entry is None:
@@ -130,24 +139,29 @@ def load_cell(name, rehearse):
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
     config = load_json(ROOT, conf["file"])
     traffic = load_json(HERE, "traffic", entry["traffic"] + ".json")
+    builder = importlib.import_module("benchmarks.builders."
+                                      + config["builder"])
     if rehearse:
         small = load_json(HERE, "rehearse.json")
-        config.update(small["config"])
+        # a family whose keys rehearse.json does not know brings its own
+        config.update(builder.rehearse(config) if hasattr(builder, "rehearse")
+                      else small["config"])
         for key, val in small[traffic["kind"]].items():
             if isinstance(val, dict):
                 traffic[key].update(val)
             else:
                 traffic[key] = val
+    listed = {m["name"] for m in bench["per_layer"]
+              if name in m["workloads"]}
     metrics = []
     for fn in sorted(os.listdir(os.path.join(HERE, "metrics"))):
         m = load_json(HERE, "metrics", fn)
-        if traffic["kind"] in m["kinds"]:
+        if traffic["kind"] in m["kinds"] and m["name"] in listed:
             metrics.append(m)
     return {"name": name, "chips": entry["chips"], "config": config,
             "traffic": traffic, "metrics": metrics,
             "units": {m["name"]: m["unit"] for m in bench["end_to_end"]},
-            "builder": importlib.import_module(
-                "benchmarks.builders." + config["builder"])}
+            "builder": builder}
 
 
 def main():
@@ -221,17 +235,16 @@ def main():
         out["metrics"] = {k: {"value": v, "unit": cell["units"][k]}
                           for k, v in values.items()}
     else:
-        trace = cell["tracer"].load()
-        ctx = {"trace": trace, "window": res["window"],
-               "config": cell["config"],
+        trace, scopes = cell["tracer"].load()
+        ctx = {"trace": trace, "scopes": scopes, "window": res["window"],
+               "config": cell["config"], "builder": cell["builder"],
                # a rehearsal has no chip, so nothing is held against peaks
                "device_kind": None if args.rehearse else kind,
                "sizes": cell["builder"].sizes(cell["config"],
                                               cell["traffic"])}
-        from benchmarks import reduce
         out["metrics"] = {}
         for m in cell["metrics"]:
-            value = reduce.READERS[m["reader"]](ctx, **m.get("args", {}))
+            value = READERS[m["reader"]](ctx, **m.get("args", {}))
             if value is not None:       # nothing to read: left out, never 0
                 out["metrics"][m["name"]] = {"value": value,
                                              "unit": m["unit"]}
@@ -242,7 +255,14 @@ def main():
                         "window_s": trace.window_s, "busy_s": trace.busy_s,
                         "bounds": ctx.get("notes", {})})
     out["device"] = device
+    # what `correct` compared, each number beside its limit: the last key of
+    # the line and the last lines of stderr, which the driver's record keeps
+    out["compared"] = {k: {"value": v, "limit": limit}
+                       for k, (v, limit) in res["compared"].items()}
     print(json.dumps(out), flush=True)
+    for k, c in out["compared"].items():
+        print(f"[compared] {k} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -14,11 +14,17 @@ delivers `steps_per_tick` tokens to each live stream at once, so arrivals
 come in bursts. The end-to-end rate is every token that arrived after the
 window's first burst, up to its last, over the time between those two: whole
 bursts, so that a tick more or less at the window's edge does not move it.
+Only the two edges matter to it: the tokens of the first burst and the first
+arrival of the last. What silence ends a burst is read from the stamps alone
+(`burst_gap_s`): the widest hole among the silences between neighbouring
+arrivals, so the cut follows the tick's length, whatever a later PR makes of
+it, and reads no counter of the program.
 """
 from __future__ import annotations
 
 import http.client
 import json
+import math
 import threading
 import time
 
@@ -156,7 +162,8 @@ def check_against_reference(builder, model, cfg, eng, traffic, seed):
 
 
 def bursts(times, gap_s):
-    """[(first arrival, tokens)] of each burst of the sorted arrivals."""
+    """[(first arrival, tokens, last arrival)] of each burst of the sorted
+    arrivals: a silence longer than `gap_s` ends a burst."""
     out = []
     for t in times:
         if out and t - out[-1][2] <= gap_s:
@@ -164,7 +171,45 @@ def bursts(times, gap_s):
             out[-1][2] = t
         else:
             out.append([t, 1, t])
-    return [(a, n) for a, n, _last in out]
+    return [tuple(b) for b in out]
+
+
+def burst_gap_s(arrivals, tokens_per_tick):
+    """The silence that ends a burst, from the sorted stamps alone: the
+    silences between neighbouring arrivals are of two kinds, those inside a
+    burst (a tick's tokens written one after another) and those between two
+    bursts (the device at work), with a hole between the kinds; the gap is
+    the middle, by ratio, of the widest hole. Only cuts are looked at that
+    leave between half and three times as many bursts as the tokens make
+    full ticks (`tokens_per_tick` from the traffic mix: clients x steps a
+    tick; a prefill's first token may be a burst of its own, a tick with
+    idle slots hands out fewer), so the arrivals can never all merge into
+    one burst, whatever the tick's length, where a constant (100 ms until
+    PR 27) merges them all once ticks come closer than itself."""
+    silences = sorted((b - a for a, b in zip(arrivals, arrivals[1:])),
+                      reverse=True)
+    full = len(arrivals) / tokens_per_tick
+    lo, hi = max(2, int(full / 2)), min(len(silences), int(3 * full) + 1)
+    if lo >= hi:
+        raise RuntimeError(f"the window holds {len(arrivals)} tokens, "
+                           f"{full:.1f} ticks' worth: too few to report")
+    floor = 1e-6        # stamps closer than the clock tells apart
+    # cutting at the k widest silences leaves k + 1 bursts
+    k = max(range(lo, hi), key=lambda k: silences[k - 1]
+            / max(silences[k], floor))
+    return math.sqrt(silences[k - 1] * max(silences[k], floor))
+
+
+def rate_over_bursts(arrivals, gap_s):
+    """(tokens/s, the bursts): every token after the window's first burst,
+    up to its last, over the time between the two bursts' first arrivals.
+    A burst split or merged in the middle moves nothing; one at an edge
+    moves the count by at most a tick's tokens."""
+    bs = bursts(arrivals, gap_s)
+    if len(bs) < 3:
+        raise RuntimeError(f"the window holds {len(bs)} bursts of "
+                           f"{len(arrivals)} tokens: too few to report")
+    return sum(b[1] for b in bs[1:]) / (bs[-1][0] - bs[0][0]), bs
 
 
 def run(cell, args, clock, clog, log):
@@ -234,7 +279,9 @@ def run(cell, args, clock, clog, log):
 
         # -- the measured window ----------------------------------------
         mark = clog.mark()
-        stats0 = dict(eng.stats)
+        # what the family counts itself, read as the window opens and closes
+        counters = getattr(builder, "counters", lambda _eng: {})
+        stats0, counted0 = dict(eng.stats), counters(eng)
         t0 = time.perf_counter()
         if args.trace:
             time.sleep(args.seconds / 3)
@@ -242,7 +289,7 @@ def run(cell, args, clock, clog, log):
                 lambda: time.sleep(traffic["trace_seconds"]))
         time.sleep(max(0.0, t0 + args.seconds - time.perf_counter()))
         t1 = time.perf_counter()
-        stats1 = dict(eng.stats)
+        stats1, counted1 = dict(eng.stats), counters(eng)
         compiled_in_window = clog.since(mark)["programs"]
         closing.set()
         give_up = time.perf_counter() + 60
@@ -270,25 +317,35 @@ def run(cell, args, clock, clog, log):
             context_sum += sum(prompt_n + k for k, _t in inside)
             gaps += [b - a for (_i, a), (_j, b) in zip(inside, inside[1:])]
     arrivals.sort()
-    bs = bursts(arrivals, traffic["burst_gap_ms"] / 1000.0)
-    if len(bs) < 3 or len(gaps) < 20:
-        raise RuntimeError(f"the window holds {len(bs)} bursts and "
-                           f"{len(gaps)} gaps: too few to report")
-    rate = sum(n for _t, n in bs[1:]) / (bs[-1][0] - bs[0][0])
+    # every counter the engine keeps, and the builder's own; a key that is
+    # a maximum or a level (`tick_max_s`) has a difference that means nothing
+    delta = {k: v - stats0.get(k, 0) for k, v in stats1.items()
+             if isinstance(v, (int, float)) and not isinstance(v, bool)}
+    delta.update({k: v - counted0.get(k, 0) for k, v in counted1.items()})
+    if len(gaps) < 20:
+        raise RuntimeError(f"the window holds {len(gaps)} gaps between "
+                           f"tokens: too few to report")
+    gap_s = burst_gap_s(arrivals, clients_n * geo["steps_per_tick"])
+    rate, bs = rate_over_bursts(arrivals, gap_s)
     gap_p95 = float(np.percentile(gaps, 95)) * 1000.0
-    delta = {k: stats1[k] - stats0[k] for k in
-             ("ticks", "prefills", "tokens_out", "admitted", "finished",
-              "tick_s", "prefill_s")}
     steps = max(1, delta["ticks"]) * geo["steps_per_tick"]
+    widest = max((b - a for a, b in zip(arrivals, arrivals[1:])
+                  if b - a <= gap_s), default=0.0)
+    narrowest = min(b[0] - a[2] for a, b in zip(bs, bs[1:]))
     log("[window]", {"bursts": len(bs), "tokens": len(arrivals),
                      "tokens_per_s": rate,
                      "from_first_to_last_burst_s": bs[-1][0] - bs[0][0],
+                     # the hole's two edges: any gap between them cuts
+                     # the same bursts
+                     "burst_gap_ms": gap_s * 1000.0,
+                     "widest_gap_inside_a_burst_ms": widest * 1000.0,
+                     "narrowest_gap_between_bursts_ms": narrowest * 1000.0,
                      "gaps": len(gaps), "gap_p50_ms":
                      float(np.percentile(gaps, 50)) * 1000.0,
                      "gap_p95_ms": gap_p95, "window_s": t1 - t0,
                      "compiled_in_window": compiled_in_window})
     log("[bursts] ms after the window opened, tokens:",
-        [[round((t - t0) * 1000.0), n] for t, n in bs])
+        [[round((t - t0) * 1000.0), n] for t, n, _last in bs])
     log("[window] engine", delta, "| requests finished", finished,
         "failed", failed)
     tol = traffic["check"]["tolerance_sd"]
@@ -303,4 +360,6 @@ def run(cell, args, clock, clog, log):
         "attempted": finished + failed, "failed": failed, "setup_s": setup_s,
         "end_to_end": {"serve_tokens_per_s": rate},
         "window": window,
+        "compared": {"logit_gap_sd": (worst, tol), "failed": (failed, 0),
+                     "compiled_in_window": (compiled_in_window, 0)},
     }

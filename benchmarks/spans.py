@@ -22,11 +22,10 @@ cannot hide them.
 Each takes the run's context like the readers of `reduce.py` and returns a
 number or None (a parent commit has no such span: nothing to read, nothing
 raised). `span` and `outside` are regular expressions matched against whole
-names. **They are not registered**: `run.py` looks readers up in
-`reduce.READERS`, and a metric file that names an unknown reader ends every
-traced run, so a `benchmark` PR adds the line `READERS.update(spans.READERS)`
-as `reduce.py`'s last line, together with the metric files (PERF.md section 7
-lists them). Until then this file is run by hand over a kept trace:
+names. `run.py` joins them with `reduce.py`'s readers (`run.READERS`), so a
+metric file names them like any other, and loads every trace through
+`load_xplane` here, so `ctx["scopes"]` always holds each op's scope path. By
+hand over a kept trace:
 
     python3 benchmarks/spans.py <trace.xplane.pb[.gz]>
 
@@ -330,7 +329,6 @@ def load_xplane(path):
     for the first device's `XLA Ops` line a list of (scope path, name,
     start, duration) of the ops whose instruction has one (`metadata_stat`);
     an empty list where none has."""
-    from jax.profiler import ProfileData
     if path.endswith(".gz"):
         with gzip.open(path, "rb") as f, tempfile.NamedTemporaryFile(
                 suffix=".xplane.pb") as tmp:
@@ -339,19 +337,12 @@ def load_xplane(path):
             return load_xplane(tmp.name)
     with open(path, "rb") as f:
         path_of = metadata_stat(f.read())
-    planes, scoped = [], []
-    for plane in ProfileData.from_file(path).planes:
-        lines = []
-        first_device = plane.name.startswith("/device:") and not scoped
-        for line in plane.lines:
-            events = [(e.name, float(e.start_ns), float(e.duration_ns))
-                      for e in line.events]
-            if first_device and line.name == "XLA Ops":
-                scoped = [(path_of[e[0]], *e) for e in events
-                          if e[0] in path_of]
-            lines.append({"name": line.name, "events": events})
-        planes.append({"name": plane.name, "lines": lines})
-    return planes, scoped
+    planes = reduce.load_xplane(path)
+    ops = next((line["events"] for plane in planes
+                if plane["name"].startswith("/device:")
+                for line in plane["lines"]
+                if line["name"] == reduce.OPS_LINE and line["events"]), [])
+    return planes, [(path_of[e[0]], *e) for e in ops if e[0] in path_of]
 
 
 def op_scopes(scoped):

@@ -87,6 +87,38 @@ def test_kernel_costs():
         == (pytest.approx((weights + kv) / 819e9), "memory")
 
 
+def test_a_builders_costs_are_asked_first_and_costs_py_otherwise():
+    """A family brings operations and bytes (`own`); the peaks stay here."""
+    import types
+    cfg = config("smollm2-1.7b-8l")
+    sizes = {"B": 4, "H": 32, "Hkv": 32, "S": 2048, "hd": 64,
+             "T": 8192, "d": 2048, "V": 49152}
+    own = types.SimpleNamespace(
+        train_flops_per_token=lambda cfg, seq: 1.97e9,
+        KERNEL_COSTS={"flash_step": lambda cfg, sizes, window: (197e12, 1.0),
+                      "experts_step": lambda cfg, sizes, window:
+                      (1.0, 819e9 * sizes["B"])})
+    assert costs.mfu(cfg, 2048, 27_300.0, "TPU v5 lite", own=own) \
+        == pytest.approx(100 * 1.97e9 * 27_300.0 / 197e12)
+    assert costs.least_seconds("flash_step", cfg, sizes, {}, "TPU v5 lite",
+                               own=own) == (pytest.approx(1.0), "compute")
+    assert costs.least_seconds("experts_step", cfg, sizes, {}, "TPU v5 lite",
+                               own=own) == (pytest.approx(4.0), "memory")
+    # what it does not name is costs.py's, and so is everything without it
+    dense = costs.least_seconds("ce_step", cfg, sizes, {}, "TPU v5 lite")
+    assert costs.least_seconds("ce_step", cfg, sizes, {}, "TPU v5 lite",
+                               own=own) == dense
+    only_kernels = types.SimpleNamespace(KERNEL_COSTS={})
+    assert costs.mfu(cfg, 2048, 27_300.0, "TPU v5 lite", own=only_kernels) \
+        == costs.mfu(cfg, 2048, 27_300.0, "TPU v5 lite", own=None) \
+        == costs.mfu(cfg, 2048, 27_300.0, "TPU v5 lite")
+    with pytest.raises(KeyError):
+        costs.least_seconds("experts_step", cfg, sizes, {}, "TPU v5 lite")
+    # the dense builder offers costs.py itself: the same numbers by either way
+    from benchmarks.builders import llama_dense
+    assert llama_dense.costs is costs
+
+
 def test_unknown_device_is_an_error():
     assert costs.peaks("TPU v5 lite")["bf16_flops"] == 197e12
     assert costs.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9

@@ -16,7 +16,8 @@ BENCH = os.path.join(ROOT, "benchmarks")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 def load(*path):
@@ -65,7 +66,8 @@ def test_names_units_and_lines(bench):
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
     for m in bench["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
+        # `workloads` too: run.py reads a metric where its entry lists the cell
+        assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert line_ok(m["layer"])
     for w in bench["workloads"]:
@@ -131,28 +133,36 @@ def test_moves_is_reported_wherever_the_metric_is(bench):
 
 
 def test_metric_files_agree_with_the_manifest(bench):
-    """BENCHMARK.json's per_layer list is what the metric files yield for the
-    cells it has: a metric is read in the cells of its `kinds`."""
-    from benchmarks.reduce import READERS as readers
+    """A metric is read in a cell when its file's `kinds` hold the cell's
+    kind and its entry under `per_layer` lists the cell (run.py:load_cell):
+    so every file has an entry, every entry a file, the two say the same,
+    and an entry lists only cells that exist and are of the file's kinds."""
+    from benchmarks import run
     kinds = {w["name"]: load(BENCH, "traffic", w["traffic"] + ".json")["kind"]
              for w in bench["workloads"]}
     listed = {m["name"]: m for m in bench["per_layer"]}
-    layers = {}
-    for m in metric_files():
+    files = metric_files()
+    assert sorted(m["name"] for m in files) == sorted(listed)
+    for m in files:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["reader"] in readers, m["name"]
+        assert callable(run.READERS[m["reader"]]), m["name"]
         assert m["source"] in SOURCES
-        layers.setdefault(m["layer"], []).append(m["name"])
-        cells = [w["name"] for w in bench["workloads"]
-                 if kinds[w["name"]] in m["kinds"]]
-        if not cells:
-            assert m["name"] not in listed
-            continue
         entry = listed[m["name"]]
-        assert sorted(cells_of(entry, bench)) == sorted(cells), m["name"]
+        cells = cells_of(entry, bench)
+        assert cells and set(cells) <= set(kinds), m["name"]
+        assert all(kinds[c] in m["kinds"] for c in cells), m["name"]
         for key in ("unit", "better", "source", "layer", "moves"):
             assert entry[key] == m[key], (m["name"], key)
-    assert set(listed) <= {m["name"] for m in metric_files()}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  load(ROOT, "BENCHMARK.json")["workloads"]])
+def test_a_cell_reads_the_metrics_that_list_it(bench, cell):
+    from benchmarks import run
+    got = {m["name"] for m in run.load_cell(cell, rehearse=False)["metrics"]}
+    assert got == {m["name"] for m in bench["per_layer"]
+                   if cell in cells_of(m, bench)}
+    assert got
 
 
 @pytest.mark.parametrize("kind", ["train", "serve"])
@@ -170,6 +180,14 @@ def test_cpu_rehearsal_prints_the_contract(bench, kind):
         assert proc.returncode == 0, proc.stderr[-2000:]
         out = json.loads(proc.stdout.strip().splitlines()[-1])
         assert set(out) == RESULT_KEYS | ({"breakdown"} if trace else set())
+        # what `correct` compared, each number beside its limit: the
+        # line's last key and the last lines of stderr
+        assert list(out)[-1] == "compared" and out["compared"]
+        assert all(set(c) == {"value", "limit"}
+                   for c in out["compared"].values())
+        said = proc.stderr.strip().splitlines()[-len(out["compared"]):]
+        assert [ln.split()[:2] for ln in said] \
+            == [["[compared]", k] for k in out["compared"]]
         assert out["correct"] is True and out["failed"] == 0
         assert out["attempted"] > 0
         assert out["device"]["platform"] == "cpu"      # never a measurement

@@ -152,7 +152,10 @@ def test_a_trace_without_the_programs_spans_reads_nothing():
     assert spans.span_share({"trace": None}, "x") is None
     assert set(spans.READERS) == {"span_share", "span_ms_p50",
                                   "idle_under", "scope_share"}
-    assert not set(spans.READERS) & set(reduce.READERS)
+    # registered: a metric file names them like any reader of reduce.py
+    from benchmarks import run
+    assert all(run.READERS[name] is fn
+               for name, fn in spans.READERS.items())
 
 
 def test_scope_share_takes_self_time_by_a_part_of_the_path():
@@ -229,3 +232,41 @@ def test_scope_paths_are_read_from_the_event_metadata(tmp_path):
     assert spans.scope_share(ctx, "qkv") == pytest.approx(75.0)
     assert spans.scope_share(ctx, "attn") == pytest.approx(75.0)
     assert spans.scope_share(ctx, "mlp") is None
+
+
+# -- PR 27's six metric files, each by its own reader and arguments ------
+# the training loop's thread: three steps, p50 4 us
+TRAINER = [("train.step#step_num=3#", 0, 3), ("train.step.place", 0, 1),
+           ("train.step.dispatch", 1, 2), ("train.step#step_num=4#", 300, 5),
+           ("train.step#step_num=5#", 600, 4)]
+SCOPED = [("jit(traced)/while/body/attn/core/kv_write/scatter",
+           "%copy.3", 420, 80),
+          ("jit(traced)/while/body/attn/core/paged_attn/pallas_call",
+           "%paged_attention_decode.2", 200, 100),
+          ("jit(step)/transpose(jvp(attn))/qkv/dot_general",
+           "%fusion.4", 600, 400),
+          ("jit(step)/jvp(mlp)/dot_general", "%fusion.1", 0, 100)]
+
+
+@pytest.mark.parametrize("name,want", [
+    ("step.mlp_share", 100 * 100 / BUSY),
+    ("step.attn_share", 100 * 580 / BUSY),
+    ("step.host_dispatch_ms_p50", 4 / 1e3),
+    ("programs.kv_write_share", 100 * 80 / BUSY),
+    ("programs.pool_copy_share", 100 * 80 / BUSY),          # %copy.3
+    ("sched.tick_host_ms_p50", (210 + 220) / 2 / 1e3),      # as above
+])
+def test_metric_files_of_pr27_over_hand_built_planes(name, want):
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "metrics", name + ".json")) as f:
+        m = json.load(f)
+    ctx = ctx_of(host=(TICKER, HANDLER, TRAINER))
+    ctx["scopes"] = spans.op_scopes(
+        [(path, n, s * 1000.0, d * 1000.0) for path, n, s, d in SCOPED])
+    from benchmarks import run
+    assert run.READERS[m["reader"]](ctx, **m["args"]) == pytest.approx(want)
+    # a parent's trace has no such span or scope: nothing, never 0
+    bare_ctx = ctx_of(host=([e for e in TICKER if e[0].startswith("$")],),
+                      ops=[o for o in OPS if "copy" not in o[0]])
+    assert run.READERS[m["reader"]](bare_ctx, **m["args"]) is None

@@ -123,6 +123,9 @@ def run(cell, args, clock, clog, log):
 
         # -- the measured window ----------------------------------------
         mark = clog.mark()
+        # what the family counts itself, read as the window opens and closes
+        counters = getattr(builder, "counters", lambda _trainer: {})
+        counted0 = counters(trainer)
         losses, marks, readings = [], collections.deque(), []
         wait_s, n, traced = 0.0, 0, False
         t0 = t_prev = time.perf_counter()
@@ -156,6 +159,8 @@ def run(cell, args, clock, clog, log):
                 t_prev = time.perf_counter()
         done = len(readings) * per
         jax.block_until_ready(losses[-1]._value)
+        counted = {k: v - counted0.get(k, 0)
+                   for k, v in counters(trainer).items()}
         compiled_in_window = clog.since(mark)["programs"]
     finally:
         it.close()
@@ -182,8 +187,8 @@ def run(cell, args, clock, clog, log):
         f"nats (tolerance {check['position_loss_abs']}); {len(values)} "
         f"losses, last {values[-1]:.4f}, non-finite {bad}; retraced "
         f"{retraced}")
-    window = {"rate": rate, "median_reading_rate": median,
-              "window_s": spent, "input_wait_s": wait_s}
+    window = dict(counted, rate=rate, median_reading_rate=median,
+                  window_s=spent, input_wait_s=wait_s)
     if args.trace:
         # the same program as the step's, so it comes from the cache
         ma = trainer.lower(placed).compile().memory_analysis()
@@ -198,6 +203,11 @@ def run(cell, args, clock, clog, log):
         "attempted": done, "failed": bad, "setup_s": setup_s,
         "end_to_end": {"train_tokens_per_s_per_chip": rate},
         "window": window,
+        "compared": {"mean_loss_rel": (rel, check["mean_loss_rel"]),
+                     "position_loss_abs": (max(probe_err),
+                                           check["position_loss_abs"]),
+                     "nonfinite_losses": (bad, 0), "retraced": (retraced, 0),
+                     "compiled_in_window": (compiled_in_window, 0)},
     }
 
 
