@@ -91,6 +91,7 @@ import jax.numpy as jnp
 from paddle_tpu.core import compile_cache, jax_compat
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu import observability
+from paddle_tpu.nn.functional.key_selection import index_scores, select_top
 from paddle_tpu.observability import requests as obs_requests
 from paddle_tpu.inference.overload import (DeadlineExceeded,
                                            EngineOverloaded,
@@ -104,6 +105,11 @@ from paddle_tpu.inference.tenancy import WeightedFairScheduler
 __all__ = ["PagedState", "paged_attention_update", "decode_kernel_scope",
            "PagedKVEngine"]
 
+
+# the float32 scores that one prefill program's whole-window attend may
+# hold (`PagedKVEngine._prefill_limit`): 16 rows x 32 heads x a 512
+# bucket x a 768-token window is 0.75 GiB
+_PREFILL_SCORE_BYTES = 2 ** 30
 
 # the phases of a scheduler tick, in order: each is the span
 # `engine.tick.<phase>` and one column of `PagedKVEngine.tick_log`
@@ -156,6 +162,34 @@ def decode_kernel_scope(kind="jnp", interpret=False):
         _decode_cfg.cfg = prev
 
 
+def _decode_kernel_choice():
+    """(kind, interpret) of the `decode_kernel_scope` this trace runs in,
+    ("jnp", False) outside any: what `paged_attention_update` takes for a
+    decode call."""
+    return getattr(_decode_cfg, "cfg", None) or ("jnp", False)
+
+
+def _token_coords(state: PagedState, s, page_size, num_pages):
+    """(physical page, offset in it) of each of this call's b * s tokens,
+    flat; an invalid row's page points past the pool, so that a scatter
+    with mode="drop" drops its write."""
+    bt, lens, n_valid = (_val(state.block_tables),
+                         _val(state.lens), _val(state.n_valid))
+    b = bt.shape[0]
+    pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (b,s)
+    valid = jnp.arange(s, dtype=jnp.int32)[None, :] < n_valid[:, None]
+    logical = pos // page_size
+    phys = jnp.take_along_axis(
+        bt, jnp.clip(logical, 0, bt.shape[1] - 1), axis=1)   # (b, s)
+    # invalid rows: point past the pool and DROP the write (r5 review:
+    # routing them to page 0 corrupted callers whose block tables
+    # legitimately allocate page 0 — the public op has no trash-page
+    # reservation; the engine's page-0 convention is gather-only)
+    phys = jnp.where(valid, phys, num_pages)
+    off = pos % page_size
+    return phys.reshape(b * s), off.reshape(b * s)
+
+
 def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
                 v_scale=None):
     """Scatter this call's (b, s, hk, d) k/v into their pages.
@@ -171,25 +205,9 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
 
     Returns (kp, vp, k_scale, v_scale) — scales None when unquantized.
     """
-    bt, lens, n_valid = (_val(state.block_tables),
-                         _val(state.lens), _val(state.n_valid))
     b, s, hk, d = k.shape
-    page_size = kp.shape[2]
     num_pages = kp.shape[0]
-
-    pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (b,s)
-    valid = jnp.arange(s, dtype=jnp.int32)[None, :] < n_valid[:, None]
-    logical = pos // page_size
-    phys = jnp.take_along_axis(
-        bt, jnp.clip(logical, 0, bt.shape[1] - 1), axis=1)   # (b, s)
-    # invalid rows: point past the pool and DROP the write (r5 review:
-    # routing them to page 0 corrupted callers whose block tables
-    # legitimately allocate page 0 — the public op has no trash-page
-    # reservation; the engine's page-0 convention is gather-only)
-    phys = jnp.where(valid, phys, num_pages)
-    off = pos % page_size
-    phys_f = phys.reshape(b * s)
-    off_f = off.reshape(b * s)
+    phys_f, off_f = _token_coords(state, s, kp.shape[2], num_pages)
 
     if k_scale is None:
         kp = kp.at[phys_f, :, off_f, :].set(
@@ -273,7 +291,123 @@ def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
     return jnp.swapaxes(out, 1, 2).reshape(b, s, hq * d).astype(q.dtype)
 
 
-def paged_attention_update(q, k, v, cache, state: PagedState):
+# what one block of scores of the selected-keys attend may take, in
+# float32 bytes: its key blocks are sized from the shapes under it
+_SELECT_SCORE_BYTES = 256 * 2 ** 20
+
+
+def _index_scores(qi, w, ip, state: PagedState):
+    """The learned index scores of this call's tokens against every key of
+    their slot's page window -> (b, s, L) float32. qi (b, s, hi, di), w
+    (b, s, hi), ip the index pool (num_pages, 1, page_size, di), one key a
+    token."""
+    bt = _val(state.block_tables)
+    ks = ip[bt].reshape(bt.shape[0], -1, qi.shape[-1])       # (b, L, di)
+    return index_scores(qi, ks, w, _SELECT_SCORE_BYTES)
+
+
+def _select_keys(qi, w, ip, state: PagedState, topk):
+    """(b, s, L) bool: the keys each of this call's tokens attends over
+    (the reference's S_t), among the columns of its slot's page window."""
+    lens = _val(state.lens)
+    s = qi.shape[1]
+    with jax.named_scope("indexer"):
+        scores = _index_scores(qi, w, ip, state)
+    with jax.named_scope("select"):
+        pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        col = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+        causal = col[None, None, :] <= pos[:, :, None]
+        return select_top(scores, causal, topk)
+
+
+def _attend_selected(q, kp, vp, state: PagedState, select):
+    """Attend over the selected keys only: `select` (b, s, L) bool over
+    the columns of each slot's page window (causality is in it). The keys
+    go by in blocks of whole pages under an online softmax, as many blocks
+    as the longest row reaches, so a long window costs no more memory than
+    one block's scores (`_SELECT_SCORE_BYTES`, from the shapes) and a
+    prefill chunk does not pay for the window's empty end.
+
+    q: (b, s, hq, d). Returns (b, s, hq*d) in q.dtype."""
+    bt, lens, n_valid = (_val(state.block_tables), _val(state.lens),
+                         _val(state.n_valid))
+    b, s, hq, d = q.shape
+    hk, ps = kp.shape[1], kp.shape[2]
+    g, mp = hq // hk, bt.shape[1]
+    # pages a block: the largest power of two whose scores fit
+    bp = 1
+    while bp * 2 <= mp and b * hq * s * bp * 2 * ps * 4 \
+            <= _SELECT_SCORE_BYTES:
+        bp *= 2
+    nblk = -(-mp // bp)
+    cols = bp * ps
+    btp = jnp.pad(bt, ((0, 0), (0, nblk * bp - mp)))
+    sel = jnp.pad(select, ((0, 0), (0, 0), (0, nblk * cols - mp * ps)))
+    qg = jnp.moveaxis(q.reshape(b, s, hk, g, d), 1, 3)     # (b,hk,g,s,d)
+    scale = 1.0 / math.sqrt(d)
+
+    def block(i, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(btp, i * bp, bp, 1)
+        kb = jnp.moveaxis(kp[pages], 2, 1).reshape(b, hk, cols, d)
+        vb = jnp.moveaxis(vp[pages], 2, 1).reshape(b, hk, cols, d)
+        seen = jax.lax.dynamic_slice_in_dim(sel, i * cols, cols, 2)
+        seen = seen[:, None, None]                          # (b,1,1,s,c)
+        sc = jnp.einsum("bhgsd,bhcd->bhgsc", qg, kb.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(seen, sc, -1e30)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum(
+            "bhgsc,bhcd->bhgsd", p.astype(q.dtype), vb.astype(q.dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    reach = jnp.max(lens + jnp.maximum(n_valid, 1))         # keys in use
+    need = jnp.clip(-(-reach // cols), 1, nblk)
+    shape = (b, hk, g, s, 1)
+    _m, l, acc = jax.lax.fori_loop(
+        0, need, block,
+        (jnp.full(shape, -1e30, jnp.float32), jnp.zeros(shape, jnp.float32),
+         jnp.zeros((b, hk, g, s, d), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)
+    return jnp.moveaxis(out, 3, 1).reshape(b, s, hq * d).astype(q.dtype)
+
+
+def _attend_indexed(q, k, v, cache, state: PagedState, index):
+    """The three-pool form of `paged_attention_update`: K, V and one index
+    key a token under the same block table; this call's index keys are
+    written where its k and v are, the keys each token attends over are
+    selected from the index scores (`index`: qi (b, s, hi, di), ki (b, s,
+    di), w (b, s, hi), topk), and attention runs over those alone."""
+    kp, vp, ip = (_val(c) for c in cache)
+    qi, ki, w, topk = index
+    qi, ki, w = _val(qi), _val(ki), _val(w)
+    b, s, hq, d = q.shape
+    with jax.named_scope("kv_write"):
+        kp, vp, _ks, _vs = _scatter_kv(kp, vp, k, v, state)
+        phys_f, off_f = _token_coords(state, s, ip.shape[2], ip.shape[0])
+        ip = ip.at[phys_f, 0, off_f, :].set(
+            ki.reshape(b * s, -1).astype(ip.dtype), mode="drop")
+    select = _select_keys(qi, w, ip, state, topk)
+    kind, interpret = _decode_kernel_choice()
+    with jax.named_scope("paged_attn"):
+        if kind == "pallas" and s == 1:
+            from paddle_tpu.kernels.paged_attention import \
+                paged_decode_attention
+            out = paged_decode_attention(
+                q[:, 0], kp, vp, _val(state.block_tables),
+                _val(state.lens), interpret=interpret,
+                select=select[:, 0])
+            out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
+        else:
+            out = _attend_selected(q, kp, vp, state, select)
+    return Tensor(out), (Tensor(kp), Tensor(vp), Tensor(ip))
+
+
+def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
     """Write this call's k/v into the slot's pages, then attend over the
     slot's whole paged window. One code path serves BOTH phases of the
     reference contract (block_multi_head_attention_kernel.cu's prefill
@@ -282,7 +416,11 @@ def paged_attention_update(q, k, v, cache, state: PagedState):
     q: (b, s, hq, d), k/v: (b, s, hk, d) — already position-encoded.
     cache: (k_pool, v_pool), each (num_pages, hk, page_size, d) — or,
     for int8 KV quantization, (k_pool, v_pool, k_scale, v_scale) with
-    int8 pools and (num_pages, hk) f32 per-page-per-head scales.
+    int8 pools and (num_pages, hk) f32 per-page-per-head scales — or,
+    for a learned key selection, (k_pool, v_pool, index_pool) with one
+    index key a token, (num_pages, 1, page_size, di), and `index` (this
+    call's index queries, index keys, head weights and topk:
+    `_attend_indexed`).
     Returns (out (b, s, hq*d), new cache of the SAME arity).
 
     Decode calls (s == 1) traced inside
@@ -294,6 +432,12 @@ def paged_attention_update(q, k, v, cache, state: PagedState):
     bookkeeping.
     """
     q, k, v = _val(q), _val(k), _val(v)
+    if len(cache) == 3:
+        if index is None:
+            raise ValueError(
+                "a 3-tuple cache is (k_pool, v_pool, index_pool): pass "
+                "index=(q_index, k_index, head_weights, topk)")
+        return _attend_indexed(q, k, v, cache, state, index)
     quantized = len(cache) == 4
     kp, vp = _val(cache[0]), _val(cache[1])
     k_scale = _val(cache[2]) if quantized else None
@@ -312,7 +456,7 @@ def paged_attention_update(q, k, v, cache, state: PagedState):
         kp, vp, k_scale, v_scale = _scatter_kv(kp, vp, k, v, state,
                                                k_scale, v_scale)
 
-    kind, interpret = getattr(_decode_cfg, "cfg", None) or ("jnp", False)
+    kind, interpret = _decode_kernel_choice()
     with jax.named_scope("paged_attn"):
         if kind == "pallas" and s == 1:
             from paddle_tpu.kernels.paged_attention import \
@@ -331,6 +475,17 @@ def paged_attention_update(q, k, v, cache, state: PagedState):
         return Tensor(out), (Tensor(kp), Tensor(vp),
                              Tensor(k_scale), Tensor(v_scale))
     return Tensor(out), (Tensor(kp), Tensor(vp))
+
+
+def _last_valid_logits(lv, n_valid):
+    """(bw, v): each prefill row's logits at its last valid token, from
+    the model's (bw, s, v) — or from (bw, 1, v) where the model projected
+    that token alone (a prefill needs no other; at a large vocabulary the
+    other rows' logits are most of a chunk's work)."""
+    if lv.shape[1] == 1:
+        return lv[:, 0]
+    idxs = jnp.clip(n_valid - 1, 0, lv.shape[1] - 1)
+    return jnp.take_along_axis(lv, idxs[:, None, None], axis=1)[:, 0]
 
 
 def _process_logits_rowwise(x, temp, topk, topp):
@@ -563,7 +718,9 @@ class PagedKVEngine:
         # prompts longer than this prefill in fixed-size chunks through
         # ONE reused program (chunked prefill — the paged core appends
         # at lens>0) instead of compiling a program per padded length.
-        # None = always use the bucketed whole-prompt path.
+        # None = the bucketed whole-prompt path for every prompt whose
+        # scores fit one program (`_prefill_limit`, from the shapes),
+        # chunks of that limit for a longer one.
         self.prefill_chunk = (int(prefill_chunk) if prefill_chunk
                               else None)
         n_kv = getattr(cfg, "num_key_value_heads", None) \
@@ -579,11 +736,48 @@ class PagedKVEngine:
         self.kv_dtype = kv_dtype
         pool_dtype = {"bf16": "bfloat16", "int8": "int8",
                       None: dtype}[kv_dtype]
-        self._cache_arity = 4 if kv_dtype == "int8" else 2
+        # a model whose attention selects its keys (a learned indexer:
+        # the config says how wide an index key is and how many keys a
+        # token keeps) carries a THIRD pool a layer under the same block
+        # table, one index key a token. What assumes two pools refuses
+        # here, by name, so that none can drop the third in silence.
+        self.index_dim = int(getattr(cfg, "index_head_dim", 0) or 0)
+        self.index_topk = int(getattr(cfg, "index_topk", 0) or 0)
+        if self.index_dim:
+            refused = [why for on, why in (
+                (kv_dtype == "int8", "kv_dtype='int8' (the scale planes "
+                 "take the cache's third and fourth place)"),
+                (int(prefix_cache_pages), "prefix_cache_pages (a warm "
+                 "tail would select over index keys no test holds to "
+                 "the reference yet)"),
+                (int(host_tier_bytes), "host_tier_bytes (a spilled page "
+                 "is its K and V alone)"),
+                (role != "both", f"role={role!r} (an exported page is "
+                 "its K and V alone)"),
+                (draft_model is not None, "draft_model (the verify pass "
+                 "attends over every key)")) if on]
+            if refused:
+                raise ValueError(
+                    "this model keeps an index pool beside K and V "
+                    f"(index_head_dim={self.index_dim}); not carried by: "
+                    + "; ".join(refused))
+        elif draft_model is not None and getattr(
+                draft_model.config, "index_head_dim", 0):
+            raise ValueError("a draft model with an index pool is not "
+                             "carried (its pools are K and V alone)")
+        self._cache_arity = (4 if kv_dtype == "int8"
+                             else 3 if self.index_dim else 2)
 
         def make_pools(n_heads, head_dim, n_layers):
             shape = (self.num_pages, n_heads, self.page_size, head_dim)
             sshape = (self.num_pages, n_heads)
+            if self.index_dim:      # never a draft's: refused above
+                ishape = (self.num_pages, 1, self.page_size,
+                          self.index_dim)
+                return [(jnp.zeros(shape, pool_dtype),
+                         jnp.zeros(shape, pool_dtype),
+                         jnp.zeros(ishape, pool_dtype))
+                        for _ in range(n_layers)]
             if kv_dtype == "int8":
                 return [(jnp.zeros(shape, "int8"),
                          jnp.zeros(shape, "int8"),
@@ -603,13 +797,19 @@ class PagedKVEngine:
             raise ValueError(f"kernel must be None, 'pallas' or 'jnp' "
                              f"(got {kernel!r})")
         self._kernel_interpret = not on_tpu
+        # a key selection rides the kernel only where its score columns
+        # are tokens in order
+        select_problems = (_pk.select_shape_problems(
+            n_kv, hd, self.page_size, pool_dtype) if self.index_dim else [])
         if kernel == "pallas":
             _pk.check_decode_shapes(cfg.num_attention_heads, n_kv, hd,
                                     self.page_size,
                                     interpret=self._kernel_interpret,
                                     kv_dtype=pool_dtype)
+            if select_problems:
+                raise ValueError("; ".join(select_problems))
             self.decode_kernel = "pallas"
-        elif kernel is None and on_tpu and \
+        elif kernel is None and on_tpu and not select_problems and \
                 not _pk.decode_shape_problems(cfg.num_attention_heads,
                                               n_kv, hd, self.page_size,
                                               kv_dtype=pool_dtype):
@@ -763,6 +963,15 @@ class PagedKVEngine:
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefix_hit_tokens": 0, "prefix_pages_shared": 0,
                       "prefix_evictions": 0}
+        if self.index_dim:
+            # decode steps of live slots, and those whose context had
+            # outgrown topk, so that the selection chose among its keys
+            self.stats.update(decode_slot_steps=0, select_engaged_steps=0)
+        # what the model counts itself a decode step (its
+        # `decode_counter_keys`, e.g. the distinct experts its rows hit):
+        # the tick program asks the forward for them (`with_counters`),
+        # sums them and returns them with its tokens
+        self._model_counts = bool(getattr(model, "decode_counter_keys", ()))
         # one row a tick, the newest 256: (seq, start by perf_counter,
         # the TICK_PHASES' seconds in order, live slots, prefills
         # admitted) — what says which phase a slow tick spent it in
@@ -1756,20 +1965,45 @@ class PagedKVEngine:
         # is the whole prefill they run
         groups = {}
         long_grp = []
+        alone = self._prefill_limit(1)
         for idx, req in admitted:
             tail = req.prompt.size \
                 - self._slots[idx].shared * self.page_size
-            if self.prefill_chunk and tail > self.prefill_chunk:
+            if tail > (self.prefill_chunk or alone):
                 long_grp.append((idx, req))
                 continue
             groups.setdefault(self._bucket(tail), []).append((idx, req))
-        if long_grp:
+        if long_grp and self.prefill_chunk:
             self._prefill_chunked_group(long_grp)
+        else:
+            # too long for one program by the engine's own reckoning:
+            # each alone, so that none is padded to the group's width
+            for pair in long_grp:
+                self._prefill_chunked_group([pair], chunk=alone)
         for ppad, grp in groups.items():
-            self._prefill_group(ppad, grp)
+            if len(grp) > 1 and ppad > self._prefill_limit(self.max_slots):
+                for pair in grp:    # the group's scores would not fit
+                    self._prefill_group(ppad, [pair])
+            else:
+                self._prefill_group(ppad, grp)
         if requeue:
             with self._lock:
                 self._pending = requeue + self._pending
+
+    def _prefill_limit(self, rows):
+        """The longest padded prompt that `rows` rows prefill in ONE
+        program, from the shapes: the float32 scores of a whole-window
+        attend (rows x heads x tokens x the block table's window, what
+        `_attend_pages` holds at once) stay under
+        `_PREFILL_SCORE_BYTES`. A power of two; a longer prompt goes
+        through the chunk program in pieces of this length."""
+        if self.draft_model is not None:
+            return 1 << 30      # the chunk program has no draft mirror
+        cfg = self.model.config
+        per_token = (rows * cfg.num_attention_heads * 4
+                     * self.max_pages_per_slot * self.page_size)
+        limit = max(8, _PREFILL_SCORE_BYTES // per_token)
+        return 1 << (limit.bit_length() - 1)
 
     def _prefill(self, slot_idx, req):
         """Single-request prefill (kept for direct callers/tests):
@@ -1777,8 +2011,9 @@ class PagedKVEngine:
         self._slots[slot_idx] = _Slot(req, lens=0, tok=0)
         self._alloc_pages(slot_idx,
                           -(-int(req.prompt.size) // self.page_size))
-        if self.prefill_chunk and req.prompt.size > self.prefill_chunk:
-            self._prefill_chunked_group([(slot_idx, req)])
+        chunk = self.prefill_chunk or self._prefill_limit(1)
+        if req.prompt.size > chunk:
+            self._prefill_chunked_group([(slot_idx, req)], chunk=chunk)
         else:
             self._prefill_group(self._bucket(int(req.prompt.size)),
                                 [(slot_idx, req)])
@@ -1797,27 +2032,29 @@ class PagedKVEngine:
             return int(np.argmax(x - np.log(-np.log(u))))
         return int(np.argmax(logits))
 
-    def _prefill_chunked_group(self, grp):
+    def _prefill_chunked_group(self, grp, chunk=None):
         """Feed long prompts through the fixed-size chunk program in
         LOCKSTEP rounds — the paged core appends at lens>0 (the
         reference's chunked-prefill contract, seq_lens_decoder > 0),
         and a storm of long prompts pays ceil(max_len/chunk) program
         calls total instead of one full chunk loop per request.
         Exhausted rows ride later rounds with n_valid=0 (writes drop)."""
-        chunk = self.prefill_chunk
+        chunk = chunk or self.prefill_chunk
         bw = 1 if len(grp) == 1 else self.max_slots
+        done = np.zeros(bw, np.int32)                  # consumed per row
+        for r, (idx, _req) in enumerate(grp):
+            # warm rows (prefix-cache hit) start past the shared pages
+            done[r] = self._slots[idx].shared * self.page_size
+        plens = [int(req.prompt.size) for _, req in grp]
+        rounds = max(-(-(n - int(done[r])) // chunk)
+                     for r, n in enumerate(plens))
         with observability.span("engine.prefill", bucket=chunk,
-                                rows=len(grp), group=bw):
+                                rows=len(grp), group=bw, chunks=rounds):
             t0 = time.perf_counter()
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_start", rid=req.rid)
             fn = self._prefill_chunk_fn(chunk, bw)
-            done = np.zeros(bw, np.int32)              # consumed per row
-            for r, (idx, _req) in enumerate(grp):
-                # warm rows (prefix-cache hit) start past the shared pages
-                done[r] = self._slots[idx].shared * self.page_size
-            plens = [int(req.prompt.size) for _, req in grp]
             final_logits = [None] * len(grp)
             while any(done[r] < plens[r] for r in range(len(grp))):
                 ids = np.zeros((bw, chunk), np.int32)
@@ -1836,11 +2073,14 @@ class PagedKVEngine:
                                 jnp.asarray(nv), jnp.asarray(bt),
                                 [a for kv in self.pools for a in kv])
                 self.pools = self._unflat_pools(flat)
-                last_np = np.asarray(last)
                 for r in range(len(grp)):
                     if nv[r] > 0 and done[r] + nv[r] >= plens[r]:
-                        final_logits[r] = last_np[r]
+                        final_logits[r] = last      # read after the loop
                     done[r] += nv[r]
+            # one wait for the whole prompt: the rounds queue on the device
+            # back to back, none waits for the host to read the one before
+            final_logits = [np.asarray(rows)[r]
+                            for r, rows in enumerate(final_logits)]
             self.stats["prefills"] += len(grp)
             self.stats["prefill_s"] += time.perf_counter() - t0
             for _idx, req in grp:
@@ -1867,11 +2107,8 @@ class PagedKVEngine:
             logits, new_caches = model(
                 Tensor(ids), caches=self._layer_caches(pool_flat),
                 position_ids=Tensor(pos), cache_index=state)
-            lv = _val(logits)                            # (bw, chunk, v)
-            idxs = jnp.clip(n_valid - 1, 0, chunk - 1)
-            last = jnp.take_along_axis(
-                lv, idxs[:, None, None], axis=1)[:, 0]   # (bw, v)
-            return last, [_val(a) for kv in new_caches for a in kv]
+            return (_last_valid_logits(_val(logits), n_valid),
+                    [_val(a) for kv in new_caches for a in kv])
 
         fn = self._jit(run, donate=(4,))
         self._programs[key] = fn
@@ -1886,7 +2123,7 @@ class PagedKVEngine:
         total."""
         bw = 1 if len(grp) == 1 else self.max_slots
         with observability.span("engine.prefill", bucket=ppad,
-                                rows=len(grp), group=bw):
+                                rows=len(grp), group=bw, chunks=1):
             t0 = time.perf_counter()
             for _idx, req in grp:
                 if req.obs is not None:
@@ -2118,16 +2355,26 @@ class PagedKVEngine:
                                  jnp.asarray(a["wants"])]
                 marks.append(clock())
                 with observability.span("engine.tick.launch"):
-                    toks_out, lens_f, flat = fn(
+                    toks_out, lens_f, flat, *counted = fn(
                         *args, [x for kv in self.pools for x in kv])
                     self.pools = self._unflat_pools(flat)
                 marks.append(clock())
                 with observability.span("engine.tick.readback"):
                     toks_np = np.asarray(toks_out)          # (b, n)
                     lens_np = np.asarray(lens_f)
+                    for name, v in (counted[0] if counted else {}).items():
+                        self.stats[name] = self.stats.get(name, 0) + int(v)
                 marks.append(clock())
                 self._ticked(marks)
                 counts = np.minimum(a["limit"], n)
+                if self.index_dim:
+                    took = counts[live]
+                    self.stats["decode_slot_steps"] += int(took.sum())
+                    # step j of a slot attends over lens + j + 1 keys
+                    self.stats["select_engaged_steps"] += int(np.clip(
+                        a["lens"][live] + took - np.maximum(
+                            a["lens"][live], self.index_topk), 0,
+                        took).sum())
                 with observability.span("engine.tick.accept"):
                     self._accept_tick(live, toks_np, counts, a["eos"],
                                       lens_np)
@@ -2448,11 +2695,8 @@ class PagedKVEngine:
             logits, new_caches = model(
                 Tensor(ids), caches=self._layer_caches(pool_flat),
                 position_ids=Tensor(pos), cache_index=state)
-            lv = _val(logits)                            # (bw, ppad, v)
-            idxs = jnp.clip(n_valid - 1, 0, ppad - 1)
-            last = jnp.take_along_axis(
-                lv, idxs[:, None, None], axis=1)[:, 0]   # (bw, v)
-            return last, [_val(a) for kv in new_caches for a in kv]
+            return (_last_valid_logits(_val(logits), n_valid),
+                    [_val(a) for kv in new_caches for a in kv])
 
         fn = self._jit(run, donate=(4,))
         self._programs[key] = fn
@@ -2657,11 +2901,16 @@ class PagedKVEngine:
                 tok, lens, fin, cnt, flat = carry
                 live = jnp.logical_and(active, jnp.logical_not(fin))
                 state = PagedState(bt, lens, live.astype(jnp.int32))
-                logits, new_caches = model(
+                logits, new_caches, *counts = model(
                     Tensor(tok[:, None]),
                     caches=self._layer_caches(list(flat)),
                     position_ids=Tensor(lens[:, None]),
-                    cache_index=state)
+                    cache_index=state,
+                    **({"with_counters": True} if self._model_counts
+                       else {}))
+                counts = ({k: jnp.asarray(_val(v), jnp.int32)
+                           for k, v in counts[0].items()}
+                          if self._model_counts else None)
                 last = _val(logits)[:, -1]
                 with jax.named_scope("sample"):
                     greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
@@ -2683,13 +2932,18 @@ class PagedKVEngine:
                 hit_eos = live & (eos >= 0) & (nxt == eos)
                 new_fin = fin | hit_eos | (new_cnt >= limit)
                 new_flat = tuple(_val(a) for kv in new_caches for a in kv)
-                return (nxt, new_lens, new_fin, new_cnt, new_flat), nxt
+                carry = (nxt, new_lens, new_fin, new_cnt, new_flat)
+                return carry, (nxt if counts is None else (nxt, counts))
 
             fin0 = jnp.logical_not(active)
             cnt0 = jnp.zeros_like(lens)
             (tok_f, lens_f, fin_f, cnt_f, flat_f), toks = jax.lax.scan(
                 body, (tok, lens, fin0, cnt0, tuple(pool_flat)),
                 jnp.arange(n, dtype=jnp.int32))
+            if self._model_counts:
+                toks, counts = toks
+                return (jnp.swapaxes(toks, 0, 1), lens_f, list(flat_f),
+                        {k: jnp.sum(v) for k, v in counts.items()})
             return jnp.swapaxes(toks, 0, 1), lens_f, list(flat_f)
 
         # donate the pool buffers (the last positional arg; its index

@@ -70,7 +70,8 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.core.jax_compat import tpu_compiler_params
 
 __all__ = ["paged_decode_attention", "decode_shape_problems",
-           "check_decode_shapes", "decode_plan", "DecodePlan"]
+           "check_decode_shapes", "select_shape_problems", "decode_plan",
+           "DecodePlan"]
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
@@ -133,6 +134,19 @@ def decode_shape_problems(hq, hk, d, page_size, interpret=False,
                             f"hk={hk} heads of {page_size // fold} "
                             f"rows a page)")
     return problems
+
+
+def select_shape_problems(hk, d, page_size, kv_dtype):
+    """Reasons a key selection cannot ride this geometry's kernel: its
+    score columns have to be tokens in order, so no folded tokens and no
+    packed heads (`_packing`)."""
+    fold, pack = _packing(hk, d, page_size, kv_dtype)
+    if (fold, pack) == (1, 1):
+        return []
+    return [f"a key selection needs head_dim % 128 == 0 and a page of "
+            f"whole {jnp.dtype(kv_dtype).name} sublane tiles (got d={d}, "
+            f"page_size={page_size}: {fold} tokens a row, {pack} heads a "
+            f"tile)"]
 
 
 def check_decode_shapes(hq, hk, d, page_size, interpret=False,
@@ -241,7 +255,8 @@ def decode_plan(hq, hk, d, page_size, max_pages, kv_dtype, slots=1):
                       fold, pack)
 
 
-def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized):
+def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized,
+                   selected=False):
     """Grid (b, head blocks, page blocks), run in order. Scalar
     prefetch: block tables (b, mp) i32, lens (b,) i32 and, for int8
     pools, the per-slot gathered f32 scales (b, mp, hk) in SMEM
@@ -258,11 +273,18 @@ def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized):
     elsewhere, so one dot over all lanes scores it against the tokens
     t = r (mod fold); columns of the other packed heads are masked.
     Each row keeps its own online softmax in the resident output blocks
-    (acc, m, l); the caller merges the rows of one query head."""
+    (acc, m, l); the caller merges the rows of one query head.
+
+    `selected`: a (1, 1, 1, t) block of a per-slot key selection follows
+    q, one float a column of this step's score tile (fold = pack = 1, so
+    column c of block j is token j * t + c); a column is seen only where
+    it is positive, on top of the length's mask."""
     if quantized:
         ks_ref, vs_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, acc_ref, m_ref, l_ref, kbuf, vbuf, sem, cur_ref \
-        = refs
+    q_ref, *refs = refs
+    if selected:
+        sel_ref, *refs = refs
+    k_hbm, v_hbm, acc_ref, m_ref, l_ref, kbuf, vbuf, sem, cur_ref = refs
     bi, hi, j = (pl.program_id(a) for a in range(3))
     nb, nh, _ = (pl.num_programs(a) for a in range(3))
     mp = bt_ref.shape[1]
@@ -359,6 +381,8 @@ def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized):
             + rem(col, rp) * fold + rem(div(row, g), fold)
         seen = (token <= pos) \
             & (rem(div(col, rp), pack) == div(row, g * fold))
+        if selected:
+            seen = seen & (sel_ref[0] > 0.0)
 
         def by_column(sc_ref):
             """(groups, 1, t): the scale of each column's page and
@@ -399,7 +423,7 @@ def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized):
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
                            k_scale=None, v_scale=None, sm_scale=None,
-                           interpret=False):
+                           interpret=False, select=None):
     """One decode step of paged attention for every slot.
 
     q: (b, hq, d) — one (position-encoded) query row per slot.
@@ -411,6 +435,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
         for unallocated entries; pages past the length are not read).
     lens: (b,) int32 — this query's position (its k/v must already be
         scattered there); columns c <= lens[i] are attended.
+    select: optional (b, max_pages * page_size) bool — attend over the
+        columns it marks only (a learned key selection), still under
+        c <= lens[i]. Needs a geometry whose score columns are tokens in
+        order (`decode_plan`: fold = pack = 1, i.e. head_dim a multiple
+        of 128 and a page that fills the pool dtype's sublane tile);
+        `select_shape_problems` says when it is not.
 
     Returns (b, hq, d) f32. Shapes must pass `check_decode_shapes`
     (call it, or gate on `decode_shape_problems`, before forcing this
@@ -425,16 +455,23 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
                          "(num_pages, hk) f32")
     check_decode_shapes(hq, hk, d, page_size, interpret,
                         kv_dtype=k_pool.dtype)
+    if select is None:
+        return _decode(q, k_pool, v_pool, block_tables, lens, k_scale,
+                       v_scale, sm_scale=sm_scale, interpret=interpret)
+    problems = select_shape_problems(hk, d, page_size, k_pool.dtype)
+    if problems:
+        raise ValueError("paged_decode_attention(select=): "
+                         + "; ".join(problems))
     return _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
-                   sm_scale=sm_scale, interpret=interpret)
+                   select, sm_scale=sm_scale, interpret=interpret)
 
 
 # jitted on its own: a model calls this once a layer, and a caller's
 # trace then holds ONE traced and lowered kernel that every layer calls,
 # not one a layer (a server's start is mostly tracing its tick)
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale, *,
-            sm_scale, interpret):
+def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
+            select=None, *, sm_scale, interpret):
     b, hq, d = q.shape
     num_pages, hk, page_size, _ = k_pool.shape
     mp = block_tables.shape[1]
@@ -483,10 +520,19 @@ def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale, *,
 
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     window = (2, groups, plan.pages * pack * page_size // fold, lanes)
+    operands, in_specs = [qf], [block(lanes)]
+    if select is not None:
+        # one float a key, cut into the page blocks of the grid
+        t = plan.pages * page_size
+        sel = jnp.pad(select.astype(jnp.float32),
+                      ((0, 0), (0, plan.grid[2] * t - select.shape[1])))
+        operands.append(sel.reshape(b, plan.grid[2], 1, t))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 1, t), lambda bi, hi, j, *_sp: (bi, j, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
         grid=plan.grid,
-        in_specs=[block(lanes), pool_spec, pool_spec],
+        in_specs=in_specs + [pool_spec, pool_spec],
         out_specs=[block(lanes), block(128), block(128)],
         scratch_shapes=[pltpu.VMEM(window, k_pool.dtype),
                         pltpu.VMEM(window, v_pool.dtype),
@@ -495,7 +541,9 @@ def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale, *,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_decode_kernel, page_size=page_size, plan=plan,
-                          g=g, quantized=quantized),
+                          g=g, quantized=quantized,
+                          **({"selected": True} if select is not None
+                             else {})),
         grid_spec=grid_spec,
         out_shape=[out(lanes), out(128), out(128)],
         # in order: a step starts the copies the next one waits for
@@ -503,7 +551,7 @@ def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale, *,
             dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
         name="paged_attention_decode",
-    )(*scalar_args, qf, rows_of(k_pool), rows_of(v_pool))
+    )(*scalar_args, *operands, rows_of(k_pool), rows_of(v_pool))
 
     # merge the `fold` partial softmaxes of each query head: row
     # (a, r, i) has its numerator in the lanes of token r

@@ -28,3 +28,7 @@ from paddle_tpu.models.generation import (  # noqa: F401
     generate, generate_speculative, generate_stream, init_kv_cache,
     process_logits,
 )
+from paddle_tpu.models.sparse_attn_moe import (  # noqa: F401
+    SparseAttnMoeConfig, SparseAttnMoeForCausalLM, SparseAttnMoeModel,
+    tiny_sparse_attn_moe_config,
+)

@@ -249,15 +249,23 @@ def moe_dropless_mlp(xt, wg, wu, wd, idx, gates):
     t, d = xt.shape
     e = wg.shape[0]
     k = idx.shape[1]
-    flat_e = idx.reshape(-1)                                # (T*k,)
-    order = jnp.argsort(flat_e, stable=True)
-    tok_of = order // k
-    sorted_x = jnp.take(xt, tok_of, axis=0)                 # (T*k, D)
-    group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
-    a = jax.lax.ragged_dot(sorted_x, wg.astype(xt.dtype), group_sizes)
-    b = jax.lax.ragged_dot(sorted_x, wu.astype(xt.dtype), group_sizes)
-    act = jax.nn.silu(a.astype(jnp.float32)).astype(xt.dtype) * b
-    o = jax.lax.ragged_dot(act, wd.astype(xt.dtype), group_sizes)
-    inv = jnp.argsort(order, stable=True)
-    out_rows = jnp.take(o, inv, axis=0).reshape(t, k, d)
-    return jnp.sum(gates[..., None].astype(xt.dtype) * out_rows, axis=1)
+    with jax.named_scope("dispatch"):
+        flat_e = idx.reshape(-1)                            # (T*k,)
+        order = jnp.argsort(flat_e, stable=True)
+        tok_of = order // k
+        sorted_x = jnp.take(xt, tok_of, axis=0)             # (T*k, D)
+        group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        # said outright for half-width operands: the TPU's grouped matmul
+        # refuses them under an ambient "highest"
+        prec = (jax.lax.Precision.DEFAULT
+                if xt.dtype in (jnp.bfloat16, jnp.float16) else None)
+        rdot = lambda x, w: jax.lax.ragged_dot(             # noqa: E731
+            x, w.astype(xt.dtype), group_sizes, precision=prec)
+        a, b = rdot(sorted_x, wg), rdot(sorted_x, wu)
+        act = jax.nn.silu(a.astype(jnp.float32)).astype(xt.dtype) * b
+        o = rdot(act, wd)
+    with jax.named_scope("combine"):
+        inv = jnp.argsort(order, stable=True)
+        out_rows = jnp.take(o, inv, axis=0).reshape(t, k, d)
+        return jnp.sum(gates[..., None].astype(xt.dtype) * out_rows, axis=1)

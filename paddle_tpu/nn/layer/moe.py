@@ -9,37 +9,51 @@ from the shardings.
 """
 from __future__ import annotations
 
+import math
 from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
 
 import paddle_tpu
-from paddle_tpu.core.jax_compat import shard_map
+from paddle_tpu.core.jax_compat import on_tpu, shard_map
 from paddle_tpu.core.dispatch import defop
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.functional import moe as FM
+from paddle_tpu.kernels.moe_experts import (experts_hit, moe_decode_problems,
+                                            moe_experts_decode)
 
 
 @defop("moe_mlp_dropless", amp_policy="white",
        spmd_note="dropless grouped matmul (ragged_dot): expert dim may "
                  "shard over 'ep' (XLA gathers tokens), token dims over "
                  "dp/sp; prefer the capacity path for ep>1 meshes")
-def _moe_mlp_dropless(x, router_w, wg, wu, wd, k):
+def _moe_mlp_dropless(x, router_w, wg, wu, wd, k, few_rows=False,
+                      with_hit=False):
     """Dropless dMoE forward (MegaBlocks semantics; VERDICT r3 item 5 —
     the reference's capacity gate at moe_layer.py:263 silently drops
     overflow tokens; this path honors every token's top-k exactly).
-    Returns (out, aux_loss)."""
+    `few_rows` takes the few-rows kernel (kernels/moe_experts.py: a
+    decode step reads each expert hit once) in place of the
+    sort-and-group path. Returns (out, aux_loss), and with `with_hit` the
+    count of distinct experts the rows hit as a third."""
     lead = x.shape[:-1]
     d = x.shape[-1]
     xt = x.reshape(-1, d)
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        router_w.astype(jnp.float32))
-    idx, gates, aux = FM.topk_gating_dropless(logits, k)
-    out = FM.moe_dropless_mlp(xt, wg, wu, wd, idx, gates)
-    return out.reshape(*lead, d), aux
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            router_w.astype(jnp.float32))
+        idx, gates, aux = FM.topk_gating_dropless(logits, k)
+        hit = experts_hit(idx, wg.shape[0]) if with_hit else None
+    if few_rows:
+        with jax.named_scope("experts"):
+            out = moe_experts_decode(xt, wg, wu, wd, idx, gates)
+    else:
+        out = FM.moe_dropless_mlp(xt, wg, wu, wd, idx, gates)
+    out = out.reshape(*lead, d)
+    return (out, aux, hit) if with_hit else (out, aux)
 
 
 # ---------------------------------------------------------------------------
@@ -168,9 +182,27 @@ class MoEMLP(Layer):
             [e, f, d], default_initializer=init)
         self.aux_loss = None
 
-    def forward(self, x):
+    def _few_rows(self, x):
+        """Whether this call's experts run in the few-rows Pallas kernel:
+        on a TPU, forward only (the kernel has no gradient), whenever the
+        shapes are a decode step's (`moe_decode_problems`); everywhere
+        else the grouped path."""
+        w = self.experts_gate_weight
+        return on_tpu() and not self.training and not moe_decode_problems(
+            math.prod(x.shape[:-1]), x.shape[-1], w.shape[-1],
+            w._value.dtype)
+
+    def forward(self, x, with_hit=False):
+        """`with_hit` (dropless, forward only: an integer output has no
+        place on a training tape) returns (out, the count of distinct
+        experts the rows hit): what a decode step reads of the experts."""
+        hit = None
+        ep = current_expert_parallel() if self.dropless else None
+        if with_hit and (ep is not None or not self.dropless):
+            raise NotImplementedError(
+                "with_hit counts the experts of the dropless path on one "
+                "device")
         if self.dropless:
-            ep = current_expert_parallel()
             if ep is not None:
                 out, aux = _moe_mlp_dropless_ep(
                     x, self.router_weight, self.experts_gate_weight,
@@ -179,11 +211,11 @@ class MoEMLP(Layer):
                     buffer_rows=ep["buffer_rows"])
                 self.aux_loss = aux
                 return out
-            out, aux = _moe_mlp_dropless(x, self.router_weight,
-                                         self.experts_gate_weight,
-                                         self.experts_up_weight,
-                                         self.experts_down_weight,
-                                         k=self.top_k)
+            out, aux, *hit = _moe_mlp_dropless(
+                x, self.router_weight, self.experts_gate_weight,
+                self.experts_up_weight, self.experts_down_weight,
+                k=self.top_k, few_rows=self._few_rows(x),
+                with_hit=with_hit)
         else:
             out, aux = _moe_mlp(x, self.router_weight,
                                 self.experts_gate_weight,
@@ -192,4 +224,4 @@ class MoEMLP(Layer):
                                 k=self.top_k,
                                 capacity_factor=self.capacity_factor)
         self.aux_loss = aux
-        return out
+        return (out, hit[0]) if with_hit else out

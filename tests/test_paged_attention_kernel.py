@@ -331,7 +331,8 @@ def test_decode_plan_reads_no_knob(monkeypatch):
     assert decode_plan(32, 32, 64, 16, 48, "bfloat16", slots=16) == want
     assert set(inspect.signature(paged_decode_attention).parameters) == {
         "q", "k_pool", "v_pool", "block_tables", "lens", "k_scale",
-        "v_scale", "sm_scale", "interpret"}
+        "v_scale", "sm_scale", "interpret",
+        "select"}       # an operand (a per-key mask), not a block size
 
 
 def test_shape_contract_names_what_cannot_be_tiled():

@@ -216,8 +216,9 @@ def test_every_pallas_call_site_passes_a_literal_name():
                     f"{path}:{node.lineno}: pallas_call without a " \
                     f"literal name="
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 13
-    assert set().union(*_NAMES.values()) | {"flash_bwd_dq"} == set(names)
+    assert len(names) == len(set(names)) == 14
+    assert set().union(*_NAMES.values()) \
+        | {"flash_bwd_dq", "moe_experts_decode"} == set(names)
 
 
 def _trainer(mesh=None, **cfg_kw):
@@ -331,6 +332,103 @@ def test_engine_programs_lower_for_tpu(as_tpu, size, b, mp, kv_dtype, page):
     # prefill attends through the jnp gather path: no flash kernel yet
     # (ROADMAP open item) — pin it so the day it changes is noticed
     assert _calls(lowered) == 0
+
+
+# -- the decoder with a key selection and routed experts, at the widths of
+# -- its serve cell (8 slots, 32 / 4 heads of 128, 416 pages of 16; 128
+# -- experts of 2048 x 768, 8 a row)
+
+def _shapes(described, *specs):
+    return [jax.ShapeDtypeStruct(shape, dt, sharding=described)
+            for shape, dt in specs]
+
+
+def test_few_rows_expert_kernel_compiles_at_the_cells_widths(
+        as_tpu, described_v5e):
+    from paddle_tpu.kernels.moe_experts import (moe_decode_problems,
+                                                moe_experts_decode)
+    bf16, e, d, f = jnp.bfloat16, 128, 2048, 768
+    assert not moe_decode_problems(8, d, f, bf16)
+    assert moe_decode_problems(8, d, 100, bf16) \
+        and moe_decode_problems(4096, d, f, bf16)
+    shapes = _shapes(described_v5e, ((8, d), bf16), ((e, d, f), bf16),
+                     ((e, d, f), bf16), ((e, f, d), bf16),
+                     ((8, 8), jnp.int32), ((8, 8), jnp.float32))
+    fn = jax.jit(moe_experts_decode)
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"moe_experts_decode"}
+    if described_v5e is not None:
+        lowered.compile()       # Mosaic: VMEM under the scoped default
+
+
+def test_paged_decode_under_a_selection_compiles_at_the_cells_geometry(
+        as_tpu, described_v5e):
+    from paddle_tpu.kernels.paged_attention import (decode_plan,
+                                                    paged_decode_attention)
+    bf16, b, mp, page = jnp.bfloat16, 8, 416, 16
+    plan = decode_plan(32, 4, 128, page, mp, bf16, slots=b)
+    assert (plan.fold, plan.pack, plan.heads) == (1, 1, 4)
+    pool = ((b * mp + 1, 4, page, 128), bf16)
+    shapes = _shapes(described_v5e, ((b, 32, 128), bf16), pool, pool,
+                     ((b, mp), jnp.int32), ((b,), jnp.int32),
+                     ((b, mp * page), jnp.bool_))
+    fn = jax.jit(lambda q, k, v, bt, lens, sel: paged_decode_attention(
+        q, k, v, bt, lens, select=sel))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"paged_attention_decode"}
+    # the tick-finding patterns: the block table is the call's first operand
+    call = re.search(r"tpu_custom_call.*", lowered.as_text()).group(0)
+    assert re.search(rf"\(tensor<{b}x{mp}xi32>", call), call[:300]
+    if described_v5e is not None:
+        lowered.compile()
+
+
+def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.models.sparse_attn_moe import (SparseAttnMoeConfig,
+                                                   SparseAttnMoeForCausalLM)
+    from paddle_tpu.nn.layer import moe as moe_layer
+    monkeypatch.setattr(moe_layer, "on_tpu", lambda: True)
+    layers, b, mp = 2, 8, 416
+    paddle_tpu.seed(0)
+    model = SparseAttnMoeForCausalLM(SparseAttnMoeConfig(
+        vocab_size=512, num_hidden_layers=layers, num_experts=16,
+        hidden_size=256, moe_intermediate_size=128))
+    model = paddle_tpu.amp.decorate(models=model, level="O2",
+                                    dtype="bfloat16")
+    model.eval()
+    eng = PagedKVEngine(model, max_slots=b, page_size=16, num_pages=65,
+                        max_pages_per_slot=mp, kernel=None)
+    assert eng.decode_kernel == "pallas" and eng.index_dim == 64
+    assert eng.decode_plan.grid == (b, 1, 52)
+    # 8 rows x 32 heads x a 6,656-token window: chunks of 1,024 alone
+    assert eng._prefill_limit(1) == 1024
+    pools = [a for kv in eng.pools for a in kv]
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+    tick = eng._tick_fn(False)
+    lowered = _lower_tpu(tick.func, *tick.args, z(b), z(b), z(b, dt=bool),
+                         z(b), z(b, mp), z(b), key, pools)
+    # each kernel traced and lowered once, called once a layer
+    assert _kernel_names(lowered) == {"paged_attention_decode",
+                                      "moe_experts_decode"}
+    assert _calls(lowered) == 2
+    text = lowered.as_text(debug_info=True)
+    for scope in ("kv_write", "paged_attn", "indexer", "select", "moe",
+                  "router", "experts", "sample"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    chunk = eng._prefill_chunk_fn(1024, 1)
+    lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 1024), z(1), z(1),
+                         z(1, mp), pools)
+    # a prefill's rows take the grouped path, its attention the jnp one
+    assert _calls(lowered) == 0
+    text = lowered.as_text(debug_info=True)
+    for scope in ("dispatch", "experts", "combine", "select"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    assert "ragged_dot" in lowered.as_text()
 
 
 @pytest.mark.parametrize("entry", ["ce", "norm", "rope"])

@@ -1,0 +1,263 @@
+#!/usr/bin/env python3
+"""Does the sparse-attention MoE serve cell's `correct` see what it claims
+to? Run by hand on the chip (the train cells have benchmarks/prove_check.py).
+
+    python3 tools/prove_serve_check.py --workload <serve cell> --seed <n> ...
+
+For each seed: the cell's own check (`benchmarks/serve.py
+check_against_reference`: one seeded request through the engine, then the
+reference's full forward) as the run makes it, and again with a fault
+planted in the program: the key selection off (every causal key attended),
+the index pool not written on decode, the last layer's experts skipped
+(their down projections zero in the engine's view of the weights).
+Last, the honest engine's tokens against the reference computed in float8
+(e4m3, scaled per tensor: every matrix, and every value the reference
+stores, through its `store`): the nearest precision under the bf16 the
+configuration states. Each reading is what the harness's own
+comparison makes of it: the max and mean logit gap in sd, and `correct` as
+`serve.run` decides it (max <= the traffic file's tolerance). A control
+also proves that its fault was planted before its reading counts (the
+patched function ran inside the engine's programs; the engine's view of
+the weights holds the zeros; the reference's matrices changed) and says
+how many of the engine's tokens it changed. The tolerance is set from this
+table (PERF.md); it is not part of a run.
+"""
+from __future__ import annotations
+
+import argparse
+import collections
+import contextlib
+import gc
+import json
+import os
+import sys
+import types
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, nargs="+", default=[0],
+                    help="seeds that take every control")
+    ap.add_argument("--honest", type=int, nargs="*", default=[],
+                    help="further seeds that take the honest reading alone")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--out", default=None)
+    args = ap.parse_args()
+    from benchmarks import run, serve
+    cell = run.load_cell(args.workload, args.rehearse)
+    if args.rehearse:
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.inference import PagedKVEngine, paged
+    from paddle_tpu.jit.functional import state_tensors
+    if jax.devices()[0].platform != "tpu" and not args.rehearse:
+        raise SystemExit("no accelerator")
+    cfg, traffic, builder = cell["config"], cell["traffic"], cell["builder"]
+    geo = traffic["engine"]
+    tol = traffic["check"]["tolerance_sd"]
+
+    @contextlib.contextmanager
+    def patched(obj, name, new):
+        old = getattr(obj, name)
+        setattr(obj, name, new)
+        try:
+            yield
+        finally:
+            setattr(obj, name, old)
+
+    calls = collections.Counter()   # how often a planted function ran
+
+    def selection_off():
+        def every_causal_key(scores, causal, k):
+            calls["selection_off"] += 1
+            return causal
+        return patched(paged, "select_top", every_causal_key)
+
+    def index_pool_stale():
+        real = paged._attend_indexed
+
+        def stale(q, k, v, cache, state, index):
+            out, new = real(q, k, v, cache, state, index)
+            if q.shape[1] > 1:
+                return out, new
+            calls["index_pool_stale_on_decode"] += 1
+            return out, (*new[:2], cache[2])
+        return patched(paged, "_attend_indexed", stale)
+
+    def engine(model):
+        return PagedKVEngine(model, max_slots=geo["max_slots"],
+                             page_size=geo["page_size"],
+                             num_pages=geo["num_pages"],
+                             max_pages_per_slot=geo["max_pages_per_slot"],
+                             steps_per_tick=geo["steps_per_tick"],
+                             kernel=None)
+
+    def check_then(model, seed, after, planted=lambda eng: None,
+                   judge=builder):
+        """The check with `after()` run between the engine's request and
+        the reference's forward: the engine takes its view of the weights
+        at its first program, the reference reads the model's afterwards.
+        `planted(eng)` looks at the engine once its request is through."""
+        eng = engine(model)
+        generate = eng.generate
+        seen = {}
+
+        def generate_then(*a, **kw):
+            out = generate(*a, **kw)
+            seen["tokens"] = [int(t) for t in out[0]]
+            planted(eng)
+            # the engine's pools, programs and their scratch are gone from
+            # the device before anything else is put beside 10 GB of weights
+            eng.stop()
+            eng.pools, eng._weights = None, None
+            eng._programs.clear()
+            jax.clear_caches()
+            gc.collect()
+            after()
+            return out
+        eng.generate = generate_then
+        worst, mean, n = serve.check_against_reference(
+            judge, model, cfg, eng, traffic, seed)
+        return {"max_sd": worst, "mean_sd": mean, "positions": n,
+                # as serve.run decides it, nothing having failed or compiled
+                "correct": bool(worst <= tol), "tokens": seen["tokens"]}
+
+    def check(model, seed, planted=lambda eng: None):
+        return check_then(model, seed, lambda: None, planted)
+
+    def to_float8(x):
+        """x as float8 holds it (e4m3: 4 bits of exponent, 3 of mantissa),
+        scaled per tensor. `reduce_precision` is an operation of its own:
+        a narrowing and widening pair of converts may be dropped by the
+        compiler as excess precision."""
+        scale = jnp.max(jnp.abs(x.astype(jnp.float32))) / 224.0 + 1e-30
+        return (jax.lax.reduce_precision(x.astype(jnp.float32) / scale, 4, 3)
+                * scale).astype(x.dtype)
+
+    matrix_to_float8 = jax.jit(to_float8)
+
+    # the family's reference with every stored value through float8
+    in_float8 = types.SimpleNamespace(reference=types.SimpleNamespace(
+        logits=lambda p, c, row: builder.reference.logits(
+            p, c, row, store=to_float8)))
+
+    def round_matrices(model):
+        moved = 0.0
+        for t in state_tensors(model).values():
+            if t._value.ndim >= 2:
+                rounded = matrix_to_float8(t._value)
+                moved += float(jnp.sum(jnp.abs(
+                    rounded.astype(jnp.float32)
+                    - t._value.astype(jnp.float32))))
+                t._value = rounded
+        if not moved > 0.0:
+            raise RuntimeError("float8 rounding changed no matrix")
+
+    def build(seed):
+        model = builder.build(cfg, seed, dtype="bfloat16",
+                              seq=traffic["prompt_tokens"][1],
+                              settings=traffic["model_settings"])
+        model.eval()
+        return model
+
+    def release(model):
+        """10 GB twice do not fit: let go of a model's weights by hand,
+        whatever still holds the model object."""
+        for t in state_tensors(model).values():
+            t._value = None
+        gc.collect()
+
+    def ran(name):
+        def planted(_eng):
+            if not calls[name]:
+                raise RuntimeError(f"{name}: the planted function never "
+                                   f"ran inside the engine's programs")
+            calls[name] = 0
+        return planted
+
+    table = []
+    for seed in args.seed:
+        row = {"seed": seed}
+
+        def read(name, reading):
+            tokens = reading.pop("tokens")
+            if name == "honest":
+                row["honest_tokens"] = tokens
+            elif "honest_tokens" in row:
+                reading["tokens_changed"] = sum(
+                    a != b for a, b in zip(tokens, row["honest_tokens"]))
+            else:
+                row["first_tokens"] = tokens
+            row[name] = reading
+            print(f"[reading] seed {seed} {name} {json.dumps(reading)}",
+                  flush=True)
+        # first, while nothing else is on the device: the honest program
+        # against a float8 reference; the matrices are rounded in place,
+        # one at a time, after the engine's request, and the model is
+        # drawn anew afterwards
+        model = build(seed)
+        read("float8_reference", check_then(
+            model, seed, lambda: round_matrices(model), judge=in_float8))
+        release(model)
+        model = build(seed)
+        read("honest", check(model, seed))
+        if row.pop("first_tokens") != row["honest_tokens"]:
+            raise RuntimeError("the honest engine gave other tokens on the "
+                               "same seed: the controls compare nothing")
+        with selection_off():
+            read("selection_off", check(model, seed, ran("selection_off")))
+        with index_pool_stale():
+            read("index_pool_stale_on_decode", check(
+                model, seed, ran("index_pool_stale_on_decode")))
+        # the last layer's experts give nothing: their down projections
+        # are zeros while the engine takes its weights, the reference
+        # reads the model's own again
+        down = model.model.layers[-1].mlp.experts_down_weight
+        name = next(n for n, t in state_tensors(model).items() if t is down)
+        kept, down._value = down._value, jnp.zeros_like(down._value)
+
+        def zeros_reached(eng):
+            if float(jnp.sum(jnp.abs(eng._weights[0][name]))) != 0.0:
+                raise RuntimeError("the engine's view of the weights does "
+                                   "not hold the zeroed experts")
+        read("last_experts_skipped", check_then(
+            model, seed, lambda: setattr(down, "_value", kept),
+            zeros_reached))
+        del kept, down, row["honest_tokens"]
+        release(model)
+        del model
+        table.append(row)
+        print(json.dumps(row), flush=True)
+    for seed in args.honest:
+        model = build(seed)
+        reading = check(model, seed)
+        del reading["tokens"]
+        print(f"[reading] seed {seed} honest {json.dumps(reading)}",
+              flush=True)
+        table.append({"seed": seed, "honest": reading})
+        release(model)
+        del model
+    for name in table[0]:
+        if name == "seed":
+            continue
+        rows = [r for r in table if name in r]
+        worst = [r[name]["max_sd"] for r in rows]
+        wrong = sum(not r[name]["correct"] for r in rows)
+        print(f"{name:28s} max_sd {min(worst):.4f} .. {max(worst):.4f}  "
+              f"mean_sd {min(r[name]['mean_sd'] for r in rows):.4f} .. "
+              f"{max(r[name]['mean_sd'] for r in rows):.4f}  tolerance "
+              f"{tol}: correct false on {wrong} of {len(rows)} seeds")
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(table, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
