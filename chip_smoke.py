@@ -311,6 +311,46 @@ def _paged_case(size, dtype, interpret, rng, *, int8):
     return fn, [q, kp, vp, bt, lens, ks, vs], ref
 
 
+def _kv_write_case(size, dtype, interpret, rng, *, tokens):
+    """`tokens` new tokens a slot into K and V pools stored as the decode
+    kernel's rows, some slots with fewer valid ones and some with none;
+    the reference is XLA's scatter over the same pools by heads."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.paged import PagedState, _token_coords
+    from paddle_tpu.kernels.paged_attention import (pages_by_head,
+                                                    paged_kv_write,
+                                                    pool_rows_shape)
+    page = 16
+    d, hk, b = size.head_dim, size.kv_heads, size.decode_slots
+    mp = size.decode_tokens // page
+    num_pages = b * mp + 1
+    rows = pool_rows_shape(num_pages, hk, d, page, dtype)
+    kp, vp = (rng.standard_normal(rows, np.float32).astype(dtype)
+              for _ in range(2))
+    k, v = (rng.standard_normal((b, tokens, hk, d), np.float32)
+            .astype(dtype) for _ in range(2))
+    bt = 1 + np.arange(b * mp, dtype=np.int32).reshape(b, mp)
+    lens = rng.integers(0, mp * page - tokens, size=b).astype(np.int32)
+    n_valid = rng.integers(0, tokens + 1, size=b).astype(np.int32)
+
+    def coords(bt, lens, n_valid):
+        return _token_coords(PagedState(bt, lens, n_valid), tokens, page,
+                             num_pages)
+
+    def fn(kp, vp, k, v, bt, lens, n_valid):
+        return paged_kv_write(kp, vp, k, v, *coords(bt, lens, n_valid),
+                              interpret=interpret)
+
+    def ref(kp, vp, k, v, bt, lens, n_valid):
+        phys, off = coords(bt, lens, n_valid)
+        return tuple(
+            pages_by_head(pool, hk, d).at[phys, :, off, :].set(
+                toks.reshape(b * tokens, hk, d), mode="drop").reshape(rows)
+            for pool, toks in ((kp, k), (vp, v)))
+
+    return fn, [kp, vp, k, v, bt, lens, n_valid], ref
+
+
 def _ce_case(size, dtype, interpret, rng, *, vocab_block):
     import jax
     import jax.numpy as jnp
@@ -445,6 +485,8 @@ def kernel_cases(size):
         ("flash_fwd_bwd_streamed_kv", p(_flash_case, stream=True)),
         ("paged_decode_bf16_page16", p(_paged_case, int8=False)),
         ("paged_decode_int8_page32", p(_paged_case, int8=True)),
+        ("paged_kv_write_one_token", p(_kv_write_case, tokens=1)),
+        ("paged_kv_write_prompt", p(_kv_write_case, tokens=40)),
         ("blockwise_ce_whole_vocab", p(_ce_case, vocab_block=0)),
         ("blockwise_ce_vocab_block",
          p(_ce_case, vocab_block=size.ce_vocab_block)),

@@ -17,6 +17,22 @@ reference's op-level API contract; THIS engine is what actually serves):
   reads are one page-gather per layer. No host bookkeeping inside the
   hot loop, and only one host<->device round trip per tick (a
   per-token fetch would put a host sync between every decode step).
+- Which shape a pool has where: an engine whose decode attends through
+  the Pallas kernel stores its plain (bf16 / f32) K and V pools as that
+  kernel's rows (`kernels/paged_attention.py pool_rows_shape`: the same
+  bytes as the shape above, which on the chip is another layout
+  wherever head_dim < 128) and writes them through the Pallas call
+  `paged_kv_write`, which aliases them; `_scatter_kv` chooses from the
+  trace's `decode_kernel_scope` and the pool it is handed, so the write
+  follows the attend and XLA never copies a pool into a layout of its
+  own. A jnp engine, int8 pools with their scale planes, the index
+  pool of a model with a key selection and direct callers of the op
+  keep the shape above and XLA's scatter (int8: the write rescales
+  whole pages; index: no Pallas call reads it). What gathers pages
+  (prefill, verify: `_attend_pages`, `_attend_selected`) views the
+  gathered window by heads and tokens, never the pool, and a page that
+  leaves the engine (a host-tier entry, an exported bundle) is
+  `(kv_heads, page_size, head_dim)` whatever its pool's shape.
 - Scheduling (admission, page allocation, retirement) is host-side
   Python BETWEEN ticks. A request can join at any tick boundary — i.e.
   mid-decode of every other request — which is the continuous-batching
@@ -91,6 +107,7 @@ import jax.numpy as jnp
 from paddle_tpu.core import compile_cache, jax_compat
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu import observability
+from paddle_tpu.kernels import paged_attention as _pk
 from paddle_tpu.nn.functional.key_selection import index_scores, select_top
 from paddle_tpu.observability import requests as obs_requests
 from paddle_tpu.inference.overload import (DeadlineExceeded,
@@ -194,7 +211,14 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
                 v_scale=None):
     """Scatter this call's (b, s, hk, d) k/v into their pages.
 
-    Plain pools: one vectorized scatter per pool. int8 pools (k_scale/
+    Plain pools: inside `decode_kernel_scope("pallas")`, pools stored as
+    the decode kernel's rows (`kernels/paged_attention.py
+    pool_rows_shape`: what a Pallas engine's K and V pools are) take the
+    Pallas write, which aliases them (`paged_kv_write`, interpreted where
+    the scope says so): the write follows the attend, so that no XLA op
+    touches such a pool and none relays it. Anything else is one
+    vectorized XLA scatter per pool, over the pool seen as (num_pages, hk,
+    page_size, d), the same values at the same places. int8 pools (k_scale/
     v_scale present, (num_pages, hk) f32): quantize AT SCATTER TIME —
     per-page-per-head symmetric scales grow monotonically (scatter-max
     of |token|/127 into the touched pages), previously written int8
@@ -207,14 +231,24 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
     """
     b, s, hk, d = k.shape
     num_pages = kp.shape[0]
-    phys_f, off_f = _token_coords(state, s, kp.shape[2], num_pages)
+    page_size = _pk.page_size_of(kp, hk, d)
+    phys_f, off_f = _token_coords(state, s, page_size, num_pages)
 
     if k_scale is None:
-        kp = kp.at[phys_f, :, off_f, :].set(
-            k.reshape(b * s, hk, d).astype(kp.dtype), mode="drop")
-        vp = vp.at[phys_f, :, off_f, :].set(
-            v.reshape(b * s, hk, d).astype(vp.dtype), mode="drop")
-        return kp, vp, None, None
+        kind, interpret = _decode_kernel_choice()
+        if kind == "pallas" and kp.shape == _pk.pool_rows_shape(
+                num_pages, hk, d, page_size, kp.dtype):
+            kp, vp = _pk.paged_kv_write(kp, vp, k, v, phys_f, off_f,
+                                        interpret=interpret)
+            return kp, vp, None, None
+
+        def scatter(pool, toks):
+            by_head = pool.reshape(num_pages, hk, page_size, d)
+            return by_head.at[phys_f, :, off_f, :].set(
+                toks.reshape(b * s, hk, d).astype(pool.dtype),
+                mode="drop").reshape(pool.shape)
+
+        return scatter(kp, k), scatter(vp, v), None, None
 
     def quant_scatter(pool, scale, toks):
         toks = toks.reshape(b * s, hk, d).astype(jnp.float32)
@@ -242,33 +276,39 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
 
 
 def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
-                  v_scale=None):
+                  v_scale=None, hk=None):
     """jnp fallback attend: gather each slot's page window and run a
     dense masked softmax in f32. GQA folds query heads into a head-
     group axis (reshape + einsum) instead of jnp.repeat-ing K/V —
     the gathered window is never materialized hq/hk times.
 
-    q: (b, s, hq, d). Returns (b, s, hq*d) in q.dtype.
+    q: (b, s, hq, d). `hk`: the pools' kv heads, where they are stored
+    as rows and do not say (the gathered window is seen by heads and
+    tokens, never the pool). Returns (b, s, hq*d) in q.dtype.
     """
     bt, lens = _val(state.block_tables), _val(state.lens)
     b, s, hq, d = q.shape
-    hk = kp.shape[1]
+    hk = hk or kp.shape[1]
     pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+    def window(pool):                                        # (b,hk,L,d)
+        return jnp.moveaxis(_pk.pages_by_head(pool[bt], hk, d), 2,
+                            1).reshape(b, hk, -1, d)
 
     # window column c IS logical position c (page j holds positions
     # [j*page_size, (j+1)*page_size)), so the causal bound is c <= pos.
-    ks = jnp.moveaxis(kp[bt], 2, 1).reshape(b, hk, -1, d)    # (b,hk,L,d)
-    vs = jnp.moveaxis(vp[bt], 2, 1).reshape(b, hk, -1, d)
+    ks, vs = window(kp), window(vp)
     L = ks.shape[2]
     ks = ks.astype(jnp.float32)
     vs = vs.astype(jnp.float32)
     if k_scale is not None:
         # dequantize the gathered window: per-page-per-head scales
         # broadcast over (page_size, d) — (b, mp, hk) -> (b, hk, L, 1)
-        ksg = jnp.repeat(jnp.swapaxes(k_scale[bt], 1, 2),
-                         kp.shape[2], axis=2)[..., None]
-        vsg = jnp.repeat(jnp.swapaxes(v_scale[bt], 1, 2),
-                         vp.shape[2], axis=2)[..., None]
+        ps = L // bt.shape[1]
+        ksg = jnp.repeat(jnp.swapaxes(k_scale[bt], 1, 2), ps,
+                         axis=2)[..., None]
+        vsg = jnp.repeat(jnp.swapaxes(v_scale[bt], 1, 2), ps,
+                         axis=2)[..., None]
         ks = ks * ksg
         vs = vs * vsg
     qt = jnp.swapaxes(q, 1, 2).astype(jnp.float32)           # (b,hq,s,d)
@@ -320,7 +360,7 @@ def _select_keys(qi, w, ip, state: PagedState, topk):
         return select_top(scores, causal, topk)
 
 
-def _attend_selected(q, kp, vp, state: PagedState, select):
+def _attend_selected(q, kp, vp, state: PagedState, select, hk=None):
     """Attend over the selected keys only: `select` (b, s, L) bool over
     the columns of each slot's page window (causality is in it). The keys
     go by in blocks of whole pages under an online softmax, as many blocks
@@ -328,11 +368,13 @@ def _attend_selected(q, kp, vp, state: PagedState, select):
     one block's scores (`_SELECT_SCORE_BYTES`, from the shapes) and a
     prefill chunk does not pay for the window's empty end.
 
-    q: (b, s, hq, d). Returns (b, s, hq*d) in q.dtype."""
+    q: (b, s, hq, d); `hk` as in `_attend_pages`. Returns (b, s, hq*d)
+    in q.dtype."""
     bt, lens, n_valid = (_val(state.block_tables), _val(state.lens),
                          _val(state.n_valid))
     b, s, hq, d = q.shape
-    hk, ps = kp.shape[1], kp.shape[2]
+    hk = hk or kp.shape[1]
+    ps = _pk.page_size_of(kp, hk, d)
     g, mp = hq // hk, bt.shape[1]
     # pages a block: the largest power of two whose scores fit
     bp = 1
@@ -349,8 +391,9 @@ def _attend_selected(q, kp, vp, state: PagedState, select):
     def block(i, carry):
         m, l, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(btp, i * bp, bp, 1)
-        kb = jnp.moveaxis(kp[pages], 2, 1).reshape(b, hk, cols, d)
-        vb = jnp.moveaxis(vp[pages], 2, 1).reshape(b, hk, cols, d)
+        kb, vb = (jnp.moveaxis(_pk.pages_by_head(pool[pages], hk, d), 2,
+                               1).reshape(b, hk, cols, d)
+                  for pool in (kp, vp))
         seen = jax.lax.dynamic_slice_in_dim(sel, i * cols, cols, 2)
         seen = seen[:, None, None]                          # (b,1,1,s,c)
         sc = jnp.einsum("bhgsd,bhcd->bhgsc", qg, kb.astype(q.dtype),
@@ -386,6 +429,7 @@ def _attend_indexed(q, k, v, cache, state: PagedState, index):
     qi, ki, w, topk = index
     qi, ki, w = _val(qi), _val(ki), _val(w)
     b, s, hq, d = q.shape
+    hk = k.shape[2]
     with jax.named_scope("kv_write"):
         kp, vp, _ks, _vs = _scatter_kv(kp, vp, k, v, state)
         phys_f, off_f = _token_coords(state, s, ip.shape[2], ip.shape[0])
@@ -395,15 +439,13 @@ def _attend_indexed(q, k, v, cache, state: PagedState, index):
     kind, interpret = _decode_kernel_choice()
     with jax.named_scope("paged_attn"):
         if kind == "pallas" and s == 1:
-            from paddle_tpu.kernels.paged_attention import \
-                paged_decode_attention
-            out = paged_decode_attention(
+            out = _pk.paged_decode_attention(
                 q[:, 0], kp, vp, _val(state.block_tables),
                 _val(state.lens), interpret=interpret,
-                select=select[:, 0])
+                select=select[:, 0], kv_heads=hk)
             out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
         else:
-            out = _attend_selected(q, kp, vp, state, select)
+            out = _attend_selected(q, kp, vp, state, select, hk)
     return Tensor(out), (Tensor(kp), Tensor(vp), Tensor(ip))
 
 
@@ -414,7 +456,9 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
     and decode): prefill is s=prompt tokens at lens=0, decode is s=1.
 
     q: (b, s, hq, d), k/v: (b, s, hk, d) — already position-encoded.
-    cache: (k_pool, v_pool), each (num_pages, hk, page_size, d) — or,
+    cache: (k_pool, v_pool), each (num_pages, hk, page_size, d), or
+    stored as the decode kernel's rows (module doc: a Pallas engine's
+    own pools; the geometry is read from k and the pool's size) — or,
     for int8 KV quantization, (k_pool, v_pool, k_scale, v_scale) with
     int8 pools and (num_pages, hk) f32 per-page-per-head scales — or,
     for a learned key selection, (k_pool, v_pool, index_pool) with one
@@ -427,7 +471,9 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
     `decode_kernel_scope("pallas")` take the Pallas paged-decode
     kernel (kernels/paged_attention.py); everything else — prefill,
     speculative verify, direct callers — runs the jnp gather/softmax
-    path. All index math is traced (block tables / lens are device
+    path. Inside that scope every call's write into plain pools
+    stored as rows is the Pallas write (`_scatter_kv`), whatever s.
+    All index math is traced (block tables / lens are device
     data), so this runs under jit — unlike the eager op's host-numpy
     bookkeeping.
     """
@@ -448,6 +494,7 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
             "k_scale, v_scale); got a 2-tuple — pass the per-page "
             "scales (see PagedKVEngine(kv_dtype='int8'))")
     b, s, hq, d = q.shape
+    hk = k.shape[2]
 
     # the scopes are metadata of the compiled ops: a device trace can
     # tell the pool writes (and the copies XLA makes for them) from the
@@ -459,18 +506,16 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
     kind, interpret = _decode_kernel_choice()
     with jax.named_scope("paged_attn"):
         if kind == "pallas" and s == 1:
-            from paddle_tpu.kernels.paged_attention import \
-                paged_decode_attention
             # the query position is lens (this token's k/v just landed
             # there); the kernel masks cols <= lens and skips pages
             # past it
-            out = paged_decode_attention(
+            out = _pk.paged_decode_attention(
                 q[:, 0], kp, vp, _val(state.block_tables),
                 _val(state.lens), k_scale=k_scale, v_scale=v_scale,
-                interpret=interpret)
+                interpret=interpret, kv_heads=hk)
             out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
         else:
-            out = _attend_pages(q, kp, vp, state, k_scale, v_scale)
+            out = _attend_pages(q, kp, vp, state, k_scale, v_scale, hk)
     if quantized:
         return Tensor(out), (Tensor(kp), Tensor(vp),
                              Tensor(k_scale), Tensor(v_scale))
@@ -769,8 +814,22 @@ class PagedKVEngine:
                              else 3 if self.index_dim else 2)
 
         def make_pools(n_heads, head_dim, n_layers):
+            """One layer's pools, `n_layers` times. Plain K and V pools
+            of an engine whose decode attends through the Pallas kernel
+            are stored as that kernel's rows (`pool_rows_shape`): the
+            kernel and the Pallas write are then the only ops that
+            touch them and XLA has no second layout to copy them into.
+            int8 pools with their scale planes and the index pool keep
+            (num_pages, heads, page_size, width) and XLA's scatter: the
+            int8 write rescales whole pages (no cell runs it, and a
+            second quantising kernel is not worth growing for it), and
+            no Pallas call reads an index pool."""
             shape = (self.num_pages, n_heads, self.page_size, head_dim)
             sshape = (self.num_pages, n_heads)
+            if self.kv_write == "pallas":
+                shape = _pk.pool_rows_shape(self.num_pages, n_heads,
+                                            head_dim, self.page_size,
+                                            pool_dtype)
             if self.index_dim:      # never a draft's: refused above
                 ishape = (self.num_pages, 1, self.page_size,
                           self.index_dim)
@@ -788,10 +847,8 @@ class PagedKVEngine:
                      jnp.zeros(shape, pool_dtype))
                     for _ in range(n_layers)]
 
-        self.pools = make_pools(n_kv, hd, cfg.num_hidden_layers)
         # decode attend path (class doc): resolve once, fail fast on a
         # forced-but-impossible geometry with the misaligned dims named
-        from paddle_tpu.kernels import paged_attention as _pk
         on_tpu = self._on_tpu = jax_compat.on_tpu()
         if kernel not in (None, "pallas", "jnp"):
             raise ValueError(f"kernel must be None, 'pallas' or 'jnp' "
@@ -845,6 +902,17 @@ class PagedKVEngine:
                         self.page_size,
                         kv_dtype=pool_dtype):  # auto: draft can't ride
                     self.decode_kernel = "jnp"
+        # which op writes a step's K and V: the Pallas write wherever
+        # the pools are stored as rows (`_scatter_kv` chooses the same
+        # way, from the scope and the pool)
+        self.kv_write = ("pallas" if self.decode_kernel == "pallas"
+                         and kv_dtype != "int8" else "xla")
+        # what a K or V page is outside the engine (a host-tier entry,
+        # an exported bundle), whatever shape the pools store it in
+        self._page_shape = (n_kv, self.page_size, hd)
+        self.pools = make_pools(n_kv, hd, cfg.num_hidden_layers)
+        if draft_model is not None:
+            self._draft_page_shape = (dn_kv, self.page_size, dhd)
             self.draft_pools = make_pools(dn_kv, dhd,
                                           dcfg.num_hidden_layers)
         # the kernel's own account of how it engages at this geometry
@@ -951,7 +1019,8 @@ class PagedKVEngine:
         # count against its bulkhead
         self._queued_by_tenant: dict[str, int] = {}
         # telemetry for tests / the serving bench
-        self.stats = {"ticks": 0, "prefills": 0, "tokens_out": 0,
+        self.stats = {"ticks": 0, "kv_write_kernel_ticks": 0,
+                      "prefills": 0, "tokens_out": 0,
                       "admitted": 0, "finished": 0, "cancelled": 0,
                       "expired": 0, "overloaded": 0,
                       "prefill_s": 0.0, "tick_s": 0.0,
@@ -1595,12 +1664,16 @@ class PagedKVEngine:
         buffers do next (recycle scale-zeroing, donation); the
         blocking D2H (np.asarray) happens on the WORKER thread, so a
         spill never stalls a tick. `copy_to_host_async` starts the
-        transfer early where the backend supports it."""
+        transfer early where the backend supports it. A K or V page
+        leaves as (kv_heads, page_size, head_dim) whatever shape its
+        pool stores it in (the same bytes), so that engines with
+        different kernels exchange pages."""
 
-        def slices(pools):
+        def slices(pools, page_shape):
             out = []
             for grp in pools:
-                cut = tuple(a[page] for a in grp)
+                cut = tuple(a[page].reshape(page_shape) if i < 2
+                            else a[page] for i, a in enumerate(grp))
                 for a in cut:
                     f = getattr(a, "copy_to_host_async", None)
                     if f is not None:
@@ -1611,9 +1684,10 @@ class PagedKVEngine:
                 out.append(cut)
             return out
 
-        draft = (slices(self.draft_pools)
+        draft = (slices(self.draft_pools, self._draft_page_shape)
                  if self.draft_pools is not None else None)
-        self.host_tier.spill(key, slices(self.pools), draft)
+        self.host_tier.spill(key, slices(self.pools, self._page_shape),
+                             draft)
 
     def _tier_entry_compatible(self, entry):
         """A host entry must match this engine's pool geometry exactly
@@ -1625,7 +1699,7 @@ class PagedKVEngine:
         ref = self.pools[0]
         if len(grp) != len(ref):
             return False
-        if tuple(grp[0].shape) != tuple(ref[0].shape[1:]) or \
+        if tuple(grp[0].shape) != self._page_shape or \
                 str(grp[0].dtype) != str(ref[0].dtype):
             return False
         # entry.draft may be None even when this engine runs a draft
@@ -1639,7 +1713,9 @@ class PagedKVEngine:
     def _tier_upload(self, ents, pages):
         """One batched H2D `.at[idx].set` per pool buffer (the
         DevicePrefetcher lesson: stack on host, place once — not one
-        tiny transfer per page per layer)."""
+        tiny transfer per page per layer). The pages land in the shape
+        their pool stores them in (`_tier_capture`); the whole-page
+        scatter is XLA's, in a program of its own."""
         idx = jnp.asarray(pages, jnp.int32)
 
         def put(pools, per_entry):
@@ -1647,7 +1723,8 @@ class PagedKVEngine:
             for li, grp in enumerate(pools):
                 out.append(tuple(
                     grp[ai].at[idx].set(jnp.asarray(
-                        np.stack([pe[li][ai] for pe in per_entry])))
+                        np.stack([pe[li][ai] for pe in per_entry]).reshape(
+                            len(per_entry), *grp[ai].shape[1:])))
                     for ai in range(len(grp))))
             return out
 
@@ -1662,7 +1739,7 @@ class PagedKVEngine:
                 if blank is None:   # draft mirror was shed (or the
                     #                 peer runs no draft): zero pages
                     blank = [tuple(np.zeros(a.shape[1:], a.dtype)
-                                   for a in grp)
+                                   for a in grp)    # any shape of a page
                              for grp in self.draft_pools]
                 drafts.append(blank)
             self.draft_pools = put(self.draft_pools, drafts)
@@ -2387,9 +2464,12 @@ class PagedKVEngine:
         self._tick_count += 1
         self.stats["ticks"] += 1
         self.stats["tick_s"] += marks[-1] - marks[3]    # upload .. readback
+        self.stats["kv_write_kernel_ticks"] += self.kv_write == "pallas"
         if observability.ENABLED:
             observability.inc("inference.decode.kernel",
                               path=self.decode_kernel)
+            observability.inc("inference.kv_write.kernel",
+                              path=self.kv_write)
 
     def _step_spec(self, live, marks):
         """Speculative tick: greedy AND sampled slots ride it together

@@ -1,4 +1,5 @@
-"""Pallas paged-attention decode kernel (TPU), with int8 KV dequant.
+"""Pallas paged-attention decode kernel (TPU), with int8 KV dequant, and
+the Pallas write of a call's new K and V into the pools it reads.
 
 The serving hot path: PagedKVEngine's decode tick attends ONE query row
 per slot over that slot's whole paged KV window. The jnp path in
@@ -48,6 +49,27 @@ decode branch):
   bf16/f32 pool is never materialized in HBM — the quant_matmul.py
   lesson applied to KV.
 
+Which shape a pool has where. The documented shape of a pool, and the
+one every page has outside an engine, is by heads: `(num_pages, hk,
+page_size, d)`. The kernel reads a pool as rows, `pool_rows_shape`:
+`(num_pages, hk / pack, pack * page_size / fold, fold * d)`, the same
+bytes in the same order. On the chip the two differ wherever d < 128 (a
+64-wide minor dimension is padded to 128 lanes), so a reshape between
+them copies the pool, and XLA's scatter of a step's tokens wants a
+third layout of its own: a pool that XLA wrote and this kernel read was
+copied four times a decode step. So an engine that decodes through this
+kernel STORES its plain (bf16 / f32) K and V pools as rows, and
+`paged_kv_write` below writes a call's tokens into them through a
+Pallas call that aliases them: one grid step a block of touched pages,
+each copied whole into VMEM, merged with the new tokens under a mask
+and copied back, double-buffered as the decode kernel is. No XLA op
+touches such a pool, so none relays it. `paged_decode_attention` takes
+either shape (`kv_heads` says a pool stored as rows; one by heads is
+reshaped, which costs the copy); `pages_by_head` is the view back for
+what gathers pages (prefill, verify) or hands one out. int8 pools with
+their scale planes stay by heads and under XLA's scatter (their write
+rescales whole pages).
+
 Masking contract: query position per slot is `lens[i]` (the new token's
 k/v is already scattered at that position), so column c is visible iff
 c <= lens[i]. Unallocated / partial pages therefore never contribute.
@@ -69,7 +91,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.core.jax_compat import tpu_compiler_params
 
-__all__ = ["paged_decode_attention", "decode_shape_problems",
+__all__ = ["paged_decode_attention", "paged_kv_write", "pool_rows_shape",
+           "pages_by_head", "page_size_of", "decode_shape_problems",
            "check_decode_shapes", "select_shape_problems", "decode_plan",
            "DecodePlan"]
 
@@ -198,6 +221,29 @@ def _packing(hk, d, page_size, kv_dtype):
     sub = _sublane(kv_dtype)
     pack = sub // math.gcd(sub, page_size // fold)
     return fold, (pack if hk % pack == 0 else 1)
+
+
+def pool_rows_shape(num_pages, hk, d, page_size, kv_dtype):
+    """The shape a K or V pool is STORED in where the Pallas calls below
+    are the only ones to touch it: `(num_pages, hk / pack, pack *
+    page_size / fold, fold * d)`, the rows of `_packing`. It is a
+    row-major reshape of `(num_pages, hk, page_size, d)`: a page is the
+    same bytes either way (`pages_by_head` is the view back), but on the
+    chip a reshape between the two moves data wherever d < 128, so a pool
+    is kept in one of them for good."""
+    fold, pack = _packing(hk, d, page_size, kv_dtype)
+    return (num_pages, hk // pack, pack * page_size // fold, fold * d)
+
+
+def page_size_of(pool, hk, d):
+    """Tokens a page of `pool` holds, in either shape."""
+    return math.prod(pool.shape[1:]) // (hk * d)
+
+
+def pages_by_head(pages, hk, d):
+    """Pages cut or gathered from a pool, `(..., *page)` with the page
+    in either shape, seen as `(..., hk, page_size, d)`."""
+    return pages.reshape(*pages.shape[:-3], hk, -1, d)
 
 
 def _tile_bytes(rows, cols, dtype):
@@ -423,13 +469,16 @@ def _decode_kernel(bt_ref, lens_ref, *refs, page_size, plan, g, quantized,
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
                            k_scale=None, v_scale=None, sm_scale=None,
-                           interpret=False, select=None):
+                           interpret=False, select=None, kv_heads=None):
     """One decode step of paged attention for every slot.
 
     q: (b, hq, d) — one (position-encoded) query row per slot.
     k_pool/v_pool: (num_pages, hk, page_size, d), bf16/f32, or int8
         with `k_scale`/`v_scale` (num_pages, hk) f32 such that
-        k ~= k_pool * k_scale[page, head, None, None].
+        k ~= k_pool * k_scale[page, head, None, None]. Or, with
+        `kv_heads` = hk said, pools stored as rows (`pool_rows_shape`):
+        the kernel reads them as they are, where a pool in the first
+        shape is reshaped for it (on the chip, at d < 128: copied).
     block_tables: (b, max_pages) int32 — physical page of each logical
         page per slot (engine convention: 0 = never-written trash page
         for unallocated entries; pages past the length are not read).
@@ -447,7 +496,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
     path — same contract as ring_attention_local(use_flash=True)).
     """
     _, hq, d = q.shape
-    _, hk, page_size, _ = k_pool.shape
+    hk = kv_heads or k_pool.shape[1]
+    page_size = page_size_of(k_pool, hk, d)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if k_pool.dtype == jnp.int8 and (k_scale is None or v_scale is None):
@@ -457,23 +507,26 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, *,
                         kv_dtype=k_pool.dtype)
     if select is None:
         return _decode(q, k_pool, v_pool, block_tables, lens, k_scale,
-                       v_scale, sm_scale=sm_scale, interpret=interpret)
+                       v_scale, hk=hk, sm_scale=sm_scale,
+                       interpret=interpret)
     problems = select_shape_problems(hk, d, page_size, k_pool.dtype)
     if problems:
         raise ValueError("paged_decode_attention(select=): "
                          + "; ".join(problems))
     return _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
-                   select, sm_scale=sm_scale, interpret=interpret)
+                   select, hk=hk, sm_scale=sm_scale, interpret=interpret)
 
 
 # jitted on its own: a model calls this once a layer, and a caller's
 # trace then holds ONE traced and lowered kernel that every layer calls,
 # not one a layer (a server's start is mostly tracing its tick)
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("hk", "sm_scale", "interpret"))
 def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
-            select=None, *, sm_scale, interpret):
+            select=None, *, hk, sm_scale, interpret):
     b, hq, d = q.shape
-    num_pages, hk, page_size, _ = k_pool.shape
+    num_pages = k_pool.shape[0]
+    page_size = page_size_of(k_pool, hk, d)
     mp = block_tables.shape[1]
     quantized = k_pool.dtype == jnp.int8
     plan = decode_plan(hq, hk, d, page_size, mp, k_pool.dtype, slots=b)
@@ -497,8 +550,9 @@ def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, gp - n), (0, 0)))
 
     def rows_of(pool):
-        return pool.reshape(num_pages, hk // pack,
-                            pack * page_size // fold, lanes)
+        # nothing to do for a pool stored as rows
+        return pool.reshape(pool_rows_shape(num_pages, hk, d, page_size,
+                                            pool.dtype))
 
     bt = block_tables.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
@@ -566,3 +620,174 @@ def _decode(q, k_pool, v_pool, block_tables, lens, k_scale, v_scale,
     # no column is visible to a negative position
     o = jnp.where((lens >= 0)[:, None, None, None], o, 0.0)
     return o.reshape(b, hq, d)
+
+
+# -- the write: a call's new tokens into the pages they belong to ----------
+
+# what the write's buffers may take of VMEM. A page of a step costs 8
+# page-sized buffers: two windows a pool, and K's and V's block of new
+# tokens, which the pipeline double-buffers
+_WRITE_VMEM_BUDGET = 4 * 2 ** 20
+
+
+def _write_kernel(pages_ref, lo_ref, hi_ref, knew_ref, vnew_ref, _k_in,
+                  _v_in, k_hbm, v_hbm, kbuf, vbuf, sem, *, num_pages, rp,
+                  fold, d):
+    """Grid (page blocks,), run in order; a step takes `t` touched pages
+    (the first dim of the blocks of new tokens). Scalar prefetch, flat
+    (steps * t,) i32: the physical page of each, and the tokens [lo, hi)
+    of it that this call writes; a page id past the pool is skipped.
+
+    The pools stay in HBM, stored as rows (`pool_rows_shape`), and are
+    the call's outputs, aliased onto its inputs: a step copies its pages
+    whole (every head) into one of two VMEM windows, puts the new tokens
+    in under a mask (row r, lane l of a page hold token (r mod rp) * fold
+    + l // d: `_packing`), and copies them back, while the pages of the
+    step after are on their way in and those of the step before on their
+    way out. A page is touched by one step of a call: what the engine
+    guarantees, since a page being written belongs to one slot."""
+    i, n = pl.program_id(0), pl.num_programs(0)
+    t = knew_ref.shape[0]
+    cur = jax.lax.rem(i, 2)
+
+    def copies(blk, slot, do, back):
+        way = 1 if back else 0
+
+        def page(p, carry):
+            at = pages_ref[blk * t + p]
+
+            @pl.when(at < num_pages)
+            def _():
+                for a, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                    ends = (buf.at[slot, p], hbm.at[at]) if back \
+                        else (hbm.at[at], buf.at[slot, p])
+                    getattr(pltpu.make_async_copy(
+                        *ends, sem.at[way, a, slot]), do)()
+            return carry
+        jax.lax.fori_loop(0, t, page, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        copies(0, 0, "start", False)
+
+    @pl.when(i >= 1)
+    def _drained():         # the window about to be filled again
+        copies(i - 1, 1 - cur, "wait", True)
+
+    @pl.when(i + 1 < n)
+    def _prefetch():
+        copies(i + 1, 1 - cur, "start", False)
+
+    copies(i, cur, "wait", False)
+    shape = kbuf.shape[2:]
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    token = jax.lax.rem(row, rp) * fold + jax.lax.div(lane, d)
+
+    def merge(p, carry):
+        at = i * t + p
+
+        @pl.when(pages_ref[at] < num_pages)
+        def _():
+            new = (token >= lo_ref[at]) & (token < hi_ref[at])
+            kbuf[cur, p] = jnp.where(new, knew_ref[p], kbuf[cur, p])
+            vbuf[cur, p] = jnp.where(new, vnew_ref[p], vbuf[cur, p])
+        return carry
+    jax.lax.fori_loop(0, t, merge, 0)
+    copies(i, cur, "start", True)
+
+    @pl.when(i == n - 1)
+    def _last():
+        copies(i, cur, "wait", True)
+
+
+# jitted on its own, as `_decode` is: one lowered kernel a program
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_write(k_pool, v_pool, k, v, pages, offsets, *,
+                   interpret=False):
+    """Write a call's new tokens into K and V pools stored as rows, in
+    place: the values `pool.at[page, :, offset, :].set(token)` writes into
+    a `(num_pages, hk, page_size, d)` pool, cast to the pool's dtype and
+    nothing else.
+
+    k_pool/v_pool: `pool_rows_shape`, bf16 or f32 (an int8 pool's write
+        rescales its pages: it keeps XLA's scatter).
+    k, v: (b, s, hk, d), a row's tokens at consecutive positions.
+    pages, offsets: (b * s,) i32, the physical page and the place in it
+        of each token; a page past the pool drops the token, and a row's
+        dropped tokens are its last ones (`n_valid`).
+
+    Returns the pools. The call aliases them, so inside a caller's jit
+    XLA neither copies a pool nor chooses a layout for it."""
+    b, s, hk, d = k.shape
+    num_pages = k_pool.shape[0]
+    page = k_pool.shape[1:]
+    page_size = page_size_of(k_pool, hk, d)
+    fold, pack = _packing(hk, d, page_size, k_pool.dtype)
+    if page != pool_rows_shape(1, hk, d, page_size, k_pool.dtype)[1:]:
+        raise ValueError(f"paged_kv_write: pools of pages {page} are not "
+                         f"stored as rows (pool_rows_shape) of {hk} heads "
+                         f"of {d}")
+    # pages a row can touch from any start, pages a step, steps
+    npg = (s + page_size - 2) // page_size + 1
+    page_bytes = math.prod(page[:-2]) * _tile_bytes(*page[-2:],
+                                                    k_pool.dtype)
+    t = max(1, min(b * npg, _WRITE_VMEM_BUDGET // (8 * page_bytes)))
+    steps = -(-b * npg // t)
+
+    # page m of a row holds its tokens [m * page_size - o0, + page_size),
+    # o0 the first token's place; it is touched where the first of them
+    # is a token the call writes
+    pages = pages.reshape(b, s).astype(jnp.int32)
+    o0 = offsets.reshape(b, s)[:, :1].astype(jnp.int32)
+    n_valid = jnp.sum(pages < num_pages, axis=1, keepdims=True)
+    first = jnp.arange(npg, dtype=jnp.int32)[None] * page_size - o0
+    at = jnp.take_along_axis(pages, jnp.clip(first, 0, s - 1), axis=1)
+    at = jnp.where(first < n_valid, at, num_pages)
+    lo = jnp.maximum(first, 0) - first
+    hi = jnp.minimum(n_valid - first, page_size)
+
+    def flat(x, fill):
+        return jnp.pad(x.reshape(-1), (0, steps * t - b * npg),
+                       constant_values=fill)
+
+    def staged(x):
+        """The new tokens laid out as the pages they go to."""
+        if s == 1:
+            x = jnp.broadcast_to(x[:, :, None], (b, 1, page_size, hk, d))
+        else:
+            tok = jnp.arange(npg * page_size, dtype=jnp.int32)[None] - o0
+            x = jnp.take_along_axis(
+                x, jnp.clip(tok, 0, s - 1)[:, :, None, None], axis=1)
+            x = x.reshape(b, npg, page_size, hk, d)
+        x = jnp.swapaxes(x, 2, 3).astype(k_pool.dtype)
+        x = x.reshape(b * npg, *page)
+        return jnp.pad(x, ((0, steps * t - b * npg),) + ((0, 0),) * 3)
+
+    new_spec = pl.BlockSpec((t, *page), lambda i, *_sp: (i, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    window = (2, t, *page)
+    return pl.pallas_call(
+        functools.partial(_write_kernel, num_pages=num_pages,
+                          rp=page_size // fold, fold=fold, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(steps,),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+            scratch_shapes=[pltpu.VMEM(window, k_pool.dtype),
+                            pltpu.VMEM(window, v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2, 2))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # operands count from the scalars on
+        input_output_aliases={5: 0, 6: 1},
+        # in order: a step starts the copies its neighbours wait for
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_kv_write",
+    )(flat(at, num_pages), flat(lo, 0), flat(hi, 0), staged(k), staged(v),
+      k_pool, v_pool)

@@ -399,6 +399,10 @@ METRICS = {
     "inference.decode.kernel": ("counter",
                                 "decode ticks by attend path (label: "
                                 "path = pallas | jnp)"),
+    "inference.kv_write.kernel": ("counter",
+                                  "decode ticks by the op that writes a "
+                                  "step's K and V (label: path = pallas "
+                                  "| xla)"),
     "inference.kv.bytes_per_slot": ("gauge",
                                     "KV-pool HBM bytes one fully-grown "
                                     "slot pins (all layers, real "

@@ -183,6 +183,50 @@ def test_engine_handoff_greedy_parity_int8(kernel):
         dec.stop()
 
 
+@pytest.mark.parametrize("pre_kernel,dec_kernel", [
+    ("pallas", "jnp"), ("jnp", "pallas"), ("pallas", "pallas")])
+def test_engines_with_different_kernels_exchange_pages(pre_kernel,
+                                                       dec_kernel):
+    """A Pallas engine stores K and V as its kernel's rows; a page that
+    leaves it is (kv_heads, page_size, head_dim) all the same, so a jnp
+    engine imports it (and the other way round) and decodes the
+    monolithic engine's tokens."""
+    model = _model()
+    kw = dict(max_slots=2, page_size=4, num_pages=32,
+              max_pages_per_slot=8, steps_per_tick=2,
+              prefix_cache_pages=8)
+    prompt = PREFIX + [21, 22, 23]
+    mono = PagedKVEngine(model, kernel="jnp", **kw)
+    want = mono.generate([prompt], max_new_tokens=6)[0]
+    mono.stop()
+    pre = PagedKVEngine(model, role="prefill", host_tier_bytes=1 << 20,
+                        kernel=pre_kernel, **kw)
+    dec = PagedKVEngine(model, role="decode", kernel=dec_kernel, **kw)
+    try:
+        stored = {"pallas": (32, 1, 8, 8), "jnp": (32, 2, 4, 8)}
+        assert pre.pools[0][0].shape == stored[pre_kernel]
+        assert dec.pools[0][0].shape == stored[dec_kernel]
+        pre.generate([prompt], max_new_tokens=1)
+        keys = chain_keys(prompt, 4)
+        entries = pre.export_pages(keys)
+        assert [e.key for e in entries] == keys
+        for e in entries:
+            assert all(a.shape == (2, 4, 8) for kv in e.layers for a in kv)
+        dec.stage_import(unpack_bundle(pack_bundle(entries)))
+        assert dec.generate([prompt], max_new_tokens=6)[0] == want
+        assert dec.disagg.snapshot()["imported_pages"] == 2
+        for key in keys:
+            for gp, gd in zip(pre.pools, dec.pools):
+                for a, b in zip(gp, gd):
+                    assert np.asarray(a[pre.prefix_cache.get(key)]) \
+                        .tobytes() == np.asarray(
+                            b[dec.prefix_cache.get(key)]).tobytes()
+        _ledger_settled(dec)
+    finally:
+        pre.stop()
+        dec.stop()
+
+
 def test_role_validation_and_stats_block():
     model = _model()
     with pytest.raises(ValueError):

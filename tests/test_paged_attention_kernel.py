@@ -26,7 +26,10 @@ from paddle_tpu.kernels.paged_attention import (_VMEM_BUDGET,
                                                 check_decode_shapes,
                                                 decode_plan,
                                                 decode_shape_problems,
-                                                paged_decode_attention)
+                                                paged_decode_attention,
+                                                paged_kv_write,
+                                                pages_by_head,
+                                                pool_rows_shape)
 
 
 def _setup(b=3, hq=4, hk=2, d=8, ps=4, npages=16, mp=4, seed=0):
@@ -332,7 +335,8 @@ def test_decode_plan_reads_no_knob(monkeypatch):
     assert set(inspect.signature(paged_decode_attention).parameters) == {
         "q", "k_pool", "v_pool", "block_tables", "lens", "k_scale",
         "v_scale", "sm_scale", "interpret",
-        "select"}       # an operand (a per-key mask), not a block size
+        "select",       # an operand (a per-key mask), not a block size
+        "kv_heads"}     # of pools stored as rows, which do not say it
 
 
 def test_shape_contract_names_what_cannot_be_tiled():
@@ -351,3 +355,162 @@ def test_shape_contract_names_what_cannot_be_tiled():
     # interpret mode has no tiles
     assert not decode_shape_problems(3, 3, 96, 16, interpret=True,
                                      kv_dtype="bfloat16")
+
+
+# -- the write: paged_kv_write against XLA's scatter, bit for bit -----------
+
+def _write_case(b, s, hk, d, ps, mp, dtype, starts, n_valid, seed=0):
+    """Pools (by heads and by rows), a call's tokens and their flat
+    coordinates, and what XLA's scatter leaves in the pools."""
+    from paddle_tpu.inference.paged import PagedState, _token_coords
+    rng = np.random.default_rng(seed)
+    n = b * mp + 1
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32), dtype)
+
+    pools = draw(n, hk, ps, d), draw(n, hk, ps, d)
+    toks = draw(b, s, hk, d), draw(b, s, hk, d)
+    bt = rng.permutation(np.arange(1, n, dtype=np.int32)).reshape(b, mp)
+    state = PagedState(jnp.asarray(bt), jnp.asarray(starts, jnp.int32),
+                       jnp.asarray(n_valid, jnp.int32))
+    phys, off = _token_coords(state, s, ps, n)
+    want = [p.at[phys, :, off, :].set(
+        t.reshape(b * s, hk, d).astype(dtype), mode="drop")
+        for p, t in zip(pools, toks)]
+    shape = pool_rows_shape(n, hk, d, ps, dtype)
+    return [p.reshape(shape) for p in pools], toks, (phys, off), want
+
+
+def _same_bits(got, want, hk, d):
+    got = pages_by_head(got, hk, d)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+
+_WRITES = {
+    # the dense serve cell's heads and pages: two tokens a 128-lane row,
+    # two heads a bf16 sublane tile -> pools of (n, 16, 16, 128)
+    "cell_one_token": dict(b=4, s=1, hk=32, d=64, ps=16, mp=3,
+                           dtype="bfloat16", starts=[0, 17, 31, 47],
+                           n_valid=[1, 1, 1, 1], rows=(16, 16, 128)),
+    "cell_dead_slots": dict(b=4, s=1, hk=32, d=64, ps=16, mp=3,
+                            dtype="bfloat16", starts=[5, 16, 0, 40],
+                            n_valid=[1, 0, 0, 1], rows=(16, 16, 128)),
+    "cell_prompt": dict(b=1, s=40, hk=32, d=64, ps=16, mp=3,
+                        dtype="bfloat16", starts=[0], n_valid=[37],
+                        rows=(16, 16, 128)),
+    # a start inside a page (a chunk after a chunk, a verify row)
+    "cell_from_inside_a_page": dict(b=3, s=40, hk=32, d=64, ps=16, mp=5,
+                                    dtype="bfloat16", starts=[21, 16, 7],
+                                    n_valid=[40, 33, 1],
+                                    rows=(16, 16, 128)),
+    "cell_verify_rows": dict(b=4, s=5, hk=32, d=64, ps=16, mp=3,
+                             dtype="bfloat16", starts=[14, 3, 27, 43],
+                             n_valid=[5, 5, 0, 5], rows=(16, 16, 128)),
+    # the doc-QA cell's: a head is a whole tile, rows are the page
+    "docqa_one_token": dict(b=3, s=1, hk=4, d=128, ps=16, mp=4,
+                            dtype="bfloat16", starts=[63, 0, 33],
+                            n_valid=[1, 1, 1], rows=(4, 16, 128)),
+    "docqa_chunk": dict(b=1, s=48, hk=4, d=128, ps=16, mp=5,
+                        dtype="bfloat16", starts=[16], n_valid=[45],
+                        rows=(4, 16, 128)),
+    "f32_pool": dict(b=2, s=20, hk=8, d=64, ps=16, mp=3, dtype="float32",
+                     starts=[3, 0], n_valid=[20, 11], rows=(8, 8, 128)),
+    # more pages than one step takes: the windows alternate
+    "many_steps": dict(b=6, s=70, hk=32, d=64, ps=16, mp=6,
+                       dtype="bfloat16", starts=[9, 0, 16, 1, 15, 2],
+                       n_valid=[70, 70, 3, 64, 17, 0],
+                       rows=(16, 16, 128)),
+    "nothing_valid": dict(b=3, s=7, hk=2, d=8, ps=4, mp=3,
+                          dtype="float32", starts=[0, 5, 11],
+                          n_valid=[0, 0, 0], rows=(1, 8, 8)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_WRITES))
+def test_kv_write_equals_the_scatter_bit_for_bit(name):
+    geo = dict(_WRITES[name])
+    rows = geo.pop("rows")
+    hk, d = geo["hk"], geo["d"]
+    pools, toks, coords, want = _write_case(**geo)
+    assert pools[0].shape[1:] == rows
+    before = [np.asarray(p.astype(jnp.float32)) for p in pools]
+    got = paged_kv_write(*pools, *toks, *coords, interpret=True)
+    for g, w in zip(got, want):
+        _same_bits(g, w, hk, d)
+    if name == "nothing_valid":
+        # nothing written, and no page past the pool read
+        for g, was in zip(got, before):
+            np.testing.assert_array_equal(
+                np.asarray(g.astype(jnp.float32)), was)
+
+
+@pytest.mark.parametrize("s", [1, 21])
+def test_kv_write_under_jit_and_scan_with_the_pools_carried(s):
+    """As the engine's tick runs it: the pools are the scan's carry and
+    each step writes the tokens after the ones before."""
+    from paddle_tpu.inference.paged import PagedState, _token_coords
+    b, hk, d, ps, mp, steps = 3, 32, 64, 16, 6, 3
+    pools, _, _, _ = _write_case(b, s, hk, d, ps, mp, "bfloat16",
+                                 [0] * b, [s] * b)
+    n = pools[0].shape[0]
+    rng = np.random.default_rng(1)
+    bt = jnp.asarray(rng.permutation(
+        np.arange(1, n, dtype=np.int32)).reshape(b, mp))
+    toks = jnp.asarray(rng.normal(size=(steps, 2, b, s, hk, d)),
+                       jnp.bfloat16)
+    lens0 = jnp.asarray([5, 16, 30], jnp.int32)
+    n_valid = jnp.asarray([s, 0, max(s - 2, 1)], jnp.int32)
+
+    def coords(lens):
+        return _token_coords(PagedState(bt, lens, n_valid), s, ps, n)
+
+    @jax.jit
+    def run(kp, vp):
+        def step(carry, kv):
+            kp, vp, lens = carry
+            kp, vp = paged_kv_write(kp, vp, kv[0], kv[1], *coords(lens),
+                                    interpret=True)
+            return (kp, vp, lens + n_valid), None
+        return jax.lax.scan(step, (kp, vp, lens0), toks)[0][:2]
+
+    got = run(*pools)
+    want = [pages_by_head(p, hk, d) for p in pools]
+    lens = lens0
+    for t in range(steps):
+        phys, off = coords(lens)
+        want = [w.at[phys, :, off, :].set(
+            toks[t, i].reshape(b * s, hk, d), mode="drop")
+            for i, w in enumerate(want)]
+        lens = lens + n_valid
+    for g, w in zip(got, want):
+        _same_bits(g, w, hk, d)
+
+
+def test_kv_write_refuses_pools_not_stored_as_rows():
+    pools, toks, coords, _ = _write_case(2, 1, 32, 64, 16, 2, "bfloat16",
+                                         [0, 3], [1, 1])
+    by_head = [pages_by_head(p, 32, 64) for p in pools]
+    with pytest.raises(ValueError, match="not stored as rows"):
+        paged_kv_write(*by_head, *toks, *coords, interpret=True)
+
+
+@pytest.mark.parametrize("name", ["cell", "docqa", "f32_small"])
+def test_decode_reads_pools_stored_as_rows(name):
+    """The decode kernel over pools in the shape they are stored in
+    (`kv_heads` said) gives what it gives over the same pools by heads."""
+    hq, hk, d, ps, dtype = {"cell": (8, 8, 64, 16, "bfloat16"),
+                            "docqa": (8, 2, 128, 16, "bfloat16"),
+                            "f32_small": (4, 2, 8, 4, "float32")}[name]
+    args, _, _ = _paged(b=2, hq=hq, hk=hk, d=d, ps=ps, mp=4,
+                        lens=[3 * ps + 1, ps - 1], dtype=dtype)
+    q, kp, vp, bt, lens = args
+    want = paged_decode_attention(q, kp, vp, bt, lens, interpret=True)
+    shape = pool_rows_shape(kp.shape[0], hk, d, ps, kp.dtype)
+    if name == "cell":
+        assert shape[1:] == (4, 16, 128)
+    got = paged_decode_attention(q, kp.reshape(shape), vp.reshape(shape),
+                                 bt, lens, interpret=True, kv_heads=hk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

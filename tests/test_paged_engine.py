@@ -664,6 +664,77 @@ def test_pallas_kernel_greedy_parity_vs_jnp():
     assert results["pallas"][0] == solo_a
 
 
+_STORED = {
+    # a request joins mid-decode; heads of 8 at pages of 4: two heads a
+    # sublane tile of rows
+    "join_mid_decode": dict(),
+    # heads of 64 at pages of 16: two tokens a 128-lane row
+    "folded_rows": dict(wide=True, page_size=16, num_pages=12,
+                        max_pages_per_slot=4),
+    # the second request of a prompt shares its pages and prefills a tail
+    "prefix_hit": dict(prefix_cache_pages=8, repeat=True),
+    # draft steps write one token, the verify pass four from inside a page
+    "speculative": dict(draft=True, spec_tokens=3),
+    # chunks after the first start where the one before ended
+    "chunked_prefill": dict(prefill_chunk=3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STORED))
+def test_pallas_engine_writes_pools_stored_as_rows(case):
+    """An engine whose decode attends through the kernel keeps K and V
+    as the kernel's rows and writes them through `paged_kv_write`
+    (interpreted here): its greedy tokens are the jnp engine's, whose
+    pools are by heads and written by XLA's scatter."""
+    from paddle_tpu.kernels.paged_attention import pool_rows_shape
+    kw = dict(_STORED[case])
+    wide, draft, repeat = (kw.pop(k, False)
+                           for k in ("wide", "draft", "repeat"))
+    model = _model()
+    if wide:
+        paddle_tpu.seed(0)
+        model = LlamaForCausalLM(tiny_llama_config(
+            num_hidden_layers=2, vocab_size=97, hidden_size=128,
+            intermediate_size=64, num_attention_heads=2,
+            num_key_value_heads=2))
+    if draft:
+        paddle_tpu.seed(5)
+        kw["draft_model"] = LlamaForCausalLM(model.config)
+    geo = dict(max_slots=2, page_size=4, num_pages=24,
+               max_pages_per_slot=6, steps_per_tick=2)
+    geo.update(kw)
+    pa, pb = [5, 9, 2, 14, 21, 7, 30, 4, 11], [17, 3, 11]
+    results = {}
+    for kern in ("jnp", "pallas"):
+        eng = PagedKVEngine(model, kernel=kern, **geo)
+        ra = eng.submit(pa, max_new_tokens=7)
+        eng.step()
+        rb = eng.submit(pa if repeat else pb, max_new_tokens=5)
+        eng.run_until_idle()
+        results[kern] = (ra.result(), rb.result())
+        cfg = model.config
+        hk = cfg.num_key_value_heads
+        hd = cfg.hidden_size // cfg.num_attention_heads
+        by_head = (eng.num_pages, hk, eng.page_size, hd)
+        if kern == "jnp":
+            assert eng.pools[0][0].shape == by_head
+            assert eng.stats["kv_write_kernel_ticks"] == 0
+            continue
+        rows = pool_rows_shape(eng.num_pages, hk, hd, eng.page_size,
+                               eng.pools[0][0].dtype)
+        assert rows != by_head
+        assert all(a.shape == rows for kv in eng.pools for a in kv)
+        if draft:
+            assert all(a.shape == rows for kv in eng.draft_pools
+                       for a in kv)
+            assert eng.stats["spec_ticks"] > 0
+        if repeat:
+            assert eng.stats["prefix_hits"] == 1
+        assert eng.stats["kv_write_kernel_ticks"] == eng.stats["ticks"] > 0
+    assert results["pallas"] == results["jnp"]
+    assert len(results["jnp"][0]) == 7 and len(results["jnp"][1]) == 5
+
+
 def test_pallas_kernel_long_generation_page_soak():
     """Long-generation parity soak: lens crosses >= 3 page boundaries
     (prompt 3 + 18 new = 21 positions over page_size-4 pages = 6
@@ -907,6 +978,11 @@ def test_decode_kernel_tick_counter():
             path="pallas") >= 1
         assert reg.counter("inference.decode.kernel").value(
             path="jnp") == 0
+        # the write follows the attend
+        assert reg.counter("inference.kv_write.kernel").value(
+            path="pallas") == eng.stats["kv_write_kernel_ticks"] >= 1
+        assert reg.counter("inference.kv_write.kernel").value(
+            path="xla") == 0
 
 
 @pytest.mark.parametrize("kernel,kv_dtype", [

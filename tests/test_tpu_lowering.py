@@ -178,6 +178,8 @@ _NAMES = {
                                   "flash_bwd_dkv"},
     "paged_decode_bf16_page16": {"paged_attention_decode"},
     "paged_decode_int8_page32": {"paged_attention_decode"},
+    "paged_kv_write_one_token": {"paged_kv_write"},
+    "paged_kv_write_prompt": {"paged_kv_write"},
     "blockwise_ce_whole_vocab": {"blockwise_ce_fwd", "blockwise_ce_dx",
                                  "blockwise_ce_dw"},
     "blockwise_ce_vocab_block": {"blockwise_ce_fwd", "blockwise_ce_dx",
@@ -216,7 +218,7 @@ def test_every_pallas_call_site_passes_a_literal_name():
                     f"{path}:{node.lineno}: pallas_call without a " \
                     f"literal name="
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 14
+    assert len(names) == len(set(names)) == 15
     assert set().union(*_NAMES.values()) \
         | {"flash_bwd_dq", "moe_experts_decode"} == set(names)
 
@@ -316,10 +318,25 @@ def test_engine_programs_lower_for_tpu(as_tpu, size, b, mp, kv_dtype, page):
                          z(b), z(b, mp), z(b), key, pools)
     # one decode kernel a layer: the kernel is traced and lowered once,
     # into a function of its own that every layer calls (XLA inlines it,
-    # so the compiled tick holds one custom call a layer)
-    assert _calls(lowered) == 1
+    # so the compiled tick holds one custom call a layer); the same for
+    # the write of a step's K and V, which plain pools take (stored as
+    # the decode kernel's rows) and int8 pools leave to XLA's scatter
+    plain = kv_dtype is None
+    assert eng.kv_write == ("pallas" if plain else "xla")
+    assert eng.pools[0][0].shape == (
+        (b * mp + 1, size.kv_heads // 2, 16, 128) if plain
+        else (b * mp + 1, size.kv_heads, page, size.head_dim))
+    assert _calls(lowered) == 1 + plain
     assert len(re.findall(r"call @_decode\(", lowered.as_text())) == layers
-    assert _kernel_names(lowered) == {"paged_attention_decode"}
+    assert len(re.findall(r"call @paged_kv_write\(", lowered.as_text())) \
+        == layers * plain
+    assert _kernel_names(lowered) == {"paged_attention_decode"} | (
+        {"paged_kv_write"} if plain else set())
+    # the benchmark finds the tick by the decode kernel's first operand,
+    # the two-dimensional block table: the write's scalars are flat
+    firsts = re.findall(r"tpu_custom_call.*?\(tensor<([\dx]+)xi32>",
+                        lowered.as_text())
+    assert sorted(firsts) == sorted([f"{b}x{mp}"] + [f"{b}"] * plain)
     text = lowered.as_text(debug_info=True)
     for scope in ("kv_write", "paged_attn", "sample"):
         assert re.search(rf'[/("]{scope}[/)"]', text), scope
@@ -330,8 +347,13 @@ def test_engine_programs_lower_for_tpu(as_tpu, size, b, mp, kv_dtype, page):
     lowered = _lower_tpu(prefill.func, *prefill.args, z(b, 512), z(b),
                          z(b), z(b, mp), pools)
     # prefill attends through the jnp gather path: no flash kernel yet
-    # (ROADMAP open item) — pin it so the day it changes is noticed
-    assert _calls(lowered) == 0
+    # (ROADMAP open item) — pin it so the day it changes is noticed; its
+    # one call is the write of the prompt's K and V
+    assert _calls(lowered) == plain
+    assert _kernel_names(lowered) == ({"paged_kv_write"} if plain
+                                      else set())
+    assert f"tensor<{b}x{mp}xi32>" not in "".join(
+        re.findall(r"tpu_custom_call.*", lowered.as_text()))
 
 
 # -- the decoder with a key selection and routed experts, at the widths of
@@ -414,8 +436,13 @@ def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
                          z(b), z(b, mp), z(b), key, pools)
     # each kernel traced and lowered once, called once a layer
     assert _kernel_names(lowered) == {"paged_attention_decode",
+                                      "paged_kv_write",
                                       "moe_experts_decode"}
-    assert _calls(lowered) == 2
+    assert _calls(lowered) == 3
+    # K and V as the kernel's rows (at heads of 128 the shape by heads),
+    # the index pool by heads under XLA's scatter
+    assert [a.shape for a in eng.pools[0]] == [
+        (65, 4, 16, 128), (65, 4, 16, 128), (65, 1, 16, 64)]
     text = lowered.as_text(debug_info=True)
     for scope in ("kv_write", "paged_attn", "indexer", "select", "moe",
                   "router", "experts", "sample"):
@@ -423,12 +450,86 @@ def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     chunk = eng._prefill_chunk_fn(1024, 1)
     lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 1024), z(1), z(1),
                          z(1, mp), pools)
-    # a prefill's rows take the grouped path, its attention the jnp one
-    assert _calls(lowered) == 0
+    # a prefill's rows take the grouped path, its attention the jnp one;
+    # the one call writes the chunk's K and V
+    assert _calls(lowered) == 1
+    assert _kernel_names(lowered) == {"paged_kv_write"}
     text = lowered.as_text(debug_info=True)
     for scope in ("dispatch", "experts", "combine", "select"):
         assert re.search(rf'[/("]{scope}[/)"]', text), scope
     assert "ragged_dot" in lowered.as_text()
+
+
+# -- no pool is copied: the serve cells' programs compiled for the chip ----
+
+def _pool_copies(compiled, pool):
+    """XLA's copies of arrays with a K or V pool's element count in a
+    compiled program's text, by op: `copy` changes a layout, `copy-start`
+    / `copy-done` move an array between memories."""
+    found = {}
+    for dims, op in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (copy|copy-start|copy-done)\(",
+            compiled.as_text()):
+        if np.prod([int(n) for n in dims.split(",")]) == pool.size:
+            found[op] = found.get(op, 0) + 1
+    return found
+
+
+def _compile_for(described, program, *args):
+    return program.func.lower(*jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=described),
+        (*program.args, *args))).compile()
+
+
+@pytest.mark.parametrize("cell", ["dense", "doc_qa"])
+def test_serve_cells_programs_copy_no_pool(as_tpu, described_v5e,
+                                           monkeypatch, cell):
+    """The K and V pools are stored as the decode kernel's rows and only
+    Pallas calls touch them, so XLA has no second layout to copy them
+    into: the tick of either serve cell, compiled for a v5e, holds no
+    copy of a pool-sized array of any kind (4 a pool a tick before), and
+    the prefill programs no layout copy (2 a pool; the compiler may still
+    move a pool through its fast memory beside the write, where it finds
+    room: `copy-start`, not counted). At the cells' slots, heads and
+    pages; two layers, which is what a copy count turns on."""
+    if described_v5e is None:
+        pytest.skip("libtpu cannot describe a v5e here: nothing compiles")
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    paddle_tpu.seed(0)
+    if cell == "dense":
+        from paddle_tpu.models import LlamaForCausalLM
+        b, mp, bucket = 16, 48, 512
+        model = LlamaForCausalLM(chip_smoke.llama_config(_SERVE_CELL, 2))
+        rows = (b * mp + 1, 16, 16, 128)
+    else:
+        from paddle_tpu.models.sparse_attn_moe import (
+            SparseAttnMoeConfig, SparseAttnMoeForCausalLM)
+        from paddle_tpu.nn.layer import moe as moe_layer
+        monkeypatch.setattr(moe_layer, "on_tpu", lambda: True)
+        b, mp, bucket = 8, 416, 1024
+        model = SparseAttnMoeForCausalLM(SparseAttnMoeConfig(
+            vocab_size=512, num_hidden_layers=2, num_experts=16,
+            hidden_size=256, moe_intermediate_size=128))
+        rows = (b * mp + 1, 4, 16, 128)
+    model = paddle_tpu.amp.decorate(models=model, level="O2",
+                                    dtype="bfloat16")
+    model.eval()
+    eng = PagedKVEngine(model, max_slots=b, page_size=16,
+                        num_pages=b * mp + 1, max_pages_per_slot=mp,
+                        kernel=None)
+    pools = [a for kv in eng.pools for a in kv]
+    assert eng.kv_write == "pallas" and pools[0].shape == rows
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+    tick = _compile_for(described_v5e, eng._tick_fn(False), z(b), z(b),
+                        z(b, dt=bool), z(b), z(b, mp), z(b), key, pools)
+    assert _pool_copies(tick, pools[0]) == {}
+    prefill = (eng._prefill_fn(bucket, 1) if cell == "dense"
+               else eng._prefill_chunk_fn(bucket, 1))
+    prefill = _compile_for(described_v5e, prefill, z(1, bucket), z(1),
+                           z(1), z(1, mp), pools)
+    assert "copy" not in _pool_copies(prefill, pools[0])
 
 
 @pytest.mark.parametrize("entry", ["ce", "norm", "rope"])
