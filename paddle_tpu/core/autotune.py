@@ -12,8 +12,8 @@ version picks Pallas block configurations instead of cuDNN algorithms:
   the winner for the rest of the process. Off-TPU (or with autotune
   disabled) it returns `default` — the hand-swept constants that were
   the only option before.
-- The cache starts SEEDED with the v5e sweep results recorded in
-  BASELINE.md, so bench-shape calls never pay a sweep.
+- The cache starts SEEDED with v5e sweep results (taken before PR 1),
+  so calls at the seeded shapes never pay a sweep.
 - Nothing is written to disk: a winner measured by one commit on one
   machine must not be read back by another commit measured after it,
   from a file git never saw. A new process re-measures a new shape (the
@@ -48,9 +48,9 @@ def time_fn(fn, iters: int = 6) -> float:
 _lock = threading.Lock()
 _mem: dict | None = None      # {"kernel|key": config}
 
-# v5e sweep results (BASELINE.md, taken before PR 1): these keys use
+# v5e sweep results (taken before PR 1): these keys use
 # the same signature format the kernels generate, so the seeded cache
-# covers the bench shapes without a first-run sweep.
+# covers those shapes without a first-run sweep.
 _SEED = {
     # flash fwd/bwd short-seq: (512, 512) won IN THE FULL TRAIN STEP
     # (larger q-blocks win in kernel isolation but lose in context).

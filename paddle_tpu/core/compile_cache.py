@@ -1,7 +1,7 @@
 """Where compiled programs are kept between processes — one rule.
 
-`Trainer`, `PagedKVEngine`, `inference.Predictor`, bench.py and
-chip_smoke.py all call `ensure()` before they build a program:
+`Trainer`, `PagedKVEngine`, `inference.Predictor`, benchmarks/run.py
+and chip_smoke.py all call `ensure()` before they build a program:
 
 - where `JAX_COMPILATION_CACHE_DIR` is set, jax reads it itself and this
   module sets NO cache directory in code — whoever runs the program

@@ -1,6 +1,6 @@
 """Published per-chip peaks, keyed by the `device_kind` jax reports.
 
-The ONE table: bench.py, the in-program MFU gauge
+The ONE table: chip_smoke.py, the in-program MFU gauge
 (observability/telemetry.py) and the auto-tuner's cost model all read
 it. A device that is not in it is an error, never a default — scoring
 an unknown chip against some other chip's peak is how rounds 1-2

@@ -61,8 +61,8 @@ def _merge_block(q, kj, vj, m, l, acc, sm_scale, causal, row_off, col_off):
 # -- flash-kernel ring (r5): per-shard Pallas flash + base-2 lse merge ------
 # The jnp _merge_block ring materializes the full (S_local, S_shard)
 # score matrix per step — ~8x slower than the flash kernel at S=8k
-# (tools/cp_bench.py). This path runs the SAME Pallas kernels the
-# single-chip flash path uses, merging per-shard partials by their
+# (round 5, on a side bench since deleted). This path runs the SAME
+# Pallas kernels the single-chip flash path uses, merging per-shard partials by their
 # base-2 lse; backward is a second ring rotating (k, v, dk, dv)
 # together so each shard's grads ride home with it.
 
@@ -228,14 +228,11 @@ def ring_attention_local(q, k, v, axis_name, causal=True, sm_scale=None,
     `axis_name`. Returns local (B, H, S_local, D). On TPU (or with
     interpret=True) block-aligned shapes take the flash-kernel ring;
     others keep the jnp online-softmax merge."""
-    import os
     from paddle_tpu.core import jax_compat
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if use_flash is None:
         use_flash = ((jax_compat.on_tpu() or interpret)
-                     and os.environ.get("PADDLE_TPU_RING_FLASH",
-                                        "1") != "0"
                      and _ring_flash_shapes_ok(q, k))
     if use_flash:
         plan = _ring_flash_plan(q.shape[1], k.shape[1], q.shape[2],

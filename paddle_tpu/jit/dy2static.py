@@ -599,8 +599,7 @@ def _while_carries(node, bound_before):
     one iteration: bound before the loop, read-before-written in the
     body, read by the test, or read anywhere after/outside the loop.
     Pure write-first temps (incl. `_` unpacking slots) stay body-local —
-    they caused spurious unbound-carry rejections (NOTES_r4
-    'environment facts', now deleted)."""
+    they caused spurious unbound-carry rejections."""
     assigned = _assigned(node.body)
     outside = getattr(node, "_pt_outside_loads", frozenset())
     return assigned & (set(bound_before) | _read_first(node.body)

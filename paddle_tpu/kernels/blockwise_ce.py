@@ -46,7 +46,6 @@ reasons into a ValueError naming every misaligned dim.
 from __future__ import annotations
 
 import functools
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +65,7 @@ _NEG_INF = -1e30
 # Pallas vocab-block default: W block (D, bv) bf16 + the (D, bv) f32 dW
 # scratch must co-reside in VMEM (at D=4096, bv=512: 4MB + 8MB — tight
 # but inside the 16MB budget with the x chunk)
-_BLOCK_V = int(_os.environ.get("PADDLE_TPU_BCE_BLOCK_V", 512))
+_BLOCK_V = 512
 
 
 def _prec(dtype):

@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os as _os
 
 import jax
 import jax.numpy as jnp
@@ -62,60 +61,29 @@ _LOG2E = 1.4426950408889634  # kernels exponentiate in base 2: exp(x) = exp2(x*l
 # broadcasting at 33 GB/s — 4.3 ms/step of layout waste.)
 _LSE_ROWS = 8
 
-if _os.environ.get("PADDLE_TPU_FLASH_LSE_LANES"):
-    import warnings as _warnings
-
-    _warnings.warn(
-        "PADDLE_TPU_FLASH_LSE_LANES no longer exists: the r4 transposed "
-        "(b, h, 8, sq) lse layout removed the lane-width knob entirely "
-        "(every tile is full). The env var is ignored.")
-
-# A/B flag: run the softmax exponentials in bf16 (packed VPU rate)
-# instead of f32. Changes numerics by ~1e-3 relative on p; the l/lse
-# accumulations stay f32.
-_BF16_EXP = _os.environ.get("PADDLE_TPU_FLASH_BF16_EXP", "0") in ("1",
-                                                                  "true")
-
-
-def _exp2(x):
-    if _BF16_EXP:
-        return jnp.exp2(x.astype(jnp.bfloat16))
-    return jnp.exp2(x)
-
-# Tuning knobs (swept on v5e: (512,512) best in the full train step; larger
+# Block sizes of a shape the autotune table (core/autotune.py) does not
+# hold (swept on v5e: (512,512) best in the full train step; larger
 # q-blocks win in kernel isolation but lose in context)
-_BLOCK_Q = int(_os.environ.get("PADDLE_TPU_FLASH_BLOCK_Q", 512))
-_BLOCK_K = int(_os.environ.get("PADDLE_TPU_FLASH_BLOCK_K", 512))
-_BLOCK_Q_BWD = int(_os.environ.get("PADDLE_TPU_FLASH_BLOCK_Q_BWD", 512))
-_BLOCK_K_BWD = int(_os.environ.get("PADDLE_TPU_FLASH_BLOCK_K_BWD", 512))
+_BLOCK_Q = 512
+_BLOCK_K = 512
+_BLOCK_Q_BWD = 512
+_BLOCK_K_BWD = 512
 # streamed-kv (long-sequence) kernels want much larger k blocks: fewer
 # grid steps and fewer lse/delta re-reads. S=16k b1 on v5e measured
 # 9.2k tok/s at bk=512 vs 13.9k at bk=2048.
-_BLOCK_K_STREAM = int(_os.environ.get("PADDLE_TPU_FLASH_BLOCK_K_STREAM",
-                                      2048))
-# hand q to the whole-kv forward kernel TRANSPOSED (b, h, d, s) so the
-# producer-side swapaxes fuses instead of XLA inserting a relayout copy
-# at the pallas boundary (A/B flag; see _flash_fwd_pallas)
-_QT = _os.environ.get("PADDLE_TPU_FLASH_QT", "0") in ("1", "true")
+_BLOCK_K_STREAM = 2048
 
 
 def _tuned_blocks(which, b, h, sq, sk, d, dtype, causal, seg_len=None):
     """(bq, bk) for the whole-kv kernels from the runtime autotune cache
-    (reference: phi/kernels/autotune/cache.h AlgorithmsCache). Explicit
-    env vars always win (the old behavior); cached/seeded shapes (the
-    bench family ships pre-seeded) never sweep; a NEW shape on a real
+    (reference: phi/kernels/autotune/cache.h AlgorithmsCache).
+    Cached/seeded shapes never sweep; a NEW shape on a real
     TPU is measured once standalone across a NARROW candidate set —
     narrow deliberately: big q-blocks win in kernel isolation but lose
     in the full train step (round-2 sweep), so only in-context-safe
     configs compete — and the winner is persisted to disk."""
     default = ((_BLOCK_Q, _BLOCK_K) if which == "flash_fwd"
                else (_BLOCK_Q_BWD, _BLOCK_K_BWD))
-    env_keys = (("PADDLE_TPU_FLASH_BLOCK_Q", "PADDLE_TPU_FLASH_BLOCK_K")
-                if which == "flash_fwd" else
-                ("PADDLE_TPU_FLASH_BLOCK_Q_BWD",
-                 "PADDLE_TPU_FLASH_BLOCK_K_BWD"))
-    if any(k in _os.environ for k in env_keys):
-        return default
     from paddle_tpu.core import autotune
     dname = {"bfloat16": "bf16", "float32": "f32",
              "float16": "f16"}.get(jnp.dtype(dtype).name,
@@ -186,7 +154,7 @@ def _pick_block(seq, target):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
-                block_k, kv_valid, seg_len=None, q_transposed=False):
+                block_k, kv_valid, seg_len=None):
     # lse_ref is None on the inference path (save_lse=False): the LSE
     # write is only needed as the backward's softmax residual.
     # seg_len: GQA fold — the q axis is G concatenated length-seg_len
@@ -195,16 +163,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     # k arrives pre-transposed as (1, 1, d, sk): the (1),(0) contraction is
     # the fastest Mosaic form for the hot q @ k dot. ((1,),(1,)) also
     # lowers for bf16 — the backward kernels use it (verified on v5e).
-    if q_transposed:   # q arrives (1, 1, d, bq): XLA's preferred
-        #                activation layout — no boundary relayout copy;
-        #                the score dot consumes the transposed lhs
-        #                directly (contract dim-0/dim-0, no VMEM
-        #                transpose). Measured -2% on v5e (BASELINE.md
-        #                round-3 perf attempts) — off by default, kept
-        #                for re-testing on other TPU generations.
-        bq, d = q_ref.shape[3], q_ref.shape[2]
-    else:
-        bq, d = q_ref.shape[2], q_ref.shape[3]
+    bq, d = q_ref.shape[2], q_ref.shape[3]
     kv_pad = k_ref.shape[3]
     iq = pl.program_id(2)
 
@@ -239,18 +198,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
         m, l, acc = carry
         kj = k_ref[0, 0, :, pl.ds(j * block_k, block_k)]   # (d, bk)
         vj = v_ref[0, 0, pl.ds(j * block_k, block_k), :]   # (bk, d)
-        if q_transposed:
-            # q is (d, bq): contract both dim-0 — the MXU streams the
-            # transposed lhs natively, no VMEM transpose
-            s = jax.lax.dot_general(
-                q, kj, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=prec)                          # (bq, bk) f32
-        else:
-            s = jax.lax.dot_general(
-                q, kj, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=prec)                          # (bq, bk) f32
+        s = jax.lax.dot_general(
+            q, kj, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec)                              # (bq, bk) f32
         # bf16: the package-global 'highest' would force an f32-contract
         # form Mosaic can't lower; bf16 inputs with f32 accumulation IS
         # the full-rate MXU mode
@@ -269,7 +220,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
                 valid = jnp.logical_and(valid, col <= row)
             s = jnp.where(valid, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = _exp2(s - m_new)
+        p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True,
                                     dtype=jnp.float32)
@@ -341,7 +292,7 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         m = m_scr[:, :1]
         l = l_scr[:, :1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = _exp2(s - m_new)
+        p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True,
                                     dtype=jnp.float32)
@@ -374,10 +325,7 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 # 3MB: S=8k (2.1MB k+v at d=64) stays whole-kv, S=16k (4.2MB) streams —
 # the whole-kv dq kernel at 16k measured 17.5M scoped vmem (>16M limit)
 # inside the full remat train step.
-_KV_VMEM_BYTES = int(_os.environ.get("PADDLE_TPU_FLASH_KV_VMEM",
-                                     3 * 1024 * 1024))
-
-
+_KV_VMEM_BYTES = 3 * 1024 * 1024
 
 
 def _stream_block_k(sk, d, itemsize, dtype=None):
@@ -386,15 +334,13 @@ def _stream_block_k(sk, d, itemsize, dtype=None):
     same VMEM budget that triggered streaming (a flat 2048 at large d or
     f32 would recreate the whole-kv overflow the budget exists to
     avoid). The target comes from the autotune cache (seeded with the
-    round-2 sweep: 2048 at 8k-32k) unless the env var is set."""
-    target = _BLOCK_K_STREAM
-    if "PADDLE_TPU_FLASH_BLOCK_K_STREAM" not in _os.environ:
-        from paddle_tpu.core import autotune
-        name = jnp.dtype(dtype).name if dtype is not None else "bf16"
-        name = {"bfloat16": "bf16", "float32": "f32",
-                "float16": "f16"}.get(name, name)
-        target = autotune.get("flash_stream_bk", f"s{sk}_{name}") \
-            or _BLOCK_K_STREAM
+    round-2 sweep: 2048 at 8k-32k), else _BLOCK_K_STREAM."""
+    from paddle_tpu.core import autotune
+    name = jnp.dtype(dtype).name if dtype is not None else "bf16"
+    name = {"bfloat16": "bf16", "float32": "f32",
+            "float16": "f16"}.get(name, name)
+    target = autotune.get("flash_stream_bk", f"s{sk}_{name}") \
+        or _BLOCK_K_STREAM
     budget_elems = _KV_VMEM_BYTES // (2 * d * itemsize)
     capped = max(512, (budget_elems // 512) * 512)
     return min(int(target), capped, sk)
@@ -492,21 +438,16 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=None, block_k=None,
                    pltpu.VMEM((bq, _LSE_ROWS), jnp.float32),
                    pltpu.VMEM((bq, d), jnp.float32)]
     else:
-        # PADDLE_TPU_FLASH_QT=1: hand q over TRANSPOSED (b, h, d, sq)
-        # so the swapaxes fuses into q's producer instead of XLA
-        # inserting a relayout copy (~5ms/step, NOTES_r2) at the pallas
-        # boundary; the kernel then uses a transposed-lhs dot. Measured
-        # SLOWER than eating the copy on v5e — default off.
-        q_t = _QT
+        # q goes in as (b, h, sq, d) and XLA inserts a relayout copy
+        # (~5 ms a step) at the pallas boundary. Handing it over
+        # transposed, so that the swapaxes fuses into q's producer and
+        # the kernel contracts a transposed lhs, measured 2 % SLOWER
+        # than eating the copy on v5e.
         kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
                                    causal=causal, block_k=bk, kv_valid=sk,
-                                   seg_len=seg_len, q_transposed=q_t)
+                                   seg_len=seg_len)
         qspec = ospec = pl.BlockSpec((1, 1, bq, d),
                                      lambda bi, hi, qi: (bi, hi, qi, 0))
-        if q_t:
-            q = jnp.swapaxes(q, 2, 3)
-            qspec = pl.BlockSpec((1, 1, d, bq),
-                                 lambda bi, hi, qi: (bi, hi, 0, qi))
         grid = (b, h, sq_p // bq)
         in_specs = [
             qspec,
@@ -587,7 +528,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                                                0) + start
                 valid = jnp.logical_and(valid, col <= row)
             s = jnp.where(valid, s, _NEG_INF)
-        p = _exp2(s - lse)                                   # (bq, bk)
+        p = jnp.exp2(s - lse)                                   # (bq, bk)
         dp = jax.lax.dot_general(
             do, vj, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)  # (bq, bk)
@@ -647,7 +588,7 @@ def _bwd_dq_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     + start
                 valid = jnp.logical_and(valid, col <= row)
             s = jnp.where(valid, s, _NEG_INF)
-        p = _exp2(s - lse)
+        p = jnp.exp2(s - lse)
         dp = jax.lax.dot_general(
             do, vj, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)
@@ -726,7 +667,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     + start
                 valid = jnp.logical_and(valid, col <= row_c)
             s_t = jnp.where(valid, s_t, _NEG_INF)
-        p_t = _exp2(s_t - lse_t)                             # (bk, bq)
+        p_t = jnp.exp2(s_t - lse_t)                             # (bk, bq)
         dv_scr[...] += jax.lax.dot_general(
             p_t.astype(doj.dtype), doj, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)  # (bk, d)
@@ -840,7 +781,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                     jnp.int32, (block_k, bq), 1) + start
                 valid = jnp.logical_and(valid, col <= row_c)
             s_t = jnp.where(valid, s_t, _NEG_INF)
-        p_t = _exp2(s_t - lse_t)                                 # (bk,bq)
+        p_t = jnp.exp2(s_t - lse_t)                                 # (bk,bq)
         dv_scr[pl.ds(j * block_k, block_k)] += jax.lax.dot_general(
             p_t.astype(doj.dtype), doj, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec)  # (bk,d)
@@ -875,8 +816,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 # back to the round-1 dq + dkv kernel pair. 1MB measured safe on v5e
 # (16MB scoped vmem); 2MB compiled standalone but blew the scoped limit
 # inside the full train step at S=8k (co-scheduled ops share VMEM).
-_FUSED_KV_BYTES = int(_os.environ.get("PADDLE_TPU_FLASH_FUSED_KV",
-                                      1024 * 1024))
+_FUSED_KV_BYTES = 1024 * 1024
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
@@ -934,8 +874,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale,
     elif fused and stream_kv:
         raise ValueError(
             "fused=True requires the whole-kv layout but stream_kv "
-            "resolved True for this kv size; pass stream_kv=False or "
-            "raise PADDLE_TPU_FLASH_KV_VMEM")
+            "resolved True for this kv size; pass stream_kv=False")
 
     if fused:
         qspec = pl.BlockSpec((1, 1, bq, d),

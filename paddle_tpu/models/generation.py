@@ -292,7 +292,7 @@ def _finish_step(tok, finished, eos_token_id, pad_token_id):
 # (batch, prompt_len) still costs a compile (static shapes), so servers
 # should pad prompts to a few canonical lengths.
 
-_GEN_CACHE_CAP = int(os.environ.get("PADDLE_TPU_GEN_STEP_CACHE", "32"))
+_GEN_CACHE_CAP = 32
 
 
 def _gen_cache_get(model, key, build):

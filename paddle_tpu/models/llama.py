@@ -492,18 +492,10 @@ class LlamaForCausalLM(nn.Layer):
 
 
 def param_count(config: LlamaConfig) -> int:
-    """Analytic parameter count (for MFU math in bench.py)."""
+    """Analytic parameter count."""
     d, f, v = config.hidden_size, config.intermediate_size, config.vocab_size
     hd = config.head_dim
     per_layer = (d * d + 2 * d * config.num_key_value_heads * hd + d * d
                  + 3 * d * f + 2 * d)
     head = 0 if config.tie_word_embeddings else d * v
     return v * d + config.num_hidden_layers * per_layer + d + head
-
-
-def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
-    """Training FLOPs/token ~= 6*N + attention term (for MFU)."""
-    n = param_count(config) - config.vocab_size * config.hidden_size * (
-        1 if config.tie_word_embeddings else 2)
-    attn = (12 * config.num_hidden_layers * config.hidden_size * seq_len)
-    return 6.0 * n + attn

@@ -14,9 +14,8 @@ Three pieces, one switch:
     trace.py      span(name, **attrs) -> a profiler annotation always,
                   and a bounded ring buffer -> chrome-trace JSON when
                   enabled; the closed span catalogue (SPANS)
-    telemetry.py  per-step training reporter: tokens/sec/chip + MFU
-                  (the bench.py math, in-framework), lagged loss,
-                  driven by parallel/trainer.py
+    telemetry.py  per-step training reporter: tokens/sec/chip + MFU,
+                  lagged loss, driven by parallel/trainer.py
     fleet.py      the cross-rank layer: per-rank heartbeats into the
                   rendezvous TCPStore, an aggregator computing step
                   skew + straggler flags (fleet.* instruments, served

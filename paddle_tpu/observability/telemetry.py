@@ -1,22 +1,18 @@
 """Per-step training telemetry: tokens/sec/chip, MFU, loss, skips.
 
-The north star (Llama-3-8B-class MFU on v5p) previously had no
-in-framework measurement — the MFU math lived only in bench.py. This
-module is that math as a runtime reporter: `TrainingTelemetry` turns
-(tokens, step wall time) into tokens/sec and an MFU estimate using the
-SAME flops-per-token helper bench.py uses (models/llama.py
-`flops_per_token`, including the 8/6 recompute replay factor) and the
-one per-chip peaks table (device/peaks.py), publishing
-gauges/histograms into the
-shared metrics registry. `parallel/trainer.py` drives it when
+`TrainingTelemetry` turns (tokens, step wall time) into tokens/sec and
+an MFU estimate as a runtime reporter, from the program's one count of a
+decoder's training FLOPs a token (`flops_per_token_for`: model work
+only, so recomputed layers count once) and the one per-chip peaks table
+(device/peaks.py), publishing gauges/histograms into the shared metrics
+registry. `parallel/trainer.py` drives it when
 observability is enabled; the cost when disabled is one attribute
 check in Trainer.step.
 
 Two measurement caveats, both deliberate:
   - step time is the interval between consecutive step() dispatches.
     Dispatch is async, but donated buffers backpressure the host, so
-    in steady state the interval converges to device step time (the
-    same quantity bench.py measures over a synced window).
+    in steady state the interval converges to device step time.
   - the loss gauge lags `loss_lag` steps: a loss read that young would
     force a host sync and stall the dispatch pipeline; by the time a
     step is `loss_lag` old its value is already on host and float() is
@@ -43,29 +39,36 @@ def detect_peak_flops():
     return None if peaks is None else peaks.bf16_flops
 
 
+def _decoder_flops_per_token(cfg, seq_len: int) -> float:
+    """Forward + backward of a dense decoder: 6 x the weights every token
+    is multiplied by (the layers' matmuls and hidden x vocabulary, tied
+    or not; the embedding lookup is a gather) + causal attention, QK^T
+    and PV over half the square, three times for training:
+    6 x layers x heads x head_dim x seq."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    h, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    hd = getattr(cfg, "head_dim", None) or d // h
+    layer = d * h * hd + 2 * d * hkv * hd + h * hd * d + 3 * d * f
+    matmul = cfg.num_hidden_layers * layer + d * cfg.vocab_size
+    return 6.0 * matmul + 6.0 * cfg.num_hidden_layers * h * hd * seq_len
+
+
 def flops_per_token_for(model, seq_len: int) -> float:
-    """Training FLOPs/token for `model`: the shared analytic helper
-    (models/llama.py flops_per_token — 6N + attention term, x8/6 when
-    the config says recompute) when the config quacks like a llama;
-    otherwise the generic 6 x trainable-param-count estimate."""
+    """Training FLOPs/token for `model`: the decoder count above when
+    the config quacks like a llama; otherwise the generic 6 x
+    trainable-param-count estimate. Recomputed work is not model work,
+    so `recompute` changes nothing here."""
     cfg = getattr(model, "config", None)
-    ftok = None
     if cfg is not None:
         try:
-            from paddle_tpu.models.llama import flops_per_token
-            ftok = flops_per_token(cfg, seq_len)
-        except Exception:
-            ftok = None
-    if ftok is None:
-        n = 0
-        for p in getattr(model, "parameters", lambda: [])():
-            if not getattr(p, "stop_gradient", False):
-                n += int(getattr(p, "size", 0) or 0)
-        ftok = 6.0 * n
-    if cfg is not None and getattr(cfg, "recompute", False):
-        # remat replays each layer's forward once: ~8N/token not 6N
-        ftok = ftok * 8.0 / 6.0
-    return float(ftok)
+            return _decoder_flops_per_token(cfg, seq_len)
+        except AttributeError:
+            pass
+    n = 0
+    for p in getattr(model, "parameters", lambda: [])():
+        if not getattr(p, "stop_gradient", False):
+            n += int(getattr(p, "size", 0) or 0)
+    return 6.0 * n
 
 
 class TrainingTelemetry:
@@ -105,8 +108,7 @@ class TrainingTelemetry:
         return float(self._fpt or 0.0)
 
     def mfu(self, tokens_per_sec, seq_len) -> float:
-        """tokens/sec/chip x FLOPs/token / chip peak — identically
-        bench.py's formula (tests cross-check)."""
+        """tokens/sec/chip x FLOPs/token / chip peak."""
         if not self.peak_flops:
             return 0.0
         return tokens_per_sec * self.flops_per_token(seq_len) \

@@ -46,8 +46,7 @@ class TrainStepConfig:
     # that interleaving measured the lm_head dW at 46% MXU eff on v5e —
     # the barrier splits matmul and update (+3% step throughput). A
     # global barrier is WORSE (materializes every grad); name-match only
-    # the big vocab params. Env PADDLE_TPU_OPT_BARRIER overrides
-    # (comma-separated substrings, '1' = all, '' = unset -> this field).
+    # the big vocab params (substrings of names; '1' = all).
     opt_barrier_params: tuple = ("lm_head", "embed_tokens")
     # keep Adam moments in PINNED HOST memory between steps (reference:
     # sharding/group_sharded_optimizer_stage2.py offload=True + the
@@ -132,10 +131,7 @@ def _memories_supported() -> bool:
 def _opt_barrier(grads: dict, cfg) -> dict:
     """optimization_barrier on grads of cfg.opt_barrier_params-matching
     names (see TrainStepConfig.opt_barrier_params for the why)."""
-    import os as _os
-    env = _os.environ.get("PADDLE_TPU_OPT_BARRIER")
-    pats = (env.split(",") if env
-            else list(getattr(cfg, "opt_barrier_params", ()) or ()))
+    pats = list(getattr(cfg, "opt_barrier_params", ()) or ())
     if not pats:
         return grads
     return {n: (jax.lax.optimization_barrier(g)
@@ -700,8 +696,8 @@ class Trainer:
         else:
             tokens = seq = 0
         # the batch is GLOBAL; tokens_per_sec/MFU are catalogued
-        # per-CHIP (bench.py's single-chip framing), so divide by the
-        # mesh size — otherwise a 4-chip run reads 4x the true MFU
+        # per-CHIP, so divide by the mesh size — otherwise a 4-chip
+        # run reads 4x the true MFU
         if self.mesh is not None:
             tokens = tokens / max(1, int(self.mesh.devices.size))
         self._tel_prev = [tokens, seq, None]

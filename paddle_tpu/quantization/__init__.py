@@ -510,8 +510,8 @@ class QuantizedConv2D(_QuantizedExec):
     factored out. Activation scale must be per-tensor for the same
     reason. Inference-only.
 
-    Measured (v5e, r3, tools/quant_bench.py conv): end-to-end W8A8 conv
-    stack is throughput PARITY with bf16 (8x Conv256@56^2: 7.6 ms both);
+    Measured (v5e, round 3, on a side bench since deleted): end-to-end
+    W8A8 conv stack is throughput PARITY with bf16 (8x Conv256@56^2: 7.6 ms both);
     a raw s8 conv micro is 0.76x of bf16 — unlike dot_general, XLA has
     no native int8 conv lowering on this generation. Use this path for
     memory (int8 weights) and numerics-faithful deployment, not speed;

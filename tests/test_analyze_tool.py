@@ -2,7 +2,7 @@
 
 Running the full suite against the live tree IS the tier-1 wiring (the
 check_*_tool.py pattern): any non-baselined finding from the seven
-passes anywhere in paddle_tpu/, tools/ or bench.py fails this module.
+passes anywhere in paddle_tpu/ or tools/ fails this module.
 Per-pass behavior is pinned on synthetic fixture modules under
 tests/data/analyze/, and the store-server convoy defect the
 thread-discipline pass found ships with a behavioral pin here too.

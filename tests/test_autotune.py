@@ -86,9 +86,9 @@ def test_seeded_bench_shapes_present(tmp_cache):
     assert autotune.get("flash_stream_bk", "s16384_bf16") == 2048
 
 
-def test_flash_block_selection_uses_cache(tmp_cache, monkeypatch):
-    """_tuned_blocks consults the cache; env vars always win; off-TPU
-    uncached shapes fall back to the defaults without measuring."""
+def test_flash_block_selection_uses_cache(tmp_cache):
+    """_tuned_blocks consults the cache; off-TPU uncached shapes fall
+    back to the defaults without measuring."""
     import jax.numpy as jnp
     from paddle_tpu.kernels import flash_attention as fa
 
@@ -98,11 +98,6 @@ def test_flash_block_selection_uses_cache(tmp_cache, monkeypatch):
                             jnp.bfloat16, True) == (256, 512)
     # uncached on CPU -> defaults, no sweep
     assert fa._tuned_blocks("flash_fwd", 2, 4, 1536, 1536, 64,
-                            jnp.bfloat16, True) == (fa._BLOCK_Q,
-                                                    fa._BLOCK_K)
-    # env override wins over the cache
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BLOCK_Q", "128")
-    assert fa._tuned_blocks("flash_fwd", 2, 4, 4096, 4096, 64,
                             jnp.bfloat16, True) == (fa._BLOCK_Q,
                                                     fa._BLOCK_K)
 

@@ -396,10 +396,10 @@ def test_compilation_cache_stats_and_layer_explain():
         paddle_tpu.jit.explain(lambda x: x)
 
 
-# -- round 5: liveness-aware carries (VERDICT r4 item 9) ---------------------
-# Branch-local temps and `_` unpacking used to fall back to eager (the
-# NOTES_r4 'environment facts' rejections); they now capture into ONE
-# lax.cond/while_loop program.
+# -- round 5: liveness-aware carries ------------------------------------------
+# Branch-local temps and `_` unpacking used to fall back to eager (spurious
+# unbound-carry rejections); they now capture into ONE lax.cond/while_loop
+# program.
 
 def _assert_one_program(fn, *args):
     """Run a to_static fn and assert NO eager-fallback warning fired."""

@@ -5,10 +5,12 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from conftest import needs_reference
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
 
 
+@needs_reference
 def test_full_nn_name_surface():
     import re
     for ref_path, mod in [

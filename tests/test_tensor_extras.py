@@ -5,8 +5,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from conftest import needs_reference
 
 
+@needs_reference
 def test_full_reference_name_surface():
     import re
     ref = open('/root/reference/python/paddle/tensor/__init__.py').read()

@@ -40,7 +40,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m tools.analyze",
         description="multi-pass static analysis for the paddle_tpu "
-                    "corpus (paddle_tpu/, tools/, bench.py)")
+                    "corpus (paddle_tpu/, tools/)")
     ap.add_argument("root", nargs="?", default=None,
                     help="tree to analyze (default: this repo)")
     ap.add_argument("--json", action="store_true", dest="as_json",

@@ -1,6 +1,6 @@
 """Core of the unified static-analysis framework.
 
-One parse of the corpus (`paddle_tpu/`, `tools/`, `bench.py`) into a
+One parse of the corpus (`paddle_tpu/`, `tools/`) into a
 shared :class:`Index` — per-module AST with parent links and def/class
 qualnames, raw source lines, and the inline-suppression table — then
 every registered pass (tools/analyze/passes/) runs over the same index
@@ -31,9 +31,8 @@ import re
 import tokenize
 from dataclasses import dataclass, field, replace
 
-# directories/files that make up the analyzed corpus, relative to root
+# directories that make up the analyzed corpus, relative to root
 CORPUS_DIRS = ("paddle_tpu", "tools")
-CORPUS_FILES = ("bench.py",)
 SKIP_DIRS = {"__pycache__", ".git"}
 
 # `# lint: disable=<id>[,<id>...] -- justification`  (the justification
@@ -133,7 +132,7 @@ class Index:
                 yield m
 
 
-def _iter_corpus(root, subdirs=CORPUS_DIRS, files=CORPUS_FILES):
+def _iter_corpus(root, subdirs=CORPUS_DIRS):
     for sub in subdirs:
         top = os.path.join(root, sub)
         if not os.path.isdir(top):
@@ -143,10 +142,6 @@ def _iter_corpus(root, subdirs=CORPUS_DIRS, files=CORPUS_FILES):
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    for fn in files:
-        path = os.path.join(root, fn)
-        if os.path.isfile(path):
-            yield path
 
 
 def _link_parents(tree):
@@ -207,14 +202,13 @@ def _parse_suppressions(mod: Module):
         mod.suppressions.setdefault(no, set()).update(ids)
 
 
-def build_index(root: str, subdirs=CORPUS_DIRS,
-                files=CORPUS_FILES) -> Index:
+def build_index(root: str, subdirs=CORPUS_DIRS) -> Index:
     """Parse the corpus once. Files that fail to parse keep their raw
     lines (line-based passes still see them) with tree=None.
-    `subdirs`/`files` narrow the corpus — the legacy `scan(root)` shims
+    `subdirs` narrows the corpus — the legacy `scan(root)` shims
     index only paddle_tpu/ instead of paying for the full tree."""
     index = Index(root)
-    for path in _iter_corpus(index.root, subdirs, files):
+    for path in _iter_corpus(index.root, subdirs):
         rel = os.path.relpath(path, index.root)
         try:
             with open(path, encoding="utf-8") as f:

@@ -110,5 +110,4 @@ def run(index):
 def scan(root: str):
     """Legacy surface (tools/check_chaos_points.py shim + its tests).
     Indexes only paddle_tpu/ — all this scanner ever looked at."""
-    return _scan_index(build_index(root, subdirs=("paddle_tpu",),
-                                   files=()))
+    return _scan_index(build_index(root, subdirs=("paddle_tpu",)))

@@ -110,5 +110,4 @@ def scan(root: str):
     """Legacy surface (tools/check_jax_compat.py shim + its tests):
     yields (relpath, lineno, line, why) for every fragile use. Indexes
     only paddle_tpu/ — all this scanner ever looked at."""
-    return list(_scan_index(build_index(root, subdirs=("paddle_tpu",),
-                                        files=())))
+    return list(_scan_index(build_index(root, subdirs=("paddle_tpu",))))

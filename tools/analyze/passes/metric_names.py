@@ -151,7 +151,7 @@ def run(index):
 
 def _index(root):
     # only paddle_tpu/ — all this scanner ever looked at
-    return build_index(root, subdirs=("paddle_tpu",), files=())
+    return build_index(root, subdirs=("paddle_tpu",))
 
 
 def scan(root: str):
