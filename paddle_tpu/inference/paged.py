@@ -34,7 +34,10 @@ reference's op-level API contract; THIS engine is what actually serves):
   leaves the engine (a host-tier entry, an exported bundle) is
   `(kv_heads, page_size, head_dim)` whatever its pool's shape.
 - Scheduling (admission, page allocation, retirement) is host-side
-  Python BETWEEN ticks. A request can join at any tick boundary — i.e.
+  Python BETWEEN ticks, and since ISSUE 32 beside the tick that flies:
+  the next tick is launched from the rows the last one left on the
+  device before the host reads that one back (`PagedKVEngine` doc).
+  A request can join at any tick boundary — i.e.
   mid-decode of every other request — which is the continuous-batching
   capability the reference's serving launcher provides; requests leave
   as soon as they hit eos or their token budget, freeing pages
@@ -622,7 +625,8 @@ class _Request:
         (only stream() does), so a bare submit()+result() would
         otherwise block forever. If the request is unfinished and
         nothing is driving the scheduler — no live ticker thread, no
-        tick in flight, no new step() call — for `stall_timeout`
+        tick running or left in flight, no new step() call — for
+        `stall_timeout`
         seconds, raise with the fix named instead of hanging. The
         default is deliberately generous: an external driver doing slow
         host work BETWEEN step() calls must not trip it (the guard
@@ -652,7 +656,8 @@ class _Request:
             # run well past any timeout) or a new step() call all count
             # as someone driving the scheduler
             progressing = ((ticker is not None and ticker.is_alive())
-                           or eng._in_step or seq != last_seq)
+                           or eng._in_step or eng._flying is not None
+                           or seq != last_seq)
             del eng, ticker   # don't pin the engine (and its KV pools)
             #                   across the wait — the collected-engine
             #                   branch above must stay reachable
@@ -686,6 +691,26 @@ class _Slot:
         self.emitted = 0            # generated tokens accepted so far
         self.shared = 0             # leading prefix-cache pages (not
         #                             drawn from the free list here)
+
+
+class _TickArgs(NamedTuple):
+    """What a plain tick takes beside its slots' rows and the pools, as
+    device arrays: a chained tick takes its predecessor's again."""
+    fn: object          # the tick program (_tick_fn)
+    bt: object          # the block table
+    bt_sent: np.ndarray     # ... as the host held it at the upload
+    eos: object
+    sample: tuple       # temp, topk, topp, wants; () in a greedy tick
+
+
+class _Flight(NamedTuple):
+    """A decode tick the device has been handed and the host has not
+    read back."""
+    toks: object        # (slots, steps)
+    carry: tuple        # the next tick's rows: tok, lens, active, limit
+    counted: dict       # the model's counters, summed over the tick
+    live: list          # (slot index, request) of every live slot
+    args: _TickArgs
 
 
 class PagedKVEngine:
@@ -740,6 +765,31 @@ class PagedKVEngine:
         tenant most over its weighted fair share instead of shedding
         a well-behaved newcomer. Per-tenant shares surface in
         `tenant_snapshot()` and the tenant.* instruments.
+
+    One decode tick in flight. The loops that own the scheduler
+    (`_ticker_loop`, `run_until_idle`) launch tick N+1 from tick N's
+    rows on the device before they read N back, whenever nothing can
+    change which request sits in which slot before N+1 (`_may_chain`:
+    no request pending or staged, no live slot cancelled, no draft
+    model, a slot that outlives N by its budget); otherwise they land
+    the tick in flight first and run retire -> admit -> launch as
+    `step()` always does. `step()` itself returns with nothing in
+    flight. While N+1 flies:
+    - a slot that ended inside N (eos or budget) is dead in N+1 on the
+      device (the rows carry `active` and `limit`): it writes no K/V
+      there and emits nothing;
+    - the accept of N+1 goes by request, not by slot index: rows of a
+      request retired meanwhile (ended in N, cancelled) are dropped;
+    - pages that a retirement frees are handed only to programs
+      dispatched after N+1 (a prefill, `_recycle_pages`' zeroing, a
+      tier upload, the next tick: one device stream, in order);
+    - an error of N+1 surfaces at its read back and fails every waiter
+      through `_ticker_loop`'s handler; `stop()` lands it before the
+      ticker ends; `has_work()` and `result()`'s stall guard count it
+      as work and as progress.
+    `stats["ticks_chained"]` beside `ticks` counts the ticks launched
+    that way; a tick's index in the step keys goes to the ticks that
+    decoded something, so sampled tokens do not depend on the depth.
     """
 
     def __init__(self, model, *, max_slots=4, page_size=16, num_pages=64,
@@ -1003,6 +1053,11 @@ class PagedKVEngine:
         self._seed = int(seed)
         self._submitted = 0
         self._key = jax.random.key(seed)
+        # on the device once: the tick program folds the tick's index in
+        self._key_data = jax.random.key_data(self._key)
+        # the decode tick launched ahead and not read back yet, between
+        # two iterations of a loop that owns the scheduler (_step_tick)
+        self._flying: _Flight | None = None
         self._ticker = None
         # multi-tenant QoS (class doc): the WFQ pick + per-tenant
         # shares; None keeps every scheduling path byte-identical
@@ -1019,7 +1074,8 @@ class PagedKVEngine:
         # count against its bulkhead
         self._queued_by_tenant: dict[str, int] = {}
         # telemetry for tests / the serving bench
-        self.stats = {"ticks": 0, "kv_write_kernel_ticks": 0,
+        self.stats = {"ticks": 0, "ticks_chained": 0,
+                      "kv_write_kernel_ticks": 0,
                       "prefills": 0, "tokens_out": 0,
                       "admitted": 0, "finished": 0, "cancelled": 0,
                       "expired": 0, "overloaded": 0,
@@ -1446,7 +1502,7 @@ class PagedKVEngine:
         # window where _admit has popped self._pending but not yet
         # assigned slots cannot read as idle
         with self._lock:
-            return self._inflight > 0
+            return self._inflight > 0 or self._flying is not None
 
     # -- scheduling core -------------------------------------------------
     def _bucket(self, n):
@@ -2352,26 +2408,58 @@ class PagedKVEngine:
     def step(self):
         """One scheduler tick: admit pending requests (prefill), then
         one fused multi-step decode over every live slot. Returns True
-        if any work was done."""
+        if any work was done. When it returns no tick is in flight:
+        only the loops that own the scheduler (`_ticker_loop`,
+        `run_until_idle`) leave one on the device between two calls."""
+        return self._step(ahead=False)
+
+    def _step(self, ahead):
         self._step_seq += 1
-        self._in_step = True   # a tick in flight (incl. a long first-
-        try:                   # call compile) counts as driver progress
-            return self._step_tick()
+        self._in_step = True   # an iteration under way (incl. a long
+        try:                   # first-call compile) is driver progress
+            return self._step_tick(ahead)
         finally:
             self._in_step = False
 
-    def _step_tick(self):
+    def _may_chain(self, live):
+        """Whether the tick after the newest one may be launched from
+        its carry before the host has read it: only while nothing can
+        change which request sits in which slot before that tick, and
+        some slot outlives the newest tick by its budget. The caller
+        has seen to the cancelled slots; a draft model never comes here
+        (`_step_spec`: what a slot emits depends on the data)."""
+        n = self.steps_per_tick
+        if not any(self._slots[i].req.max_new_tokens
+                   - self._slots[i].emitted > n for i in live):
+            return False
+        with self._lock:
+            return not self._pending and not self._import_staged
+
+    def _step_tick(self, ahead):
         """One tick under its spans (observability/trace.py SPANS) and
         its own clock: `engine.tick` holds the TICK_PHASES in order,
         `marks` the perf_counter reading at each boundary. The
         counters and `tick_log` are always on (`_note_tick`); the
-        spans show in any profiler capture."""
+        spans show in any profiler capture.
+
+        An iteration lands exactly one decode tick (read back, accept):
+        the one in flight, or with none in flight the one it launches
+        from the host's rows after `retire -> admit`. Before it lands
+        that tick it launches the next from the tick's carry on the
+        device, if `ahead` and `_may_chain`, and leaves it in flight.
+        With a tick in flight that must not be chained (a request came
+        in, a slot was cancelled) the iteration lands it and does
+        nothing else: the next one admits. What holds while a tick is
+        in flight is in the class doc."""
         from paddle_tpu.distributed import chaos
         if chaos.ENABLED:
             # a slow scheduler tick (congested chip, straggler host):
             # stretches TTFT and ITL — the request-tracing tests' lever
             chaos.maybe_delay("engine.tick.delay")
-        if not any(self._slots):
+        # taken off the engine while this iteration owns it: an error
+        # drops it, and the ticker's handler fails its requests
+        flight, self._flying = self._flying, None
+        if flight is None and not any(self._slots):
             with self._lock:
                 idle = not self._pending and not self._import_staged
             if idle:
@@ -2381,87 +2469,142 @@ class PagedKVEngine:
                 return False
         clock = time.perf_counter
         pre_s, pre_n = self.stats["prefill_s"], self.stats["prefills"]
+        n = self.steps_per_tick
         with observability.span("engine.tick", seq=self._step_seq):
             marks = [clock()]
             with observability.span("engine.tick.retire"):
+                retired = 0
                 for i, slot in enumerate(self._slots):
                     if slot is not None and slot.req.cancelled.is_set():
                         self.stats["cancelled"] += 1
                         self._retire(i)
+                        retired += 1
                 if self.suspend_after_s is not None:
                     self._suspend_sweep()
             marks.append(clock())
             with observability.span("engine.tick.admit"):
-                self._admit()
+                if flight is None:
+                    self._admit()
             marks.append(clock())
-            live = [i for i, s in enumerate(self._slots) if s is not None]
-            if live and self.tenancy is not None:
-                self._note_slot_ticks(live)
+            if flight is None:
+                live = [i for i, s in enumerate(self._slots)
+                        if s is not None]
+            else:
+                live = [i for i, req in flight.live
+                        if self._slots[i] is not None
+                        and self._slots[i].req is req]
             if live and self.draft_model is not None:
+                if self.tenancy is not None:
+                    self._note_slot_ticks(live)
                 self._step_spec(live, marks)
-            elif live:
+            elif live or flight is not None:
                 # the decode tick, phase by phase. The program is called
                 # from this frame, as it always was: with a helper method
                 # and a closure between here and the jitted call the
                 # program's first call (trace and lowering) took 13 s
                 # against 6 s on the v5e host, a quarter more set-up for
                 # a server (measured, cause not found: PERF.md, PR 25)
-                n = self.steps_per_tick
+                chain = bool(ahead and live
+                             and not (retired and flight is not None)
+                             and self._may_chain(live))
+                args = None if flight is None else flight.args
                 with observability.span("engine.tick.alloc"):
-                    for i in live:
-                        slot = self._slots[i]
-                        budget_tokens = (slot.req.prompt.size
-                                         + slot.req.max_new_tokens)
-                        need = min(slot.lens + n, budget_tokens)
-                        self._alloc_pages(i, -(-need // self.page_size))
-                    a = self._slot_arrays(live)
+                    if flight is None or chain:
+                        # pages for the tokens of the tick to land and,
+                        # ahead, of the one chained after it: the host
+                        # knows `lens` as of the former's launch
+                        for i in live:
+                            slot = self._slots[i]
+                            budget_tokens = (slot.req.prompt.size
+                                             + slot.req.max_new_tokens)
+                            need = min(slot.lens + n * (1 + chain),
+                                       budget_tokens)
+                            self._alloc_pages(i, -(-need // self.page_size))
+                    if flight is None:
+                        a = self._slot_arrays(live)
                 marks.append(clock())
                 with observability.span("engine.tick.upload"):
-                    any_sample = bool(a["wants"].any())
-                    fn = self._tick_fn(any_sample)
-                    key = jax.random.fold_in(self._key, self._tick_count)
-                    args = [jnp.asarray(a["tok"]), jnp.asarray(a["lens"]),
-                            jnp.asarray(a["active"]),
-                            jnp.asarray(a["limit"]), jnp.asarray(self._bt),
-                            jnp.asarray(a["eos"]),
-                            jax.random.key_data(key)]
-                    if any_sample:
-                        args += [jnp.asarray(a["temp"]),
-                                 jnp.asarray(a["topk"]),
-                                 jnp.asarray(a["topp"]),
-                                 jnp.asarray(a["wants"])]
+                    if flight is None:
+                        any_sample = bool(a["wants"].any())
+                        sent = self._bt.copy()  # never written again
+                        rows = tuple(jnp.asarray(a[k]) for k in
+                                     ("tok", "lens", "active", "limit"))
+                        args = _TickArgs(
+                            self._tick_fn(any_sample),
+                            jnp.asarray(sent), sent, jnp.asarray(a["eos"]),
+                            tuple(jnp.asarray(a[k]) for k in
+                                  ("temp", "topk", "topp", "wants"))
+                            if any_sample else ())
+                    elif chain and not np.array_equal(self._bt,
+                                                      args.bt_sent):
+                        # a chained tick uploads the block table if a
+                        # page was added or a row retired, nothing else
+                        sent = self._bt.copy()
+                        args = args._replace(bt=jnp.asarray(sent),
+                                             bt_sent=sent)
                 marks.append(clock())
                 with observability.span("engine.tick.launch"):
-                    toks_out, lens_f, flat, *counted = fn(
-                        *args, [x for kv in self.pools for x in kv])
-                    self.pools = self._unflat_pools(flat)
+                    pairs = [(i, self._slots[i].req) for i in live]
+                    newest = flight
+                    for _ in range((flight is None) + chain):
+                        toks_out, carry_f, flat, *counted = args.fn(
+                            rows if newest is None else newest.carry,
+                            args.bt, args.eos, self._key_data,
+                            np.int32(self._tick_count), *args.sample,
+                            [x for kv in self.pools for x in kv])
+                        self.pools = self._unflat_pools(flat)
+                        self._tick_count += 1
+                        if self.tenancy is not None:
+                            self._note_slot_ticks(live)
+                        newest = _Flight(toks_out, carry_f,
+                                         counted[0] if counted else {},
+                                         pairs, args)
+                        if flight is None:
+                            flight = newest
+                    self.stats["ticks_chained"] += chain
                 marks.append(clock())
                 with observability.span("engine.tick.readback"):
-                    toks_np = np.asarray(toks_out)          # (b, n)
-                    lens_np = np.asarray(lens_f)
-                    for name, v in (counted[0] if counted else {}).items():
+                    toks_np = np.asarray(flight.toks)       # (b, n)
+                    lens_np = np.asarray(flight.carry[1])
+                    for name, v in flight.counted.items():
                         self.stats[name] = self.stats.get(name, 0) + int(v)
                 marks.append(clock())
                 self._ticked(marks)
-                counts = np.minimum(a["limit"], n)
+                # what each slot took, from the host's rows as the tick
+                # before left them: the carry the device started from
+                counts = np.zeros(self.max_slots, np.int32)
+                eos = np.full(self.max_slots, -1, np.int32)
+                for i in live:
+                    slot = self._slots[i]
+                    counts[i] = min(slot.req.max_new_tokens - slot.emitted,
+                                    n)
+                    eos[i] = slot.req.eos_token_id
                 if self.index_dim:
                     took = counts[live]
+                    lens0 = np.asarray([self._slots[i].lens for i in live],
+                                       np.int32)
                     self.stats["decode_slot_steps"] += int(took.sum())
                     # step j of a slot attends over lens + j + 1 keys
                     self.stats["select_engaged_steps"] += int(np.clip(
-                        a["lens"][live] + took - np.maximum(
-                            a["lens"][live], self.index_topk), 0,
-                        took).sum())
+                        lens0 + took - np.maximum(lens0, self.index_topk),
+                        0, took).sum())
                 with observability.span("engine.tick.accept"):
-                    self._accept_tick(live, toks_np, counts, a["eos"],
-                                      lens_np)
+                    # rows of a request retired since the launch (ended
+                    # in the tick before, cancelled) are not in `live`
+                    self._accept_tick(live, toks_np, counts, eos, lens_np)
                 marks.append(clock())
+                if not live:
+                    # every slot was dead in this tick (or cancelled
+                    # under it): the index it took goes to the next, as
+                    # it would have with nothing launched ahead
+                    self._tick_count -= 1
+                if newest is not flight:
+                    self._flying = newest
         self._note_tick(marks, len(live), pre_s, pre_n)
-        return bool(live)
+        return bool(live) or flight is not None
 
     def _ticked(self, marks):
         """Count one decode tick whose read back has just ended."""
-        self._tick_count += 1
         self.stats["ticks"] += 1
         self.stats["tick_s"] += marks[-1] - marks[3]    # upload .. readback
         self.stats["kv_write_kernel_ticks"] += self.kv_write == "pallas"
@@ -2500,6 +2643,7 @@ class PagedKVEngine:
                 [x for kv in self.draft_pools for x in kv])
             self.pools = self._unflat_pools(tflat)
             self.draft_pools = self._unflat_pools(dflat)
+            self._tick_count += 1
         marks.append(clock())
         with observability.span("engine.tick.readback"):
             out_np = np.asarray(out)
@@ -2548,7 +2692,7 @@ class PagedKVEngine:
                 time.sleep(0.005)
             return
         while self.has_work():
-            if not self.step():
+            if not self._step(ahead=True):
                 # nothing live but pending couldn't admit: impossible by
                 # construction unless slots freed next step; guard
                 # against a spin if the pool is wedged.  _pending is
@@ -2596,37 +2740,39 @@ class PagedKVEngine:
     def _ticker_loop(self):
         import time
         idle = 0.0
-        while not getattr(self, "_stop_flag", False):
-            try:
-                if self.step():
+        try:
+            while not getattr(self, "_stop_flag", False):
+                if self._step(ahead=True):
                     idle = 0.0
                 else:
                     idle = min(0.05, idle + 0.005)
                     with observability.span("engine.idle"):
                         time.sleep(idle)
-            except Exception as e:      # noqa: BLE001 — fail all waiters
-                with self._lock:
-                    doomed = self._pending
-                    self._pending = []
-                    self._inflight -= len(doomed)   # dropped, not retired
-                    for req in doomed:
-                        self._queued_dec_locked(req)
-                for req in doomed:                  # never got a slot
-                    req.error = e
-                    if req.obs is not None:
-                        req.obs.engine_finish("error")
-                    req.queue.put(None)
-                    req.done.set()
-                for i, s in enumerate(self._slots):
-                    if s is not None:
-                        s.req.error = e
-                        # _retire returns the slot's pages + reservation
-                        # to the pool (a restarted ticker isn't
-                        # permanently short on capacity), releases the
-                        # row's tracing ref with the real outcome, and
-                        # wakes the waiter
-                        self._retire(i, reason="error")
-                raise
+            if self._flying is not None:
+                self.step()     # stop(): land the tick in flight
+        except Exception as e:      # noqa: BLE001 — fail all waiters
+            with self._lock:
+                doomed = self._pending
+                self._pending = []
+                self._inflight -= len(doomed)   # dropped, not retired
+                for req in doomed:
+                    self._queued_dec_locked(req)
+            for req in doomed:                  # never got a slot
+                req.error = e
+                if req.obs is not None:
+                    req.obs.engine_finish("error")
+                req.queue.put(None)
+                req.done.set()
+            for i, s in enumerate(self._slots):
+                if s is not None:
+                    s.req.error = e
+                    # _retire returns the slot's pages + reservation
+                    # to the pool (a restarted ticker isn't
+                    # permanently short on capacity), releases the
+                    # row's tracing ref with the real outcome, and
+                    # wakes the waiter
+                    self._retire(i, reason="error")
+            raise
 
     def stream(self, input_ids, max_new_tokens=32, *, eos_token_id=None,
                pad_token_id=0, do_sample=False, temperature=1.0,
@@ -2963,19 +3109,35 @@ class PagedKVEngine:
         return fn
 
     def _tick_fn(self, any_sample):
+        """The decode tick: `steps_per_tick` steps over every slot, one
+        program for every tick of the engine (two with the sampling
+        variant). `rows` is the slots' (tok, lens, active, limit): the
+        host's, where it launches a tick with nothing in flight and so
+        knows every slot, or the previous tick's second output handed
+        on as it is, where a tick is chained before the host has read
+        the previous one. (No mask mixes the two: every launch is all
+        of one or all of the other, and a select at the program's head
+        made XLA move a pool between memories every step.) The step
+        keys are fold_in(fold_in(engine key, tick_i), step), folded
+        inside the program so that a chained tick uploads no key.
+        Returns the tokens (slots, steps), the next tick's rows, whose
+        second element is the slots' final lens, the pools and, from a
+        model that counts, the sums of its counters."""
         key = ("tick", any_sample)
         if key in self._programs:
             return self._programs[key]
         model = self.model
         n = self.steps_per_tick
-        nl = len(self.pools)
 
-        def run(tok, lens, active, limit, bt, eos, key_data, *rest):
+        def run(rows, bt, eos, key_data, tick_i, *rest):
             if any_sample:
                 temp, topk, topp, wants = rest[:4]
                 pool_flat = rest[4]
+                tick_key = jax.random.fold_in(
+                    jax.random.wrap_key_data(key_data), tick_i)
             else:
                 pool_flat = rest[0]
+            tok, lens, active, limit = rows
 
             def body(carry, step_i):
                 tok, lens, fin, cnt, flat = carry
@@ -2995,8 +3157,7 @@ class PagedKVEngine:
                 with jax.named_scope("sample"):
                     greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
                     if any_sample:
-                        sk = jax.random.fold_in(
-                            jax.random.wrap_key_data(key_data), step_i)
+                        sk = jax.random.fold_in(tick_key, step_i)
                         noise = jax.random.gumbel(sk, last.shape,
                                                   jnp.float32)
                         proc = _process_logits_rowwise(last, temp, topk,
@@ -3020,16 +3181,20 @@ class PagedKVEngine:
             (tok_f, lens_f, fin_f, cnt_f, flat_f), toks = jax.lax.scan(
                 body, (tok, lens, fin0, cnt0, tuple(pool_flat)),
                 jnp.arange(n, dtype=jnp.int32))
+            # a slot that ended inside this tick (eos or budget) is dead
+            # in the next one: it writes no K/V there and emits nothing
+            nxt = (tok_f, lens_f, active & ~fin_f, limit - cnt_f)
             if self._model_counts:
                 toks, counts = toks
-                return (jnp.swapaxes(toks, 0, 1), lens_f, list(flat_f),
+                return (jnp.swapaxes(toks, 0, 1), nxt, list(flat_f),
                         {k: jnp.sum(v) for k, v in counts.items()})
-            return jnp.swapaxes(toks, 0, 1), lens_f, list(flat_f)
+            return jnp.swapaxes(toks, 0, 1), nxt, list(flat_f)
 
         # donate the pool buffers (the last positional arg; its index
         # depends on the 4 sampling vectors), like _prefill_fn and
         # _spec_tick_fn do — without it steady-state decode holds ~2x
-        # KV-pool memory
-        fn = self._jit(run, donate=(11 if any_sample else 7,))
+        # KV-pool memory. The rows are not donated: the host reads a
+        # tick's back after the next tick has taken them
+        fn = self._jit(run, donate=(9 if any_sample else 5,))
         self._programs[key] = fn
         return fn

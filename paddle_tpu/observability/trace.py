@@ -56,6 +56,16 @@ __all__ = ["Span", "SPANS", "annotation", "step_annotation",
 # The thread is the one that owns the work: the ticker for engine.*,
 # the training loop for train.* and input.wait, the prefetch worker for
 # input.h2d, a handler thread for http.write.
+# The children of engine.tick are paged.TICK_PHASES, always all seven in
+# that order (or the first two, when admission left no live slot), one
+# decode tick landed an iteration. With a tick in flight (ISSUE 32) the
+# phases belong to two ticks: alloc, upload and launch to the tick
+# launched ahead (N+1, from N's rows on the device), readback and accept
+# to the one landed (N); an iteration that must not chain has the first
+# five empty. What holds meanwhile (a slot ended in N is dead in N+1;
+# accept goes by request; freed pages go to later programs only; errors,
+# stop() and the stall guard see the tick in flight) is PagedKVEngine's
+# class doc.
 SPANS = {
     "input.wait": (
         "input", "DevicePrefetcher.__next__ blocked on its queue",
@@ -86,20 +96,24 @@ SPANS = {
         "reservations, and the prefills (engine.prefill) inside it",
         "serve.idle_in_launch_share"),
     "engine.tick.alloc": (
-        "scheduler", "pages for this tick's tokens and the per-slot "
-        "host arrays", "serve.idle_in_launch_share"),
+        "scheduler", "pages for the tokens of the tick to land and of "
+        "the one chained after it, and with nothing in flight the "
+        "per-slot host arrays", "serve.idle_in_launch_share"),
     "engine.tick.upload": (
-        "scheduler", "the tick program's lookup and its arguments "
-        "made device arrays", "serve.idle_in_launch_share"),
+        "scheduler", "with nothing in flight the tick program's lookup "
+        "and its arguments made device arrays; for a chained tick the "
+        "block table if it changed", "serve.idle_in_launch_share"),
     "engine.tick.launch": (
-        "scheduler", "the call of the tick program (the enqueue)",
-        "serve.idle_in_launch_share"),
+        "scheduler", "the calls of the tick program (the enqueue): the "
+        "tick to land if none flew, the one chained after it (counter "
+        "ticks_chained)", "sched.tick_chained_share"),
     "engine.tick.readback": (
-        "scheduler", "np.asarray of the tick's tokens and lengths: "
-        "the wait for the device", "counter readback_s"),
+        "scheduler", "np.asarray of the landed tick's tokens and "
+        "lengths: the wait for the device, which by then works on "
+        "the tick launched ahead", "counter readback_s"),
     "engine.tick.accept": (
-        "scheduler", "_accept_tick: tokens to the requests' queues, "
-        "retirements", "serve.idle_in_accept_share"),
+        "scheduler", "_accept_tick of the landed tick: tokens to the "
+        "requests' queues, retirements", "serve.idle_in_accept_share"),
     "engine.prefill": (
         "scheduler", "one prefill program call and its read back, "
         "attrs bucket, rows, group", "counter prefill_s"),

@@ -38,3 +38,39 @@ def test_the_benchmarks_tests_are_collected_here():
         os.path.join(ROOT, "benchmarks", "tests", "test_*.py"))}
     assert files and set(COLLECTED.values()) == files
     assert sum(1 for n in COLLECTED if n.startswith("test")) >= 48
+
+
+def test_tick_chained_share_reads_the_counter_or_nothing():
+    """ISSUE 32's metric is data alone: `counter_ratio` reads it over a
+    window that holds `ticks_chained`, and over one that does not (a
+    parent's engine) it reads nothing, which `run.py` leaves out of the
+    line, never 0. Its entry is the last of `per_layer` and lists both
+    serve cells. (Here and not under `benchmarks/tests`: the issue allows
+    that directory's owner, the benchmark, two additions only.)"""
+    import json
+
+    import pytest
+
+    from benchmarks import reduce, run
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry["name"] == "sched.tick_chained_share"
+    assert entry["workloads"] == ["serve.smollm2-1.7b.batch-decode",
+                                  "serve.keye-vl-2.0-30b-a3b.doc-qa"]
+    assert entry["moves"] == "serve_tokens_per_s" \
+        and entry["better"] == "higher" and entry["layer"] == "scheduler"
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           entry["name"] + ".json")) as f:
+        spec = json.load(f)
+    reader = run.READERS[spec["reader"]]
+    assert reader is reduce.counter_ratio
+    has = {"window": {"ticks": 640, "ticks_chained": 560}, "sizes": {}}
+    assert reader(has, **spec["args"]) == pytest.approx(87.5)
+    never = {"window": {"ticks": 640, "ticks_chained": 0}, "sizes": {}}
+    assert reader(never, **spec["args"]) == 0.0
+    parents = {"window": {"ticks": 640}, "sizes": {}}
+    assert reader(parents, **spec["args"]) is None
+    for cell in entry["workloads"]:
+        assert entry["name"] in {m["name"] for m in run.load_cell(
+            cell, rehearse=False)["metrics"]}

@@ -912,9 +912,9 @@ def test_int8_kv_sampling_matches_target_distribution():
 
     trials = 400
     counts = np.zeros(model.config.vocab_size)
-    args_fixed = (jnp.asarray(a["tok"]), jnp.asarray(a["lens"]),
-                  jnp.asarray(a["active"]), jnp.asarray(a["limit"]),
-                  jnp.asarray(eng._bt), jnp.asarray(a["eos"]))
+    rows = tuple(jnp.asarray(a[k]) for k in
+                 ("tok", "lens", "active", "limit"))
+    args_fixed = (rows, jnp.asarray(eng._bt), jnp.asarray(a["eos"]))
     sample_args = (jnp.asarray(a["temp"]), jnp.asarray(a["topk"]),
                    jnp.asarray(a["topp"]), jnp.asarray(a["wants"]))
     donated = jax.default_backend() != "cpu"   # mirror the engine gate
@@ -922,7 +922,7 @@ def test_int8_kv_sampling_matches_target_distribution():
         key = jax.random.key(1000 + s)
         fl = [jnp.copy(x) for x in flat] if donated else list(flat)
         toks, _, _ = fn(*args_fixed, jax.random.key_data(key),
-                        *sample_args, fl)
+                        np.int32(0), *sample_args, fl)
         counts[int(np.asarray(toks)[0, 0])] += 1
     tv = 0.5 * np.abs(counts / trials - want).sum()
     # same bound as the speculative pin: sampling noise at 400 trials
@@ -1034,3 +1034,256 @@ def test_engine_export_metrics():
     assert reg.gauge("engine.tokens_out").value() >= 4
     assert reg.gauge("engine.pending").value() == 0
     assert "paddle_tpu_engine_finished 1" in reg.prometheus_text()
+
+
+# -- one decode tick in flight (ISSUE 32) -----------------------------------
+
+def _drive(eng, ahead, events=()):
+    """Run the engine dry one scheduler iteration at a time: as the loops
+    that own it do (`ahead`: one tick left in flight between two
+    iterations) or by plain step() calls. `events` maps an iteration's
+    index to a callable run before it."""
+    events, k = dict(events), 0
+    while eng.has_work() or events:
+        if k in events:
+            events.pop(k)(eng)
+        eng._step(ahead=True) if ahead else eng.step()
+        k += 1
+        assert k < 400
+
+
+_PROMPTS = ([5, 9, 2, 14], [17, 3, 11], [7, 8], [21, 4, 6, 13, 2])
+_FLYING = {
+    # four requests over two slots: first, chained and post-admission ticks
+    "greedy": dict(),
+    "sampled": dict(submit=dict(do_sample=True, temperature=0.9, top_k=20)),
+    # request 0 ends on its eos inside a tick that others outlive, after
+    # the next tick was launched with it live
+    "eos_mid_tick": dict(eos=5),
+    # budgets that end on a tick's first and second step
+    "budget_mid_tick": dict(budgets=(5, 9, 12, 6)),
+    # every request in a slot from the start, so ticks chain at once
+    "submit_in_flight": dict(slots=3, late=2),
+    "cancel_in_flight": dict(slots=3, late=4, cancel=2,
+                             budgets=(20, 20, 20, 8)),
+    "pallas": dict(engine=dict(kernel="pallas")),
+    "int8": dict(engine=dict(kernel="pallas", kv_dtype="int8")),
+    "draft": dict(draft=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLYING))
+def test_tick_in_flight_emits_the_synchronous_orders_tokens(case):
+    """The loop that keeps one decode tick in flight emits, request by
+    request, exactly what a loop of plain step() calls emits on a twin
+    engine (same seed, same script), and launches every tick through the
+    one tick program."""
+    kw = _FLYING[case]
+    model = _model()
+    draft = None
+    if kw.get("draft"):
+        paddle_tpu.seed(5)
+        draft = LlamaForCausalLM(model.config)
+    budgets = kw.get("budgets", (11, 6, 14, 9))
+    submit = dict(kw.get("submit", {}))
+    eos = None
+    if "eos" in kw:
+        solo = np.asarray(generate(
+            model, np.asarray([_PROMPTS[0]], np.int32),
+            max_new_tokens=budgets[0]))[0].tolist()[len(_PROMPTS[0]):]
+        eos = solo[kw["eos"]]
+    seen = {}
+
+    def run(ahead):
+        eng = PagedKVEngine(
+            model, max_slots=kw.get("slots", 2), page_size=4, num_pages=64,
+            max_pages_per_slot=8, steps_per_tick=3, seed=7,
+            draft_model=draft, spec_tokens=3, **kw.get("engine", {}))
+        upfront = len(_PROMPTS) - ("late" in kw)
+        reqs = [eng.submit(p, b, eos_token_id=eos if i == 0 else None,
+                           **submit)
+                for i, (p, b) in enumerate(zip(_PROMPTS[:upfront], budgets))]
+        events = {}
+        if "late" in kw:
+            def late(eng):
+                if ahead:       # the tick before is still on the device
+                    assert eng._flying is not None
+                    seen["chained_at_submit"] = eng.stats["ticks_chained"]
+                reqs.append(eng.submit(_PROMPTS[-1], budgets[-1], **submit))
+
+            def landed(eng):
+                if ahead:
+                    # the iteration between landed the tick in flight and
+                    # admitted nothing: the request joins after the landing
+                    assert eng._flying is None
+                    assert eng.stats["admitted"] == upfront
+                    assert eng.stats["ticks_chained"] \
+                        == seen["chained_at_submit"]
+            events[kw["late"]] = late
+            events[kw["late"] + 1] = landed
+        if "cancel" in kw:
+            def cancel(eng):
+                if ahead:
+                    assert eng._flying is not None
+                seen[ahead] = len(reqs[1].tokens)
+                reqs[1].cancel()
+            events[kw["cancel"]] = cancel
+        _drive(eng, ahead, events)
+        return eng, reqs
+
+    flying, got = run(ahead=True)
+    twin, want = run(ahead=False)
+    assert [r.tokens for r in got] == [r.tokens for r in want]
+    assert all(r.done.is_set() and r.error is None for r in got)
+    if eos is not None:
+        assert got[0].tokens[-1] == eos and len(got[0].tokens) < budgets[0]
+        assert (len(got[0].tokens) - 1) % 3 != 0       # inside a tick
+    if "cancel" in kw:
+        # the rows of the tick that flew under the cancel were dropped,
+        # and the late request decoded over the pages it gave back
+        assert len(got[1].tokens) == seen[True] == seen[False] < budgets[1]
+        assert flying.stats["cancelled"] == 1
+    # nothing left on the device, every page back
+    assert flying._flying is None and not flying.has_work()
+    assert len(flying._free) == flying.num_pages - 1
+    assert flying._reserved_unalloc == 0
+    assert twin.stats["ticks_chained"] == 0
+    if draft is not None:
+        assert flying.stats["ticks_chained"] == 0       # depth stays 0
+        assert flying.stats["ticks"] == twin.stats["ticks"]
+        return
+    assert 0 < flying.stats["ticks_chained"] < flying.stats["ticks"]
+    assert flying.stats["kv_write_kernel_ticks"] == (
+        flying.stats["ticks"] if flying.kv_write == "pallas" else 0)
+    # first, chained and post-admission ticks: one program, traced once
+    for eng in (flying, twin):
+        ticks = [k for k in eng._programs if k[0] == "tick"]
+        assert ticks == [("tick", bool(submit))]
+        assert eng._programs[ticks[0]].func._cache_size() == 1
+    if "late" not in kw:
+        # the step keys: a tick's index goes to a tick that decoded
+        # something (a request that came under a tick in flight joins a
+        # tick later than in the twin, and the counts part there)
+        assert flying._tick_count == twin._tick_count
+
+
+def _two_long(eng, new=9):
+    return [eng.submit(p, new) for p in _PROMPTS[:2]]
+
+
+def test_tick_in_flight_counters_and_tick_log():
+    """A scripted run with nothing pending: every tick but the one
+    launched from the host's rows is chained, each iteration lands one
+    tick and logs the TICK_PHASES in order."""
+    from paddle_tpu.inference.paged import TICK_PHASES
+    eng = PagedKVEngine(_model(), max_slots=2, page_size=4, num_pages=24,
+                        max_pages_per_slot=6, steps_per_tick=2)
+    reqs = _two_long(eng)       # 1 token from the prefill, 8 from 4 ticks
+    chained = []
+    while eng.has_work():
+        eng._step(ahead=True)
+        chained.append(eng._flying is not None)
+    # the last tick but one would find every slot ended by its budget
+    assert chained == [True, True, True, False]
+    assert eng.stats["ticks"] == 4 and eng.stats["ticks_chained"] == 3
+    assert all(len(r.tokens) == 9 for r in reqs)
+    rows = list(eng.tick_log)
+    assert len(rows) == 4
+    for row in rows:
+        assert len(row) == 2 + len(TICK_PHASES) + 2 and row[-2] == 2
+        assert all(p >= 0.0 for p in row[2:2 + len(TICK_PHASES)])
+        assert row[2 + TICK_PHASES.index("readback")] > 0.0
+    assert [r[-1] for r in rows] == [2, 0, 0, 0]        # prefills
+    assert [r[0] for r in rows] == [1, 2, 3, 4]         # one seq a tick
+    s = eng.stats
+    assert s["tick_host_s"] + s["readback_s"] + s["prefill_s"] \
+        == pytest.approx(s["tick_wall_s"], rel=0.05)
+
+
+def test_step_returns_with_nothing_in_flight():
+    model = _model()
+    solo = [np.asarray(generate(model, np.asarray([p], np.int32),
+                                max_new_tokens=9))[0].tolist()[len(p):]
+            for p in _PROMPTS[:2]]
+    eng = PagedKVEngine(model, max_slots=2, page_size=4, num_pages=24,
+                        max_pages_per_slot=6, steps_per_tick=2)
+    reqs = _two_long(eng)
+    assert eng._step(ahead=True) and eng._flying is not None
+    ticks = eng.stats["ticks"]
+    assert eng.step() is True           # lands it, launches nothing
+    assert eng._flying is None and eng.stats["ticks"] == ticks + 1
+    assert eng.step() is True and eng._flying is None
+    eng.run_until_idle()
+    assert [r.result() for r in reqs] == solo
+
+
+def test_a_wholly_dead_tick_hands_its_index_on():
+    """Every slot ends on its eos inside tick N with N+1 launched: N+1
+    decodes nothing, `has_work()` holds until it has landed, and the next
+    request's sampled tokens are those of an engine that never launched
+    ahead."""
+    model = _model()
+    solo = np.asarray(generate(model, np.asarray([_PROMPTS[0]], np.int32),
+                               max_new_tokens=12))[0].tolist()[4:]
+
+    def run(ahead):
+        eng = PagedKVEngine(model, max_slots=2, page_size=4, num_pages=24,
+                            max_pages_per_slot=6, steps_per_tick=2, seed=3)
+        first = eng.submit(_PROMPTS[0], 12, eos_token_id=solo[5])
+        _drive(eng, ahead)
+        second = eng.submit(_PROMPTS[1], 8, do_sample=True, temperature=0.9)
+        _drive(eng, ahead)
+        return eng, first.tokens, second.tokens
+
+    flying, *got = run(True)
+    twin, *want = run(False)
+    assert got == want and got[0] == solo[:6]
+    # the dead tick ran on the device and is counted; its index is not
+    assert flying.stats["ticks"] == twin.stats["ticks"] + 1
+    assert flying._tick_count == twin._tick_count
+
+
+class _Boom:
+    def __array__(self, *a, **k):
+        raise RuntimeError("chip fell over")
+
+
+def test_error_from_a_tick_in_flight_fails_every_waiter():
+    eng = PagedKVEngine(_model(), max_slots=2, page_size=4, num_pages=24,
+                        max_pages_per_slot=6, steps_per_tick=2)
+    reqs = _two_long(eng, new=18)
+    assert eng._step(ahead=True) and eng._flying is not None
+    reqs.append(eng.submit(_PROMPTS[2], 4))         # still queued
+    eng._flying = eng._flying._replace(toks=_Boom())
+    with pytest.raises(RuntimeError, match="chip fell over"):
+        eng._ticker_loop()
+    for r in reqs:
+        assert r.done.is_set() and isinstance(r.error, RuntimeError)
+        with pytest.raises(RuntimeError, match="chip fell over"):
+            r.result()
+    assert eng._flying is None and not eng.has_work()
+    assert not any(eng._slots)
+    assert len(eng._free) == eng.num_pages - 1       # pages returned
+    assert eng._reserved_unalloc == 0
+
+
+def test_stop_lands_the_tick_in_flight_and_joins():
+    model = _model()
+    solo = [np.asarray(generate(model, np.asarray([p], np.int32),
+                                max_new_tokens=40))[0].tolist()[len(p):]
+            for p in _PROMPTS[:2]]
+    eng = PagedKVEngine(model, max_slots=2, page_size=4, num_pages=64,
+                        max_pages_per_slot=12, steps_per_tick=2)
+    reqs = _two_long(eng, new=40)
+    eng.start()
+    import time
+    give_up = time.monotonic() + 60
+    while eng.stats["ticks_chained"] < 2 and time.monotonic() < give_up:
+        time.sleep(0.002)
+    eng.stop()
+    assert not eng._ticker.is_alive() and eng._flying is None
+    # what was on the device reached the streams: nothing lost, and a
+    # later driver goes on from there
+    assert sum(len(r.tokens) for r in reqs) == eng.stats["tokens_out"]
+    eng.run_until_idle()
+    assert [r.result() for r in reqs] == solo

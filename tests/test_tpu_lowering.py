@@ -314,8 +314,9 @@ def test_engine_programs_lower_for_tpu(as_tpu, size, b, mp, kv_dtype, page):
     key = np.asarray(jax.random.key_data(jax.random.key(0)))
 
     tick = eng._tick_fn(False)
-    lowered = _lower_tpu(tick.func, *tick.args, z(b), z(b), z(b, dt=bool),
-                         z(b), z(b, mp), z(b), key, pools)
+    rows = (z(b), z(b), z(b, dt=bool), z(b))    # tok, lens, active, limit
+    lowered = _lower_tpu(tick.func, *tick.args, rows, z(b, mp), z(b), key,
+                         np.int32(0), pools)
     # one decode kernel a layer: the kernel is traced and lowered once,
     # into a function of its own that every layer calls (XLA inlines it,
     # so the compiled tick holds one custom call a layer); the same for
@@ -432,8 +433,9 @@ def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
     key = np.asarray(jax.random.key_data(jax.random.key(0)))
     tick = eng._tick_fn(False)
-    lowered = _lower_tpu(tick.func, *tick.args, z(b), z(b), z(b, dt=bool),
-                         z(b), z(b, mp), z(b), key, pools)
+    rows = (z(b), z(b), z(b, dt=bool), z(b))    # tok, lens, active, limit
+    lowered = _lower_tpu(tick.func, *tick.args, rows, z(b, mp), z(b), key,
+                         np.int32(0), pools)
     # each kernel traced and lowered once, called once a layer
     assert _kernel_names(lowered) == {"paged_attention_decode",
                                       "paged_kv_write",
@@ -522,8 +524,9 @@ def test_serve_cells_programs_copy_no_pool(as_tpu, described_v5e,
     assert eng.kv_write == "pallas" and pools[0].shape == rows
     z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
     key = np.asarray(jax.random.key_data(jax.random.key(0)))
-    tick = _compile_for(described_v5e, eng._tick_fn(False), z(b), z(b),
-                        z(b, dt=bool), z(b), z(b, mp), z(b), key, pools)
+    slot_rows = (z(b), z(b), z(b, dt=bool), z(b))
+    tick = _compile_for(described_v5e, eng._tick_fn(False), slot_rows,
+                        z(b, mp), z(b), key, np.int32(0), pools)
     assert _pool_copies(tick, pools[0]) == {}
     prefill = (eng._prefill_fn(bucket, 1) if cell == "dense"
                else eng._prefill_chunk_fn(bucket, 1))
