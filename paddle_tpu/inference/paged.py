@@ -111,6 +111,8 @@ from paddle_tpu.core import compile_cache, jax_compat
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu import observability
 from paddle_tpu.kernels import paged_attention as _pk
+from paddle_tpu.kernels.prefill_attention import (chunk_attention,
+                                                  chunk_attention_problems)
 from paddle_tpu.nn.functional.key_selection import index_scores, select_top
 from paddle_tpu.observability import requests as obs_requests
 from paddle_tpu.inference.overload import (DeadlineExceeded,
@@ -150,10 +152,26 @@ class PagedState(NamedTuple):
         real per slot (prefill: the unpadded prompt length; decode: 1
         for live slots, 0 for finished/empty ones — their writes are
         dropped).
+    ring_tables: (b, ring_pages) int32, or None where no layer attends
+        inside a window — the second page table, of the layers that do
+        (`paged_attention_update(window=)`): position p of slot i lives
+        in physical page ring_tables[i, (p // page_size) % ring_pages]
+        of THOSE layers' pools, a ring that is never grown and whose
+        pages are overwritten in place.
     """
     block_tables: jnp.ndarray
     lens: jnp.ndarray
     n_valid: jnp.ndarray
+    ring_tables: jnp.ndarray | None = None
+
+
+def ring_pages_for(window, tokens, page_size):
+    """Pages a ring must hold so that a call of `tokens` new tokens a
+    slot finds, after its write, every key of every one of them: the
+    positions (t - window, t] of its first to its last token are window +
+    tokens - 1 in a row, which from any offset in a page touch this many
+    pages."""
+    return (window + tokens + page_size - 3) // page_size + 1
 
 
 def _val(x):
@@ -189,18 +207,21 @@ def _decode_kernel_choice():
     return getattr(_decode_cfg, "cfg", None) or ("jnp", False)
 
 
-def _token_coords(state: PagedState, s, page_size, num_pages):
+def _token_coords(state: PagedState, s, page_size, num_pages, ring=False):
     """(physical page, offset in it) of each of this call's b * s tokens,
     flat; an invalid row's page points past the pool, so that a scatter
-    with mode="drop" drops its write."""
-    bt, lens, n_valid = (_val(state.block_tables),
+    with mode="drop" drops its write. `ring`: through the ring table,
+    whose logical pages wrap."""
+    bt, lens, n_valid = (_val(state.ring_tables if ring
+                              else state.block_tables),
                          _val(state.lens), _val(state.n_valid))
     b = bt.shape[0]
     pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (b,s)
     valid = jnp.arange(s, dtype=jnp.int32)[None, :] < n_valid[:, None]
     logical = pos // page_size
-    phys = jnp.take_along_axis(
-        bt, jnp.clip(logical, 0, bt.shape[1] - 1), axis=1)   # (b, s)
+    logical = (logical % bt.shape[1] if ring
+               else jnp.clip(logical, 0, bt.shape[1] - 1))
+    phys = jnp.take_along_axis(bt, logical, axis=1)          # (b, s)
     # invalid rows: point past the pool and DROP the write (r5 review:
     # routing them to page 0 corrupted callers whose block tables
     # legitimately allocate page 0 — the public op has no trash-page
@@ -211,8 +232,9 @@ def _token_coords(state: PagedState, s, page_size, num_pages):
 
 
 def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
-                v_scale=None):
-    """Scatter this call's (b, s, hk, d) k/v into their pages.
+                v_scale=None, ring=False):
+    """Scatter this call's (b, s, hk, d) k/v into their pages (`ring`:
+    the pages of the ring table, `_token_coords`).
 
     Plain pools: inside `decode_kernel_scope("pallas")`, pools stored as
     the decode kernel's rows (`kernels/paged_attention.py
@@ -235,7 +257,7 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
     b, s, hk, d = k.shape
     num_pages = kp.shape[0]
     page_size = _pk.page_size_of(kp, hk, d)
-    phys_f, off_f = _token_coords(state, s, page_size, num_pages)
+    phys_f, off_f = _token_coords(state, s, page_size, num_pages, ring)
 
     if k_scale is None:
         kind, interpret = _decode_kernel_choice()
@@ -294,13 +316,9 @@ def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
     hk = hk or kp.shape[1]
     pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
 
-    def window(pool):                                        # (b,hk,L,d)
-        return jnp.moveaxis(_pk.pages_by_head(pool[bt], hk, d), 2,
-                            1).reshape(b, hk, -1, d)
-
     # window column c IS logical position c (page j holds positions
     # [j*page_size, (j+1)*page_size)), so the causal bound is c <= pos.
-    ks, vs = window(kp), window(vp)
+    ks, vs = _gathered(kp, bt, hk, d), _gathered(vp, bt, hk, d)
     L = ks.shape[2]
     ks = ks.astype(jnp.float32)
     vs = vs.astype(jnp.float32)
@@ -452,7 +470,95 @@ def _attend_indexed(q, k, v, cache, state: PagedState, index):
     return Tensor(out), (Tensor(kp), Tensor(vp), Tensor(ip))
 
 
-def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
+def _gathered(pool, table, hk, d):
+    """The pages `table` (b, P) names, in its order, as a row's keys in
+    order: (b, hk, P * page_size, d)."""
+    b = table.shape[0]
+    return jnp.moveaxis(_pk.pages_by_head(pool[table], hk, d), 2,
+                        1).reshape(b, hk, -1, d)
+
+
+def _chunk_kernel_takes(q, hk):
+    """Whether this call's many-token attend goes through the Pallas chunk
+    kernel: inside `decode_kernel_scope("pallas")`, for shapes the kernel
+    takes (`chunk_attention_problems`)."""
+    kind, interpret = _decode_kernel_choice()
+    _b, s, hq, d = q.shape
+    return kind == "pallas" and s > 1 \
+        and not chunk_attention_problems(s, hq, hk, d, interpret)
+
+
+def _ring_view(state: PagedState, s, window, page_size):
+    """What a layer that attends inside a window reads of its ring, after
+    the call's write: the pages that hold the positions (t - window, t] of
+    the call's first to its last token, in order, as a block table of
+    their own -> (pages (b, P), the first token's column (b,), seen (b, s,
+    P * page_size) bool). Column c of row i is position first_page_i *
+    page_size + c, so columns are tokens in order and a ring that has
+    wrapped reads like a table that has not; a page of the ring outside
+    those P is not read."""
+    rt, lens = _val(state.ring_tables), _val(state.lens)
+    ring = rt.shape[1]
+    # a ring shorter than that holds every context whole (the engine caps
+    # it at a slot's longest): its columns past a context are never seen
+    pages = min(ring_pages_for(window, s, page_size), ring)
+    first = jnp.maximum(lens - window + 1, 0) // page_size          # (b,)
+    logical = first[:, None] + jnp.arange(pages, dtype=jnp.int32)[None]
+    table = jnp.take_along_axis(rt, logical % ring, axis=1)
+    col = first[:, None] * page_size \
+        + jnp.arange(pages * page_size, dtype=jnp.int32)[None]     # (b, C)
+    t = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None]        # (b, s)
+    seen = (col[:, None, :] <= t[:, :, None]) \
+        & (col[:, None, :] > t[:, :, None] - window)
+    return table, lens - first * page_size, seen
+
+
+def _attend_window(q, k, v, cache, state: PagedState, window):
+    """The ring form of `paged_attention_update`: this call's k and v go
+    into the ring's pages, over what they held a ring's length ago, and
+    each token attends over the `window` newest positions up to its own
+    (itself included) and nothing else, through `_ring_view`: the decode
+    kernel and the blocked attend take the view as they take a key
+    selection."""
+    if len(cache) != 2:
+        raise ValueError("a window layer's cache is (k_pool, v_pool): "
+                         "neither scale planes nor an index pool ride a "
+                         "ring")
+    if state.ring_tables is None:
+        raise ValueError(
+            "paged_attention_update(window=) reads PagedState.ring_tables: "
+            "the page table of the layers that attend inside a window "
+            "(PagedKVEngine builds it from the model's config)")
+    kp, vp = _val(cache[0]), _val(cache[1])
+    b, s, hq, d = q.shape
+    hk = k.shape[2]
+    with jax.named_scope("kv_write"):
+        kp, vp, _ks, _vs = _scatter_kv(kp, vp, k, v, state, ring=True)
+    table, at, seen = _ring_view(state, s, int(window),
+                                 _pk.page_size_of(kp, hk, d))
+    kind, interpret = _decode_kernel_choice()
+    with jax.named_scope("paged_attn"):
+        if kind == "pallas" and s == 1:
+            out = _pk.paged_decode_attention(
+                q[:, 0], kp, vp, table, at, interpret=interpret,
+                select=seen[:, 0], kv_heads=hk)
+            out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
+        elif _chunk_kernel_takes(q, hk):
+            # the view's columns are positions in order from its first
+            # page on: the kernel's mask is that rule, not `seen`
+            lens = _val(state.lens)
+            out = chunk_attention(
+                q, _gathered(kp, table, hk, d), _gathered(vp, table, hk, d),
+                lens, lens - at, window=window, interpret=interpret)
+        else:
+            out = _attend_selected(
+                q, kp, vp, PagedState(table, at, _val(state.n_valid)),
+                seen, hk)
+    return Tensor(out), (Tensor(kp), Tensor(vp))
+
+
+def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
+                           window=None):
     """Write this call's k/v into the slot's pages, then attend over the
     slot's whole paged window. One code path serves BOTH phases of the
     reference contract (block_multi_head_attention_kernel.cu's prefill
@@ -468,6 +574,9 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
     index key a token, (num_pages, 1, page_size, di), and `index` (this
     call's index queries, index keys, head weights and topk:
     `_attend_indexed`).
+    `window`: this layer attends over the newest `window` positions alone
+    and its pools ride `state.ring_tables`, not the block table
+    (`_attend_window`).
     Returns (out (b, s, hq*d), new cache of the SAME arity).
 
     Decode calls (s == 1) traced inside
@@ -481,6 +590,8 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
     bookkeeping.
     """
     q, k, v = _val(q), _val(k), _val(v)
+    if window:
+        return _attend_window(q, k, v, cache, state, window)
     if len(cache) == 3:
         if index is None:
             raise ValueError(
@@ -517,7 +628,24 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None):
                 _val(state.lens), k_scale=k_scale, v_scale=v_scale,
                 interpret=interpret, kv_heads=hk)
             out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
+        elif not quantized and _chunk_kernel_takes(q, hk):
+            # a chunk's keys are the slot's pages from the first on, under
+            # the kernel a window layer's chunk takes; the choice is the
+            # call's own (the scope, the shapes), whatever the model's
+            # other layers are
+            bt = _val(state.block_tables)
+            out = chunk_attention(
+                q, _gathered(kp, bt, hk, d), _gathered(vp, bt, hk, d),
+                _val(state.lens), jnp.zeros_like(_val(state.lens)),
+                interpret=interpret)
         else:
+            # what the chunk kernel does not take: int8 pools, a chunk
+            # that is no whole sublane tile, the jnp scope, and on the
+            # chip a head width that is no multiple of 128 (the dense
+            # cell's 64). This fork is kept only so that such a model's
+            # lowered prefill stays byte-equal until ROADMAP M3's PR
+            # measures it through the kernel and deletes `_attend_pages`
+            # with the score budget (`PagedKVEngine._prefill_limit`)
             out = _attend_pages(q, kp, vp, state, k_scale, v_scale, hk)
     if quantized:
         return Tensor(out), (Tensor(kp), Tensor(vp),
@@ -693,6 +821,60 @@ class _Slot:
         #                             drawn from the free list here)
 
 
+class _PageGroup:
+    """The pages of the model's layers of one kind, and what hands them
+    out: which layers ride this table, their pools (one tuple a layer,
+    each pool `num_pages` long, page 0 the trash page), the block table
+    (slots x pages a slot) and the free list. An engine holds one for the
+    layers that keep every token of a slot, whose rows grow with the
+    context, and, for a model with layers that attend inside a window, a
+    second (`window` > 0) whose rows are rings: `pages_per_slot` pages a
+    slot, taken as the context first reaches them, then overwritten in
+    place and never more (`PagedState.ring_tables`)."""
+    __slots__ = ("layers", "num_pages", "pages_per_slot", "window", "bt",
+                 "free", "pools")
+
+    def __init__(self, layers, num_pages, pages_per_slot, slots, window=0):
+        self.layers = list(layers)
+        self.num_pages = int(num_pages)
+        self.pages_per_slot = int(pages_per_slot)
+        self.window = int(window)
+        self.bt = np.zeros((slots, self.pages_per_slot), np.int32)
+        self.free = list(range(self.num_pages - 1, 0, -1))   # 0 = trash
+        self.pools = []
+
+    def held(self, slot_idx):
+        """Pages the slot's row names (a page is never 0)."""
+        return int(np.count_nonzero(self.bt[slot_idx]))
+
+    def grow(self, slot_idx, need_total):
+        """A ring's row up to `need_total` pages, at most the ring: the
+        pool holds a ring a slot, so the free list cannot run dry."""
+        for j in range(self.held(slot_idx),
+                       min(need_total, self.pages_per_slot)):
+            self.bt[slot_idx, j] = self.free.pop()
+
+    def release(self, slot_idx):
+        """A ring's pages back to the free list."""
+        row = self.bt[slot_idx]
+        self.free.extend(int(p) for p in row[row > 0][::-1])
+        row[:] = 0
+
+    def bytes_per_slot(self):
+        """HBM bytes a slot's full row pins in these layers' pools, from
+        the real buffers."""
+        return self.pages_per_slot * sum(
+            a.size * a.dtype.itemsize // self.num_pages
+            for grp in self.pools for a in grp)
+
+
+def _state_of(tables, lens, n_valid):
+    """`PagedState` from what a program was handed as its table(s)."""
+    if isinstance(tables, tuple):
+        return PagedState(tables[0], lens, n_valid, tables[1])
+    return PagedState(tables, lens, n_valid)
+
+
 class _TickArgs(NamedTuple):
     """What a plain tick takes beside its slots' rows and the pools, as
     device arrays: a chained tick takes its predecessor's again."""
@@ -860,11 +1042,48 @@ class PagedKVEngine:
                 draft_model.config, "index_head_dim", 0):
             raise ValueError("a draft model with an index pool is not "
                              "carried (its pools are K and V alone)")
+        # a model with layers that attend inside a window (the config's
+        # `layer_types` names them, `sliding_window` is the window) keeps
+        # those layers' K and V in rings under a SECOND page table
+        # (`_PageGroup`); num_pages and max_pages_per_slot describe the
+        # layers that keep every token. What assumes one table refuses
+        # here, by name.
+        window = int(getattr(cfg, "sliding_window", 0) or 0)
+        kinds = list(getattr(cfg, "layer_types", None) or [])
+        window_layers = [i for i, kind in enumerate(kinds)
+                         if kind == "sliding_attention"] if window else []
+        # the window of the layers in rings; 0: no layer is
+        self.window = window if window_layers else 0
+        if window_layers:
+            if len(kinds) != cfg.num_hidden_layers:
+                raise ValueError(
+                    f"layer_types names {len(kinds)} layers, the model "
+                    f"has {cfg.num_hidden_layers}")
+            refused = [why for on, why in (
+                (self.index_dim, "an index pool (the key selection reads "
+                 "every key of a slot)"),
+                (kv_dtype == "int8", "kv_dtype='int8' (a ring's pages "
+                 "are overwritten in place; their scales only grow)"),
+                (int(prefix_cache_pages), "prefix_cache_pages (a window "
+                 "layer's page of a prefix is overwritten once the "
+                 "context has gone round the ring)"),
+                (int(host_tier_bytes), "host_tier_bytes (a spilled page "
+                 "is a page of every layer under one table)"),
+                (role != "both", f"role={role!r} (an exported page is a "
+                 "page of every layer under one table)"),
+                (draft_model is not None, "draft_model (a rejected draft "
+                 "token has already overwritten the ring's oldest)")) if on]
+            if refused:
+                raise ValueError(
+                    f"this model keeps layers {window_layers} in rings of "
+                    f"a window of {self.window} under a second page "
+                    "table; not carried by: " + "; ".join(refused))
         self._cache_arity = (4 if kv_dtype == "int8"
                              else 3 if self.index_dim else 2)
 
-        def make_pools(n_heads, head_dim, n_layers):
-            """One layer's pools, `n_layers` times. Plain K and V pools
+        def make_pools(n_heads, head_dim, n_layers, num_pages=None):
+            """One layer's pools (`num_pages` long: the engine's, or a
+            group's of its own), `n_layers` times. Plain K and V pools
             of an engine whose decode attends through the Pallas kernel
             are stored as that kernel's rows (`pool_rows_shape`): the
             kernel and the Pallas write are then the only ops that
@@ -874,14 +1093,15 @@ class PagedKVEngine:
             int8 write rescales whole pages (no cell runs it, and a
             second quantising kernel is not worth growing for it), and
             no Pallas call reads an index pool."""
-            shape = (self.num_pages, n_heads, self.page_size, head_dim)
-            sshape = (self.num_pages, n_heads)
+            num_pages = num_pages or self.num_pages
+            shape = (num_pages, n_heads, self.page_size, head_dim)
+            sshape = (num_pages, n_heads)
             if self.kv_write == "pallas":
-                shape = _pk.pool_rows_shape(self.num_pages, n_heads,
+                shape = _pk.pool_rows_shape(num_pages, n_heads,
                                             head_dim, self.page_size,
                                             pool_dtype)
             if self.index_dim:      # never a draft's: refused above
-                ishape = (self.num_pages, 1, self.page_size,
+                ishape = (num_pages, 1, self.page_size,
                           self.index_dim)
                 return [(jnp.zeros(shape, pool_dtype),
                          jnp.zeros(shape, pool_dtype),
@@ -906,8 +1126,10 @@ class PagedKVEngine:
         self._kernel_interpret = not on_tpu
         # a key selection rides the kernel only where its score columns
         # are tokens in order
+        # (a window layer's view of its ring rides it as one does)
         select_problems = (_pk.select_shape_problems(
-            n_kv, hd, self.page_size, pool_dtype) if self.index_dim else [])
+            n_kv, hd, self.page_size, pool_dtype)
+            if self.index_dim or window_layers else [])
         if kernel == "pallas":
             _pk.check_decode_shapes(cfg.num_attention_heads, n_kv, hd,
                                     self.page_size,
@@ -960,7 +1182,34 @@ class PagedKVEngine:
         # what a K or V page is outside the engine (a host-tier entry,
         # an exported bundle), whatever shape the pools store it in
         self._page_shape = (n_kv, self.page_size, hd)
-        self.pools = make_pools(n_kv, hd, cfg.num_hidden_layers)
+        # the page tables (`_PageGroup`): `_full` is every layer's but
+        # for a model with window layers, whose rings are `_ring`'s, one a
+        # slot, each long enough for the longest write of one call
+        self._full = _PageGroup(
+            [i for i in range(cfg.num_hidden_layers)
+             if i not in window_layers],
+            self.num_pages, self.max_pages_per_slot, self.max_slots)
+        self._ring = None
+        # whether a prefill's attention goes through the Pallas chunk
+        # kernel: `paged_attention_update` chooses by the scope and the
+        # call's shapes, and this is the same rule (plain pools, a chunk
+        # of whole sublane tiles). Then no score is ever written to
+        # memory (`_prefill_limit`)
+        self._chunk_kernel = bool(
+            self.decode_kernel == "pallas" and self._cache_arity == 2
+            and not chunk_attention_problems(
+                8, cfg.num_attention_heads, n_kv, hd,
+                self._kernel_interpret))
+        if window_layers:
+            longest = self._bucket(self.prefill_chunk
+                                   or self._prefill_limit(1))
+            ring = min(ring_pages_for(self.window, longest, self.page_size),
+                       self.max_pages_per_slot)
+            self._ring = _PageGroup(window_layers,
+                                    self.max_slots * ring + 1, ring,
+                                    self.max_slots, window=self.window)
+        for grp in self._groups:
+            grp.pools = make_pools(n_kv, hd, len(grp.layers), grp.num_pages)
         if draft_model is not None:
             self._draft_page_shape = (dn_kv, self.page_size, dhd)
             self.draft_pools = make_pools(dn_kv, dhd,
@@ -973,7 +1222,6 @@ class PagedKVEngine:
             self.decode_plan = _pk.decode_plan(
                 cfg.num_attention_heads, n_kv, hd, self.page_size,
                 self.max_pages_per_slot, pool_dtype, slots=self.max_slots)
-        self._free = list(range(self.num_pages - 1, 0, -1))  # 0 = trash
         # pages promised to admitted slots but not yet popped from the
         # free list; admission headroom = len(_free) - _reserved_unalloc
         self._reserved_unalloc = 0
@@ -1038,8 +1286,6 @@ class PagedKVEngine:
         self._cached_pages: set[int] = set()
         self._reclaimable = 0
         self._slots: list[_Slot | None] = [None] * self.max_slots
-        self._bt = np.zeros((self.max_slots, self.max_pages_per_slot),
-                            np.int32)
         self._pending: list[_Request] = []
         self._inflight = 0      # submitted, not yet retired/dropped
         self._lock = threading.Lock()
@@ -1092,6 +1338,13 @@ class PagedKVEngine:
             # decode steps of live slots, and those whose context had
             # outgrown topk, so that the selection chose among its keys
             self.stats.update(decode_slot_steps=0, select_engaged_steps=0)
+        if self._ring is not None:
+            # decode steps of live slots, those whose context had outgrown
+            # the window, and per such step the tokens the two tables hold
+            # for the slot, summed over the layers, beside what one table
+            # for every layer would hold
+            self.stats.update(decode_slot_steps=0, window_engaged_steps=0,
+                              kv_tokens_held=0, kv_tokens_flat=0)
         # what the model counts itself a decode step (its
         # `decode_counter_keys`, e.g. the distinct experts its rows hit):
         # the tick program asks the forward for them (`with_counters`),
@@ -1113,12 +1366,79 @@ class PagedKVEngine:
         computed from the REAL buffer dtypes — so `kv_dtype` is honored
         end-to-end instead of assuming f32/bf16 element sizes."""
         per_page = 0
-        for pools in (self.pools, self.draft_pools or []):
-            for grp in pools:
-                for arr in grp:
-                    per_page += (arr.size * arr.dtype.itemsize
-                                 // self.num_pages)
-        return per_page * self.max_pages_per_slot
+        for grp in self.draft_pools or []:
+            for arr in grp:
+                per_page += (arr.size * arr.dtype.itemsize
+                             // self.num_pages)
+        return per_page * self.max_pages_per_slot \
+            + sum(grp.bytes_per_slot() for grp in self._groups)
+
+    # the table of the layers that keep every token, under the names the
+    # scheduler has always used for it
+    @property
+    def _bt(self):
+        return self._full.bt
+
+    @property
+    def _free(self):
+        return self._full.free
+
+    @property
+    def _groups(self):
+        return [self._full] if self._ring is None \
+            else [self._full, self._ring]
+
+    @property
+    def pools(self):
+        """Every layer's pools in the model's order, as the programs take
+        them; each lives in its group."""
+        if self._ring is None:
+            return self._full.pools
+        merged = [None] * sum(len(g.layers) for g in self._groups)
+        for grp in self._groups:
+            for i, pool in zip(grp.layers, grp.pools):
+                merged[i] = pool
+        return merged
+
+    @pools.setter
+    def pools(self, pools):
+        for grp in self._groups:
+            grp.pools = pools if self._ring is None or pools is None \
+                else [pools[i] for i in grp.layers]
+
+    def page_groups(self):
+        """What each page table holds, as the engine built it: the layers
+        that ride it, their window (0: every token is kept), the pages of
+        one layer's pool and of a slot's row, and the bytes of its pools
+        from the real buffers."""
+        return [{"layers": list(g.layers), "window": g.window,
+                 "pool_pages": g.num_pages,
+                 "pages_per_slot": g.pages_per_slot,
+                 "pool_bytes": sum(a.size * a.dtype.itemsize
+                                   for kv in g.pools or [] for a in kv)}
+                for g in self._groups]
+
+    def _tables(self, rows=None):
+        """The block table as the host holds it now, a copy: one array,
+        or with window layers (the table, the ring table). `rows`: of
+        these slots only, one row each, None for a row left empty."""
+        def cut(bt):
+            if rows is None:
+                return bt.copy()
+            out = np.zeros((len(rows), bt.shape[1]), np.int32)
+            for r, idx in enumerate(rows):
+                if idx is not None:
+                    out[r] = bt[idx]
+            return out
+        if self._ring is None:
+            return cut(self._full.bt)
+        return cut(self._full.bt), cut(self._ring.bt)
+
+    def _tables_changed(self, sent):
+        """Whether the host's table(s) differ from the copy `sent`."""
+        sent = sent if isinstance(sent, tuple) else (sent,)
+        return not all(np.array_equal(grp.bt, was)
+                       for grp, was in zip(self._groups, sent))
 
     def export_metrics(self, registry):
         """Publish the engine's telemetry counters into a metrics
@@ -1627,6 +1947,8 @@ class PagedKVEngine:
             self._reserved_unalloc -= 1
             self._bt[slot_idx, len(slot.pages)] = page
             slot.pages.append(page)
+        if self._ring is not None:
+            self._ring.grow(slot_idx, need_total)
 
     def _prefix_lookup(self, req):
         """Longest cached run of the prompt's full pages, capped at
@@ -2132,6 +2454,18 @@ class PagedKVEngine:
         through the chunk program in pieces of this length."""
         if self.draft_model is not None:
             return 1 << 30      # the chunk program has no draft mirror
+        if self._chunk_kernel and self.window:
+            # through the chunk kernel a call holds no scores; what a
+            # longer call costs is a longer ring (`ring_pages_for`). A
+            # call of as many tokens as the window, all rows together,
+            # keeps a ring under two windows and reads each weight once
+            # a window of tokens. (A model without rings whose shapes the
+            # kernel takes holds no scores either and keeps the budget
+            # below all the same: the rule goes, with `_attend_pages`,
+            # in the PR that measures the one-table cells' prefill
+            # through the kernel, ROADMAP M3.)
+            limit = max(8, self.window // rows)
+            return 1 << (limit.bit_length() - 1)
         cfg = self.model.config
         per_token = (rows * cfg.num_attention_heads * 4
                      * self.max_pages_per_slot * self.page_size)
@@ -2193,7 +2527,7 @@ class PagedKVEngine:
                 ids = np.zeros((bw, chunk), np.int32)
                 lens = np.zeros(bw, np.int32)
                 nv = np.zeros(bw, np.int32)
-                bt = np.zeros((bw, self.max_pages_per_slot), np.int32)
+                rows = [None] * bw
                 for r, (idx, req) in enumerate(grp):
                     take = min(chunk, plens[r] - int(done[r]))
                     if take <= 0:
@@ -2201,9 +2535,10 @@ class PagedKVEngine:
                     ids[r, :take] = req.prompt[done[r]:done[r] + take]
                     lens[r] = done[r]
                     nv[r] = take
-                    bt[r] = self._bt[idx]
+                    rows[r] = idx
                 last, flat = fn(jnp.asarray(ids), jnp.asarray(lens),
-                                jnp.asarray(nv), jnp.asarray(bt),
+                                jnp.asarray(nv),
+                                jax.tree.map(jnp.asarray, self._tables(rows)),
                                 [a for kv in self.pools for a in kv])
                 self.pools = self._unflat_pools(flat)
                 for r in range(len(grp)):
@@ -2234,7 +2569,7 @@ class PagedKVEngine:
         model = self.model
 
         def run(ids, lens, n_valid, bt_rows, pool_flat):
-            state = PagedState(bt_rows, lens, n_valid)
+            state = _state_of(bt_rows, lens, n_valid)
             pos = lens[:, None] + jnp.arange(chunk,
                                              dtype=jnp.int32)[None, :]
             logits, new_caches = model(
@@ -2265,7 +2600,6 @@ class PagedKVEngine:
             ids = np.zeros((bw, ppad), np.int32)
             lens = np.zeros(bw, np.int32)
             nv = np.zeros(bw, np.int32)
-            bt = np.zeros((bw, self.max_pages_per_slot), np.int32)
             for row, (idx, req) in enumerate(grp):
                 # warm slots (prefix-cache hit) prefill ONLY the uncached
                 # tail: lens starts past the shared pages, and the tail
@@ -2275,10 +2609,11 @@ class PagedKVEngine:
                 ids[row, :tail.size] = tail
                 lens[row] = off
                 nv[row] = tail.size
-                bt[row] = self._bt[idx]
+            bt = jax.tree.map(jnp.asarray, self._tables(
+                [idx for idx, _req in grp] + [None] * (bw - len(grp))))
             last_logits, flat = fn(
                 jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(nv),
-                jnp.asarray(bt), [a for kv in self.pools for a in kv])
+                bt, [a for kv in self.pools for a in kv])
             self.pools = self._unflat_pools(flat)
             if self.draft_model is not None:
                 # the draft's pools share the same block tables, so shared
@@ -2287,7 +2622,7 @@ class PagedKVEngine:
                 # prefill also runs only the tail
                 dfn = self._draft_prefill_fn(ppad, bw)
                 dflat = dfn(jnp.asarray(ids), jnp.asarray(lens),
-                            jnp.asarray(nv), jnp.asarray(bt),
+                            jnp.asarray(nv), bt,
                             [a for kv in self.draft_pools for a in kv])
                 self.draft_pools = self._unflat_pools(dflat)
             logits_np = np.asarray(last_logits)              # (bw, vocab)
@@ -2356,6 +2691,8 @@ class PagedKVEngine:
         # list, so pages_needed - len(pages) is the remainder either way)
         self._reserved_unalloc -= slot.req.pages_needed - len(slot.pages)
         self._bt[slot_idx, :] = 0
+        if self._ring is not None:
+            self._ring.release(slot_idx)
         self._slots[slot_idx] = None
         with self._lock:
             self._inflight -= 1
@@ -2526,21 +2863,21 @@ class PagedKVEngine:
                 with observability.span("engine.tick.upload"):
                     if flight is None:
                         any_sample = bool(a["wants"].any())
-                        sent = self._bt.copy()  # never written again
+                        sent = self._tables()   # never written again
                         rows = tuple(jnp.asarray(a[k]) for k in
                                      ("tok", "lens", "active", "limit"))
                         args = _TickArgs(
                             self._tick_fn(any_sample),
-                            jnp.asarray(sent), sent, jnp.asarray(a["eos"]),
+                            jax.tree.map(jnp.asarray, sent), sent,
+                            jnp.asarray(a["eos"]),
                             tuple(jnp.asarray(a[k]) for k in
                                   ("temp", "topk", "topp", "wants"))
                             if any_sample else ())
-                    elif chain and not np.array_equal(self._bt,
-                                                      args.bt_sent):
+                    elif chain and self._tables_changed(args.bt_sent):
                         # a chained tick uploads the block table if a
                         # page was added or a row retired, nothing else
-                        sent = self._bt.copy()
-                        args = args._replace(bt=jnp.asarray(sent),
+                        sent = self._tables()
+                        args = args._replace(bt=jax.tree.map(jnp.asarray, sent),
                                              bt_sent=sent)
                 marks.append(clock())
                 with observability.span("engine.tick.launch"):
@@ -2579,15 +2916,30 @@ class PagedKVEngine:
                     counts[i] = min(slot.req.max_new_tokens - slot.emitted,
                                     n)
                     eos[i] = slot.req.eos_token_id
-                if self.index_dim:
+                if self.index_dim or self._ring is not None:
                     took = counts[live]
                     lens0 = np.asarray([self._slots[i].lens for i in live],
-                                       np.int32)
+                                       np.int64)
                     self.stats["decode_slot_steps"] += int(took.sum())
                     # step j of a slot attends over lens + j + 1 keys
-                    self.stats["select_engaged_steps"] += int(np.clip(
-                        lens0 + took - np.maximum(lens0, self.index_topk),
-                        0, took).sum())
+                    edge = self.index_topk or self.window
+                    self.stats["select_engaged_steps" if self.index_dim
+                               else "window_engaged_steps"] += int(np.clip(
+                                   lens0 + took - np.maximum(lens0, edge),
+                                   0, took).sum())
+                if self._ring is not None:
+                    # the keys of those steps: every one in the layers
+                    # that keep every token, the window's in the rings
+                    keys = lens0[:, None] + 1 + np.arange(n)[None]
+                    keys = np.where(np.arange(n)[None] < took[:, None],
+                                    keys, 0)
+                    whole = int(keys.sum())
+                    ringed = int(np.minimum(keys, self.window).sum())
+                    rings = len(self._ring.layers)
+                    self.stats["kv_tokens_flat"] += whole * (
+                        rings + len(self._full.layers))
+                    self.stats["kv_tokens_held"] += (
+                        whole * len(self._full.layers) + ringed * rings)
                 with observability.span("engine.tick.accept"):
                     # rows of a request retired since the launch (ended
                     # in the tick before, cancelled) are not in `live`
@@ -2915,7 +3267,7 @@ class PagedKVEngine:
         model = self.model
 
         def run(ids, lens, n_valid, bt_rows, pool_flat):
-            state = PagedState(bt_rows, lens, n_valid)
+            state = _state_of(bt_rows, lens, n_valid)
             pos = lens[:, None] + jnp.arange(ppad,
                                              dtype=jnp.int32)[None, :]
             logits, new_caches = model(
@@ -3142,7 +3494,7 @@ class PagedKVEngine:
             def body(carry, step_i):
                 tok, lens, fin, cnt, flat = carry
                 live = jnp.logical_and(active, jnp.logical_not(fin))
-                state = PagedState(bt, lens, live.astype(jnp.int32))
+                state = _state_of(bt, lens, live.astype(jnp.int32))
                 logits, new_caches, *counts = model(
                     Tensor(tok[:, None]),
                     caches=self._layer_caches(list(flat)),

@@ -36,7 +36,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.core.jax_compat import tpu_compiler_params
 
-__all__ = ["moe_experts_decode", "moe_decode_problems", "experts_hit"]
+__all__ = ["moe_experts_decode", "moe_decode_problems", "experts_hit",
+           "held_ids"]
 
 # rows up to which the kernel is taken. Every row meets every listed
 # expert, so past the rows at which every expert is hit the work grows
@@ -75,10 +76,22 @@ def moe_decode_problems(rows, d, f, dtype):
     return problems
 
 
-def experts_hit(idx, num_experts):
+def held_ids(idx, first, num_held):
+    """The rows' choices as ids among the `num_held` experts held here,
+    those from `first` on of all the router scores; a choice held
+    elsewhere becomes `num_held`, one past the last, which a scatter
+    with mode="drop" leaves out."""
+    local = idx.astype(jnp.int32) - first
+    return jnp.where((local >= 0) & (local < num_held), local, num_held)
+
+
+def experts_hit(idx, num_experts, share=False):
     """How many distinct experts the rows' choices name: what a step must
-    read of the expert weights. idx (T, k) int -> int32 scalar."""
-    hit = jnp.zeros((num_experts,), jnp.int32).at[idx.reshape(-1)].set(1)
+    read of the expert weights. idx (T, k) int -> int32 scalar. `share`:
+    idx is `held_ids`' and names experts held elsewhere by `num_experts`,
+    which count for nothing."""
+    hit = jnp.zeros((num_experts,), jnp.int32).at[idx.reshape(-1)].set(
+        1, **({"mode": "drop"} if share else {}))
     return jnp.sum(hit)
 
 
@@ -102,10 +115,16 @@ def _kernel(ids_ref, x_ref, wg_ref, wu_ref, wd_ref, g_ref, o_ref):
     o_ref[...] += g_ref[0][:, :1] * dot(act, wd_ref[0])
 
 
-def moe_experts_decode(x, wg, wu, wd, idx, gates, *, interpret=False):
+def moe_experts_decode(x, wg, wu, wd, idx, gates, *, interpret=False,
+                       share=False):
     """x (T, d), T <= MAX_ROWS; wg, wu (E, d, f); wd (E, f, d); idx, gates
     (T, k): each row's experts and their weights. Returns (T, d) in
-    x.dtype: sum_j gates[t, j] * expert_{idx[t, j]}(x[t])."""
+    x.dtype: sum_j gates[t, j] * expert_{idx[t, j]}(x[t]).
+
+    `share`: the E experts are a share of those the rows chose among and
+    idx is `held_ids`': a choice of value E is of an expert held
+    elsewhere, adds nothing and names no expert, so a row whose choices
+    are all elsewhere costs nothing."""
     t, d = x.shape
     e, _, f = wg.shape
     problems = moe_decode_problems(t, d, f, wg.dtype)
@@ -116,13 +135,14 @@ def moe_experts_decode(x, wg, wu, wd, idx, gates, *, interpret=False):
     x, wg, wu, wd, gates = (jax.lax.stop_gradient(a)
                             for a in (x, wg, wu, wd, gates))
     return _call(x, wg, wu, wd, idx.astype(jnp.int32),
-                 gates.astype(jnp.float32), interpret=interpret)
+                 gates.astype(jnp.float32), interpret=interpret,
+                 **({"share": True} if share else {}))
 
 
 # jitted on its own: a model calls this once a layer, and a caller's trace
 # then holds ONE traced and lowered kernel (kernels/paged_attention.py)
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(x, wg, wu, wd, idx, gates, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "share"))
+def _call(x, wg, wu, wd, idx, gates, *, interpret, share=False):
     t, d = x.shape
     e, _, f = wg.shape
     k = idx.shape[1]
@@ -131,9 +151,11 @@ def _call(x, wg, wu, wd, idx, gates, *, interpret):
     rows = -(-t // 16) * 16             # whole sublane tiles of bf16
 
     # the distinct experts hit, ascending, then the last one repeated
+    # (`share`: a choice of value e is held elsewhere and left out)
+    drop = {"mode": "drop"} if share else {}
     dense = jnp.zeros((t, e), jnp.float32).at[
-        jnp.arange(t)[:, None], idx].add(gates)                 # (T, E)
-    hit = jnp.zeros((e,), jnp.int32).at[idx.reshape(-1)].set(1)
+        jnp.arange(t)[:, None], idx].add(gates, **drop)         # (T, E)
+    hit = jnp.zeros((e,), jnp.int32).at[idx.reshape(-1)].set(1, **drop)
     n = jnp.sum(hit)
     order = jnp.argsort(1 - hit, stable=True).astype(jnp.int32)[:m]
     pos = jnp.arange(m, dtype=jnp.int32)

@@ -32,3 +32,7 @@ from paddle_tpu.models.sparse_attn_moe import (  # noqa: F401
     SparseAttnMoeConfig, SparseAttnMoeForCausalLM, SparseAttnMoeModel,
     tiny_sparse_attn_moe_config,
 )
+from paddle_tpu.models.window_attn_moe import (  # noqa: F401
+    WindowAttnMoeConfig, WindowAttnMoeForCausalLM, WindowAttnMoeModel,
+    tiny_window_attn_moe_config,
+)

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu
@@ -47,38 +48,61 @@ def tiny_qwen2_moe_config(**overrides) -> Qwen2MoeConfig:
     return Qwen2MoeConfig(**base)
 
 
-class Qwen2MoeSparseBlock(nn.Layer):
-    """MoE experts + always-on shared expert with sigmoid gate
-    (Qwen2-MoE architecture)."""
+class SharedExpertMoE(nn.Layer):
+    """Routed experts (`moe`, a `MoEMLP` the caller built with its
+    router's options) plus one always-on SwiGLU shared expert that every
+    token passes through. `shared_gate`: the shared expert's output is
+    weighed by a sigmoid gate from the token (Qwen2-MoE); without it the
+    shared expert is added whole (the layer of models/window_attn_moe.py).
+    Where `moe` holds a share of its experts the shared expert is still
+    whole here: every device of the group computes it alike for its own
+    tokens."""
 
-    def __init__(self, config: Qwen2MoeConfig):
+    def __init__(self, moe, hidden_size, shared_intermediate_size,
+                 initializer_range=0.02, shared_gate=True):
         super().__init__()
-        self.moe = MoEMLP(
-            config.hidden_size, config.moe_intermediate_size,
-            config.num_experts, top_k=config.num_experts_per_tok,
-            capacity_factor=config.capacity_factor,
-            initializer_range=config.initializer_range,
-            dropless=config.moe_dropless)
-        shared_cfg = LlamaConfig(
-            hidden_size=config.hidden_size,
-            intermediate_size=config.shared_expert_intermediate_size,
-            initializer_range=config.initializer_range)
-        self.shared_expert = LlamaMLP(shared_cfg)
-        init = nn.initializer.Normal(0.0, config.initializer_range)
-        self.shared_expert_gate = nn.Linear(
-            config.hidden_size, 1,
-            weight_attr=paddle_tpu.nn.ParamAttr(initializer=init),
-            bias_attr=False)
+        self.moe = moe
+        self.shared_expert = LlamaMLP(LlamaConfig(
+            hidden_size=hidden_size,
+            intermediate_size=shared_intermediate_size,
+            initializer_range=initializer_range))
+        self.shared_expert_gate = None
+        if shared_gate:
+            init = nn.initializer.Normal(0.0, initializer_range)
+            self.shared_expert_gate = nn.Linear(
+                hidden_size, 1,
+                weight_attr=paddle_tpu.nn.ParamAttr(initializer=init),
+                bias_attr=False)
 
-    def forward(self, x):
-        moe_out = self.moe(x)
-        shared = self.shared_expert(x)
-        g = F.sigmoid(self.shared_expert_gate(x))
-        return moe_out + g * shared
+    def forward(self, x, with_hit=False):
+        """-> the block's output; `with_hit` as `MoEMLP.forward`'s."""
+        with jax.named_scope("shared_expert"):
+            shared = self.shared_expert(x)
+            if self.shared_expert_gate is not None:
+                shared = F.sigmoid(self.shared_expert_gate(x)) * shared
+        if not with_hit:
+            return self.moe(x) + shared
+        moe_out, hit = self.moe(x, with_hit=True)
+        return moe_out + shared, hit
 
     @property
     def aux_loss(self):
         return self.moe.aux_loss
+
+
+class Qwen2MoeSparseBlock(SharedExpertMoE):
+    """MoE experts + always-on shared expert with sigmoid gate
+    (Qwen2-MoE architecture)."""
+
+    def __init__(self, config: Qwen2MoeConfig):
+        super().__init__(
+            MoEMLP(config.hidden_size, config.moe_intermediate_size,
+                   config.num_experts, top_k=config.num_experts_per_tok,
+                   capacity_factor=config.capacity_factor,
+                   initializer_range=config.initializer_range,
+                   dropless=config.moe_dropless),
+            config.hidden_size, config.shared_expert_intermediate_size,
+            initializer_range=config.initializer_range)
 
 
 class Qwen2MoeDecoderLayer(nn.Layer):

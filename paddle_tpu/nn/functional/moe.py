@@ -118,7 +118,8 @@ def moe_combine(expert_out, combine):
                       expert_out)
 
 
-def topk_gating_dropless(logits, k):
+def topk_gating_dropless(logits, k, score_func="softmax", bias=None,
+                         route_norm=True, route_scale=1.0):
     """Dropless top-k gating (MegaBlocks/dMoE semantics; the reference's
     gshard gate at moe/gate/gshard_gate.py drops at capacity — this path
     never drops): every token's top-k experts are honored exactly.
@@ -127,8 +128,32 @@ def topk_gating_dropless(logits, k):
     renormalized over the top-k, aux_loss scalar). The aux loss keeps
     the GShard form (E * sum(me * ce)) with ce = mean assignment
     fraction over all T*k slots — load balance still matters for
-    grouped-matmul efficiency even though nothing is dropped."""
+    grouped-matmul efficiency even though nothing is dropped.
+
+    The router's options, as a config states them (the defaults are the
+    softmax router above): `score_func` "sigmoid" scores each expert on
+    its own; `bias` (E,) is added to the scores to CHOOSE the k experts
+    and never weighs them (a load-balancing term that no gradient
+    trains); `route_norm` renormalises the chosen scores to sum to 1;
+    `route_scale` multiplies the gates. Ties go to the lower index."""
     t, e = logits.shape
+    if score_func == "sigmoid":
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        picked = probs if bias is None else probs + bias.astype(jnp.float32)
+        _, idx = jax.lax.top_k(picked, k)                   # (T, k)
+        gates = jnp.take_along_axis(probs, idx, axis=-1)
+        if route_norm:
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        gates = gates * route_scale
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(jnp.sum(_one_hot(idx, e), axis=1), axis=0) / k
+        return idx.astype(jnp.int32), gates, e * jnp.sum(me * ce)
+    if score_func != "softmax" or bias is not None or not route_norm \
+            or route_scale != 1.0:
+        raise NotImplementedError(
+            f"router options score_func={score_func!r}, bias, "
+            f"route_norm={route_norm}, route_scale={route_scale}: the "
+            "softmax router is renormalised, unbiased and unscaled")
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gates, idx = jax.lax.top_k(probs, k)                    # (T, k)
     gates = gates / jnp.maximum(
@@ -209,23 +234,9 @@ def moe_dropless_mlp_ep_local(xt, router_w, wg, wu, wd, k, axis_name,
     recv_e = a2a(send_e).reshape(p * cbuf)
 
     # ---- local ragged grouped matmul over MY experts ----------------
-    # received ids are all in [me*e_l, (me+1)*e_l) or the sentinel;
-    # sort groups them, the sentinel rows form a trailing junk group
-    # consumed by a zero dummy expert so group sizes sum to the row
-    # count (lax.ragged_dot contract)
-    order2 = jnp.argsort(recv_e, stable=True)
-    rx = jnp.take(recv_x, order2, axis=0)
-    le = jnp.take(recv_e, order2) - me * e_l
-    le = jnp.where(le < e_l, le, e_l).astype(jnp.int32)
-    group_sizes = jnp.bincount(le, length=e_l + 1).astype(jnp.int32)
-    pad = lambda w: jnp.concatenate(                         # noqa: E731
-        [w, jnp.zeros((1,) + w.shape[1:], w.dtype)], axis=0)
-    a = jax.lax.ragged_dot(rx, pad(wg).astype(rx.dtype), group_sizes)
-    b_up = jax.lax.ragged_dot(rx, pad(wu).astype(rx.dtype), group_sizes)
-    act = jax.nn.silu(a.astype(jnp.float32)).astype(rx.dtype) * b_up
-    o = jax.lax.ragged_dot(act, pad(wd).astype(rx.dtype), group_sizes)
-    inv2 = jnp.argsort(order2, stable=True)
-    out_recv = jnp.take(o, inv2, axis=0).reshape(p, cbuf, d)
+    # received ids are all in [me*e_l, (me+1)*e_l) or the sentinel
+    out_recv = moe_held_experts(recv_x, recv_e, wg, wu, wd,
+                                me * e_l).reshape(p, cbuf, d)
 
     # ---- return trip + unpack ---------------------------------------
     back = a2a(out_recv)                                     # (P,cbuf,D)
@@ -237,7 +248,40 @@ def moe_dropless_mlp_ep_local(xt, router_w, wg, wu, wd, k, axis_name,
     return out, aux
 
 
-def moe_dropless_mlp(xt, wg, wu, wd, idx, gates):
+def moe_held_experts(rows, ids, wg, wu, wd, first):
+    """What the experts HELD here give for rows that name their expert by
+    its global id: wg/wu (E_held, D, F), wd (E_held, F, D) are experts
+    [first, first + E_held) of all; a row whose expert is held elsewhere
+    (or the sentinel of an empty buffer place) gives zeros. The local
+    half of expert parallelism, with or without the exchange around it:
+    `moe_dropless_mlp_ep_local` calls it on the rows it received, a layer
+    that holds a share of the experts on one device (`moe_dropless_mlp`,
+    `first=`) on its own.
+
+    rows (N, D), ids (N,) int -> (N, D). The rows are sorted by expert,
+    those of experts held elsewhere last, behind every group: the grouped
+    matmul is given the held experts' group sizes alone, and what it
+    leaves in the rows past them is set to zero."""
+    e_l = wg.shape[0]
+    le = ids.astype(jnp.int32) - first
+    le = jnp.where((le >= 0) & (le < e_l), le, e_l)
+    order = jnp.argsort(le, stable=True)
+    rx = jnp.take(rows, order, axis=0)
+    sorted_le = jnp.take(le, order)
+    group_sizes = jnp.bincount(le, length=e_l + 1).astype(jnp.int32)[:e_l]
+    # said outright for half-width operands: the TPU's grouped matmul
+    # refuses them under an ambient "highest"
+    prec = (jax.lax.Precision.DEFAULT
+            if rows.dtype in (jnp.bfloat16, jnp.float16) else None)
+    rdot = lambda x, w: jax.lax.ragged_dot(                 # noqa: E731
+        x, w.astype(rows.dtype), group_sizes, precision=prec)
+    a, b = rdot(rx, wg), rdot(rx, wu)
+    act = jax.nn.silu(a.astype(jnp.float32)).astype(rows.dtype) * b
+    o = jnp.where((sorted_le < e_l)[:, None], rdot(act, wd), 0)
+    return jnp.take(o, jnp.argsort(order, stable=True), axis=0)
+
+
+def moe_dropless_mlp(xt, wg, wu, wd, idx, gates, first=None):
     """Sort-based grouped-matmul expert MLP with ZERO token drops
     (MegaBlocks-style; TPU-native via jax.lax.ragged_dot — the
     XLA grouped matmul MaxText uses for dMoE).
@@ -245,10 +289,24 @@ def moe_dropless_mlp(xt, wg, wu, wd, idx, gates):
     xt (T, D); wg/wu (E, D, F); wd (E, F, D); idx/gates (T, k).
     All shapes static: the T*k (token, expert) pairs are sorted by
     expert id, each expert consumes a contiguous ragged row-group, and
-    outputs unsort back to token order. -> (T, D)."""
+    outputs unsort back to token order. -> (T, D).
+
+    `first`: the weights are a SHARE of the experts `idx` names, those
+    from `first` on (`moe_held_experts`): the pairs of experts held
+    elsewhere give nothing, and the sum is the part of the layer's
+    result that this share gives."""
     t, d = xt.shape
     e = wg.shape[0]
     k = idx.shape[1]
+    if first is not None:
+        with jax.named_scope("dispatch"):
+            pairs_x = jnp.repeat(xt, k, axis=0)             # (T*k, D)
+        with jax.named_scope("experts"):
+            o = moe_held_experts(pairs_x, idx.reshape(-1), wg, wu, wd,
+                                 first)
+        with jax.named_scope("combine"):
+            return jnp.sum(gates[..., None].astype(xt.dtype)
+                           * o.reshape(t, k, d), axis=1)
     with jax.named_scope("dispatch"):
         flat_e = idx.reshape(-1)                            # (T*k,)
         order = jnp.argsort(flat_e, stable=True)

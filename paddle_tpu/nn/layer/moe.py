@@ -22,7 +22,8 @@ from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.nn import initializer as I
 from paddle_tpu.nn.functional import moe as FM
-from paddle_tpu.kernels.moe_experts import (experts_hit, moe_decode_problems,
+from paddle_tpu.kernels.moe_experts import (experts_hit, held_ids,
+                                            moe_decode_problems,
                                             moe_experts_decode)
 
 
@@ -31,27 +32,46 @@ from paddle_tpu.kernels.moe_experts import (experts_hit, moe_decode_problems,
                  "shard over 'ep' (XLA gathers tokens), token dims over "
                  "dp/sp; prefer the capacity path for ep>1 meshes")
 def _moe_mlp_dropless(x, router_w, wg, wu, wd, k, few_rows=False,
-                      with_hit=False):
+                      with_hit=False, router=None, bias=None, first=None):
     """Dropless dMoE forward (MegaBlocks semantics; VERDICT r3 item 5 —
     the reference's capacity gate at moe_layer.py:263 silently drops
     overflow tokens; this path honors every token's top-k exactly).
     `few_rows` takes the few-rows kernel (kernels/moe_experts.py: a
     decode step reads each expert hit once) in place of the
     sort-and-group path. Returns (out, aux_loss), and with `with_hit` the
-    count of distinct experts the rows hit as a third."""
+    count of distinct experts the rows hit as a third.
+
+    `router` / `bias`: the router's options beyond softmax top-k
+    (`FM.topk_gating_dropless`'s keywords and the bias it chooses by).
+    `first`: wg, wu, wd are a SHARE of the experts the router scores,
+    those from `first` on: the rows are routed over all of them, the
+    result is the part this share gives, and `with_hit` counts (the
+    distinct experts hit among those held, the (row, expert) pairs that
+    fell on them) as an int32 pair."""
     lead = x.shape[:-1]
     d = x.shape[-1]
     xt = x.reshape(-1, d)
     with jax.named_scope("router"):
         logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                             router_w.astype(jnp.float32))
-        idx, gates, aux = FM.topk_gating_dropless(logits, k)
-        hit = experts_hit(idx, wg.shape[0]) if with_hit else None
+        idx, gates, aux = FM.topk_gating_dropless(logits, k, bias=bias,
+                                                  **(router or {}))
+        share = first is not None
+        # the choices as ids among the experts held here (a share: those
+        # held elsewhere become one past the last)
+        local = held_ids(idx, first, wg.shape[0]) if share else idx
+        hit = experts_hit(local, wg.shape[0], share=share) \
+            if with_hit else None
+        if with_hit and share:
+            hit = jnp.stack([hit, jnp.sum(local < wg.shape[0])]).astype(
+                jnp.int32)
     if few_rows:
         with jax.named_scope("experts"):
-            out = moe_experts_decode(xt, wg, wu, wd, idx, gates)
+            out = moe_experts_decode(xt, wg, wu, wd, local, gates,
+                                     share=share)
     else:
-        out = FM.moe_dropless_mlp(xt, wg, wu, wd, idx, gates)
+        out = FM.moe_dropless_mlp(xt, wg, wu, wd, idx, gates,
+                                  **({"first": first} if share else {}))
     out = out.reshape(*lead, d)
     return (out, aux, hit) if with_hit else (out, aux)
 
@@ -164,16 +184,48 @@ class MoEMLP(Layer):
 
     def __init__(self, hidden_size, intermediate_size, num_experts,
                  top_k=2, capacity_factor=1.25, initializer_range=0.02,
-                 dropless=False):
+                 dropless=False, score_func="softmax", route_norm=True,
+                 route_scale=1.0, expert_bias=False, held=None):
+        """The router's options as a config states them, for the dropless
+        path (defaults: the softmax router, renormalised, as ever):
+        `score_func` "sigmoid", `route_norm`, `route_scale`
+        (`FM.topk_gating_dropless`); `expert_bias` adds `expert_bias` (E,),
+        which chooses the experts with the scores and never weighs them
+        (kept out of the gradient: a load-balancing term is set, not
+        trained).
+
+        `held` = (first, count): this layer holds experts [first, first +
+        count) of the `num_experts` its router scores, the share of one
+        device of an expert-parallel group, run without the exchange: it
+        routes over all, computes the part of the result that its own
+        experts give and leaves the rest out."""
         super().__init__()
         self.num_experts = num_experts
         self.top_k = top_k
         self.capacity_factor = capacity_factor
         self.dropless = dropless
+        self.router = None if (score_func, route_norm, route_scale) == (
+            "softmax", True, 1.0) else dict(
+                score_func=score_func, route_norm=bool(route_norm),
+                route_scale=float(route_scale))
+        self.held = None if held is None else (int(held[0]), int(held[1]))
+        if (self.router or expert_bias or held) and not dropless:
+            raise NotImplementedError(
+                "router options and a share of the experts are the "
+                "dropless path's (dropless=True)")
         init = I.Normal(0.0, initializer_range)
         d, f, e = hidden_size, intermediate_size, num_experts
         self.router_weight = self.create_parameter(
             [d, e], default_initializer=init)
+        self.expert_bias = None
+        if expert_bias:
+            self.expert_bias = self.create_parameter(
+                [e], default_initializer=I.Constant(0.0))
+            self.expert_bias.stop_gradient = True
+        if held is not None:
+            if not 0 <= self.held[0] <= e - self.held[1]:
+                raise ValueError(f"held={held} is no range of {e} experts")
+            e = self.held[1]
         self.experts_gate_weight = self.create_parameter(
             [e, d, f], default_initializer=init)
         self.experts_up_weight = self.create_parameter(
@@ -195,13 +247,21 @@ class MoEMLP(Layer):
     def forward(self, x, with_hit=False):
         """`with_hit` (dropless, forward only: an integer output has no
         place on a training tape) returns (out, the count of distinct
-        experts the rows hit): what a decode step reads of the experts."""
+        experts the rows hit): what a decode step reads of the experts;
+        from a layer that holds a share (`held`), the pair (distinct
+        experts hit among those held, pairs that fell on them)."""
         hit = None
         ep = current_expert_parallel() if self.dropless else None
         if with_hit and (ep is not None or not self.dropless):
             raise NotImplementedError(
                 "with_hit counts the experts of the dropless path on one "
                 "device")
+        if ep is not None and (self.router or self.held
+                               or self.expert_bias is not None):
+            raise NotImplementedError(
+                "expert_parallel_guard exchanges tokens of the softmax "
+                "router over every expert; a layer that holds a share "
+                "runs without the exchange")
         if self.dropless:
             if ep is not None:
                 out, aux = _moe_mlp_dropless_ep(
@@ -215,7 +275,9 @@ class MoEMLP(Layer):
                 x, self.router_weight, self.experts_gate_weight,
                 self.experts_up_weight, self.experts_down_weight,
                 k=self.top_k, few_rows=self._few_rows(x),
-                with_hit=with_hit)
+                with_hit=with_hit, router=self.router,
+                bias=self.expert_bias,
+                first=None if self.held is None else self.held[0])
         else:
             out, aux = _moe_mlp(x, self.router_weight,
                                 self.experts_gate_weight,

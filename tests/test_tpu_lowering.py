@@ -218,9 +218,10 @@ def test_every_pallas_call_site_passes_a_literal_name():
                     f"{path}:{node.lineno}: pallas_call without a " \
                     f"literal name="
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 15
+    assert len(names) == len(set(names)) == 16
     assert set().union(*_NAMES.values()) \
-        | {"flash_bwd_dq", "moe_experts_decode"} == set(names)
+        | {"flash_bwd_dq", "moe_experts_decode",
+           "paged_attention_prefill"} == set(names)
 
 
 def _trainer(mesh=None, **cfg_kw):
@@ -458,6 +459,158 @@ def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     assert _kernel_names(lowered) == {"paged_kv_write"}
     text = lowered.as_text(debug_info=True)
     for scope in ("dispatch", "experts", "combine", "select"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    assert "ragged_dot" in lowered.as_text()
+
+
+# -- the decoder of window and full layers with a share of its experts, at
+# -- the widths of its serve cell (16 slots, 48 / 8 heads of 128, pages of
+# -- 16: 1,152 a slot in the full layer, a 257-page view of a 273-page ring
+# -- in a window layer; 32 held experts of 3072 x 3072)
+
+@pytest.mark.parametrize("rows", [16, 256], ids=["decode", "prefill-chunk"])
+def test_few_rows_expert_kernel_at_a_share_compiles_at_the_cells_widths(
+        as_tpu, described_v5e, rows):
+    from paddle_tpu.kernels.moe_experts import (_f_tile, moe_decode_problems,
+                                                moe_experts_decode)
+    bf16, e, d, f = jnp.bfloat16, 32, 3072, 3072
+    assert not moe_decode_problems(rows, d, f, bf16)
+    assert _f_tile(d, f, 2) == 256              # twelve tiles of f
+    shapes = _shapes(described_v5e, ((rows, d), bf16), ((e, d, f), bf16),
+                     ((e, d, f), bf16), ((e, f, d), bf16),
+                     ((rows, 4), jnp.int32), ((rows, 4), jnp.float32))
+    fn = jax.jit(lambda *a: moe_experts_decode(*a, share=True))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"moe_experts_decode"}
+    if described_v5e is not None:
+        lowered.compile()       # Mosaic: VMEM under the scoped default
+
+
+def test_paged_decode_over_a_rings_view_compiles_at_the_cells_geometry(
+        as_tpu, described_v5e):
+    """A group of 6 query heads a kv head, over the 257 pages that hold a
+    window of 4,096, the view's edges as the kernel's per-key mask."""
+    from paddle_tpu.inference.paged import ring_pages_for
+    from paddle_tpu.kernels.paged_attention import (decode_plan,
+                                                    decode_shape_problems,
+                                                    paged_decode_attention,
+                                                    select_shape_problems)
+    bf16, b, page = jnp.bfloat16, 16, 16
+    view, ring = ring_pages_for(4096, 1, page), ring_pages_for(4096, 256, page)
+    assert (view, ring) == (257, 273)
+    assert not decode_shape_problems(48, 8, 128, page, kv_dtype=bf16)
+    assert not select_shape_problems(8, 128, page, bf16)
+    plan = decode_plan(48, 8, 128, page, view, bf16, slots=b)
+    assert (plan.fold, plan.pack, plan.heads, plan.pages) == (1, 1, 8, 8)
+    assert plan.grid == (b, 1, 33)
+    assert decode_plan(48, 8, 128, page, 1152, bf16, slots=b).grid \
+        == (b, 1, 144)
+    pool = ((b * ring + 1, 8, page, 128), bf16)
+    shapes = _shapes(described_v5e, ((b, 48, 128), bf16), pool, pool,
+                     ((b, view), jnp.int32), ((b,), jnp.int32),
+                     ((b, view * page), jnp.bool_))
+    fn = jax.jit(lambda q, k, v, bt, lens, sel: paged_decode_attention(
+        q, k, v, bt, lens, select=sel))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"paged_attention_decode"}
+    if described_v5e is not None:
+        lowered.compile()
+
+
+@pytest.mark.parametrize("tokens,keys,window", [
+    (4096, 513 * 16, 4096), (4096, 1152 * 16, 0), (2048, 385 * 16, 4096)],
+    ids=["ring-view", "full-table", "bucket-2048"])
+def test_chunk_attention_compiles_at_the_cells_geometry(
+        as_tpu, described_v5e, tokens, keys, window):
+    """A prefill call's attention: 48 / 8 heads of 128, a chunk of as many
+    tokens as the window over a ring's 513-page view, and over the full
+    layer's whole table."""
+    from paddle_tpu.kernels.prefill_attention import (
+        chunk_attention, chunk_attention_problems)
+    bf16 = jnp.bfloat16
+    assert not chunk_attention_problems(tokens, 48, 8, 128)
+    assert chunk_attention_problems(12, 48, 8, 128) \
+        and chunk_attention_problems(16, 48, 8, 64)
+    shapes = _shapes(described_v5e, ((1, tokens, 48, 128), bf16),
+                     ((1, 8, keys, 128), bf16), ((1, 8, keys, 128), bf16),
+                     ((1,), jnp.int32), ((1,), jnp.int32))
+    fn = jax.jit(lambda q, k, v, qp, kp: chunk_attention(
+        q, k, v, qp, kp, window=window))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"paged_attention_prefill"}
+    if described_v5e is not None:
+        lowered.compile()
+
+
+def test_window_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.models.window_attn_moe import (WindowAttnMoeConfig,
+                                                   WindowAttnMoeForCausalLM)
+    from paddle_tpu.nn.layer import moe as moe_layer
+    monkeypatch.setattr(moe_layer, "on_tpu", lambda: True)
+    b, mp = 16, 1152
+    paddle_tpu.seed(0)
+    model = WindowAttnMoeForCausalLM(WindowAttnMoeConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=3, num_dense_layers=1, num_attention_heads=12,
+        num_key_value_heads=2, head_dim=128,
+        layer_types=["sliding_attention", "sliding_attention",
+                     "full_attention"],
+        sliding_window=4096, num_experts=16, held_experts=(4, 4),
+        moe_intermediate_size=128))
+    model = paddle_tpu.amp.decorate(models=model, level="O2",
+                                    dtype="bfloat16")
+    model.eval()
+    eng = PagedKVEngine(model, max_slots=b, page_size=16, num_pages=65,
+                        max_pages_per_slot=mp, kernel=None)
+    assert eng.decode_kernel == "pallas" and eng.kv_write == "pallas"
+    # a prefill attends through the chunk kernel, which holds no scores:
+    # a call takes as many tokens as the window, so a ring is the 257
+    # pages of a window and 256 more
+    assert eng._chunk_kernel and eng._prefill_limit(1) == 4096
+    assert eng._prefill_limit(b) == 256
+    assert eng._ring.pages_per_slot == 513
+    assert eng._ring.num_pages == b * 513 + 1 and eng._full.num_pages == 65
+    assert [kv[0].shape[0] for kv in eng.pools] == [8209, 8209, 65]
+    pools = [a for kv in eng.pools for a in kv]
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+    tick = eng._tick_fn(False)
+    rows = (z(b), z(b), z(b, dt=bool), z(b))    # tok, lens, active, limit
+    lowered = _lower_tpu(tick.func, *tick.args, rows, (z(b, mp), z(b, 513)),
+                         z(b), key, np.int32(0), pools)
+    assert _kernel_names(lowered) == {"paged_attention_decode",
+                                      "paged_kv_write",
+                                      "moe_experts_decode"}
+    # each kernel lowered once a geometry: the decode kernel over the full
+    # table and over a ring's view, the write into either pool, the experts
+    assert _calls(lowered) == 5
+    text = lowered.as_text()
+    # the tick-finding patterns: the full layer's call has the [slots,
+    # max_pages_per_slot] block table as its first operand, a window
+    # layer's the 257 pages of its view
+    firsts = set(re.findall(r"tpu_custom_call[^\n]*?\(tensor<(\d+x\d+)xi32>",
+                            text))
+    assert {f"{b}x{mp}", f"{b}x257"} <= firsts, firsts
+    text = lowered.as_text(debug_info=True)
+    for scope in ("kv_write", "paged_attn", "window", "gate", "mlp", "moe",
+                  "router", "shared_expert", "experts", "sample"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    chunk = eng._prefill_chunk_fn(4096, 1)
+    lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 4096), z(1), z(1),
+                         (z(1, mp), z(1, 513)), pools)
+    # a 4,096-row chunk takes the grouped path at the share, its attention
+    # the chunk kernel (over a ring's view, and over the full table from
+    # its start); the other calls write the chunk's K and V
+    assert _kernel_names(lowered) == {"paged_kv_write",
+                                      "paged_attention_prefill"}
+    text = lowered.as_text(debug_info=True)
+    for scope in ("dispatch", "experts", "combine", "window",
+                  "shared_expert"):
         assert re.search(rf'[/("]{scope}[/)"]', text), scope
     assert "ragged_dot" in lowered.as_text()
 

@@ -1,15 +1,21 @@
 #!/usr/bin/env python3
-"""Does the sparse-attention MoE serve cell's `correct` see what it claims
-to? Run by hand on the chip (the train cells have benchmarks/prove_check.py).
+"""Does a serve cell's `correct` see what it claims to? For the families
+that bring their own builder. Run by hand on the chip (the train cells have
+benchmarks/prove_check.py).
 
     python3 tools/prove_serve_check.py --workload <serve cell> --seed <n> ...
 
 For each seed: the cell's own check (`benchmarks/serve.py
 check_against_reference`: one seeded request through the engine, then the
 reference's full forward) as the run makes it, and again with a fault
-planted in the program: the key selection off (every causal key attended),
-the index pool not written on decode, the last layer's experts skipped
-(their down projections zero in the engine's view of the weights).
+planted in the program. Builder `sparse_attn_moe`: the key selection off
+(every causal key attended), the index pool not written on decode, the last
+layer's experts skipped (their down projections zero in the engine's view
+of the weights). Builder `window_attn_moe`: the window mask off (a window
+layer attends over every causal key, as a full layer does), the routing
+bias in the gates (it weighs as well as chooses), the last layer's shared
+expert dropped (its down projection zero in the engine's view of the
+weights).
 Last, the honest engine's tokens against the reference computed in float8
 (e4m3, scaled per tensor: every matrix, and every value the reference
 stores, through its `store`): the nearest precision under the bf16 the
@@ -44,6 +50,14 @@ def main():
                     help="seeds that take every control")
     ap.add_argument("--honest", type=int, nargs="*", default=[],
                     help="further seeds that take the honest reading alone")
+    ap.add_argument("--readings", default=None,
+                    help="comma-separated readings a --seed takes beside the "
+                         "honest one (default: all): float8_reference, a "
+                         "fault's name")
+    ap.add_argument("--draw", default=None,
+                    help="a JSON object read in the place of the "
+                         "configuration's `draw` (to try one before it is "
+                         "written into the file)")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
@@ -56,11 +70,19 @@ def main():
     import jax.numpy as jnp
     from paddle_tpu.inference import PagedKVEngine, paged
     from paddle_tpu.jit.functional import state_tensors
+    from paddle_tpu.nn.functional import moe as FM
     if jax.devices()[0].platform != "tpu" and not args.rehearse:
         raise SystemExit("no accelerator")
     cfg, traffic, builder = cell["config"], cell["traffic"], cell["builder"]
     geo = traffic["engine"]
     tol = traffic["check"]["tolerance_sd"]
+    if args.draw is not None:
+        cfg["draw"] = json.loads(args.draw)
+        print("[draw]", json.dumps(cfg["draw"]), flush=True)
+    wanted = args.readings.split(",") if args.readings else None
+
+    def asked(name):
+        return wanted is None or name in wanted
 
     @contextlib.contextmanager
     def patched(obj, name, new):
@@ -89,6 +111,72 @@ def main():
             calls["index_pool_stale_on_decode"] += 1
             return out, (*new[:2], cache[2])
         return patched(paged, "_attend_indexed", stale)
+
+    def window_mask_off():
+        """Every window layer attends over every causal key, as a full
+        layer does: a decode step takes the whole ring as its view, in
+        order from position 0, and a prefill call's kernel is given no
+        window. That is the fault only while the ring still holds every
+        key, i.e. while the checked context is no longer than a ring
+        (`rings_hold_the_context`); the ring's own view with its lower
+        edge off would add the at most page_size - 1 keys of the view's
+        first page to a window's worth, which is no fault to see."""
+        real_chunk = paged.chunk_attention
+
+        def every_causal_key(state, s, window, page_size):
+            calls["window_mask_off"] += 1
+            rt, lens = paged._val(state.ring_tables), paged._val(state.lens)
+            col = jnp.arange(rt.shape[1] * page_size)[None, None]
+            t = (lens[:, None] + jnp.arange(s)[None])[..., None]
+            return rt, lens, col <= t
+
+        def no_window(*a, window=None, **kw):
+            return real_chunk(*a, **kw)
+        stack = contextlib.ExitStack()
+        stack.enter_context(patched(paged, "_ring_view", every_causal_key))
+        stack.enter_context(patched(paged, "chunk_attention", no_window))
+        return stack
+
+    def rings_hold_the_context(eng):
+        held = min(g["pages_per_slot"] for g in eng.page_groups()
+                   if g["window"]) * geo["page_size"]
+        checked = traffic["check"]["prompt_tokens"] \
+            + traffic["check"]["new_tokens"]
+        if checked > held:
+            raise RuntimeError(
+                f"window_mask_off: the check's {checked} tokens have gone "
+                f"round a ring of {held}: the keys outside the window are "
+                f"overwritten and the fault cannot be planted this way")
+
+    def bias_in_the_gates():
+        real = FM.topk_gating_dropless
+
+        def weighed_by_the_bias(logits, k, bias=None, **kw):
+            calls["bias_in_the_gates"] += 1
+            idx, _gates, aux = real(logits, k, bias=bias, **kw)
+            picked = jnp.take_along_axis(
+                jax.nn.sigmoid(logits.astype(jnp.float32))
+                + bias.astype(jnp.float32), idx, -1)
+            gates = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+            return idx, gates * kw["route_scale"], aux
+        return patched(FM, "topk_gating_dropless", weighed_by_the_bias)
+
+    # by family: the faults planted by a patch of the program (name ->
+    # the context that plants it) and the matrix of the last layer whose
+    # zeros in the engine's view drop a part of the block
+    family = cell["config"]["builder"]
+    patches, zeroed = {
+        "sparse_attn_moe": (
+            {"selection_off": selection_off,
+             "index_pool_stale_on_decode": index_pool_stale},
+            ("last_experts_skipped",
+             lambda m: m.model.layers[-1].mlp.experts_down_weight)),
+        "window_attn_moe": (
+            {"window_mask_off": window_mask_off,
+             "bias_in_the_gates": bias_in_the_gates},
+            ("shared_expert_dropped", lambda m: m.model.layers[-1]
+             .mlp.shared_expert.down_proj.weight)),
+    }[family]
 
     def engine(model):
         return PagedKVEngine(model, max_slots=geo["max_slots"],
@@ -174,11 +262,13 @@ def main():
         gc.collect()
 
     def ran(name):
-        def planted(_eng):
+        def planted(eng):
             if not calls[name]:
                 raise RuntimeError(f"{name}: the planted function never "
                                    f"ran inside the engine's programs")
             calls[name] = 0
+            if name == "window_mask_off":
+                rings_hold_the_context(eng)
         return planted
 
     table = []
@@ -201,35 +291,40 @@ def main():
         # against a float8 reference; the matrices are rounded in place,
         # one at a time, after the engine's request, and the model is
         # drawn anew afterwards
-        model = build(seed)
-        read("float8_reference", check_then(
-            model, seed, lambda: round_matrices(model), judge=in_float8))
-        release(model)
+        if asked("float8_reference"):
+            model = build(seed)
+            read("float8_reference", check_then(
+                model, seed, lambda: round_matrices(model), judge=in_float8))
+            release(model)
         model = build(seed)
         read("honest", check(model, seed))
-        if row.pop("first_tokens") != row["honest_tokens"]:
+        if row.pop("first_tokens", row["honest_tokens"]) \
+                != row["honest_tokens"]:
             raise RuntimeError("the honest engine gave other tokens on the "
                                "same seed: the controls compare nothing")
-        with selection_off():
-            read("selection_off", check(model, seed, ran("selection_off")))
-        with index_pool_stale():
-            read("index_pool_stale_on_decode", check(
-                model, seed, ran("index_pool_stale_on_decode")))
-        # the last layer's experts give nothing: their down projections
-        # are zeros while the engine takes its weights, the reference
-        # reads the model's own again
-        down = model.model.layers[-1].mlp.experts_down_weight
-        name = next(n for n, t in state_tensors(model).items() if t is down)
-        kept, down._value = down._value, jnp.zeros_like(down._value)
+        for fault, plant in patches.items():
+            if asked(fault):
+                with plant():
+                    read(fault, check(model, seed, ran(fault)))
+        # a part of the last layer gives nothing: its down projection is
+        # zeros while the engine takes its weights, the reference reads
+        # the model's own again
+        fault, matrix = zeroed
+        if asked(fault):
+            down = matrix(model)
+            name = next(n for n, t in state_tensors(model).items()
+                        if t is down)
+            kept, down._value = down._value, jnp.zeros_like(down._value)
 
-        def zeros_reached(eng):
-            if float(jnp.sum(jnp.abs(eng._weights[0][name]))) != 0.0:
-                raise RuntimeError("the engine's view of the weights does "
-                                   "not hold the zeroed experts")
-        read("last_experts_skipped", check_then(
-            model, seed, lambda: setattr(down, "_value", kept),
-            zeros_reached))
-        del kept, down, row["honest_tokens"]
+            def zeros_reached(eng):
+                if float(jnp.sum(jnp.abs(eng._weights[0][name]))) != 0.0:
+                    raise RuntimeError("the engine's view of the weights "
+                                       "does not hold the zeroed matrix")
+            read(fault, check_then(
+                model, seed, lambda: setattr(down, "_value", kept),
+                zeros_reached))
+            del kept, down
+        del row["honest_tokens"]
         release(model)
         del model
         table.append(row)
