@@ -51,9 +51,12 @@ reference's op-level API contract; THIS engine is what actually serves):
 - One compiled decode program per engine (static `(max_slots,
   steps_per_tick, max_pages_per_slot)` shapes, do_sample variants
   compiled separately); prefill programs are bucketed by padded prompt
-  length. Per-request sampling params (temperature / top_k / top_p /
-  eos) are TRACED per-slot vectors, so heterogeneous sampling configs
-  share one compile.
+  length, two widths a bucket (1 and `max_slots`): requests admitted
+  together to one bucket prefill under one wait, through the padded
+  program only where that costs less than a call a row at width 1
+  (`prefill_width`). Per-request sampling params (temperature / top_k /
+  top_p / eos) are TRACED per-slot vectors, so heterogeneous sampling
+  configs share one compile.
 
 Models opt in exactly like dense KV-cache decode (models/generation.py)
 but receive a `PagedState` as `cache_index` and per-layer `(k_pool,
@@ -132,6 +135,48 @@ __all__ = ["PagedState", "paged_attention_update", "decode_kernel_scope",
 # hold (`PagedKVEngine._prefill_limit`): 16 rows x 32 heads x a 512
 # bucket x a 768-token window is 0.75 GiB
 _PREFILL_SCORE_BYTES = 2 ** 30
+
+# What a prefill program costs by its shape, in tokens (`prefill_width`),
+# fitted once on a v5e over the dense serve cell's model (SmolLM2-1.7B in
+# bf16, 3.4 GB of weights; 48 pages of 16 a slot) through the engine's own
+# admission: `prefill_s` over 5 groups each, ms, of which 2.8 a group are
+# its dispatch and wait (my chip runs, PR 34, calls 1 and 3).
+# A call at width 1, by the slope from 8 to 16 calls under one wait, at
+# buckets 8 .. 512: 5.00 4.97 5.12 5.59 6.64 9.36 16.37: one read of the
+# weights (4.2 at the HBM's peak) up to ~128 tokens, then 0.032 a token.
+# Two rows alone under one wait: 12.8 12.8 13.1 14.0 16.2 21.5 36.0.
+# The program padded to 16 rows, two rows live or sixteen alike, at
+# buckets 8 .. 128: 40.3 42.3 52.0 74.0 103.4 (172 / 325 at 256 / 512:
+# PR 30); padded to 4 rows: 9.8 10.6 11.4 13.6 21.8. So the padded
+# program is one read of the weights or its tokens, whichever is more,
+# and beside that ~0.13 x rows x rows (33 at 16 rows, 2 at 4: every row,
+# padding too, gathers its whole block-table window in float32 in every
+# layer, and sixteen rows' windows no longer fit the fast memory).
+_PREFILL_BALANCE_TOKENS = 128   # under these a call is one read of the weights
+_PREFILL_ROWS_SQ_TOKENS = 3.3   # a padded program's rows x rows, each
+
+
+def prefill_width(n, ppad, max_slots):
+    """The width of the program that prefills `n` rows of one bucket of
+    `ppad` tokens: `max_slots` (all rows in one call, the rest padding)
+    or 1 (a call a row, back to back under one wait). In tokens, a call
+    at width 1 costs max(B, ppad), B being the tokens under which it is
+    one read of the weights, and the padded program max(B, max_slots x
+    ppad) + Q x max_slots x max_slots, padding included (the constants
+    above and their readings). A bucket's groups all take one width, the
+    one that is cheaper for TWO rows, whatever their number: pairs are
+    what an admission pass mostly finds, and a bucket that has prefilled
+    one prompt alone and one pair has then compiled every program it
+    will ask for (a storm never meets a first compile). So short rows on
+    an engine of few slots ride the padded program (4 slots: up to 32
+    tokens); many slots (from 7 on), or rows past B, where a row shares
+    nothing with its neighbours and padding is pure cost, run at width
+    1."""
+    pair = 2 * max(_PREFILL_BALANCE_TOKENS, ppad)
+    padded = (max(_PREFILL_BALANCE_TOKENS, max_slots * ppad)
+              + _PREFILL_ROWS_SQ_TOKENS * max_slots * max_slots)
+    return max_slots if n > 1 and pair > padded else 1
+
 
 # the phases of a scheduler tick, in order: each is the span
 # `engine.tick.<phase>` and one column of `PagedKVEngine.tick_log`
@@ -1323,6 +1368,11 @@ class PagedKVEngine:
         self.stats = {"ticks": 0, "ticks_chained": 0,
                       "kv_write_kernel_ticks": 0,
                       "prefills": 0, "tokens_out": 0,
+                      # rows every prefill call computed, the rows of
+                      # padding among them, and the rows of groups of
+                      # two or more that ran at width 1 (_prefill_width)
+                      "prefill_rows_run": 0, "prefill_rows_padded": 0,
+                      "prefill_rows_split": 0,
                       "admitted": 0, "finished": 0, "cancelled": 0,
                       "expired": 0, "overloaded": 0,
                       "prefill_s": 0.0, "tick_s": 0.0,
@@ -2414,10 +2464,10 @@ class PagedKVEngine:
                 # rid pairs this row's scheduled with ITS queued event
                 # (per-row queue_wait clock in a shared context)
                 req.obs.record("scheduled", rid=req.rid, slot=idx)
-        # batch same-TAIL-bucket prefills into ONE program call (an
-        # admission storm used to pay one ~full prefill latency per
-        # request); warm requests bucket by their UNCACHED tail — that
-        # is the whole prefill they run
+        # same-TAIL-bucket prefills run as ONE group under one wait: one
+        # padded program call or a call a row, whichever costs less
+        # (`_prefill_width`); warm requests bucket by their UNCACHED
+        # tail — that is the whole prefill they run
         groups = {}
         long_grp = []
         alone = self._prefill_limit(1)
@@ -2436,11 +2486,7 @@ class PagedKVEngine:
             for pair in long_grp:
                 self._prefill_chunked_group([pair], chunk=alone)
         for ppad, grp in groups.items():
-            if len(grp) > 1 and ppad > self._prefill_limit(self.max_slots):
-                for pair in grp:    # the group's scores would not fit
-                    self._prefill_group(ppad, [pair])
-            else:
-                self._prefill_group(ppad, grp)
+            self._prefill_group(ppad, grp)
         if requeue:
             with self._lock:
                 self._pending = requeue + self._pending
@@ -2499,58 +2545,85 @@ class PagedKVEngine:
             return int(np.argmax(x - np.log(-np.log(u))))
         return int(np.argmax(logits))
 
+    def _prefill_width(self, n, ppad):
+        """`prefill_width` of `n` rows of `ppad` tokens, and 1 where the
+        scores of `max_slots` rows would not fit one program."""
+        if ppad > self._prefill_limit(self.max_slots):
+            return 1
+        return prefill_width(n, ppad, self.max_slots)
+
+    def _note_prefills(self, rows, bw, calls, padded, t0):
+        """Count a group of `rows` prompts that went through `calls`
+        calls of width `bw`, `padded` of their rows padding, since
+        `t0`."""
+        s = self.stats
+        s["prefills"] += rows
+        s["prefill_rows_run"] += bw * calls
+        s["prefill_rows_padded"] += padded
+        if rows > 1 and bw == 1:
+            s["prefill_rows_split"] += rows
+        s["prefill_s"] += time.perf_counter() - t0
+
     def _prefill_chunked_group(self, grp, chunk=None):
         """Feed long prompts through the fixed-size chunk program in
         LOCKSTEP rounds — the paged core appends at lens>0 (the
         reference's chunked-prefill contract, seq_lens_decoder > 0),
         and a storm of long prompts pays ceil(max_len/chunk) program
         calls total instead of one full chunk loop per request.
-        Exhausted rows ride later rounds with n_valid=0 (writes drop)."""
+        Exhausted rows ride later rounds with n_valid=0 (writes drop).
+        Where the padded rounds would cost more than the rows alone
+        (`_prefill_width`), each row runs its own rounds at width 1,
+        one row's after another's, all under one wait."""
         chunk = chunk or self.prefill_chunk
-        bw = 1 if len(grp) == 1 else self.max_slots
-        done = np.zeros(bw, np.int32)                  # consumed per row
-        for r, (idx, _req) in enumerate(grp):
-            # warm rows (prefix-cache hit) start past the shared pages
-            done[r] = self._slots[idx].shared * self.page_size
+        bw = self._prefill_width(len(grp), chunk)
+        parts = ([range(len(grp))] if bw > 1
+                 else [[r] for r in range(len(grp))])
+        # consumed per row: warm rows (prefix-cache hit) start past the
+        # shared pages
+        done = [self._slots[idx].shared * self.page_size for idx, _ in grp]
         plens = [int(req.prompt.size) for _, req in grp]
-        rounds = max(-(-(n - int(done[r])) // chunk)
-                     for r, n in enumerate(plens))
+        rounds = [-(-(n - d) // chunk) for n, d in zip(plens, done)]
+        calls = sum(max(rounds[r] for r in part) for part in parts)
         with observability.span("engine.prefill", bucket=chunk,
-                                rows=len(grp), group=bw, chunks=rounds):
+                                rows=len(grp), group=bw,
+                                chunks=max(rounds), calls=calls):
             t0 = time.perf_counter()
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_start", rid=req.rid)
             fn = self._prefill_chunk_fn(chunk, bw)
             final_logits = [None] * len(grp)
-            while any(done[r] < plens[r] for r in range(len(grp))):
-                ids = np.zeros((bw, chunk), np.int32)
-                lens = np.zeros(bw, np.int32)
-                nv = np.zeros(bw, np.int32)
-                rows = [None] * bw
-                for r, (idx, req) in enumerate(grp):
-                    take = min(chunk, plens[r] - int(done[r]))
-                    if take <= 0:
-                        continue
-                    ids[r, :take] = req.prompt[done[r]:done[r] + take]
-                    lens[r] = done[r]
-                    nv[r] = take
-                    rows[r] = idx
-                last, flat = fn(jnp.asarray(ids), jnp.asarray(lens),
-                                jnp.asarray(nv),
-                                jax.tree.map(jnp.asarray, self._tables(rows)),
-                                [a for kv in self.pools for a in kv])
-                self.pools = self._unflat_pools(flat)
-                for r in range(len(grp)):
-                    if nv[r] > 0 and done[r] + nv[r] >= plens[r]:
-                        final_logits[r] = last      # read after the loop
-                    done[r] += nv[r]
-            # one wait for the whole prompt: the rounds queue on the device
+            padded = 0
+            for part in parts:
+                while any(done[r] < plens[r] for r in part):
+                    ids = np.zeros((bw, chunk), np.int32)
+                    lens = np.zeros(bw, np.int32)
+                    nv = np.zeros(bw, np.int32)
+                    rows = [None] * bw
+                    for j, r in enumerate(part):
+                        idx, req = grp[r]
+                        take = min(chunk, plens[r] - done[r])
+                        if take <= 0:
+                            continue
+                        ids[j, :take] = req.prompt[done[r]:done[r] + take]
+                        lens[j] = done[r]
+                        nv[j] = take
+                        rows[j] = idx
+                    last, flat = fn(
+                        jnp.asarray(ids), jnp.asarray(lens),
+                        jnp.asarray(nv),
+                        jax.tree.map(jnp.asarray, self._tables(rows)),
+                        [a for kv in self.pools for a in kv])
+                    self.pools = self._unflat_pools(flat)
+                    padded += bw - int((nv > 0).sum())
+                    for j, r in enumerate(part):
+                        done[r] += int(nv[j])
+                        if nv[j] > 0 and done[r] >= plens[r]:
+                            final_logits[r] = (last, j)  # read below
+            # one wait for the whole group: the calls queue on the device
             # back to back, none waits for the host to read the one before
-            final_logits = [np.asarray(rows)[r]
-                            for r, rows in enumerate(final_logits)]
-            self.stats["prefills"] += len(grp)
-            self.stats["prefill_s"] += time.perf_counter() - t0
+            final_logits = [np.asarray(last)[j] for last, j in final_logits]
+            self._note_prefills(len(grp), bw, calls, padded, t0)
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_end", rid=req.rid)
@@ -2584,50 +2657,60 @@ class PagedKVEngine:
 
     def _prefill_group(self, ppad, grp):
         """Prefill all (slot, request) pairs of one padded-length bucket
-        in ONE program call. Two static batch widths per bucket — 1 for
-        the steady trickle, max_slots (padded with n_valid=0 rows whose
-        writes drop) for admission storms — so the compile count stays
-        at two per bucket while a storm pays one prefill latency
-        total."""
-        bw = 1 if len(grp) == 1 else self.max_slots
+        under ONE wait. Two static batch widths per bucket, chosen by
+        `_prefill_width`: max_slots (one call, padded with n_valid=0
+        rows whose writes drop) where short rows on few slots cost less
+        together, else 1, a call a row dispatched back to back, each
+        call's donated pools feeding the next — the compile count stays
+        at two per bucket, and the groups of a bucket use one of them."""
+        bw = self._prefill_width(len(grp), ppad)
+        calls = [grp] if bw > 1 else [[pair] for pair in grp]
         with observability.span("engine.prefill", bucket=ppad,
-                                rows=len(grp), group=bw, chunks=1):
+                                rows=len(grp), group=bw, chunks=1,
+                                calls=len(calls)):
             t0 = time.perf_counter()
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_start", rid=req.rid)
             fn = self._prefill_fn(ppad, bw)
-            ids = np.zeros((bw, ppad), np.int32)
-            lens = np.zeros(bw, np.int32)
-            nv = np.zeros(bw, np.int32)
-            for row, (idx, req) in enumerate(grp):
-                # warm slots (prefix-cache hit) prefill ONLY the uncached
-                # tail: lens starts past the shared pages, and the tail
-                # attends over their KV through the block table
-                off = self._slots[idx].shared * self.page_size
-                tail = req.prompt[off:]
-                ids[row, :tail.size] = tail
-                lens[row] = off
-                nv[row] = tail.size
-            bt = jax.tree.map(jnp.asarray, self._tables(
-                [idx for idx, _req in grp] + [None] * (bw - len(grp))))
-            last_logits, flat = fn(
-                jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(nv),
-                bt, [a for kv in self.pools for a in kv])
-            self.pools = self._unflat_pools(flat)
-            if self.draft_model is not None:
-                # the draft's pools share the same block tables, so shared
-                # pages already hold the PREFIX's draft KV too (same
-                # tokens, written when the entry was cached) — the draft
-                # prefill also runs only the tail
-                dfn = self._draft_prefill_fn(ppad, bw)
-                dflat = dfn(jnp.asarray(ids), jnp.asarray(lens),
-                            jnp.asarray(nv), bt,
-                            [a for kv in self.draft_pools for a in kv])
-                self.draft_pools = self._unflat_pools(dflat)
-            logits_np = np.asarray(last_logits)              # (bw, vocab)
-            self.stats["prefills"] += len(grp)
-            self.stats["prefill_s"] += time.perf_counter() - t0
+            outs = []
+            for part in calls:
+                ids = np.zeros((bw, ppad), np.int32)
+                lens = np.zeros(bw, np.int32)
+                nv = np.zeros(bw, np.int32)
+                for row, (idx, req) in enumerate(part):
+                    # warm slots (prefix-cache hit) prefill ONLY the
+                    # uncached tail: lens starts past the shared pages,
+                    # and the tail attends over their KV through the
+                    # block table
+                    off = self._slots[idx].shared * self.page_size
+                    tail = req.prompt[off:]
+                    ids[row, :tail.size] = tail
+                    lens[row] = off
+                    nv[row] = tail.size
+                bt = jax.tree.map(jnp.asarray, self._tables(
+                    [idx for idx, _req in part]
+                    + [None] * (bw - len(part))))
+                last_logits, flat = fn(
+                    jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(nv),
+                    bt, [a for kv in self.pools for a in kv])
+                self.pools = self._unflat_pools(flat)
+                if self.draft_model is not None:
+                    # the draft's pools share the same block tables, so
+                    # shared pages already hold the PREFIX's draft KV too
+                    # (same tokens, written when the entry was cached) —
+                    # the draft prefill also runs only the tail
+                    dfn = self._draft_prefill_fn(ppad, bw)
+                    dflat = dfn(jnp.asarray(ids), jnp.asarray(lens),
+                                jnp.asarray(nv), bt,
+                                [a for kv in self.draft_pools for a in kv])
+                    self.draft_pools = self._unflat_pools(dflat)
+                outs.append(last_logits)
+            # one wait for the whole group, after the last dispatch
+            logits_np = [row for out, part in zip(outs, calls)
+                         for row in np.asarray(out)[:len(part)]]
+            self._note_prefills(len(grp), bw, len(calls),
+                                bw * len(calls) - len(grp), t0)
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_end", rid=req.rid)

@@ -115,8 +115,13 @@ SPANS = {
         "scheduler", "_accept_tick of the landed tick: tokens to the "
         "requests' queues, retirements", "serve.idle_in_accept_share"),
     "engine.prefill": (
-        "scheduler", "one prefill program call and its read back, "
-        "attrs bucket, rows, group", "counter prefill_s"),
+        "scheduler", "the prefill of the prompts admitted together "
+        "to one bucket: its program calls (attr calls: one call group "
+        "rows wide, or at group 1 a call a row or a chunk, dispatched "
+        "back to back) and the one read back after the last, attrs "
+        "bucket, rows, group, chunks, calls",
+        "sched.prefill_time_share (counter prefill_s); counters "
+        "prefill_rows_run, prefill_rows_padded, prefill_rows_split"),
     "engine.idle": (
         "scheduler", "the ticker's sleep after a step() with no work",
         "serve.idle_between_ticks_share"),

@@ -77,3 +77,61 @@ def test_tick_chained_share_reads_the_counter_or_nothing():
     for cell in entry["workloads"]:
         assert entry["name"] in {m["name"] for m in run.load_cell(
             cell, rehearse=False)["metrics"]}
+
+
+# ISSUE 34's two per-layer metrics are NOT in BENCHMARK.json: an entry
+# appended to `per_layer` fails `benchmarks/tests/test_window_attn_moe.py::
+# test_the_window_metrics_list_this_cell_alone`, which holds that list's
+# last six entries to PR 33's, and that file is the benchmark's to edit
+# (PERF.md section 7: the next `benchmark` PR's first item). What stays
+# here: the engine's counters under the names those metrics will read,
+# and the reader they will use, over a hand-built window of 40 prompts:
+# 10 alone, 12 in six pairs split into calls at width 1, 18 short tails
+# in three groups padded to 16 rows.
+_PREFILL_WINDOW = {"prefills": 40, "prefill_rows_run": 10 + 12 + 3 * 16,
+                   "prefill_rows_padded": 3 * 16 - 18,
+                   "prefill_rows_split": 12}
+_PREFILL_ROW_METRICS = {
+    "sched.prefill_pad_row_share": (
+        {"num": ["prefill_rows_padded"], "den": ["prefill_rows_run"]},
+        100.0 * 30 / 70),
+    "sched.prefill_split_share": (
+        {"num": ["prefill_rows_split"], "den": ["prefills"]}, 30.0)}
+
+
+def _check_prefill_row_metric(name):
+    import json
+
+    import pytest
+
+    from benchmarks import reduce, run
+    from paddle_tpu.inference import paged
+    args, value = _PREFILL_ROW_METRICS[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        assert name not in {m["name"] for m in json.load(f)["per_layer"]}
+    src = open(paged.__file__).read()
+    for counter in args["num"] + args["den"]:
+        assert f'"{counter}": 0' in src     # a key of engine.stats
+    reader = run.READERS["counter_ratio"]
+    assert reader is reduce.counter_ratio
+    has = {"window": dict(_PREFILL_WINDOW), "sizes": {}}
+    assert reader(has, **args) == pytest.approx(value)
+    none = {"window": dict(_PREFILL_WINDOW, **{args["num"][0]: 0}),
+            "sizes": {}}
+    assert reader(none, **args) == 0.0
+    # a parent's engine counts neither: nothing is read, and `run.py`
+    # leaves the metric out of the line
+    parents = {"window": {"prefills": 40, "ticks": 640}, "sizes": {}}
+    assert reader(parents, **args) is None
+
+
+def test_prefill_pad_row_share_reads_the_counters_or_nothing():
+    """Of the rows the window's prefill calls computed, the share that
+    was padding."""
+    _check_prefill_row_metric("sched.prefill_pad_row_share")
+
+
+def test_prefill_split_share_reads_the_counters_or_nothing():
+    """Of the window's prompts, the share that met others of their
+    bucket and ran at width 1."""
+    _check_prefill_row_metric("sched.prefill_split_share")

@@ -385,6 +385,106 @@ def test_admission_storm_batched_prefill_parity():
     assert ("prefill", 8, 1) not in eng._programs
 
 
+# the width a group of one bucket prefills at (ISSUE 34): the dense serve
+# cell's groups (16 slots, buckets 128-512) all run at width 1, as every
+# group of 16 slots does; the storm tests' shapes (short rows, 4 slots)
+# stay on the padded program, which the chip read cheaper than a pair
+# alone up to 32 tokens at 4 slots; a bucket's groups take one width
+# whatever their number of rows
+_WIDTHS = (
+    [(n, ppad, 16, 1) for n in (2, 3, 8, 16) for ppad in (128, 256, 512)]
+    + [(1, 8, 4, 1), (1, 512, 16, 1), (2, 4096, 16, 1),
+       (4, 8, 4, 4),            # test_admission_storm_batched_prefill_parity
+       (3, 8, 4, 4),            # test_chunked_prefill_storm_lockstep
+       (2, 8, 4, 4), (2, 16, 4, 4), (4, 32, 4, 4), (2, 64, 4, 1),
+       (3, 64, 4, 1), (2, 64, 2, 2), (2, 128, 2, 1), (3, 32, 3, 3),
+       (2, 16, 6, 6), (2, 8, 7, 1), (8, 8, 8, 1), (2, 8, 16, 1),
+       (16, 8, 16, 1), (16, 64, 16, 1)])
+
+
+@pytest.mark.parametrize("n,ppad,max_slots,want", _WIDTHS)
+def test_prefill_width_rule(n, ppad, max_slots, want):
+    """One rule from what the engine can see: the padded program only
+    where it costs less than two of its rows alone, a program costing
+    max(balance point, its tokens) and the padded one a constant times
+    its rows squared beside."""
+    from paddle_tpu.inference.paged import prefill_width
+    assert prefill_width(n, ppad, max_slots) == want
+
+
+def _prefill_spans():
+    from paddle_tpu.observability import trace
+    return [s.attrs for s in trace.spans() if s.name == "engine.prefill"]
+
+
+@pytest.mark.parametrize("n", [2, 3])
+def test_same_bucket_long_rows_prefill_split_parity(n):
+    """Rows of one bucket long enough that the program padded to
+    max_slots rows would cost more than they do run at width 1, back to
+    back under one span: the tokens of solo runs, no padded program, no
+    padding."""
+    from paddle_tpu import observability
+    model = _model()
+    rng = np.random.RandomState(5)
+    prompts = [list(rng.randint(1, 90, m)) for m in (40, 64, 33)[:n]]
+    solo = [np.asarray(generate(model, np.asarray([p], np.int32),
+                                max_new_tokens=5))[0].tolist()[len(p):]
+            for p in prompts]
+    eng = PagedKVEngine(model, max_slots=4, page_size=8, num_pages=80,
+                        max_pages_per_slot=9, steps_per_tick=3)
+    with observability.scoped():
+        reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+        eng.run_until_idle()
+        spans = _prefill_spans()
+    for r, want in zip(reqs, solo):
+        assert r.result() == want
+    assert ("prefill", 64, 1) in eng._programs
+    assert ("prefill", 64, 4) not in eng._programs
+    assert {k: eng.stats[k] for k in (
+        "prefills", "prefill_rows_run", "prefill_rows_padded",
+        "prefill_rows_split")} == {
+            "prefills": n, "prefill_rows_run": n,
+            "prefill_rows_padded": 0, "prefill_rows_split": n}
+    assert spans == [{"bucket": 64, "rows": n, "group": 1, "chunks": 1,
+                      "calls": n}]
+
+
+@pytest.mark.parametrize("n", [2, 3])
+def test_warm_tails_of_split_rows_still_pad(n):
+    """The same prompts after a prefix-cache hit: what is left to
+    prefill is a short tail a row, and short tails ride one padded
+    program as before — the tokens of solo runs either way."""
+    from paddle_tpu import observability
+    model = _model()
+    rng = np.random.RandomState(6)
+    prefix = list(rng.randint(1, 90, 40))           # five full pages of 8
+    prompts = [prefix + list(rng.randint(1, 90, m)) for m in (3, 5, 2)[:n]]
+    solo = [np.asarray(generate(model, np.asarray([p], np.int32),
+                                max_new_tokens=5))[0].tolist()[len(p):]
+            for p in prompts]
+    eng = PagedKVEngine(model, max_slots=4, page_size=8, num_pages=80,
+                        max_pages_per_slot=9, steps_per_tick=3,
+                        prefix_cache_pages=16)
+    eng.generate([prefix + [7]], max_new_tokens=1)  # cold, alone: width 1
+    s0 = dict(eng.stats)
+    with observability.scoped():
+        reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+        eng.run_until_idle()
+        spans = _prefill_spans()
+    for r, want in zip(reqs, solo):
+        assert r.result() == want
+    assert eng.stats["prefix_hits"] - s0["prefix_hits"] == n
+    assert ("prefill", 8, 4) in eng._programs
+    assert ("prefill", 8, 1) not in eng._programs
+    assert {k: eng.stats[k] - s0[k] for k in (
+        "prefills", "prefill_rows_run", "prefill_rows_padded",
+        "prefill_rows_split")} == {
+            "prefills": n, "prefill_rows_run": 4,
+            "prefill_rows_padded": 4 - n, "prefill_rows_split": 0}
+    assert spans == [{"bucket": 8, "rows": n, "group": 4, "chunks": 1,
+                      "calls": 1}]
+
+
 def test_chunked_prefill_long_prompt_parity():
     """prefill_chunk: a prompt longer than the chunk streams through
     the ONE chunk-sized program (appending at lens>0 — the reference's
@@ -428,6 +528,66 @@ def test_chunked_prefill_storm_lockstep():
     for r, want in zip(reqs, solo):
         assert r.result() == want
     assert ("prefill_chunk", 8, 4) in eng._programs
+
+
+def test_chunked_prefill_split_rows_parity():
+    """Long prompts whose lockstep rounds padded to max_slots rows would
+    compute more than the rows alone: each row's rounds at width 1, one
+    row's after another's, one span and one wait — exact token parity."""
+    from paddle_tpu import observability
+    model = _model()
+    rng = np.random.RandomState(12)
+    prompts = [list(rng.randint(1, 90, m)) for m in (100, 70)]
+    solo = [np.asarray(generate(model, np.asarray([p], np.int32),
+                                max_new_tokens=4))[0].tolist()[len(p):]
+            for p in prompts]
+    eng = PagedKVEngine(model, max_slots=8, page_size=8, num_pages=120,
+                        max_pages_per_slot=14, steps_per_tick=3,
+                        prefill_chunk=64)
+    with observability.scoped():
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.run_until_idle()
+        spans = _prefill_spans()
+    for r, want in zip(reqs, solo):
+        assert r.result() == want
+    assert ("prefill_chunk", 64, 1) in eng._programs
+    assert ("prefill_chunk", 64, 8) not in eng._programs
+    assert (eng.stats["prefill_rows_run"], eng.stats["prefill_rows_padded"],
+            eng.stats["prefill_rows_split"]) == (4, 0, 2)
+    assert spans == [{"bucket": 64, "rows": 2, "group": 1, "chunks": 2,
+                      "calls": 4}]
+
+
+def test_rows_whose_scores_would_not_fit_share_one_wait(monkeypatch):
+    """Where the scores of max_slots rows of a bucket would not fit one
+    program the rows run at width 1 whatever the cost rule says, through
+    the same back-to-back path: one span, exact parity."""
+    from paddle_tpu import observability
+    from paddle_tpu.inference import paged
+    model = _model()
+    rng = np.random.RandomState(13)
+    prompts = [list(rng.randint(1, 90, m)) for m in (11, 14)]
+    solo = [np.asarray(generate(model, np.asarray([p], np.int32),
+                                max_new_tokens=4))[0].tolist()[len(p):]
+            for p in prompts]
+    eng = PagedKVEngine(model, max_slots=2, page_size=8, num_pages=80,
+                        max_pages_per_slot=9, steps_per_tick=3)
+    # 4 heads x 4 B x a 72-token window: one row may hold 16 tokens of
+    # scores, two rows the least bucket
+    monkeypatch.setattr(paged, "_PREFILL_SCORE_BYTES", 16 * 4 * 4 * 72)
+    assert eng._prefill_limit(1) == 16 and eng._prefill_limit(2) == 8
+    assert paged.prefill_width(2, 16, 2) == 2
+    with observability.scoped():
+        reqs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+        eng.run_until_idle()
+        spans = _prefill_spans()
+    for r, want in zip(reqs, solo):
+        assert r.result() == want
+    assert ("prefill", 16, 2) not in eng._programs
+    assert eng.stats["prefill_rows_split"] == 2
+    assert eng.stats["prefill_rows_padded"] == 0
+    assert spans == [{"bucket": 16, "rows": 2, "group": 1, "chunks": 1,
+                      "calls": 2}]
 
 
 def test_speculative_paged_lossless_parity():
