@@ -241,7 +241,9 @@ def run(cell, args, clock, clog, log):
     clock.mark("check")
 
     # every other prefill program the window can ask for: the buckets of
-    # the seed's own prompts, alone (width 1) and in a group (max_slots)
+    # the seed's own prompts, alone and as a pair, which takes whichever
+    # width the engine gives a group of that bucket (one width a bucket,
+    # however many meet: `paged.prefill_width`)
     work, left = deal(traffic, cfg["vocab_size"], args.seed)
     for b in sorted({bucket(len(ids)) for ids, _ in work}):
         one = next(ids for ids, _ in work if bucket(len(ids)) == b)

@@ -2,7 +2,9 @@
 a temporary copy of `benchmarks/` and `BENCHMARK.json` a scratch family (a
 builder that wraps the dense model and offers its own `costs`, `counters`
 and `rehearse`, a configuration, one metric listed for its cell alone) is
-added without touching a file that is there, and its cell runs.
+added without touching a file that is there, its cell runs, and the tests
+of every other file of this directory pass in the copy, where the scratch
+entries stand last in their lists.
 
 Run by hand: JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
 """
@@ -10,6 +12,7 @@ import filecmp
 import importlib.util
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -121,6 +124,39 @@ def test_nothing_that_was_there_is_edited(copy):
             assert {k: v for k, v in was.items() if k not in lists} \
                 == {k: v for k, v in now.items() if k not in lists}
             assert all(now[k][:len(was[k])] == was[k] for k in lists)
+
+
+def test_appended_entries_leave_the_other_files_tests_passing(copy):
+    """The seam, proven: a test of this directory that finds an entry of
+    `BENCHMARK.json` by its position (`per_layer[-6:]`, `workloads[-1]`)
+    passes in the PR that writes it and fails the next PR that appends, which
+    may not edit it. So every other file's tests run here in the copy, where
+    a later configuration, cell and metric already stand last; all but the
+    rehearsals (`*rehearsal_prints*`: a minute each, and they read entries
+    by the cell's name)."""
+    with open(os.path.join(copy, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    assert [manifest[g][-1]["name"]
+            for g in ("configs", "workloads", "per_layer")] \
+        == ["scratch-8l", SCRATCH, "scratch.steps_counted"]
+    tests = os.path.join(copy, "benchmarks", "tests")
+    shutil.copytree(os.path.join(ROOT, "benchmarks", "tests"), tests,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-m", "pytest", tests, "-v", "-p",
+             "no:cacheprovider", "--ignore",
+             os.path.join(tests, "test_family.py"), "-k",
+             "not rehearsal_prints"], cwd=copy, capture_output=True,
+            text=True, timeout=600,
+            env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT))
+    finally:
+        shutil.rmtree(tests)
+    assert proc.returncode == 0, proc.stdout[-4000:]
+    # they read the copy's manifest, and as many ran as the directory has
+    assert f"test_a_cell_reads_the_metrics_that_list_it[{SCRATCH}] PASSED" \
+        in proc.stdout
+    assert int(re.search(r"(\d+) passed", proc.stdout).group(1)) >= 75
 
 
 def test_each_cell_reads_what_lists_it_and_rehearses_at_its_own_sizes(copy):

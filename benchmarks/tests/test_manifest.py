@@ -165,6 +165,43 @@ def test_a_cell_reads_the_metrics_that_list_it(bench, cell):
     assert got
 
 
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  load(ROOT, "BENCHMARK.json")["workloads"]])
+def test_what_a_metric_asks_of_a_cells_builder_is_there(cell):
+    """A metric file names sizes (`{slots}` in a pattern, `steps_per_module`)
+    and, for a roofline, a cost function. Each has to be there in the builder
+    of every cell the entry lists: a missing cost raises KeyError in the
+    traced run on the chip, and a size that is not substituted leaves a
+    pattern that matches nothing, so the metric is absent where the driver
+    expects it. Neither shows in a rehearsal, which holds nothing against a
+    peak and has no device trace."""
+    from benchmarks import costs, run
+    found = run.load_cell(cell, rehearse=False)
+    sizes = found["builder"].sizes(found["config"], found["traffic"])
+    own = getattr(getattr(found["builder"], "costs", None), "KERNEL_COSTS", {})
+
+    def strings(value):
+        if isinstance(value, dict):
+            for v in value.values():
+                yield from strings(v)
+        elif isinstance(value, str):
+            yield value
+    for m in found["metrics"]:
+        args = m.get("args", {})
+        for text in strings({k: v for k, v in args.items()
+                             if k in ("pattern", "exclude", "module",
+                                      "holds", "lacks")}):
+            named = [n for n in re.findall(r"\{(\w+)\}", text)
+                     if not n.isdigit()]
+            assert set(named) <= set(sizes), (m["name"], text)
+            re.compile(text)
+        if m["reader"] == "roofline":
+            assert args["cost"] in own or args["cost"] in costs.KERNEL_COSTS, \
+                (m["name"], args["cost"])
+            steps = args.get("steps_per_module", 1)
+            assert steps in sizes or isinstance(steps, int), m["name"]
+
+
 @pytest.mark.parametrize("kind", ["train", "serve"])
 def test_cpu_rehearsal_prints_the_contract(bench, kind):
     cell = next(w for w in bench["workloads"]

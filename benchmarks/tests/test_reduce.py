@@ -213,14 +213,17 @@ def _sweep_busy(events):
     return busy
 
 
-@pytest.mark.parametrize("name,config,traffic,window,recorded_with", [
-    ("train_two_steps", "smollm2-1.7b-8l", "pretrain-s2048", {}, {}),
+@pytest.mark.parametrize("name,cell,config,traffic,window,recorded_with", [
+    ("train_two_steps", "train.smollm2-1.7b.s2048", "smollm2-1.7b-8l",
+     "pretrain-s2048", {}, {}),
     # PR 23 recorded it with 64 pages a slot; the mix has 48 since
-    ("serve_prefill_tick", "smollm2-1.7b", "batch-decode",
-     {"live_context_tokens": 6400.0}, {"pages_per_slot": 64}),
+    ("serve_prefill_tick", "serve.smollm2-1.7b.batch-decode", "smollm2-1.7b",
+     "batch-decode", {"live_context_tokens": 6400.0}, {"pages_per_slot": 64}),
 ])
-def test_recorded_trace(name, config, traffic, window, recorded_with):
-    """The metric files' own patterns over real event text."""
+def test_recorded_trace(name, cell, config, traffic, window, recorded_with):
+    """The patterns of the metric files that list the trace's cell
+    (`run.load_cell`'s filter, so a family's metric over its builder's
+    `costs` is not asked of the dense cell's trace) over real event text."""
     bench = os.path.join(ROOT, "benchmarks")
     path = os.path.join(DATA, name + ".xplane.pb")
     with open(os.path.join(bench, "configs", config + ".json")) as f:
@@ -239,10 +242,8 @@ def test_recorded_trace(name, config, traffic, window, recorded_with):
            "device_kind": "TPU v5 lite",
            "sizes": dict(llama_dense.sizes(cfg, tr), **recorded_with)}
     got = {"window_s": trace.window_s, "busy_s": trace.busy_s}
-    for fn in sorted(os.listdir(os.path.join(bench, "metrics"))):
-        with open(os.path.join(bench, "metrics", fn)) as f:
-            m = json.load(f)
-        if tr["kind"] in m["kinds"] and m["source"] == "device_trace":
+    for m in run.load_cell(cell, rehearse=False)["metrics"]:
+        if m["source"] == "device_trace":
             # a cut trace keeps no scope paths: the metrics over them read
             # nothing here (test_spans.py builds their planes by hand)
             value = run.READERS[m["reader"]](ctx, **m["args"])

@@ -15,6 +15,9 @@ import types
 
 import pytest
 
+from benchmarks.tests.planes import (kernel_call, roofline_file_reads,
+                                     tick_of)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "benchmarks")
 CELL = "serve.keye-vl-2.0-30b-a3b.doc-qa"
@@ -22,6 +25,17 @@ NEW_METRICS = {"programs.moe_share", "programs.indexer_share",
                "programs.select_share", "moe.experts_hit_share",
                "sched.select_engaged_share",
                "kernels.moe_experts_decode_share"}
+# the key selection is this model's alone; the expert block's three read
+# in every cell whose layers route to experts
+OWN_MECHANISM = {"programs.indexer_share", "programs.select_share",
+                 "sched.select_engaged_share"}
+
+
+# a window's counters: 8 live slots at ~4,000 keys each, 51.6 experts hit a
+# layer a step
+COUNTED = {"live_context_tokens": 32_000.0, "ticks": 100,
+           "decode_slot_steps": 3200, "moe_experts_hit": 51.6 * 2800,
+           "moe_layer_steps": 2800}
 
 
 def load(*path):
@@ -71,10 +85,14 @@ def test_the_cells_rehearsal_prints_the_contract_and_its_metrics(trace):
 
 def test_the_new_metrics_list_this_cell_alone():
     bench = load(ROOT, "BENCHMARK.json")
-    for m in bench["per_layer"]:
-        if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [CELL] and m["moves"] == \
-                "serve_tokens_per_s"
+    found = [m for m in bench["per_layer"] if m["name"] in NEW_METRICS]
+    assert {m["name"] for m in found} == NEW_METRICS
+    for m in found:
+        assert m["moves"] == "serve_tokens_per_s"
+        if m["name"] in OWN_MECHANISM:
+            assert m["workloads"] == [CELL]
+        else:       # a later cell with routed experts is appended
+            assert m["workloads"][0] == CELL
     cells = {w["name"]: w for w in bench["workloads"]}
     assert cells[CELL]["chips"] == 1
     assert CELL in next(m for m in bench["end_to_end"]
@@ -90,10 +108,7 @@ def test_costs_count_the_least_work_at_the_published_widths(published):
     attn, index, router, expert = family.costs.layer_weights(published)
     assert (attn, index, router, expert) == (18_874_368, 2_260_992, 262_144,
                                              4_718_592)
-    # 8 live slots at ~4,000 keys each, 51.6 experts hit a layer a step
-    window = {"live_context_tokens": 32_000.0, "ticks": 100,
-              "decode_slot_steps": 3200, "moe_experts_hit": 51.6 * 2800,
-              "moe_layer_steps": 2800}
+    window = COUNTED
     flops, bytes_ = family.costs.decode_step(published, sizes, window)
     experts = 7 * 51.6 * expert * 2
     other = 7 * (attn + index + router) * 2
@@ -117,6 +132,38 @@ def test_costs_count_the_least_work_at_the_published_widths(published):
     assert family.costs.hits({}, sizes) == 64.0
     assert set(family.costs.KERNEL_COSTS) == {
         "decode_step", "moe_experts_step", "paged_attn_step"}
+
+
+def test_the_kernels_rooflines_read_the_builders_costs_over_a_tick(published):
+    """The cell's two roofline files over a hand-built tick of seven layers:
+    each kernel by its name, its calls inside tick programs only, against
+    the builder's own cost function and the window's counts."""
+    from benchmarks import reduce
+    from benchmarks.builders import sparse_attn_moe as family
+    sizes = family.sizes(published, load(BENCH, "traffic",
+                                         "doc-qa-2k-6k.json"))
+    outs = "(" + ", ".join(["f32[8,4,8,128]{3,2,1,0:T(8,128)}"] * 3) + ")"
+    attn = kernel_call("paged_attention_decode", 35, outs, [
+        ("s32[8,416]", "1,0:T(8,128)S(1)"), ("s32[8]", "0"),
+        ("bf16[8,4,8,128]", "3,2,1,0:T(8,128)(2,1)"),
+        ("f32[8,52,1,128]", "3,2,1,0")])
+    moe = kernel_call("moe_experts_decode", 35, "f32[16,2048]{1,0:T(8,128)}", [
+        ("s32[64]", "0"), ("bf16[16,2048]", "1,0:T(8,128)(2,1)"),
+        ("bf16[128,2048,768]", "2,1,0:T(8,128)(2,1)")])
+    planes = tick_of([(attn, 200_000), (moe, 650_000)] * 7,
+                     prefill=[(moe, 9_000_000)])
+    context = {"trace": reduce.Trace(planes), "window": COUNTED,
+               "config": published, "device_kind": "TPU v5 lite",
+               "sizes": sizes, "builder": family}
+    bench = load(ROOT, "BENCHMARK.json")
+    for name, cost, ns_a_step in (
+            ("kernels.paged_attn_roofline", "paged_attn_step", 7 * 200_000),
+            ("kernels.moe_experts_decode_roofline", "moe_experts_step",
+             7 * 650_000)):
+        m = roofline_file_reads(context, name, cost, ns_a_step)
+        entry = next(e for e in bench["per_layer"] if e["name"] == name)
+        assert entry["workloads"][0] == CELL
+        assert entry["moves"] == m["moves"] == "serve_tokens_per_s"
 
 
 def test_counters_read_the_engines_counts_and_zero_where_it_has_none():

@@ -14,18 +14,40 @@ import types
 
 import pytest
 
+from benchmarks.tests.planes import (kernel_call, roofline_file_reads,
+                                     tick_of)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "benchmarks")
 WINDOW_CELL = "serve.trinity-large-preview.mixed-1k-16k"
-WINDOW_METRICS = {"sched.window_engaged_share", "cache.kv_held_share",
+# in the order PR 33 appended them
+WINDOW_METRICS = ("sched.window_engaged_share", "cache.kv_held_share",
                   "moe.pairs_held_share", "programs.window_attn_share",
                   "programs.shared_expert_share",
-                  "kernels.paged_attn_decode_share"}
+                  "kernels.paged_attn_decode_share")
+
+
+# what a window's counters hold after 1,000 decode steps of 16 slots at 6,000
+# keys each: a window layer reads 4,096 of them; 7 held experts hit a layer,
+# 8 pairs on them
+STEPS, CTX = 1000, 16 * 6000
+COUNTED = {"live_context_tokens": float(CTX), "ticks": STEPS // 4,
+           "kv_tokens_flat": 5 * CTX * STEPS,
+           "kv_tokens_held": (CTX + 4 * 16 * 4096) * STEPS,
+           "moe_experts_hit": 7 * 4 * STEPS, "moe_layer_steps": 4 * STEPS,
+           "moe_pairs_held": 8 * 4 * STEPS,
+           "moe_pairs_routed": 64 * 4 * STEPS}
 
 
 def load(*path):
     with open(os.path.join(*path)) as f:
         return json.load(f)
+
+
+def named(entries, name):
+    """An entry of one of the manifest's lists, wherever it stands: later
+    PRs append to all four (README.md, "Entries are found by name")."""
+    return next(e for e in entries if e["name"] == name)
 
 
 @pytest.fixture(scope="module")
@@ -53,7 +75,7 @@ def test_the_window_cells_rehearsal_prints_the_contract_and_its_metrics(trace):
         return
     listed = {m["name"] for m in bench["per_layer"]
               if WINDOW_CELL in m["workloads"]}
-    assert WINDOW_METRICS <= listed and set(out["metrics"]) <= listed
+    assert set(WINDOW_METRICS) <= listed and set(out["metrics"]) <= listed
     # what the engine counts reads without a chip; what a device trace
     # holds (scopes, kernels) reads nothing here and is left out, never 0
     got = {k: v["value"] for k, v in out["metrics"].items()}
@@ -81,33 +103,33 @@ def test_the_window_cells_rehearsal_prints_the_contract_and_its_metrics(trace):
 def test_the_window_metrics_list_this_cell_alone():
     bench = load(ROOT, "BENCHMARK.json")
     new = [m for m in bench["per_layer"] if m["name"] in WINDOW_METRICS]
-    assert len(new) == 6 and bench["per_layer"][-6:] == new
+    assert tuple(m["name"] for m in new) == WINDOW_METRICS
     for m in new:
         assert m["workloads"] == [WINDOW_CELL]
         assert m["moves"] == "serve_tokens_per_s"
         f = load(BENCH, "metrics", m["name"] + ".json")
         assert {k: f[k] for k in ("unit", "better", "source", "layer")} \
             == {k: m[k] for k in ("unit", "better", "source", "layer")}
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["chips"], cell["traffic"]) == (
-        WINDOW_CELL, 1, "mixed-1k-16k")
-    assert bench["configs"][-1]["reduced"] == [
+    cell = named(bench["workloads"], WINDOW_CELL)
+    assert (cell["chips"], cell["traffic"]) == (1, "mixed-1k-16k")
+    assert named(bench["configs"], cell["config"])["reduced"] == [
         "num_hidden_layers", "num_dense_layers", "layer_types",
         "num_experts", "vocab_size"]
-    assert WINDOW_CELL in next(m for m in bench["end_to_end"] if m["name"]
-                               == "serve_tokens_per_s")["workloads"]
-    # the metrics of another family's mechanism do not list it, nor those
-    # that family's own test holds to its cell alone (none is doubled
-    # under a second name: PERF.md section 7), nor the operand pattern
-    # that finds one of this model's five decode-kernel calls a step
+    assert WINDOW_CELL in named(bench["end_to_end"],
+                                "serve_tokens_per_s")["workloads"]
+    # the metrics of another family's mechanism do not list it, nor the
+    # operand pattern that finds one of this model's five decode-kernel
+    # calls a step (none is doubled under a second name: the expert
+    # block's three read here under the names the doc-QA cell gave them)
     assert not os.path.exists(os.path.join(
         BENCH, "metrics", "moe.held_experts_hit_share.json"))
     for name in ("programs.indexer_share", "programs.select_share",
-                 "sched.select_engaged_share", "programs.moe_share",
-                 "moe.experts_hit_share", "kernels.moe_experts_decode_share",
-                 "kernels.paged_attn_share"):
-        assert WINDOW_CELL not in next(
-            m for m in bench["per_layer"] if m["name"] == name)["workloads"]
+                 "sched.select_engaged_share", "kernels.paged_attn_share"):
+        assert WINDOW_CELL not in named(bench["per_layer"],
+                                        name)["workloads"]
+    for name in ("programs.moe_share", "moe.experts_hit_share",
+                 "kernels.moe_experts_decode_share"):
+        assert WINDOW_CELL in named(bench["per_layer"], name)["workloads"]
 
 
 def test_the_configuration_states_the_share_and_cuts_no_width(as_run):
@@ -143,15 +165,7 @@ def test_window_costs_count_the_least_work_at_the_published_widths(as_run):
             sizes["W"], sizes["Lw"], sizes["Lf"], sizes["Ld"], sizes["Le"]) \
         == (16, 1152, 32, 256, 4096, 4, 1, 1, 4)
     attn, router, expert, dense = family.costs.layer_weights(as_run)
-    # 1,000 decode steps of 16 slots at 6,000 keys each: a window layer
-    # reads 4,096 of them; 7 held experts hit a layer, 8 pairs on them
-    steps, ctx = 1000, 16 * 6000
-    window = {"live_context_tokens": float(ctx), "ticks": steps // 4,
-              "kv_tokens_flat": 5 * ctx * steps,
-              "kv_tokens_held": (ctx + 4 * 16 * 4096) * steps,
-              "moe_experts_hit": 7 * 4 * steps, "moe_layer_steps": 4 * steps,
-              "moe_pairs_held": 8 * 4 * steps,
-              "moe_pairs_routed": 64 * 4 * steps}
+    steps, ctx, window = STEPS, CTX, COUNTED
     assert family.costs.kv_tokens(window, sizes) == (ctx, 16 * 4096)
     flops, bytes_ = family.costs.decode_step(as_run, sizes, window)
     experts = 4 * 7 * expert * 2
@@ -191,6 +205,50 @@ def test_window_costs_count_the_least_work_at_the_published_widths(as_run):
     assert set(family.costs.KERNEL_COSTS) == {
         "decode_step", "moe_experts_step", "paged_attn_window_step",
         "paged_attn_full_step", "prefill_attn_chunk"}
+
+
+def test_the_kernels_rooflines_part_the_calls_over_the_two_tables(as_run):
+    """The cell's three roofline files over a hand-built tick: the expert
+    kernel by its name; the decode kernel's calls parted by their first
+    operand, the full block table (`s32[slots, pages_per_slot]`) or
+    anything else (a ring's view); each against its own cost function of
+    the builder, over the calls inside tick programs only."""
+    from benchmarks import reduce
+    from benchmarks.builders import window_attn_moe as family
+    sizes = family.sizes(as_run, load(BENCH, "traffic", "mixed-1k-16k.json"))
+    tiled, flat = "1,0:T(8,128)S(1)", "0"
+    lens, q = ("s32[16]", flat), ("bf16[16,8,8,128]", "3,2,1,0:T(8,128)(2,1)")
+    outs = "(" + ", ".join(["f32[16,8,8,128]{3,2,1,0:T(8,128)}"] * 3) + ")"
+    ring = kernel_call("paged_attention_decode", 31, outs, [
+        ("s32[16,257]", tiled), lens, q, ("f32[16,33,1,128]", "3,2,1,0")])
+    full = kernel_call("paged_attention_decode", 35, outs,
+                       [("s32[16,1152]", tiled), lens, q])
+    moe = kernel_call("moe_experts_decode", 20, "f32[16,3072]{1,0:T(8,128)}", [
+        ("s32[32]", flat), ("bf16[16,3072]", "1,0:T(8,128)(2,1)"),
+        ("bf16[32,3072,3072]", "2,1,0:T(8,128)(2,1)")])
+    took = {"ring": 400_000, "full": 700_000, "moe": 800_000}
+    planes = tick_of(
+        [(ring, took["ring"])] * 4 + [(full, took["full"])]
+        + [(moe, took["moe"])] * 4,
+        # a short prompt's rows go through the few-rows kernel too: no tick
+        prefill=[(moe, 5_000_000)])
+    context = {"trace": reduce.Trace(planes), "window": COUNTED,
+               "config": as_run, "device_kind": "TPU v5 lite",
+               "sizes": sizes, "builder": family}
+    bench = load(ROOT, "BENCHMARK.json")
+    for name, cost, ns_a_step in (
+            ("kernels.paged_attn_window_roofline", "paged_attn_window_step",
+             4 * took["ring"]),
+            ("kernels.paged_attn_full_roofline", "paged_attn_full_step",
+             took["full"]),
+            ("kernels.moe_experts_decode_roofline", "moe_experts_step",
+             4 * took["moe"])):
+        roofline_file_reads(context, name, cost, ns_a_step)
+        assert WINDOW_CELL in named(bench["per_layer"], name)["workloads"]
+    # the one pattern over both tables would hold a step's five calls
+    # against one table's bytes
+    assert WINDOW_CELL not in named(bench["per_layer"],
+                                    "kernels.paged_attn_roofline")["workloads"]
 
 
 def test_window_counters_read_the_engines_counts_and_zero_where_it_has_none(
@@ -236,13 +294,14 @@ def test_the_window_familys_files_are_reached_from_the_manifest():
     """What this family brought: each file is named by an entry of
     BENCHMARK.json or by a file that one names."""
     bench = load(ROOT, "BENCHMARK.json")
-    conf = bench["configs"][-1]
+    cell = named(bench["workloads"], WINDOW_CELL)
+    conf = named(bench["configs"], cell["config"])
     assert conf["file"] == "benchmarks/configs/trinity-large-preview-ep8.json"
     assert load(ROOT, conf["file"])["builder"] == "window_attn_moe"
     from benchmarks.builders import window_attn_moe as family
     assert family.reference.__file__ == os.path.join(
         BENCH, "reference_window_attn_moe.py")
     assert os.path.exists(os.path.join(
-        BENCH, "traffic", bench["workloads"][-1]["traffic"] + ".json"))
+        BENCH, "traffic", cell["traffic"] + ".json"))
     for name in WINDOW_METRICS:
         assert os.path.exists(os.path.join(BENCH, "metrics", name + ".json"))
