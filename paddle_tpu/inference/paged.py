@@ -94,6 +94,19 @@ plus `suspend_after_s` generalize this to live conversations: a
 finished turn's full pages (prompt AND generated tokens) stay keyed in
 the cache, a long-idle session's pages spill to host freeing their
 HBM, and the next turn rebuilds its block table from restored pages.
+
+Generation by diffusion over blocks (ISSUE 36): a model whose config has
+`block_length` (models/block_diffusion_moe.py) has no one-token step. Its
+tick settles `steps_per_tick / block_length` whole blocks a slot
+(`_block_tick_fn`: denoising forwards of `block_length` query rows a slot,
+whose K and V overwrite the step's before, the model's unmasking rule, and
+one more forward that stores the settled block), so a tick still hands up
+to `steps_per_tick` tokens to each live slot and everything around the
+tick program (admission, the chain of ticks in flight, accept, streams)
+is the one-token engine's. A prompt's whole blocks are prefilled under the
+mask by blocks; its last tokens open the slot's first block (`_Slot.open`)
+and a prefill yields no token. `paged_attention_update(block=)` is the
+op's side of it.
 """
 from __future__ import annotations
 
@@ -346,7 +359,7 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
 
 
 def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
-                  v_scale=None, hk=None):
+                  v_scale=None, hk=None, block=0):
     """jnp fallback attend: gather each slot's page window and run a
     dense masked softmax in f32. GQA folds query heads into a head-
     group axis (reshape + einsum) instead of jnp.repeat-ing K/V —
@@ -354,12 +367,16 @@ def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
 
     q: (b, s, hq, d). `hk`: the pools' kv heads, where they are stored
     as rows and do not say (the gathered window is seen by heads and
-    tokens, never the pool). Returns (b, s, hq*d) in q.dtype.
+    tokens, never the pool). `block`: causal by blocks of `block`
+    positions, a query sees every key of its own block. Returns
+    (b, s, hq*d) in q.dtype.
     """
     bt, lens = _val(state.block_tables), _val(state.lens)
     b, s, hq, d = q.shape
     hk = hk or kp.shape[1]
     pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    if block:
+        pos = (pos // block + 1) * block - 1    # its block's last position
 
     # window column c IS logical position c (page j holds positions
     # [j*page_size, (j+1)*page_size)), so the causal bound is c <= pos.
@@ -602,8 +619,25 @@ def _attend_window(q, k, v, cache, state: PagedState, window):
     return Tensor(out), (Tensor(kp), Tensor(vp))
 
 
+def _fold_rows(q, hk):
+    """A block's s query rows a slot as ONE decode row of s times the
+    heads: (b, s, hq, d) -> (b, hk * g * s, d), the s rows of a query head
+    beside each other in the group of its kv head, which is where the
+    decode kernel folds a group's heads (`_unfold_rows` is the way back)."""
+    b, s, hq, d = q.shape
+    return jnp.transpose(q.reshape(b, s, hk, hq // hk, d),
+                         (0, 2, 3, 1, 4)).reshape(b, hq * s, d)
+
+
+def _unfold_rows(out, s, hk):
+    """(b, hk * g * s, d) of `_fold_rows` -> (b, s, hq * d)."""
+    b, rows, d = out.shape
+    return jnp.transpose(out.reshape(b, hk, rows // (hk * s), s, d),
+                         (0, 3, 1, 2, 4)).reshape(b, s, rows // s * d)
+
+
 def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
-                           window=None):
+                           window=None, block=None):
     """Write this call's k/v into the slot's pages, then attend over the
     slot's whole paged window. One code path serves BOTH phases of the
     reference contract (block_multi_head_attention_kernel.cu's prefill
@@ -622,6 +656,15 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
     `window`: this layer attends over the newest `window` positions alone
     and its pools ride `state.ring_tables`, not the block table
     (`_attend_window`).
+    `block`: attention is causal by blocks of `block` positions (generation
+    by diffusion over blocks): a query sees every key up to the last
+    position of its own block, later rows of this call included. A call
+    of s == block rows at a block's start is a denoising step: its K and
+    V overwrite what an earlier step of the same block left at lens ..
+    lens + block - 1, and every row attends over lens + block keys; inside
+    `decode_kernel_scope("pallas")` it rides the decode kernel with the
+    rows folded beside the heads of their kv head (`_fold_rows`: no mask
+    inside the kernel but the length's). Plain two-pool caches only.
     Returns (out (b, s, hq*d), new cache of the SAME arity).
 
     Decode calls (s == 1) traced inside
@@ -635,6 +678,9 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
     bookkeeping.
     """
     q, k, v = _val(q), _val(k), _val(v)
+    if block and (window or len(cache) != 2):
+        raise ValueError("paged_attention_update(block=) takes a plain "
+                         "(k_pool, v_pool) cache under one block table")
     if window:
         return _attend_window(q, k, v, cache, state, window)
     if len(cache) == 3:
@@ -673,6 +719,13 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
                 _val(state.lens), k_scale=k_scale, v_scale=v_scale,
                 interpret=interpret, kv_heads=hk)
             out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
+        elif kind == "pallas" and block and s == block:
+            # a denoising step: every row sees the keys up to the block's
+            # end, lens + block - 1, which is the kernel's length mask
+            out = _unfold_rows(_pk.paged_decode_attention(
+                _fold_rows(q, hk), kp, vp, _val(state.block_tables),
+                _val(state.lens) + (block - 1), interpret=interpret,
+                kv_heads=hk), s, hk).astype(q.dtype)
         elif not quantized and _chunk_kernel_takes(q, hk):
             # a chunk's keys are the slot's pages from the first on, under
             # the kernel a window layer's chunk takes; the choice is the
@@ -682,7 +735,7 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
             out = chunk_attention(
                 q, _gathered(kp, bt, hk, d), _gathered(vp, bt, hk, d),
                 _val(state.lens), jnp.zeros_like(_val(state.lens)),
-                interpret=interpret)
+                block=block or 0, interpret=interpret)
         else:
             # what the chunk kernel does not take: int8 pools, a chunk
             # that is no whole sublane tile, the jnp scope, and on the
@@ -691,7 +744,8 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
             # lowered prefill stays byte-equal until ROADMAP M3's PR
             # measures it through the kernel and deletes `_attend_pages`
             # with the score budget (`PagedKVEngine._prefill_limit`)
-            out = _attend_pages(q, kp, vp, state, k_scale, v_scale, hk)
+            out = _attend_pages(q, kp, vp, state, k_scale, v_scale, hk,
+                                block or 0)
     if quantized:
         return Tensor(out), (Tensor(kp), Tensor(vp),
                              Tensor(k_scale), Tensor(v_scale))
@@ -854,7 +908,8 @@ class _Request:
 
 
 class _Slot:
-    __slots__ = ("req", "lens", "tok", "pages", "emitted", "shared")
+    __slots__ = ("req", "lens", "tok", "pages", "emitted", "shared",
+                 "open")
 
     def __init__(self, req, lens, tok):
         self.req = req
@@ -864,6 +919,10 @@ class _Slot:
         self.emitted = 0            # generated tokens accepted so far
         self.shared = 0             # leading prefix-cache pages (not
         #                             drawn from the free list here)
+        self.open = ()              # a block engine: the prompt's tokens
+        #                             past its last whole block, known
+        #                             from the start in the first block
+        #                             it generates and never emitted
 
 
 class _PageGroup:
@@ -934,7 +993,9 @@ class _Flight(NamedTuple):
     """A decode tick the device has been handed and the host has not
     read back."""
     toks: object        # (slots, steps)
-    carry: tuple        # the next tick's rows: tok, lens, active, limit
+    carry: tuple        # the next tick's rows: tok (a block engine: the
+    #                     open block's tokens and which are known), lens,
+    #                     active, limit
     counted: dict       # the model's counters, summed over the tick
     live: list          # (slot index, request) of every live slot
     args: _TickArgs
@@ -1123,6 +1184,50 @@ class PagedKVEngine:
                     f"this model keeps layers {window_layers} in rings of "
                     f"a window of {self.window} under a second page "
                     "table; not carried by: " + "; ".join(refused))
+        # a model that generates by diffusion over blocks (the config
+        # says how long a block is) has no one-token step: a tick settles
+        # steps_per_tick / block_length whole blocks a slot, each by the
+        # config's denoising steps of block_length query rows a slot and
+        # one more forward that stores the block's K and V
+        # (`_block_tick_fn`). What assumes a token a step, or pages that
+        # are final once written, refuses here, by name.
+        self.block_length = int(getattr(cfg, "block_length", 0) or 0)
+        if self.block_length:
+            if self.steps_per_tick % self.block_length \
+                    or self.page_size % self.block_length:
+                raise ValueError(
+                    f"this model generates by blocks of "
+                    f"{self.block_length}: steps_per_tick "
+                    f"({self.steps_per_tick}) and page_size "
+                    f"({self.page_size}) must be multiples of it (a tick "
+                    "settles whole blocks, a block lies in one page)")
+            if (self.prefill_chunk or 0) % self.block_length:
+                raise ValueError(
+                    f"prefill_chunk ({self.prefill_chunk}) must be a "
+                    f"multiple of block_length ({self.block_length}): a "
+                    "chunk ends where a block ends")
+            refused = [why for on, why in (
+                (self.index_dim, "an index pool (the key selection is "
+                 "causal by position)"),
+                (window_layers, "layers in rings (a window inside a block "
+                 "is not defined)"),
+                (kv_dtype == "int8", "kv_dtype='int8' (a block's K and V "
+                 "are rewritten every denoising step; a page's scales "
+                 "only grow)"),
+                (int(prefix_cache_pages), "prefix_cache_pages (a prompt's "
+                 "last partial block is not final, and no test holds a "
+                 "warm tail under the mask by blocks to the reference)"),
+                (int(host_tier_bytes), "host_tier_bytes (it spills "
+                 "prefix-cache pages)"),
+                (role != "both", f"role={role!r} (an exported page is a "
+                 "prefix-cache page)"),
+                (draft_model is not None, "draft_model (a block is "
+                 "denoised, not verified)")) if on]
+            if refused:
+                raise ValueError(
+                    f"this model generates by blocks of "
+                    f"{self.block_length} (block_length); not carried by: "
+                    + "; ".join(refused))
         self._cache_arity = (4 if kv_dtype == "int8"
                              else 3 if self.index_dim else 2)
 
@@ -1165,6 +1270,9 @@ class PagedKVEngine:
         # decode attend path (class doc): resolve once, fail fast on a
         # forced-but-impossible geometry with the misaligned dims named
         on_tpu = self._on_tpu = jax_compat.on_tpu()
+        # query rows the decode kernel sees a kv head's group as: a
+        # block's rows ride beside the heads (`_fold_rows`)
+        q_heads = cfg.num_attention_heads * max(1, self.block_length)
         if kernel not in (None, "pallas", "jnp"):
             raise ValueError(f"kernel must be None, 'pallas' or 'jnp' "
                              f"(got {kernel!r})")
@@ -1176,16 +1284,15 @@ class PagedKVEngine:
             n_kv, hd, self.page_size, pool_dtype)
             if self.index_dim or window_layers else [])
         if kernel == "pallas":
-            _pk.check_decode_shapes(cfg.num_attention_heads, n_kv, hd,
-                                    self.page_size,
+            _pk.check_decode_shapes(q_heads, n_kv, hd, self.page_size,
                                     interpret=self._kernel_interpret,
                                     kv_dtype=pool_dtype)
             if select_problems:
                 raise ValueError("; ".join(select_problems))
             self.decode_kernel = "pallas"
         elif kernel is None and on_tpu and not select_problems and \
-                not _pk.decode_shape_problems(cfg.num_attention_heads,
-                                              n_kv, hd, self.page_size,
+                not _pk.decode_shape_problems(q_heads, n_kv, hd,
+                                              self.page_size,
                                               kv_dtype=pool_dtype):
             self.decode_kernel = "pallas"
         else:
@@ -1265,7 +1372,7 @@ class PagedKVEngine:
         self.decode_plan = None
         if self.decode_kernel == "pallas":
             self.decode_plan = _pk.decode_plan(
-                cfg.num_attention_heads, n_kv, hd, self.page_size,
+                q_heads, n_kv, hd, self.page_size,
                 self.max_pages_per_slot, pool_dtype, slots=self.max_slots)
         # pages promised to admitted slots but not yet popped from the
         # free list; admission headroom = len(_free) - _reserved_unalloc
@@ -1395,6 +1502,14 @@ class PagedKVEngine:
             # for every layer would hold
             self.stats.update(decode_slot_steps=0, window_engaged_steps=0,
                               kv_tokens_held=0, kv_tokens_flat=0)
+        if self.block_length:
+            # slot-forwards of the denoising steps and of the forwards that
+            # store a settled block, positions those steps unmasked, and
+            # blocks settled, each a live slot's
+            self.stats.update({"block_forwards_denoise": 0,
+                               "block_forwards_store": 0,
+                               "block_positions_unmasked": 0,
+                               "blocks_done": 0})
         # what the model counts itself a decode step (its
         # `decode_counter_keys`, e.g. the distinct experts its rows hit):
         # the tick program asks the forward for them (`with_counters`),
@@ -1657,6 +1772,11 @@ class PagedKVEngine:
             raise DeadlineExceeded(
                 "deadline exceeded before engine admission")
         ids = np.asarray(ids, np.int32).reshape(-1)
+        if self.block_length and do_sample:
+            raise ValueError(
+                "this model generates by blocks (block_length="
+                f"{self.block_length}): do_sample is not carried (a "
+                "denoising step takes each position's best token)")
         total = ids.size + int(max_new_tokens)
         pages = -(-total // self.page_size)
         if pages > self.max_pages_per_slot:
@@ -2531,6 +2651,31 @@ class PagedKVEngine:
             self._prefill_group(self._bucket(int(req.prompt.size)),
                                 [(slot_idx, req)])
 
+    def _prefill_len(self, req):
+        """The tokens of the prompt a prefill stores: all of it, or for a
+        model that generates by blocks its whole blocks (the rest opens
+        the first block it generates: `_Slot.open`). The program is the
+        one of the whole prompt's bucket either way."""
+        n = int(req.prompt.size)
+        return n - n % self.block_length if self.block_length else n
+
+    def _prefilled(self, slot_idx, req, logits):
+        """A prompt's prefill has been read back: the slot's length, and
+        its first token from the last position's `logits`, accepted at
+        once. A model that generates by blocks yields no token here."""
+        slot = self._slots[slot_idx]
+        slot.lens = self._prefill_len(req)
+        if self.block_length:
+            slot.open = req.prompt[slot.lens:]
+            return
+        slot.tok = self._first_token(logits, req)
+        # register the prompt's full pages BEFORE accept (a
+        # max_new_tokens=1 request retires inside _accept, freeing
+        # its pages — too late to share them)
+        self._prefix_insert(slot_idx, req)
+        self._disagg_capture(req)
+        self._accept(slot_idx, [slot.tok])
+
     def _first_token(self, logits, req):
         """Select a request's first token from its prefill logits —
         host-side, seeded from (engine seed, submission index) so
@@ -2581,7 +2726,7 @@ class PagedKVEngine:
         # consumed per row: warm rows (prefix-cache hit) start past the
         # shared pages
         done = [self._slots[idx].shared * self.page_size for idx, _ in grp]
-        plens = [int(req.prompt.size) for _, req in grp]
+        plens = [self._prefill_len(req) for _, req in grp]
         rounds = [-(-(n - d) // chunk) for n, d in zip(plens, done)]
         calls = sum(max(rounds[r] for r in part) for part in parts)
         with observability.span("engine.prefill", bucket=chunk,
@@ -2628,12 +2773,7 @@ class PagedKVEngine:
                 if req.obs is not None:
                     req.obs.record("prefill_end", rid=req.rid)
         for r, (idx, req) in enumerate(grp):
-            slot = self._slots[idx]
-            slot.lens = plens[r]
-            slot.tok = self._first_token(final_logits[r], req)
-            self._prefix_insert(idx, req)
-            self._disagg_capture(req)
-            self._accept(idx, [slot.tok])
+            self._prefilled(idx, req, final_logits[r])
 
     def _prefill_chunk_fn(self, chunk, bw=1):
         key = ("prefill_chunk", chunk, bw)
@@ -2684,7 +2824,7 @@ class PagedKVEngine:
                     # and the tail attends over their KV through the
                     # block table
                     off = self._slots[idx].shared * self.page_size
-                    tail = req.prompt[off:]
+                    tail = req.prompt[off:self._prefill_len(req)]
                     ids[row, :tail.size] = tail
                     lens[row] = off
                     nv[row] = tail.size
@@ -2715,15 +2855,7 @@ class PagedKVEngine:
                 if req.obs is not None:
                     req.obs.record("prefill_end", rid=req.rid)
         for row, (idx, req) in enumerate(grp):
-            slot = self._slots[idx]
-            slot.lens = int(req.prompt.size)
-            slot.tok = self._first_token(logits_np[row], req)
-            # register the prompt's full pages BEFORE accept (a
-            # max_new_tokens=1 request retires inside _accept, freeing
-            # its pages — too late to share them)
-            self._prefix_insert(idx, req)
-            self._disagg_capture(req)
-            self._accept(idx, [slot.tok])
+            self._prefilled(idx, req, logits_np[row])
 
     def _accept(self, slot_idx, toks):
         """Feed accepted tokens to the request; retire the slot when the
@@ -2800,8 +2932,16 @@ class PagedKVEngine:
                     topk=np.zeros(b, np.int32),
                     topp=np.ones(b, np.float32),
                     wants=np.zeros(b, bool))
+        if self.block_length:
+            # the first block a slot generates: the prompt's tokens past
+            # its last whole block, known from the start
+            arrs["open_tok"] = np.zeros((b, self.block_length), np.int32)
+            arrs["open_known"] = np.zeros((b, self.block_length), bool)
         for i in live:
             slot = self._slots[i]
+            if len(slot.open):
+                arrs["open_tok"][i, :len(slot.open)] = slot.open
+                arrs["open_known"][i, :len(slot.open)] = True
             arrs["tok"][i] = slot.tok
             arrs["lens"][i] = slot.lens
             arrs["active"][i] = True
@@ -2815,7 +2955,11 @@ class PagedKVEngine:
 
     def _accept_tick(self, live, out_np, counts, eos, lens_np):
         """Shared accept epilogue: truncate by budget then eos, feed the
-        request, advance slot state for survivors."""
+        request, advance slot state for survivors. `out_np` (slots,
+        steps a tick) holds each slot's new tokens first and `counts` how
+        many of them are new: a tick's steps, less in a slot's last tick
+        by its budget and, where a tick settles blocks, in its first by
+        the prompt's tokens that opened the block."""
         for i in live:
             slot = self._slots[i]
             emitted = list(out_np[i, :int(counts[i])])
@@ -2824,6 +2968,7 @@ class PagedKVEngine:
             if self._accept(i, emitted):
                 slot.lens = int(lens_np[i])
                 slot.tok = int(emitted[-1])
+                slot.open = ()
 
     def step(self):
         """One scheduler tick: admit pending requests (prefill), then
@@ -2890,7 +3035,10 @@ class PagedKVEngine:
         clock = time.perf_counter
         pre_s, pre_n = self.stats["prefill_s"], self.stats["prefills"]
         n = self.steps_per_tick
-        with observability.span("engine.tick", seq=self._step_seq):
+        with observability.span(
+                "engine.tick", seq=self._step_seq,
+                **({"blocks": n // self.block_length}
+                   if self.block_length else {})):
             marks = [clock()]
             with observability.span("engine.tick.retire"):
                 retired = 0
@@ -2947,8 +3095,9 @@ class PagedKVEngine:
                     if flight is None:
                         any_sample = bool(a["wants"].any())
                         sent = self._tables()   # never written again
-                        rows = tuple(jnp.asarray(a[k]) for k in
-                                     ("tok", "lens", "active", "limit"))
+                        rows = tuple(jnp.asarray(a[k]) for k in (
+                            ("open_tok", "open_known") if self.block_length
+                            else ("tok",)) + ("lens", "active", "limit"))
                         args = _TickArgs(
                             self._tick_fn(any_sample),
                             jax.tree.map(jnp.asarray, sent), sent,
@@ -2985,7 +3134,7 @@ class PagedKVEngine:
                 marks.append(clock())
                 with observability.span("engine.tick.readback"):
                     toks_np = np.asarray(flight.toks)       # (b, n)
-                    lens_np = np.asarray(flight.carry[1])
+                    lens_np = np.asarray(flight.carry[-3])
                     for name, v in flight.counted.items():
                         self.stats[name] = self.stats.get(name, 0) + int(v)
                 marks.append(clock())
@@ -2997,7 +3146,7 @@ class PagedKVEngine:
                 for i in live:
                     slot = self._slots[i]
                     counts[i] = min(slot.req.max_new_tokens - slot.emitted,
-                                    n)
+                                    n - len(slot.open))
                     eos[i] = slot.req.eos_token_id
                 if self.index_dim or self._ring is not None:
                     took = counts[live]
@@ -3558,6 +3707,8 @@ class PagedKVEngine:
         Returns the tokens (slots, steps), the next tick's rows, whose
         second element is the slots' final lens, the pools and, from a
         model that counts, the sums of its counters."""
+        if self.block_length:
+            return self._block_tick_fn()
         key = ("tick", any_sample)
         if key in self._programs:
             return self._programs[key]
@@ -3631,5 +3782,131 @@ class PagedKVEngine:
         # KV-pool memory. The rows are not donated: the host reads a
         # tick's back after the next tick has taken them
         fn = self._jit(run, donate=(9 if any_sample else 5,))
+        self._programs[key] = fn
+        return fn
+
+    def _block_forward(self, ids, lens, rows_live, bt, flat):
+        """One forward of a block's rows through the model against the
+        pages: ids (slots, B) at positions lens .. lens + B - 1; the slots
+        of `rows_live` write their rows' K and V there (over what an
+        earlier forward of the block left) and every row attends over the
+        lens + B keys of its slot. -> (the logits of every row, the pools,
+        the model's counters of this forward)."""
+        blen = self.block_length
+        state = _state_of(bt, lens, rows_live.astype(jnp.int32) * blen)
+        place = jnp.arange(blen, dtype=jnp.int32)
+        logits, new_caches, *counts = self.model(
+            Tensor(ids), caches=self._layer_caches(list(flat)),
+            position_ids=Tensor(lens[:, None] + place[None]),
+            cache_index=state,
+            **({"with_counters": True} if self._model_counts else {}))
+        counts = ({k: jnp.asarray(_val(v), jnp.int32)
+                   for k, v in counts[0].items()}
+                  if self._model_counts else {})
+        return (_val(logits),
+                tuple(_val(a) for kv in new_caches for a in kv), counts)
+
+    def _block_tick_fn(self):
+        """The tick of a model that generates by diffusion over blocks
+        (`models/block_diffusion_moe.py`): steps_per_tick / block_length
+        blocks a slot, one after another, so that a tick delivers up to
+        steps_per_tick tokens a slot as a one-token tick does. A block,
+        B = block_length rows a slot at positions lens .. lens + B - 1
+        (`_block_forward`):
+
+        - it starts as the mask id wherever a position is not known (all
+          of it, but for the first block a slot generates, which the
+          prompt's tokens past its last whole block open: `rows`);
+        - `denoising_steps` forwards (scope `denoise`), each against the
+          pages of the earlier blocks and the block's own current rows,
+          whose K and V the forward writes over the step's before. After
+          each (scope `unmask`), a masked position's token is the best of
+          ITS logits and its confidence that token's probability, and
+          the model's rule says which positions the step unmasks
+          (`block_diffusion_moe.confidence`, `.unmask`). Which positions
+          are masked is carried as booleans, never read off the ids: a
+          prompt may hold the mask id. A slot with no position masked
+          sits the remaining steps out (it writes nothing);
+        - one more forward over the final tokens (scope `store`), whose K
+          and V are the block's cache; its logits are not computed.
+
+        Returns what `_tick_fn`'s program does: the tokens (slots, steps),
+        each slot's NEW ones first (a block's positions not known at its
+        start, in order; the host knows how many: `_accept_tick`), the
+        next tick's rows, the pools, and the counters: the model's own a
+        forward, the slot-forwards of either kind, the positions unmasked
+        and the blocks settled. Greedy only (`submit` refuses sampling),
+        so `key_data` and `tick_i` are taken and not used."""
+        from paddle_tpu.models import block_diffusion_moe as rule
+        key = ("block_tick",)
+        if key in self._programs:
+            return self._programs[key]
+        cfg = self.model.config
+        n, blen = self.steps_per_tick, self.block_length
+        steps = cfg.denoising_steps
+        how = (blen // steps, cfg.remasking, float(cfg.confidence_threshold))
+        mask_id = int(cfg.mask_token_id)
+
+        def run(rows, bt, eos, key_data, tick_i, pool_flat):
+            tok, known, lens, active, limit = rows
+
+            def block(carry, _):
+                tok, known, lens, active, limit, flat = carry
+                known0 = known
+                ids = jnp.where(known, tok, mask_id)
+
+                def denoise(c, _):
+                    ids, known, flat = c
+                    work = active & ~jnp.all(known, -1)
+                    with jax.named_scope("denoise"):
+                        logits, flat, counts = self._block_forward(
+                            ids, lens, work, bt, flat)
+                    with jax.named_scope("unmask"):
+                        best, conf = rule.confidence(logits)
+                        pick = rule.unmask(conf, ~known & work[:, None],
+                                           *how)
+                        ids = jnp.where(pick, best, ids)
+                    return (ids, known | pick, flat), dict(
+                        counts, block_forwards_denoise=jnp.sum(work),
+                        block_positions_unmasked=jnp.sum(pick))
+
+                (ids, known, flat), counted = jax.lax.scan(
+                    denoise, (ids, known, flat), None, length=steps)
+                with jax.named_scope("store"):
+                    _lg, flat, counts = self._block_forward(
+                        ids, lens, active, bt, flat)
+                counted = {k: jnp.sum(v) for k, v in counted.items()}
+                for k, v in counts.items():
+                    counted[k] = counted[k] + v
+                counted["block_forwards_store"] = jnp.sum(active)
+                counted["blocks_done"] = jnp.sum(active)
+                # the block's new tokens: those not known at its start, up
+                # to the slot's budget, in order; an eos among them ends it
+                new = ~known0 & active[:, None]
+                order = jnp.cumsum(new, -1) - 1
+                out = new & (order < limit[:, None])
+                hit_eos = jnp.any(out & (eos[:, None] >= 0)
+                                  & (ids == eos[:, None]), -1)
+                limit = limit - jnp.sum(out, -1).astype(limit.dtype)
+                lens = lens + active.astype(lens.dtype) * blen
+                active = active & ~hit_eos & (limit > 0)
+                carry = (jnp.zeros_like(tok), jnp.zeros_like(known), lens,
+                         active, limit, flat)
+                return carry, (ids, counted)
+
+            opened = jnp.sum(known, -1)     # of a slot's first block
+            (tok_f, known_f, lens_f, active_f, limit_f, flat_f), (
+                ids, counted) = jax.lax.scan(
+                    block, (tok, known, lens, active, limit,
+                            tuple(pool_flat)), None, length=n // blen)
+            # (blocks, slots, B) -> (slots, steps), the new tokens first
+            ids = jnp.swapaxes(ids, 0, 1).reshape(-1, n)
+            at = jnp.minimum(jnp.arange(n)[None] + opened[:, None], n - 1)
+            toks = jnp.take_along_axis(ids, at, axis=1)
+            return (toks, (tok_f, known_f, lens_f, active_f, limit_f),
+                    list(flat_f),
+                    {k: jnp.sum(v) for k, v in counted.items()})
+
+        fn = self._jit(run, donate=(5,))
         self._programs[key] = fn
         return fn

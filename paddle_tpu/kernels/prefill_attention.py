@@ -1,6 +1,7 @@
 """Pallas attention for a prefill chunk over keys gathered in order (TPU):
 many query tokens a slot against that slot's cached keys, under a mask that
-is a rule of positions (causal, and optionally a window), never a tensor.
+is a rule of positions (causal, optionally a window, or causal by blocks),
+never a tensor.
 
 The jnp paths of inference/paged.py (`_attend_pages`, `_attend_selected`)
 write a chunk's float32 scores to HBM and read them back several times: a
@@ -13,7 +14,10 @@ online softmax, base-2 exponentials) cut to what a chunk needs:
   position k_pos[i] + c (the caller gathers the pages it names: a ring's
   view, or a block table from its start). A query sees the columns whose
   position is at most its own and, with `window`, more than its own less
-  the window. Positions ride in as scalar-prefetch operands;
+  the window; with `block`, at most the last position of its own block of
+  `block` positions (generation by diffusion over blocks: a query sees
+  every key of its own block). Positions ride in as scalar-prefetch
+  operands;
 - GQA by the head fold: the g = hq / hk query heads of a kv head ride the
   rows of one q tile (g x block_q rows), so a key block is read once a kv
   head;
@@ -76,8 +80,17 @@ def chunk_attention_problems(s, hq, hk, d, interpret=False):
     return problems
 
 
+def _last_seen(at, block):
+    """The last position a query at `at` sees: its own, or with `block` the
+    last of its block."""
+    if not block:
+        return at
+    # positions are never negative: the truncating division is the floor
+    return (jax.lax.div(at, jnp.int32(block)) + 1) * block - 1
+
+
 def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-            acc_scr, *, bq, bk, nk, window):
+            acc_scr, *, bq, bk, nk, window, block):
     bi, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     rows = q_ref.shape[3]
 
@@ -89,7 +102,7 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     q_lo = qpos_ref[bi] + qi * bq            # the tile's first query
     k_lo = kpos_ref[bi] + ki * bk            # the block's first key
-    run = k_lo <= q_lo + bq - 1
+    run = k_lo <= _last_seen(q_lo + bq - 1, block)
     if window:
         run = jnp.logical_and(run, k_lo + bk - 1 > q_lo - window)
 
@@ -106,7 +119,7 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         col = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
         at = q_lo + jax.lax.rem(row, bq)
         key = k_lo + col
-        seen = key <= at
+        seen = key <= _last_seen(at, block)
         if window:
             seen = jnp.logical_and(seen, key > at - window)
         s = jnp.where(seen, s, _NEG_INF)
@@ -127,30 +140,36 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                           ).astype(o_ref.dtype)
 
 
-def chunk_attention(q, k, v, q_pos, k_pos, *, window=0, sm_scale=None,
-                    interpret=False):
+def chunk_attention(q, k, v, q_pos, k_pos, *, window=0, block=0,
+                    sm_scale=None, interpret=False):
     """q (b, s, hq, d); k, v (b, hk, L, d), a row's keys in order; q_pos,
     k_pos (b,) int32, the positions of each row's first query and first
     key column. Returns (b, s, hq * d) in q.dtype: each query's attention
     over the columns at positions (own - window, own] (window 0: every
-    position up to its own). A query that sees no column gets zeros."""
+    position up to its own; `block`: up to the last position of its own
+    block of `block`, which no window cuts). A query that sees no column
+    gets zeros."""
     b, s, hq, d = q.shape
     hk = k.shape[1]
     problems = chunk_attention_problems(s, hq, hk, d, interpret)
     if problems:
         raise ValueError("chunk_attention: " + "; ".join(problems))
+    if window and block:
+        raise ValueError("chunk_attention: a window inside block-causal "
+                         "attention is not defined")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     return _call(q, k, v, q_pos.astype(jnp.int32), k_pos.astype(jnp.int32),
                  window=int(window or 0), sm_scale=float(sm_scale),
-                 interpret=interpret)
+                 block=int(block or 0), interpret=interpret)
 
 
 # jitted on its own, as the decode kernel is: one lowered kernel a program
 # and a window, however many layers call it
 @functools.partial(jax.jit,
-                   static_argnames=("window", "sm_scale", "interpret"))
-def _call(q, k, v, q_pos, k_pos, *, window, sm_scale, interpret):
+                   static_argnames=("window", "sm_scale", "interpret",
+                                    "block"))
+def _call(q, k, v, q_pos, k_pos, *, window, sm_scale, interpret, block):
     b, s, hq, d = q.shape
     hk, length = k.shape[1], k.shape[2]
     g = hq // hk
@@ -171,7 +190,8 @@ def _call(q, k, v, q_pos, k_pos, *, window, sm_scale, interpret):
         """ki clamped into the key blocks q tile qi sees: a step outside
         them names the block of a step inside, which is not fetched again."""
         q_lo = qpos[bi] + qi * bq
-        last = jnp.clip((q_lo + bq - 1 - kpos[bi]) // bk, 0, nk - 1)
+        last = jnp.clip((_last_seen(q_lo + bq - 1, block) - kpos[bi]) // bk,
+                        0, nk - 1)
         first = jnp.clip((q_lo - window + 1 - kpos[bi]) // bk, 0, last) \
             if window else 0
         return jnp.clip(ki, first, last)
@@ -182,7 +202,8 @@ def _call(q, k, v, q_pos, k_pos, *, window, sm_scale, interpret):
         (1, 1, bk, d), lambda bi, hi, qi, ki, qpos, kpos:
         (bi, hi, needed(bi, qi, ki, qpos, kpos), 0))
     out = pl.pallas_call(
-        functools.partial(_kernel, bq=bq, bk=bk, nk=nk, window=window),
+        functools.partial(_kernel, bq=bq, bk=bk, nk=nk, window=window,
+                          block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hk, nq, nk),

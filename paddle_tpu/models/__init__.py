@@ -36,3 +36,7 @@ from paddle_tpu.models.window_attn_moe import (  # noqa: F401
     WindowAttnMoeConfig, WindowAttnMoeForCausalLM, WindowAttnMoeModel,
     tiny_window_attn_moe_config,
 )
+from paddle_tpu.models.block_diffusion_moe import (  # noqa: F401
+    BlockDiffusionMoeConfig, BlockDiffusionMoeForCausalLM,
+    BlockDiffusionMoeModel, tiny_block_diffusion_moe_config,
+)

@@ -41,7 +41,7 @@ from paddle_tpu.nn.layer.moe import MoEMLP
 from paddle_tpu.nn.layer.norm import LayerNorm, RMSNorm
 
 __all__ = ["SparseAttnMoeConfig", "tiny_sparse_attn_moe_config",
-           "IndexedAttention", "SparseAttnMoeDecoderLayer",
+           "NormedGQA", "IndexedAttention", "SparseAttnMoeDecoderLayer",
            "SparseAttnMoeModel", "SparseAttnMoeForCausalLM"]
 
 
@@ -107,11 +107,13 @@ class Indexer(nn.Layer):
         self.weights_proj = _linear(d, config.index_num_heads, config)
 
 
-class IndexedAttention(nn.Layer):
-    """GQA at its own head width with per-head q/k RMSNorm, over the keys
-    the indexer selects."""
+class NormedGQA(nn.Layer):
+    """GQA at its own head width with an RMSNorm over each q and k head
+    before RoPE: the projections, and the dense attend of a whole
+    sequence over the keys a boolean mask keeps. What chooses the keys
+    (a learned selection, a mask by blocks) is the subclass's."""
 
-    def __init__(self, config: SparseAttnMoeConfig):
+    def __init__(self, config):
         super().__init__()
         self.config = config
         d, hd = config.hidden_size, config.head_dim
@@ -121,14 +123,13 @@ class IndexedAttention(nn.Layer):
         self.o_proj = _linear(config.num_attention_heads * hd, d, config)
         self.q_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
         self.k_norm = RMSNorm(hd, epsilon=config.rms_norm_eps)
-        self.indexer = Indexer(config)
 
-    def forward(self, x, position_ids=None, cache=None, cache_index=None):
+    def project(self, x, position_ids):
+        """-> q (b, s, h, hd), k, v (b, s, hk, hd), normed and rotated."""
         cfg = self.config
         b, s = x.shape[0], x.shape[1]
         h, hk, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
-        theta = cfg.rope_theta
         with jax.named_scope("qkv"):
             q = self.q_norm(self.q_proj(x).reshape([b, s, h, hd]))
             k = self.k_norm(self.k_proj(x).reshape([b, s, hk, hd]))
@@ -136,7 +137,37 @@ class IndexedAttention(nn.Layer):
         with jax.named_scope("rope"):
             q, k, _ = fused_rotary_position_embedding(
                 q, k, None, position_ids=position_ids,
-                rotary_emb_base=theta)
+                rotary_emb_base=cfg.rope_theta)
+        return q, k, v
+
+    @staticmethod
+    def attend_kept(q, k, v, keep):
+        """A whole sequence without a cache: softmax over the keys `keep`
+        (b, s, s) bool marks, densely, as the paged paths compute it."""
+        q, k, v = _val(q), _val(k), _val(v)
+        b, s, h, hd = q.shape
+        hk = k.shape[2]
+        qg = q.reshape(b, s, hk, h // hk, hd)
+        att = jnp.einsum("bshgd,blhd->bhgsl", qg, k,
+                         preferred_element_type=jnp.float32) / math.sqrt(hd)
+        att = jnp.where(keep[:, None, None], att, -1e30)
+        p = jax.nn.softmax(att, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bhgsl,blhd->bshgd", p, v)
+        return Tensor(out.reshape(b, s, h * hd).astype(q.dtype))
+
+
+class IndexedAttention(NormedGQA):
+    """`NormedGQA` over the keys the indexer selects."""
+
+    def __init__(self, config: SparseAttnMoeConfig):
+        super().__init__(config)
+        self.indexer = Indexer(config)
+
+    def forward(self, x, position_ids=None, cache=None, cache_index=None):
+        cfg = self.config
+        b, s = x.shape[0], x.shape[1]
+        theta = cfg.rope_theta
+        q, k, v = self.project(x, position_ids)
         with jax.named_scope("indexer"):
             ix = self.indexer
             qi = ix.wq(x).reshape([b, s, cfg.index_num_heads,
@@ -168,29 +199,23 @@ class IndexedAttention(nn.Layer):
     def _attend_whole(self, q, k, v, index):
         """A whole sequence without a cache: the same selection and the
         same softmax as the paged path, densely."""
-        q, k, v = _val(q), _val(k), _val(v)
         qi, ki, w, topk = index
-        b, s, h, hd = q.shape
-        hk = k.shape[2]
+        b, s = q.shape[0], q.shape[1]
         with jax.named_scope("indexer"):
             scores = index_scores(_val(qi), _val(ki), _val(w))
         with jax.named_scope("select"):
             causal = jnp.tril(jnp.ones((s, s), bool))[None]
             keep = select_top(scores, jnp.broadcast_to(causal, (b, s, s)),
                               topk)
-        qg = q.reshape(b, s, hk, h // hk, hd)
-        att = jnp.einsum("bshgd,blhd->bhgsl", qg, k,
-                         preferred_element_type=jnp.float32) / math.sqrt(hd)
-        att = jnp.where(keep[:, None, None], att, -1e30)
-        p = jax.nn.softmax(att, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bhgsl,blhd->bshgd", p, v)
-        return Tensor(out.reshape(b, s, h * hd).astype(q.dtype))
+        return self.attend_kept(q, k, v, keep)
 
 
 class SparseAttnMoeDecoderLayer(nn.Layer):
-    def __init__(self, config: SparseAttnMoeConfig):
+    attention_class = IndexedAttention
+
+    def __init__(self, config):
         super().__init__()
-        self.self_attn = IndexedAttention(config)
+        self.self_attn = self.attention_class(config)
         # the repo's expert layer, dropless: a served token is never
         # dropped (Qwen2MoeSparseBlock wraps the same MoEMLP)
         self.mlp = MoEMLP(config.hidden_size, config.moe_intermediate_size,
@@ -203,9 +228,10 @@ class SparseAttnMoeDecoderLayer(nn.Layer):
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
                                                 epsilon=config.rms_norm_eps)
 
-    def forward(self, h, position_ids=None, cache=None, cache_index=None):
+    def forward(self, h, position_ids=None, cache=None, cache_index=None,
+                **attn_kw):
         """-> h, or with a cache (h, the new cache, the distinct experts
-        the rows hit)."""
+        the rows hit). `attn_kw` goes to the attention as it is."""
         res = h
         with jax.named_scope("norm"):
             h = self.input_layernorm(h)
@@ -214,9 +240,9 @@ class SparseAttnMoeDecoderLayer(nn.Layer):
             if cache is not None:
                 h, new_cache = self.self_attn(
                     h, position_ids=position_ids, cache=cache,
-                    cache_index=cache_index)
+                    cache_index=cache_index, **attn_kw)
             else:
-                h = self.self_attn(h, position_ids=position_ids)
+                h = self.self_attn(h, position_ids=position_ids, **attn_kw)
         with jax.named_scope("norm"):
             h = res + h
             res = h
@@ -229,7 +255,9 @@ class SparseAttnMoeDecoderLayer(nn.Layer):
 
 
 class SparseAttnMoeModel(nn.Layer):
-    def __init__(self, config: SparseAttnMoeConfig):
+    layer_class = SparseAttnMoeDecoderLayer
+
+    def __init__(self, config):
         super().__init__()
         self.config = config
         init = nn.initializer.Normal(0.0, config.initializer_range)
@@ -237,19 +265,19 @@ class SparseAttnMoeModel(nn.Layer):
             config.vocab_size, config.hidden_size,
             weight_attr=paddle_tpu.nn.ParamAttr(initializer=init))
         self.layers = nn.LayerList(
-            [SparseAttnMoeDecoderLayer(config)
+            [self.layer_class(config)
              for _ in range(config.num_hidden_layers)])
         self.norm = RMSNorm(config.hidden_size, epsilon=config.rms_norm_eps)
 
     def forward(self, input_ids, position_ids=None, caches=None,
-                cache_index=None):
+                cache_index=None, **attn_kw):
         with jax.named_scope("embed"):
             h = self.embed_tokens(input_ids)
         new_caches, hits = [], []
         for layer, cache in zip(self.layers,
                                 caches or [None] * len(self.layers)):
             h = layer(h, position_ids=position_ids, cache=cache,
-                      cache_index=cache_index)
+                      cache_index=cache_index, **attn_kw)
             if cache is not None:
                 h, c, hit = h
                 new_caches.append(c)
@@ -258,10 +286,15 @@ class SparseAttnMoeModel(nn.Layer):
 
 
 class SparseAttnMoeForCausalLM(nn.Layer):
-    def __init__(self, config: SparseAttnMoeConfig):
+    model_class = SparseAttnMoeModel
+    # query rows a slot a decode call carries: a cached call of more rows
+    # is a prefill, which needs its last valid token's logits alone
+    rows_a_step = 1
+
+    def __init__(self, config):
         super().__init__()
         self.config = config
-        self.model = SparseAttnMoeModel(config)
+        self.model = self.model_class(config)
         self.lm_head = _linear(config.hidden_size, config.vocab_size, config)
 
     def _logits(self, h):
@@ -289,9 +322,14 @@ class SparseAttnMoeForCausalLM(nn.Layer):
         if caches is None:
             return self._logits(self.model(input_ids,
                                            position_ids=position_ids))
+        return self.cached(input_ids, position_ids, caches, cache_index,
+                           with_counters)
+
+    def cached(self, input_ids, position_ids, caches, cache_index,
+               with_counters):
         h, caches, hits = self.model(input_ids, position_ids=position_ids,
                                      caches=caches, cache_index=cache_index)
-        if h.shape[1] > 1:
+        if h.shape[1] > self.rows_a_step:
             # a prefill needs each row's LAST valid token's logits alone:
             # at a large vocabulary the rest would be most of its work
             hv = _val(h)

@@ -85,8 +85,11 @@ SPANS = {
         "first call, else the enqueue)",
         "none yet; a child of train.step"),
     "engine.tick": (
-        "scheduler", "one scheduler tick that found work, attr seq; "
-        "its children follow in this order",
+        "scheduler", "one scheduler tick that found work, attr seq and, "
+        "where a tick settles whole blocks (a model that generates by "
+        "diffusion over blocks: counters block_forwards_denoise, "
+        "block_forwards_store, block_positions_unmasked, blocks_done), "
+        "attr blocks; its children follow in this order",
         "sched.tick_host_ms_p50 (counters tick_wall_s, tick_host_s)"),
     "engine.tick.retire": (
         "scheduler", "the cancel sweep and the session suspend sweep",

@@ -79,15 +79,11 @@ def test_tick_chained_share_reads_the_counter_or_nothing():
             cell, rehearse=False)["metrics"]}
 
 
-# ISSUE 34's two per-layer metrics are NOT in BENCHMARK.json: an entry
-# appended to `per_layer` fails `benchmarks/tests/test_window_attn_moe.py::
-# test_the_window_metrics_list_this_cell_alone`, which holds that list's
-# last six entries to PR 33's, and that file is the benchmark's to edit
-# (PERF.md section 7: the next `benchmark` PR's first item). What stays
-# here: the engine's counters under the names those metrics will read,
-# and the reader they will use, over a hand-built window of 40 prompts:
-# 10 alone, 12 in six pairs split into calls at width 1, 18 short tails
-# in three groups padded to 16 rows.
+# ISSUE 34's two per-layer metrics (in BENCHMARK.json since PR 36, over
+# every serve cell): the engine's counters under the names the metric files
+# read, and their reader, over a hand-built window of 40 prompts: 10 alone,
+# 12 in six pairs split into calls at width 1, 18 short tails in three
+# groups padded to 16 rows.
 _PREFILL_WINDOW = {"prefills": 40, "prefill_rows_run": 10 + 12 + 3 * 16,
                    "prefill_rows_padded": 3 * 16 - 18,
                    "prefill_rows_split": 12}
@@ -107,8 +103,10 @@ def _check_prefill_row_metric(name):
     from benchmarks import reduce, run
     from paddle_tpu.inference import paged
     args, value = _PREFILL_ROW_METRICS[name]
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        assert name not in {m["name"] for m in json.load(f)["per_layer"]}
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert (spec["reader"], spec["args"]) == ("counter_ratio", args)
     src = open(paged.__file__).read()
     for counter in args["num"] + args["den"]:
         assert f'"{counter}": 0' in src     # a key of engine.stats

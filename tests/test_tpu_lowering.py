@@ -615,6 +615,124 @@ def test_window_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     assert "ragged_dot" in lowered.as_text()
 
 
+# -- the decoder that generates by diffusion over blocks, at the widths of
+# -- its serve cell (16 slots, 32 / 4 heads of 128, 192 pages of 16, blocks
+# -- of 4: a forward carries 64 rows, 32 a kv head in the decode kernel)
+
+def test_a_blocks_rows_compile_into_the_decode_kernel_at_the_cells_geometry(
+        as_tpu, described_v5e):
+    from paddle_tpu.inference.paged import _fold_rows
+    from paddle_tpu.kernels.paged_attention import (decode_plan,
+                                                    paged_decode_attention)
+    bf16, b, mp, page, blk = jnp.bfloat16, 16, 192, 16, 4
+    plan = decode_plan(32 * blk, 4, 128, page, mp, bf16, slots=b)
+    assert (plan.fold, plan.pack, plan.heads, plan.grid) == (
+        1, 1, 4, (b, 1, 24))
+    pool = ((b * mp + 1, 4, page, 128), bf16)
+    shapes = _shapes(described_v5e, ((b, blk, 32, 128), bf16), pool, pool,
+                     ((b, mp), jnp.int32), ((b,), jnp.int32))
+    fn = jax.jit(lambda q, k, v, bt, lens: paged_decode_attention(
+        _fold_rows(q, 4), k, v, bt, lens + (blk - 1)))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"paged_attention_decode"}
+    # the tick-finding patterns: the block table is the call's first operand
+    call = re.search(r"tpu_custom_call.*", lowered.as_text()).group(0)
+    assert re.search(rf"\(tensor<{b}x{mp}xi32>", call), call[:300]
+    if described_v5e is not None:
+        lowered.compile()
+
+
+@pytest.mark.parametrize("tokens", [256, 2048])
+def test_chunk_attention_by_blocks_compiles_at_the_cells_geometry(
+        as_tpu, described_v5e, tokens):
+    from paddle_tpu.kernels.prefill_attention import chunk_attention
+    bf16, keys = jnp.bfloat16, 192 * 16
+    shapes = _shapes(described_v5e, ((1, tokens, 32, 128), bf16),
+                     ((1, 4, keys, 128), bf16), ((1, 4, keys, 128), bf16),
+                     ((1,), jnp.int32), ((1,), jnp.int32))
+    fn = jax.jit(lambda q, k, v, qp, kp: chunk_attention(
+        q, k, v, qp, kp, block=4))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"paged_attention_prefill"}
+    if described_v5e is not None:
+        lowered.compile()
+
+
+def test_few_rows_expert_kernel_compiles_at_a_blocks_rows(as_tpu,
+                                                           described_v5e):
+    """64 rows a forward (16 slots x 4) of 8 picks each: the list of
+    experts hit is all 128."""
+    from paddle_tpu.kernels.moe_experts import (moe_decode_problems,
+                                                moe_experts_decode)
+    bf16, e, d, f, rows = jnp.bfloat16, 128, 2048, 768, 64
+    assert not moe_decode_problems(rows, d, f, bf16)
+    shapes = _shapes(described_v5e, ((rows, d), bf16), ((e, d, f), bf16),
+                     ((e, d, f), bf16), ((e, f, d), bf16),
+                     ((rows, 8), jnp.int32), ((rows, 8), jnp.float32))
+    fn = jax.jit(moe_experts_decode)
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"moe_experts_decode"}
+    if described_v5e is not None:
+        lowered.compile()
+
+
+def test_block_diffusion_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.models.block_diffusion_moe import (
+        BlockDiffusionMoeConfig, BlockDiffusionMoeForCausalLM)
+    from paddle_tpu.nn.layer import moe as moe_layer
+    monkeypatch.setattr(moe_layer, "on_tpu", lambda: True)
+    layers, b, mp, blk = 2, 16, 192, 4
+    paddle_tpu.seed(0)
+    model = BlockDiffusionMoeForCausalLM(BlockDiffusionMoeConfig(
+        vocab_size=512, num_hidden_layers=layers, num_experts=16,
+        hidden_size=256, moe_intermediate_size=128, mask_token_id=511,
+        denoising_steps=2))
+    model = paddle_tpu.amp.decorate(models=model, level="O2",
+                                    dtype="bfloat16")
+    model.eval()
+    eng = PagedKVEngine(model, max_slots=b, page_size=16, num_pages=65,
+                        max_pages_per_slot=mp, kernel=None)
+    assert eng.decode_kernel == "pallas" and eng.kv_write == "pallas"
+    assert eng.block_length == blk and eng._chunk_kernel
+    assert eng.decode_plan.grid == (b, 1, 24)
+    # 1 row x 32 heads x a 3,072-token table: a whole 2,048-token prompt
+    assert eng._prefill_limit(1) == 2048
+    pools = [a for kv in eng.pools for a in kv]
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+    tick = eng._tick_fn(False)
+    # the open block's tokens and which are known, lens, active, limit
+    rows = (z(b, blk), z(b, blk, dt=bool), z(b), z(b, dt=bool), z(b))
+    lowered = _lower_tpu(tick.func, *tick.args, rows, z(b, mp), z(b), key,
+                         np.int32(0), pools)
+    # each kernel traced and lowered once, whatever the forwards of a tick
+    assert _kernel_names(lowered) == {"paged_attention_decode",
+                                      "paged_kv_write",
+                                      "moe_experts_decode"}
+    assert _calls(lowered) == 3
+    text = lowered.as_text()
+    firsts = set(re.findall(r"tpu_custom_call[^\n]*?\(tensor<(\d+x\d+)xi32>",
+                            text))
+    assert f"{b}x{mp}" in firsts, firsts
+    text = lowered.as_text(debug_info=True)
+    for scope in ("denoise", "unmask", "store", "kv_write", "paged_attn",
+                  "moe", "router", "experts"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    prefill = eng._prefill_fn(2048, 1)
+    lowered = _lower_tpu(prefill.func, *prefill.args, z(1, 2048), z(1), z(1),
+                         z(1, mp), pools)
+    # a 2,048-row prefill takes the grouped path, its attention the chunk
+    # kernel under the mask by blocks; the other call writes its K and V
+    assert _kernel_names(lowered) == {"paged_kv_write",
+                                      "paged_attention_prefill"}
+    assert "ragged_dot" in lowered.as_text()
+
+
 # -- no pool is copied: the serve cells' programs compiled for the chip ----
 
 def _pool_copies(compiled, pool):

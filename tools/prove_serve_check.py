@@ -15,7 +15,14 @@ of the weights). Builder `window_attn_moe`: the window mask off (a window
 layer attends over every causal key, as a full layer does), the routing
 bias in the gates (it weighs as well as chooses), the last layer's shared
 expert dropped (its down projection zero in the engine's view of the
-weights).
+weights). Builder `block_diffusion_moe`: the forward that stores a settled
+block left out (its K and V stay the last denoising step's), a prompt
+prefilled under the causal mask, the mask inside a block made causal (a
+denoising step's rows see no later row of their block), the last layer's
+experts skipped; and beside the honest reading how often two confidences
+of a step lie within 1 % of each other, by the reference's own float32
+confidences over the honest tokens (`close_confidences`: where they do,
+bf16 may unmask in another order than the replay).
 Last, the honest engine's tokens against the reference computed in float8
 (e4m3, scaled per tensor: every matrix, and every value the reference
 stores, through its `store`): the nearest precision under the bf16 the
@@ -161,6 +168,32 @@ def main():
             return idx, gates * kw["route_scale"], aux
         return patched(FM, "topk_gating_dropless", weighed_by_the_bias)
 
+    def store_forward_left_out():
+        """The tick's second trace of `_block_forward` is the forward that
+        stores a settled block (the first is the denoising step's, traced
+        once for all steps): it is handed no live row, so it writes
+        nothing."""
+        real = PagedKVEngine._block_forward
+
+        def unstored(self, ids, lens, rows_live, bt, flat):
+            calls["store_forward_left_out"] += 1
+            if calls["store_forward_left_out"] % 2 == 0:
+                rows_live = jnp.zeros_like(rows_live)
+            return real(self, ids, lens, rows_live, bt, flat)
+        return patched(PagedKVEngine, "_block_forward", unstored)
+
+    def causal_where(name, rows_hit):
+        """`paged_attention_update` loses its `block=` for the calls whose
+        rows a slot `rows_hit` takes: those attend causally by position."""
+        real = paged.paged_attention_update
+
+        def causal(q, k, v, cache, state, block=None, **kw):
+            if rows_hit(q.shape[1], block):
+                calls[name] += 1
+                block = None
+            return real(q, k, v, cache, state, block=block, **kw)
+        return patched(paged, "paged_attention_update", causal)
+
     # by family: the faults planted by a patch of the program (name ->
     # the context that plants it) and the matrix of the last layer whose
     # zeros in the engine's view drop a part of the block
@@ -169,6 +202,14 @@ def main():
         "sparse_attn_moe": (
             {"selection_off": selection_off,
              "index_pool_stale_on_decode": index_pool_stale},
+            ("last_experts_skipped",
+             lambda m: m.model.layers[-1].mlp.experts_down_weight)),
+        "block_diffusion_moe": (
+            {"store_forward_left_out": store_forward_left_out,
+             "causal_prefill": lambda: causal_where(
+                 "causal_prefill", lambda s, b: s > b),
+             "causal_inside_the_block": lambda: causal_where(
+                 "causal_inside_the_block", lambda s, b: s == b)},
             ("last_experts_skipped",
              lambda m: m.model.layers[-1].mlp.experts_down_weight)),
         "window_attn_moe": (
@@ -215,6 +256,35 @@ def main():
         return {"max_sd": worst, "mean_sd": mean, "positions": n,
                 # as serve.run decides it, nothing having failed or compiled
                 "correct": bool(worst <= tol), "tokens": seen["tokens"]}
+
+    # built once: one program for every seed's replay
+    replay_steps = jax.jit(
+        lambda p, row: builder.reference.replay(p, cfg, row))
+
+    def close_confidences(model, seed, tokens):
+        """Of the blocks the check generated, the share in which the
+        reference's own confidences of a step lie within 1 % of each other
+        where the order decides something: the last position a step
+        unmasks against the first it leaves masked."""
+        import numpy as np
+        from paddle_tpu.jit.functional import state_arrays
+        c = traffic["check"]
+        prompt = np.random.default_rng(seed + 1).integers(
+            1, cfg["vocab_size"], size=c["prompt_tokens"])
+        ids = np.concatenate([prompt, tokens[:-1]]).astype(np.int32)
+        _rows, steps = replay_steps(state_arrays(model), ids)
+        first = c["prompt_tokens"] // cfg["block_length"]
+        per_step = cfg["block_length"] // len(steps)
+        close = blocks = 0
+        for conf, masked in steps:
+            conf = np.where(np.asarray(masked), np.asarray(conf), -np.inf)
+            ranked = -np.sort(-conf[first:], axis=-1)
+            decides = np.isfinite(ranked[:, per_step]) \
+                if per_step < ranked.shape[1] else np.zeros(len(ranked), bool)
+            a, b = ranked[decides, per_step - 1], ranked[decides, per_step]
+            close += int(np.sum((a - b) <= 0.01 * a))
+            blocks += int(np.sum(decides))
+        return {"steps_that_choose": blocks, "within_1_percent": close}
 
     def check(model, seed, planted=lambda eng: None):
         return check_then(model, seed, lambda: None, planted)
@@ -266,6 +336,11 @@ def main():
             if not calls[name]:
                 raise RuntimeError(f"{name}: the planted function never "
                                    f"ran inside the engine's programs")
+            if name == "store_forward_left_out" and calls[name] != 2:
+                raise RuntimeError(
+                    f"{name}: the tick traced _block_forward "
+                    f"{calls[name]} times, not once a denoising step and "
+                    f"once to store: which trace stores is not known")
             calls[name] = 0
             if name == "window_mask_off":
                 rings_hold_the_context(eng)
@@ -298,6 +373,11 @@ def main():
             release(model)
         model = build(seed)
         read("honest", check(model, seed))
+        if family == "block_diffusion_moe":
+            row["close_confidences"] = close_confidences(
+                model, seed, row["honest_tokens"])
+            print(f"[reading] seed {seed} close_confidences "
+                  f"{json.dumps(row['close_confidences'])}", flush=True)
         if row.pop("first_tokens", row["honest_tokens"]) \
                 != row["honest_tokens"]:
             raise RuntimeError("the honest engine gave other tokens on the "
@@ -339,7 +419,7 @@ def main():
         release(model)
         del model
     for name in table[0]:
-        if name == "seed":
+        if name in ("seed", "close_confidences"):
             continue
         rows = [r for r in table if name in r]
         worst = [r[name]["max_sd"] for r in rows]
