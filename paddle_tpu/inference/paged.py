@@ -126,6 +126,7 @@ import jax.numpy as jnp
 from paddle_tpu.core import compile_cache, jax_compat
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu import observability
+from paddle_tpu.kernels import moe_grouped as _mg
 from paddle_tpu.kernels import paged_attention as _pk
 from paddle_tpu.kernels.prefill_attention import (chunk_attention,
                                                   chunk_attention_problems)
@@ -1490,7 +1491,12 @@ class PagedKVEngine:
                       "readback_s": 0.0, "tick_max_s": 0.0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefix_hit_tokens": 0, "prefix_pages_shared": 0,
-                      "prefix_evictions": 0}
+                      "prefix_evictions": 0,
+                      # prefill programs' calls of the grouped expert
+                      # kernel, their rows, and the bounds on their
+                      # (m-tile, expert) visits (_note_grouped)
+                      "moe_grouped_calls": 0, "moe_grouped_rows": 0,
+                      "moe_grouped_visits_bound": 0}
         if self.index_dim:
             # decode steps of live slots, and those whose context had
             # outgrown topk, so that the selection chose among its keys
@@ -1630,6 +1636,11 @@ class PagedKVEngine:
         registry.set_gauge("engine.decode_grid_steps",
                            self.decode_plan.grid_steps
                            if self.decode_plan else 0)
+        for plan in {p for fn in self._programs.values()
+                     for p in getattr(fn, "grouped_plans", ())}:
+            registry.set_gauge("moe.grouped_plan", plan.visits,
+                               m=plan.m, e=plan.e, tm=plan.tm, tk=plan.tk,
+                               tn=plan.tn)
         # _pending is swapped by the ticker under _lock; an unguarded
         # len() here races the swap (found by the guarded-field
         # analyzer pass — the same shape as the PR 12 quota bypass)
@@ -2709,6 +2720,16 @@ class PagedKVEngine:
             s["prefill_rows_split"] += rows
         s["prefill_s"] += time.perf_counter() - t0
 
+    def _note_grouped(self, fn, calls):
+        """Count `calls` calls of the program `fn` into the grouped
+        expert kernel's counters: each call runs every kernel call its
+        trace recorded."""
+        s = self.stats
+        for p in fn.grouped_plans:
+            s["moe_grouped_calls"] += calls
+            s["moe_grouped_rows"] += calls * p.m
+            s["moe_grouped_visits_bound"] += calls * p.visits
+
     def _prefill_chunked_group(self, grp, chunk=None):
         """Feed long prompts through the fixed-size chunk program in
         LOCKSTEP rounds — the paged core appends at lens>0 (the
@@ -2769,6 +2790,7 @@ class PagedKVEngine:
             # back to back, none waits for the host to read the one before
             final_logits = [np.asarray(last)[j] for last, j in final_logits]
             self._note_prefills(len(grp), bw, calls, padded, t0)
+            self._note_grouped(fn, calls)
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_end", rid=req.rid)
@@ -2851,6 +2873,7 @@ class PagedKVEngine:
                          for row in np.asarray(out)[:len(part)]]
             self._note_prefills(len(grp), bw, len(calls),
                                 bw * len(calls) - len(grp), t0)
+            self._note_grouped(fn, len(calls))
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_end", rid=req.rid)
@@ -3135,10 +3158,10 @@ class PagedKVEngine:
                 with observability.span("engine.tick.readback"):
                     toks_np = np.asarray(flight.toks)       # (b, n)
                     lens_np = np.asarray(flight.carry[-3])
-                    for name, v in flight.counted.items():
-                        self.stats[name] = self.stats.get(name, 0) + int(v)
+                    counted = {name: int(v)
+                               for name, v in flight.counted.items()}
                 marks.append(clock())
-                self._ticked(marks)
+                self._ticked(marks, counted)
                 # what each slot took, from the host's rows as the tick
                 # before left them: the carry the device started from
                 counts = np.zeros(self.max_slots, np.int32)
@@ -3187,9 +3210,15 @@ class PagedKVEngine:
         self._note_tick(marks, len(live), pre_s, pre_n)
         return bool(live) or flight is not None
 
-    def _ticked(self, marks):
-        """Count one decode tick whose read back has just ended."""
-        self.stats["ticks"] += 1
+    def _ticked(self, marks, counted=None):
+        """Count one decode tick whose read back has just ended, with
+        the counts its program returned (`counted`, read back already):
+        in one update, so that a copy of the stats taken on another
+        thread (the benchmark's window) never holds a tick's counts
+        without the tick."""
+        s = self.stats
+        s.update({k: s.get(k, 0) + v for k, v in (counted or {}).items()},
+                 ticks=s["ticks"] + 1)
         self.stats["tick_s"] += marks[-1] - marks[3]    # upload .. readback
         self.stats["kv_write_kernel_ticks"] += self.kv_write == "pallas"
         if observability.ENABLED:
@@ -3475,17 +3504,24 @@ class PagedKVEngine:
         if self._weights is None:
             self._weights = [state_arrays(m) for m in models]
 
+        plans = []
+
         def traced(weights, *args):
             with contextlib.ExitStack() as stack:
                 stack.enter_context(decode_kernel_scope(
                     self.decode_kernel, self._kernel_interpret))
+                stack.enter_context(_mg.recording(plans))
                 for m, w in zip(models, weights):
                     stack.enter_context(_swapped(m, w))
                 return run(*args)
 
         fn = jax.jit(traced, donate_argnums=tuple(
             i + 1 for i in donate) if self._on_tpu else ())
-        return functools.partial(fn, self._weights)
+        call = functools.partial(fn, self._weights)
+        # the grouped expert kernel's plan of each call the trace holds
+        # (kernels/moe_grouped.py `grouped_plan`), filled when it traces
+        call.grouped_plans = plans
+        return call
 
     def _prefill_fn(self, ppad, bw=1):
         """Bucketed prefill program. `lens` is the per-row start

@@ -43,9 +43,13 @@ __all__ = ["moe_experts_decode", "moe_decode_problems", "experts_hit",
 # expert, so past the rows at which every expert is hit the work grows
 # with rows x experts where the grouped path's grows with rows. Measured
 # on a v5e at 128 experts of 2048 x 768 in bf16, 8 a row, one layer, ms
-# a call, kernel / grouped (PERF.md section 6, PR 29): 8 rows 1.31 / 1.65,
-# 32: 2.18 / 3.52, 128: 2.28 / 5.13, 512: 4.00 / 5.50, 1,024: 7.25 / 5.98
-MAX_ROWS = 512
+# a call, this kernel / the grouped path through the grouped kernel
+# (kernels/moe_grouped.py) / through ragged_dot (PERF.md section 6, PR
+# 37): 128 rows 1.66 / 1.78 / 4.47, 256: 1.76 / 1.88 / 4.58, 320: 2.14 /
+# 1.95 / 4.64, 384: 2.54 / 1.98 / 4.67, 512: 3.36 / 2.06 / 4.81, 1,024:
+# 6.60 / 2.44 / 5.27; the crossover lies between 256 and 320 (PR 29,
+# against ragged_dot alone: 8 rows 1.31 / 1.65, 32: 2.18 / 3.52)
+MAX_ROWS = 256
 # what the double-buffered weight tiles of a step may take of VMEM: under
 # the 16 MiB a v5e kernel is scoped to by default, with room for x, the
 # gates, the output block and the float32 temporaries

@@ -248,7 +248,26 @@ def moe_dropless_mlp_ep_local(xt, router_w, wg, wu, wd, k, axis_name,
     return out, aux
 
 
-def moe_held_experts(rows, ids, wg, wu, wd, first):
+def ragged_swiglu(x, wg, wu, wd, group_sizes):
+    """silu(x wg[i]) * (x wu[i]) wd[i] on each row of group i, by XLA's
+    grouped matmul (`jax.lax.ragged_dot`): x (N, D) sorted by expert,
+    group_sizes (E,) int32, wg/wu (E, D, F), wd (E, F, D) -> (N, D),
+    of which the rows past the last group are no one's. The grouped
+    path's SwiGLU wherever the Pallas kernel (`kernels/moe_grouped.py`
+    `moe_grouped`, the same contract) is not taken: off a TPU, under a
+    gradient, in the expert-parallel exchange."""
+    # said outright for half-width operands: the TPU's grouped matmul
+    # refuses them under an ambient "highest"
+    prec = (jax.lax.Precision.DEFAULT
+            if x.dtype in (jnp.bfloat16, jnp.float16) else None)
+    rdot = lambda a, w: jax.lax.ragged_dot(                 # noqa: E731
+        a, w.astype(x.dtype), group_sizes, precision=prec)
+    a, b = rdot(x, wg), rdot(x, wu)
+    act = jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype) * b
+    return rdot(act, wd)
+
+
+def moe_held_experts(rows, ids, wg, wu, wd, first, swiglu=ragged_swiglu):
     """What the experts HELD here give for rows that name their expert by
     its global id: wg/wu (E_held, D, F), wd (E_held, F, D) are experts
     [first, first + E_held) of all; a row whose expert is held elsewhere
@@ -260,7 +279,8 @@ def moe_held_experts(rows, ids, wg, wu, wd, first):
 
     rows (N, D), ids (N,) int -> (N, D). The rows are sorted by expert,
     those of experts held elsewhere last, behind every group: the grouped
-    matmul is given the held experts' group sizes alone, and what it
+    matmul (`swiglu`: `ragged_swiglu`, or the Pallas kernel with its
+    contract) is given the held experts' group sizes alone, and what it
     leaves in the rows past them is set to zero."""
     e_l = wg.shape[0]
     le = ids.astype(jnp.int32) - first
@@ -269,22 +289,17 @@ def moe_held_experts(rows, ids, wg, wu, wd, first):
     rx = jnp.take(rows, order, axis=0)
     sorted_le = jnp.take(le, order)
     group_sizes = jnp.bincount(le, length=e_l + 1).astype(jnp.int32)[:e_l]
-    # said outright for half-width operands: the TPU's grouped matmul
-    # refuses them under an ambient "highest"
-    prec = (jax.lax.Precision.DEFAULT
-            if rows.dtype in (jnp.bfloat16, jnp.float16) else None)
-    rdot = lambda x, w: jax.lax.ragged_dot(                 # noqa: E731
-        x, w.astype(rows.dtype), group_sizes, precision=prec)
-    a, b = rdot(rx, wg), rdot(rx, wu)
-    act = jax.nn.silu(a.astype(jnp.float32)).astype(rows.dtype) * b
-    o = jnp.where((sorted_le < e_l)[:, None], rdot(act, wd), 0)
+    o = jnp.where((sorted_le < e_l)[:, None],
+                  swiglu(rx, wg, wu, wd, group_sizes), 0)
     return jnp.take(o, jnp.argsort(order, stable=True), axis=0)
 
 
-def moe_dropless_mlp(xt, wg, wu, wd, idx, gates, first=None):
+def moe_dropless_mlp(xt, wg, wu, wd, idx, gates, first=None,
+                     swiglu=ragged_swiglu):
     """Sort-based grouped-matmul expert MLP with ZERO token drops
     (MegaBlocks-style; TPU-native via jax.lax.ragged_dot — the
-    XLA grouped matmul MaxText uses for dMoE).
+    XLA grouped matmul MaxText uses for dMoE — or, where the layer takes
+    it, `swiglu` = the Pallas grouped kernel, `kernels/moe_grouped.py`).
 
     xt (T, D); wg/wu (E, D, F); wd (E, F, D); idx/gates (T, k).
     All shapes static: the T*k (token, expert) pairs are sorted by
@@ -303,7 +318,7 @@ def moe_dropless_mlp(xt, wg, wu, wd, idx, gates, first=None):
             pairs_x = jnp.repeat(xt, k, axis=0)             # (T*k, D)
         with jax.named_scope("experts"):
             o = moe_held_experts(pairs_x, idx.reshape(-1), wg, wu, wd,
-                                 first)
+                                 first, swiglu)
         with jax.named_scope("combine"):
             return jnp.sum(gates[..., None].astype(xt.dtype)
                            * o.reshape(t, k, d), axis=1)
@@ -314,15 +329,7 @@ def moe_dropless_mlp(xt, wg, wu, wd, idx, gates, first=None):
         sorted_x = jnp.take(xt, tok_of, axis=0)             # (T*k, D)
         group_sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
     with jax.named_scope("experts"):
-        # said outright for half-width operands: the TPU's grouped matmul
-        # refuses them under an ambient "highest"
-        prec = (jax.lax.Precision.DEFAULT
-                if xt.dtype in (jnp.bfloat16, jnp.float16) else None)
-        rdot = lambda x, w: jax.lax.ragged_dot(             # noqa: E731
-            x, w.astype(xt.dtype), group_sizes, precision=prec)
-        a, b = rdot(sorted_x, wg), rdot(sorted_x, wu)
-        act = jax.nn.silu(a.astype(jnp.float32)).astype(xt.dtype) * b
-        o = rdot(act, wd)
+        o = swiglu(sorted_x, wg, wu, wd, group_sizes)
     with jax.named_scope("combine"):
         inv = jnp.argsort(order, stable=True)
         out_rows = jnp.take(o, inv, axis=0).reshape(t, k, d)

@@ -9,6 +9,7 @@ from the shardings.
 """
 from __future__ import annotations
 
+import functools
 import math
 from contextlib import contextmanager
 
@@ -25,20 +26,23 @@ from paddle_tpu.nn.functional import moe as FM
 from paddle_tpu.kernels.moe_experts import (experts_hit, held_ids,
                                             moe_decode_problems,
                                             moe_experts_decode)
+from paddle_tpu.kernels.moe_grouped import grouped_problems, moe_grouped
 
 
 @defop("moe_mlp_dropless", amp_policy="white",
        spmd_note="dropless grouped matmul (ragged_dot): expert dim may "
                  "shard over 'ep' (XLA gathers tokens), token dims over "
                  "dp/sp; prefer the capacity path for ep>1 meshes")
-def _moe_mlp_dropless(x, router_w, wg, wu, wd, k, few_rows=False,
+def _moe_mlp_dropless(x, router_w, wg, wu, wd, k, kernel=None,
                       with_hit=False, router=None, bias=None, first=None):
     """Dropless dMoE forward (MegaBlocks semantics; VERDICT r3 item 5 —
     the reference's capacity gate at moe_layer.py:263 silently drops
     overflow tokens; this path honors every token's top-k exactly).
-    `few_rows` takes the few-rows kernel (kernels/moe_experts.py: a
-    decode step reads each expert hit once) in place of the
-    sort-and-group path. Returns (out, aux_loss), and with `with_hit` the
+    `kernel` "few_rows" takes the few-rows kernel (kernels/moe_experts.py:
+    a decode step reads each expert hit once) in place of the
+    sort-and-group path, "grouped" the Pallas grouped kernel
+    (kernels/moe_grouped.py) in place of that path's `ragged_dot`
+    (`MoEMLP._kernel`). Returns (out, aux_loss), and with `with_hit` the
     count of distinct experts the rows hit as a third.
 
     `router` / `bias`: the router's options beyond softmax top-k
@@ -65,13 +69,17 @@ def _moe_mlp_dropless(x, router_w, wg, wu, wd, k, few_rows=False,
         if with_hit and share:
             hit = jnp.stack([hit, jnp.sum(local < wg.shape[0])]).astype(
                 jnp.int32)
-    if few_rows:
+    if kernel == "few_rows":
         with jax.named_scope("experts"):
             out = moe_experts_decode(xt, wg, wu, wd, local, gates,
                                      share=share)
     else:
+        grouped = {"swiglu": functools.partial(
+            moe_grouped, routed=router_w.shape[1])} \
+            if kernel == "grouped" else {}
         out = FM.moe_dropless_mlp(xt, wg, wu, wd, idx, gates,
-                                  **({"first": first} if share else {}))
+                                  **({"first": first} if share else {}),
+                                  **grouped)
     out = out.reshape(*lead, d)
     return (out, aux, hit) if with_hit else (out, aux)
 
@@ -234,15 +242,19 @@ class MoEMLP(Layer):
             [e, f, d], default_initializer=init)
         self.aux_loss = None
 
-    def _few_rows(self, x):
-        """Whether this call's experts run in the few-rows Pallas kernel:
-        on a TPU, forward only (the kernel has no gradient), whenever the
-        shapes are a decode step's (`moe_decode_problems`); everywhere
-        else the grouped path."""
+    def _kernel(self, x):
+        """Which Pallas kernel this call's experts run in: on a TPU,
+        forward only (neither kernel has a gradient), "few_rows" whenever
+        the shapes are a decode step's (`moe_decode_problems`), else
+        "grouped" wherever the widths allow it (`grouped_problems`); None
+        (the grouped path through `ragged_dot`) everywhere else."""
+        if not on_tpu() or self.training:
+            return None
         w = self.experts_gate_weight
-        return on_tpu() and not self.training and not moe_decode_problems(
-            math.prod(x.shape[:-1]), x.shape[-1], w.shape[-1],
-            w._value.dtype)
+        d, f, dt = x.shape[-1], w.shape[-1], w._value.dtype
+        if not moe_decode_problems(math.prod(x.shape[:-1]), d, f, dt):
+            return "few_rows"
+        return None if grouped_problems(d, f, dt) else "grouped"
 
     def forward(self, x, with_hit=False):
         """`with_hit` (dropless, forward only: an integer output has no
@@ -274,9 +286,8 @@ class MoEMLP(Layer):
             out, aux, *hit = _moe_mlp_dropless(
                 x, self.router_weight, self.experts_gate_weight,
                 self.experts_up_weight, self.experts_down_weight,
-                k=self.top_k, few_rows=self._few_rows(x),
-                with_hit=with_hit, router=self.router,
-                bias=self.expert_bias,
+                k=self.top_k, kernel=self._kernel(x), with_hit=with_hit,
+                router=self.router, bias=self.expert_bias,
                 first=None if self.held is None else self.held[0])
         else:
             out, aux = _moe_mlp(x, self.router_weight,

@@ -506,6 +506,10 @@ METRICS = {
     "engine.decode_grid_steps": ("gauge", "grid steps of one paged-"
                                  "decode kernel call (engine."
                                  "decode_plan; 0 on the jnp path)"),
+    "moe.grouped_plan": ("gauge", "bound on the (m-tile, expert) visits "
+                         "of one grouped expert kernel call, a series "
+                         "a plan its programs traced (labels m, e, tm, "
+                         "tk, tn: kernels/moe_grouped.py grouped_plan)"),
 }
 
 

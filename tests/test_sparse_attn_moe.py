@@ -253,9 +253,9 @@ def test_expert_layer_returns_its_hit_count_and_keeps_none():
     assert int(hit) == len(np.unique(np.asarray(idx)))
     assert np.allclose(np.asarray(out._value), np.asarray(layer(x)._value))
     assert not hasattr(layer, "experts_hit")
-    # off the chip the grouped path is the only one; the count belongs to
-    # the dropless path
-    assert not layer._few_rows(x)
+    # off the chip the grouped path through ragged_dot is the only one;
+    # the count belongs to the dropless path
+    assert layer._kernel(x) is None
     with pytest.raises(NotImplementedError):
         MoEMLP(32, 16, 8, top_k=2)(x, with_hit=True)
 

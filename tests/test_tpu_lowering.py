@@ -218,10 +218,10 @@ def test_every_pallas_call_site_passes_a_literal_name():
                     f"{path}:{node.lineno}: pallas_call without a " \
                     f"literal name="
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 16
+    assert len(names) == len(set(names)) == 17
     assert set().union(*_NAMES.values()) \
         | {"flash_bwd_dq", "moe_experts_decode",
-           "paged_attention_prefill"} == set(names)
+           "paged_attention_prefill", "moe_grouped"} == set(names)
 
 
 def _trainer(mesh=None, **cfg_kw):
@@ -412,6 +412,7 @@ def test_paged_decode_under_a_selection_compiles_at_the_cells_geometry(
 def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     import paddle_tpu
     from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.kernels.moe_grouped import grouped_plan
     from paddle_tpu.models.sparse_attn_moe import (SparseAttnMoeConfig,
                                                    SparseAttnMoeForCausalLM)
     from paddle_tpu.nn.layer import moe as moe_layer
@@ -453,14 +454,19 @@ def test_indexed_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     chunk = eng._prefill_chunk_fn(1024, 1)
     lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 1024), z(1), z(1),
                          z(1, mp), pools)
-    # a prefill's rows take the grouped path, its attention the jnp one;
-    # the one call writes the chunk's K and V
-    assert _calls(lowered) == 1
-    assert _kernel_names(lowered) == {"paged_kv_write"}
+    # a prefill's rows take the grouped kernel, its attention the jnp one;
+    # the other call writes the chunk's K and V
+    assert _calls(lowered) == 2
+    assert _kernel_names(lowered) == {"paged_kv_write", "moe_grouped"}
+    # the plan of each layer's call, from the shapes: 8,192 pairs over 16
+    # experts, 512 rows an expert
+    assert chunk.grouped_plans == [
+        grouped_plan(1024 * 8, 16, 256, 128, jnp.bfloat16)] * layers
+    assert chunk.grouped_plans[0].tm == 512
     text = lowered.as_text(debug_info=True)
     for scope in ("dispatch", "experts", "combine", "select"):
         assert re.search(rf'[/("]{scope}[/)"]', text), scope
-    assert "ragged_dot" in lowered.as_text()
+    assert "ragged_dot" not in lowered.as_text()
 
 
 # -- the decoder of window and full layers with a share of its experts, at
@@ -603,16 +609,17 @@ def test_window_moe_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     chunk = eng._prefill_chunk_fn(4096, 1)
     lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 4096), z(1), z(1),
                          (z(1, mp), z(1, 513)), pools)
-    # a 4,096-row chunk takes the grouped path at the share, its attention
-    # the chunk kernel (over a ring's view, and over the full table from
-    # its start); the other calls write the chunk's K and V
+    # a 4,096-row chunk takes the grouped kernel at the share, its
+    # attention the chunk kernel (over a ring's view, and over the full
+    # table from its start); the other calls write the chunk's K and V
     assert _kernel_names(lowered) == {"paged_kv_write",
-                                      "paged_attention_prefill"}
+                                      "paged_attention_prefill",
+                                      "moe_grouped"}
     text = lowered.as_text(debug_info=True)
     for scope in ("dispatch", "experts", "combine", "window",
                   "shared_expert"):
         assert re.search(rf'[/("]{scope}[/)"]', text), scope
-    assert "ragged_dot" in lowered.as_text()
+    assert "ragged_dot" not in lowered.as_text()
 
 
 # -- the decoder that generates by diffusion over blocks, at the widths of
@@ -726,11 +733,13 @@ def test_block_diffusion_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     prefill = eng._prefill_fn(2048, 1)
     lowered = _lower_tpu(prefill.func, *prefill.args, z(1, 2048), z(1), z(1),
                          z(1, mp), pools)
-    # a 2,048-row prefill takes the grouped path, its attention the chunk
-    # kernel under the mask by blocks; the other call writes its K and V
+    # a 2,048-row prefill takes the grouped kernel, its attention the
+    # chunk kernel under the mask by blocks; the other call writes its K
+    # and V
     assert _kernel_names(lowered) == {"paged_kv_write",
-                                      "paged_attention_prefill"}
-    assert "ragged_dot" in lowered.as_text()
+                                      "paged_attention_prefill",
+                                      "moe_grouped"}
+    assert "ragged_dot" not in lowered.as_text()
 
 
 # -- no pool is copied: the serve cells' programs compiled for the chip ----
