@@ -823,6 +823,7 @@ class _Request:
         self.session = None         # conversation id (tiered KV; set
         #                             by submit — keys suspend/resume)
         self.queued_at = time.monotonic()   # per-tenant queue-wait clock
+        self.admitted_at = None     # same clock, when it took a slot
         self.tokens: list[int] = []          # accepted generated tokens
         self.queue: queue.Queue = queue.Queue()
         self.done = threading.Event()
@@ -1483,12 +1484,20 @@ class PagedKVEngine:
                       "prefill_rows_split": 0,
                       "admitted": 0, "finished": 0, "cancelled": 0,
                       "expired": 0, "overloaded": 0,
-                      "prefill_s": 0.0, "tick_s": 0.0,
+                      # the prefills' wall, and the ticker's CPU time
+                      # over the same intervals (time.thread_time)
+                      "prefill_s": 0.0, "prefill_cpu_s": 0.0,
                       # always on, by the tick's own clock (_note_tick):
                       # tick_wall_s = tick_host_s + readback_s + the
-                      # prefill_s spent inside ticks
+                      # prefill_s spent inside ticks; of tick_host_s,
+                      # tick_host_wait_s is what the ticker spent off a
+                      # CPU (the interpreter lock, the engine lock, the OS)
                       "tick_wall_s": 0.0, "tick_host_s": 0.0,
+                      "tick_host_wait_s": 0.0,
                       "readback_s": 0.0, "tick_max_s": 0.0,
+                      # of requests retired from a slot: the time from
+                      # submit to the slot, and from submit to the end
+                      "queue_wait_s": 0.0, "request_s": 0.0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "prefix_hit_tokens": 0, "prefix_pages_shared": 0,
                       "prefix_evictions": 0,
@@ -1523,8 +1532,9 @@ class PagedKVEngine:
         self._model_counts = bool(getattr(model, "decode_counter_keys", ()))
         # one row a tick, the newest 256: (seq, start by perf_counter,
         # the TICK_PHASES' seconds in order, live slots, prefills
-        # admitted) — what says which phase a slow tick spent it in
-        # when no profiler was running
+        # admitted, the ticker's CPU seconds over the host phases) —
+        # what says which phase a slow tick spent it in, and whether the
+        # ticker was working, when no profiler was running
         self.tick_log: collections.deque = collections.deque(maxlen=256)
         # serving integration: PredictorServer must not serialize
         # concurrent streams through its executable lock — the engine's
@@ -1633,6 +1643,8 @@ class PagedKVEngine:
         registry.set_gauge("engine.overloaded", s["overloaded"])
         registry.set_gauge("engine.tick_max_seconds", s["tick_max_s"])
         registry.set_gauge("engine.tick_host_seconds", s["tick_host_s"])
+        registry.set_gauge("engine.tick_host_wait_seconds",
+                           s["tick_host_wait_s"])
         registry.set_gauge("engine.decode_grid_steps",
                            self.decode_plan.grid_steps
                            if self.decode_plan else 0)
@@ -2587,6 +2599,7 @@ class PagedKVEngine:
                 slot.pages.append(p)
             self._alloc_pages(idx, -(-req.prompt.size // self.page_size))
             self.stats["admitted"] += 1
+            req.admitted_at = time.monotonic()
             if self.tenancy is not None:
                 with self._lock:
                     self._queued_dec_locked(req)
@@ -2711,14 +2724,15 @@ class PagedKVEngine:
     def _note_prefills(self, rows, bw, calls, padded, t0):
         """Count a group of `rows` prompts that went through `calls`
         calls of width `bw`, `padded` of their rows padding, since
-        `t0`."""
+        `t0`: the (perf_counter, thread_time) readings at its start."""
         s = self.stats
         s["prefills"] += rows
         s["prefill_rows_run"] += bw * calls
         s["prefill_rows_padded"] += padded
         if rows > 1 and bw == 1:
             s["prefill_rows_split"] += rows
-        s["prefill_s"] += time.perf_counter() - t0
+        s["prefill_s"] += time.perf_counter() - t0[0]
+        s["prefill_cpu_s"] += time.thread_time() - t0[1]
 
     def _note_grouped(self, fn, calls):
         """Count `calls` calls of the program `fn` into the grouped
@@ -2753,7 +2767,7 @@ class PagedKVEngine:
         with observability.span("engine.prefill", bucket=chunk,
                                 rows=len(grp), group=bw,
                                 chunks=max(rounds), calls=calls):
-            t0 = time.perf_counter()
+            t0 = time.perf_counter(), time.thread_time()
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_start", rid=req.rid)
@@ -2830,7 +2844,7 @@ class PagedKVEngine:
         with observability.span("engine.prefill", bucket=ppad,
                                 rows=len(grp), group=bw, chunks=1,
                                 calls=len(calls)):
-            t0 = time.perf_counter()
+            t0 = time.perf_counter(), time.thread_time()
             for _idx, req in grp:
                 if req.obs is not None:
                     req.obs.record("prefill_start", rid=req.rid)
@@ -2936,6 +2950,15 @@ class PagedKVEngine:
             self._inflight -= 1
         if not cancelled:
             self.stats["finished"] += 1      # cancelled counts separately
+        req = slot.req
+        if req.admitted_at is not None:
+            # in one update, so that a copy of the stats taken on another
+            # thread never holds the one without the other
+            s = self.stats
+            s.update(queue_wait_s=s["queue_wait_s"]
+                     + req.admitted_at - req.queued_at,
+                     request_s=s["request_s"]
+                     + time.monotonic() - req.queued_at)
         if slot.req.obs is not None:
             slot.req.obs.engine_finish(
                 reason or ("cancelled" if cancelled else "finished"))
@@ -3026,9 +3049,9 @@ class PagedKVEngine:
     def _step_tick(self, ahead):
         """One tick under its spans (observability/trace.py SPANS) and
         its own clock: `engine.tick` holds the TICK_PHASES in order,
-        `marks` the perf_counter reading at each boundary. The
-        counters and `tick_log` are always on (`_note_tick`); the
-        spans show in any profiler capture.
+        `marks` the perf_counter and the ticker's thread_time readings
+        at each boundary. The counters and `tick_log` are always on
+        (`_note_tick`); the spans show in any profiler capture.
 
         An iteration lands exactly one decode tick (read back, accept):
         the one in flight, or with none in flight the one it launches
@@ -3055,14 +3078,15 @@ class PagedKVEngine:
                 if self.suspend_after_s is not None:
                     self._suspend_sweep()
                 return False
-        clock = time.perf_counter
-        pre_s, pre_n = self.stats["prefill_s"], self.stats["prefills"]
+        clock, cpu = time.perf_counter, time.thread_time
+        pre = {k: self.stats[k] for k in ("prefill_s", "prefill_cpu_s",
+                                          "prefills")}
         n = self.steps_per_tick
         with observability.span(
                 "engine.tick", seq=self._step_seq,
                 **({"blocks": n // self.block_length}
                    if self.block_length else {})):
-            marks = [clock()]
+            marks = [(clock(), cpu())]
             with observability.span("engine.tick.retire"):
                 retired = 0
                 for i, slot in enumerate(self._slots):
@@ -3072,11 +3096,11 @@ class PagedKVEngine:
                         retired += 1
                 if self.suspend_after_s is not None:
                     self._suspend_sweep()
-            marks.append(clock())
+            marks.append((clock(), cpu()))
             with observability.span("engine.tick.admit"):
                 if flight is None:
                     self._admit()
-            marks.append(clock())
+            marks.append((clock(), cpu()))
             if flight is None:
                 live = [i for i, s in enumerate(self._slots)
                         if s is not None]
@@ -3113,7 +3137,7 @@ class PagedKVEngine:
                             self._alloc_pages(i, -(-need // self.page_size))
                     if flight is None:
                         a = self._slot_arrays(live)
-                marks.append(clock())
+                marks.append((clock(), cpu()))
                 with observability.span("engine.tick.upload"):
                     if flight is None:
                         any_sample = bool(a["wants"].any())
@@ -3134,7 +3158,7 @@ class PagedKVEngine:
                         sent = self._tables()
                         args = args._replace(bt=jax.tree.map(jnp.asarray, sent),
                                              bt_sent=sent)
-                marks.append(clock())
+                marks.append((clock(), cpu()))
                 with observability.span("engine.tick.launch"):
                     pairs = [(i, self._slots[i].req) for i in live]
                     newest = flight
@@ -3154,14 +3178,14 @@ class PagedKVEngine:
                         if flight is None:
                             flight = newest
                     self.stats["ticks_chained"] += chain
-                marks.append(clock())
+                marks.append((clock(), cpu()))
                 with observability.span("engine.tick.readback"):
                     toks_np = np.asarray(flight.toks)       # (b, n)
                     lens_np = np.asarray(flight.carry[-3])
                     counted = {name: int(v)
                                for name, v in flight.counted.items()}
-                marks.append(clock())
-                self._ticked(marks, counted)
+                marks.append((clock(), cpu()))
+                self._ticked(counted)
                 # what each slot took, from the host's rows as the tick
                 # before left them: the carry the device started from
                 counts = np.zeros(self.max_slots, np.int32)
@@ -3199,7 +3223,7 @@ class PagedKVEngine:
                     # rows of a request retired since the launch (ended
                     # in the tick before, cancelled) are not in `live`
                     self._accept_tick(live, toks_np, counts, eos, lens_np)
-                marks.append(clock())
+                marks.append((clock(), cpu()))
                 if not live:
                     # every slot was dead in this tick (or cancelled
                     # under it): the index it took goes to the next, as
@@ -3207,10 +3231,10 @@ class PagedKVEngine:
                     self._tick_count -= 1
                 if newest is not flight:
                     self._flying = newest
-        self._note_tick(marks, len(live), pre_s, pre_n)
+        self._note_tick(marks, len(live), pre)
         return bool(live) or flight is not None
 
-    def _ticked(self, marks, counted=None):
+    def _ticked(self, counted=None):
         """Count one decode tick whose read back has just ended, with
         the counts its program returned (`counted`, read back already):
         in one update, so that a copy of the stats taken on another
@@ -3219,7 +3243,6 @@ class PagedKVEngine:
         s = self.stats
         s.update({k: s.get(k, 0) + v for k, v in (counted or {}).items()},
                  ticks=s["ticks"] + 1)
-        self.stats["tick_s"] += marks[-1] - marks[3]    # upload .. readback
         self.stats["kv_write_kernel_ticks"] += self.kv_write == "pallas"
         if observability.ENABLED:
             observability.inc("inference.decode.kernel",
@@ -3231,7 +3254,7 @@ class PagedKVEngine:
         """Speculative tick: greedy AND sampled slots ride it together
         (per-slot regimes in-graph; _spec_tick_fn doc). The same phases
         as the plain tick's, their clock marks appended to `marks`."""
-        clock = time.perf_counter
+        clock, cpu = time.perf_counter, time.thread_time
         g = self.spec_tokens
         with observability.span("engine.tick.alloc"):
             for i in live:
@@ -3240,7 +3263,7 @@ class PagedKVEngine:
                 need = min(slot.lens + g + 1, budget)
                 self._alloc_pages(i, -(-need // self.page_size))
             a = self._slot_arrays(live)
-        marks.append(clock())
+        marks.append((clock(), cpu()))
         with observability.span("engine.tick.upload"):
             fn = self._spec_tick_fn(bool(a["wants"].any()))
             key = jax.random.fold_in(self._key, self._tick_count)
@@ -3249,7 +3272,7 @@ class PagedKVEngine:
                     jax.random.key_data(key), jnp.asarray(a["temp"]),
                     jnp.asarray(a["topk"]), jnp.asarray(a["topp"]),
                     jnp.asarray(a["wants"])]
-        marks.append(clock())
+        marks.append((clock(), cpu()))
         with observability.span("engine.tick.launch"):
             out, n_emit, lens_f, tflat, dflat = fn(
                 *args, [x for kv in self.pools for x in kv],
@@ -3257,13 +3280,13 @@ class PagedKVEngine:
             self.pools = self._unflat_pools(tflat)
             self.draft_pools = self._unflat_pools(dflat)
             self._tick_count += 1
-        marks.append(clock())
+        marks.append((clock(), cpu()))
         with observability.span("engine.tick.readback"):
             out_np = np.asarray(out)
             emit_np = np.asarray(n_emit)
             lens_np = np.asarray(lens_f)
-        marks.append(clock())
-        self._ticked(marks)
+        marks.append((clock(), cpu()))
+        self._ticked()
         self.stats["spec_ticks"] = self.stats.get("spec_ticks", 0) + 1
         self.stats["spec_proposed"] = (self.stats.get("spec_proposed", 0)
                                        + g * len(live))
@@ -3273,25 +3296,37 @@ class PagedKVEngine:
         counts = np.minimum(emit_np, a["limit"])
         with observability.span("engine.tick.accept"):
             self._accept_tick(live, out_np, counts, a["eos"], lens_np)
-        marks.append(clock())
+        marks.append((clock(), cpu()))
 
-    def _note_tick(self, marks, live, pre_s, pre_n):
+    def _note_tick(self, marks, live, pre):
         """The always-on record of one tick from its clock marks: the
-        counters and one `tick_log` row. A tick that found no live
-        slot after admission has the first two phases only; the rest
-        read 0."""
-        phases = [b - a for a, b in zip(marks, marks[1:])]
-        phases += [0.0] * (len(TICK_PHASES) - len(phases))
+        counters and one `tick_log` row. `pre` holds the prefill
+        counters as the tick began. A tick that found no live slot after
+        admission has the first two phases only; the rest read 0."""
+        phases, cpu_phases = ([b - a for a, b in zip(x, x[1:])]
+                              + [0.0] * (len(TICK_PHASES) + 1 - len(x))
+                              for x in zip(*marks))
         s = self.stats
-        wall, readback = marks[-1] - marks[0], phases[5]
-        s["tick_wall_s"] += wall
-        s["readback_s"] += readback
+        wall, readback = sum(phases), phases[5]
         # what the host spent while the device could not be working
-        # for this engine: the wall less the waits on the device
-        s["tick_host_s"] += wall - readback - (s["prefill_s"] - pre_s)
-        s["tick_max_s"] = max(s["tick_max_s"], wall)
-        self.tick_log.append((self._step_seq, marks[0], *phases, live,
-                              s["prefills"] - pre_n))
+        # for this engine: the wall less the waits on the device; and
+        # the ticker's CPU time over the same phases
+        host = wall - readback - (s["prefill_s"] - pre["prefill_s"])
+        host_cpu = (sum(cpu_phases) - cpu_phases[5]
+                    - (s["prefill_cpu_s"] - pre["prefill_cpu_s"]))
+        # in one update, so that a copy of the stats taken on another
+        # thread never holds a tick's wait without its host time. The
+        # wait is not clamped at 0 a tick: where the thread's CPU clock
+        # advances in scheduler ticks (10 ms on the v5e host) a tick's
+        # CPU reads 0 or 10 ms, so only a sum over many ticks is sound,
+        # and a clamp would count every tick read as 0 as all wait
+        s.update(tick_wall_s=s["tick_wall_s"] + wall,
+                 readback_s=s["readback_s"] + readback,
+                 tick_host_s=s["tick_host_s"] + host,
+                 tick_host_wait_s=s["tick_host_wait_s"] + host - host_cpu,
+                 tick_max_s=max(s["tick_max_s"], wall))
+        self.tick_log.append((self._step_seq, marks[0][0], *phases, live,
+                              s["prefills"] - pre["prefills"], host_cpu))
 
     def run_until_idle(self):
         """Synchronously drain all pending + active requests (tests,

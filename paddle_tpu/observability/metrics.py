@@ -503,6 +503,11 @@ METRICS = {
     "engine.tick_host_seconds": ("gauge", "tick wall seconds spent "
                                  "outside the waits on the device "
                                  "(readback, prefill), cumulative"),
+    "engine.tick_host_wait_seconds": ("gauge", "of engine.tick_host_"
+                                      "seconds, the ticker's wall less "
+                                      "its CPU time: waits for the "
+                                      "interpreter lock, the engine "
+                                      "lock or the OS, cumulative"),
     "engine.decode_grid_steps": ("gauge", "grid steps of one paged-"
                                  "decode kernel call (engine."
                                  "decode_plan; 0 on the jnp path)"),

@@ -27,8 +27,8 @@ annotations in the capture for that.
 
 SPANS is the closed catalogue of span names at call sites, like
 metrics.METRICS: name -> (layer, what it covers, the per-layer metric
-designed to read it; benchmarks/spans.py holds the readers, PERF.md
-says which are registered). tools/check_metric_names.py holds call
+that reads it, or none; benchmarks/spans.py holds the readers and the
+report of every span). tools/check_metric_names.py holds call
 sites to it.
 
 Spans nest naturally: chrome-trace "X" (complete) events reconstruct
@@ -51,8 +51,9 @@ __all__ = ["Span", "SPANS", "annotation", "step_annotation",
            "record_span", "set_ring_capacity", "ring_capacity",
            "spans", "clear", "export_chrome_trace", "chrome_events"]
 
-# name -> (layer, what the span covers, the per-layer metric designed to
-# read it).
+# name -> (layer, what the span covers, the per-layer metric that reads
+# it and the counters beside it; a span no metric reads is in the report
+# of benchmarks/spans.py).
 # The thread is the one that owns the work: the ticker for engine.*,
 # the training loop for train.* and input.wait, the prefetch worker for
 # input.h2d, a handler thread for http.write.
@@ -66,72 +67,81 @@ __all__ = ["Span", "SPANS", "annotation", "step_annotation",
 # accept goes by request; freed pages go to later programs only; errors,
 # stop() and the stall guard see the tick in flight) is PagedKVEngine's
 # class doc.
+_REPORT = "none; benchmarks/spans.py report"
 SPANS = {
     "input.wait": (
         "input", "DevicePrefetcher.__next__ blocked on its queue",
-        "input.prefetch_wait_share (counter DevicePrefetcher.wait_s)"),
+        f"{_REPORT}; counter DevicePrefetcher.wait_s (input.wait_share "
+        "reads the benchmark's own clock around next())"),
     "input.h2d": (
         "input", "the prefetch worker placing one batch on the device",
-        "none yet; io.h2d.seconds times the same work when enabled"),
+        f"{_REPORT}; io.h2d.seconds times the same work when enabled"),
     "train.step": (
         "step", "the whole of Trainer.step; a step span whose step_num "
         "is the optimizer's step count before the step",
         "step.host_dispatch_ms_p50"),
     "train.step.place": (
         "step", "the mesh device_put loop over the batch's leaves",
-        "none yet; a child of train.step"),
+        f"{_REPORT}; a child of train.step"),
     "train.step.dispatch": (
         "step", "the call of the jitted step (trace and compile on a "
         "first call, else the enqueue)",
-        "none yet; a child of train.step"),
+        f"{_REPORT}; a child of train.step"),
     "engine.tick": (
         "scheduler", "one scheduler tick that found work, attr seq and, "
         "where a tick settles whole blocks (a model that generates by "
         "diffusion over blocks: counters block_forwards_denoise, "
         "block_forwards_store, block_positions_unmasked, blocks_done), "
         "attr blocks; its children follow in this order",
-        "sched.tick_host_ms_p50 (counters tick_wall_s, tick_host_s)"),
+        "sched.tick_host_ms_p50; counters tick_wall_s, tick_host_s, "
+        "readback_s, tick_max_s, and tick_host_wait_s (the ticker's "
+        "host phases off a CPU: sched.tick_host_wait_share)"),
     "engine.tick.retire": (
         "scheduler", "the cancel sweep and the session suspend sweep",
-        "serve.idle_in_accept_share"),
+        _REPORT),
     "engine.tick.admit": (
         "scheduler", "_admit(): queue swap, prefix lookups, page "
         "reservations, and the prefills (engine.prefill) inside it",
-        "serve.idle_in_launch_share"),
+        f"{_REPORT}; a request's admitted_at, whose counters queue_wait_s "
+        "and request_s (sched.queue_wait_share) are added as it retires"),
     "engine.tick.alloc": (
         "scheduler", "pages for the tokens of the tick to land and of "
         "the one chained after it, and with nothing in flight the "
-        "per-slot host arrays", "serve.idle_in_launch_share"),
+        "per-slot host arrays", _REPORT),
     "engine.tick.upload": (
         "scheduler", "with nothing in flight the tick program's lookup "
         "and its arguments made device arrays; for a chained tick the "
-        "block table if it changed", "serve.idle_in_launch_share"),
+        "block table if it changed", _REPORT),
     "engine.tick.launch": (
         "scheduler", "the calls of the tick program (the enqueue): the "
-        "tick to land if none flew, the one chained after it (counter "
-        "ticks_chained)", "sched.tick_chained_share"),
+        "tick to land if none flew, the one chained after it",
+        f"{_REPORT}; counter ticks_chained (sched.tick_chained_share)"),
     "engine.tick.readback": (
         "scheduler", "np.asarray of the landed tick's tokens and "
         "lengths: the wait for the device, which by then works on "
-        "the tick launched ahead", "counter readback_s"),
+        "the tick launched ahead",
+        "sched.tick_host_ms_p50 (taken off the tick); counter "
+        "readback_s"),
     "engine.tick.accept": (
         "scheduler", "_accept_tick of the landed tick: tokens to the "
-        "requests' queues, retirements", "serve.idle_in_accept_share"),
+        "requests' queues, retirements", _REPORT),
     "engine.prefill": (
         "scheduler", "the prefill of the prompts admitted together "
         "to one bucket: its program calls (attr calls: one call group "
         "rows wide, or at group 1 a call a row or a chunk, dispatched "
         "back to back) and the one read back after the last, attrs "
         "bucket, rows, group, chunks, calls",
-        "sched.prefill_time_share (counter prefill_s); counters "
-        "prefill_rows_run, prefill_rows_padded, prefill_rows_split"),
+        "sched.tick_host_ms_p50 (taken off the tick); counters "
+        "prefill_s (sched.prefill_time_share), prefill_cpu_s (taken "
+        "off the tick's CPU time), prefill_rows_run, "
+        "prefill_rows_padded, prefill_rows_split"),
     "engine.idle": (
         "scheduler", "the ticker's sleep after a step() with no work",
-        "serve.idle_between_ticks_share"),
+        _REPORT),
     "http.write": (
         "HTTP", "one chunk of a streamed reply written and flushed, "
         "attr rid where the request is traced",
-        "none yet; laid beside the ticker's idle gaps"),
+        f"{_REPORT}, laid beside the ticker's idle gaps"),
 }
 
 _annotations = None     # (TraceAnnotation, StepTraceAnnotation) of jax

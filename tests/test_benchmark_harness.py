@@ -95,32 +95,61 @@ _PREFILL_ROW_METRICS = {
         {"num": ["prefill_rows_split"], "den": ["prefills"]}, 30.0)}
 
 
-def _check_prefill_row_metric(name):
+# The two metrics over the serve host path's counters: the ticker's
+# host phases off a CPU over the window, and of the requests' time in the
+# engine the share before a slot. `window_s` is the window's own length,
+# every other counter a key of engine.stats.
+_HOST_PATH_WINDOW = {"window_s": 50.0, "tick_host_s": 12.0,
+                     "tick_host_wait_s": 5.0, "queue_wait_s": 30.0,
+                     "request_s": 600.0}
+_HOST_PATH_METRICS = {
+    "sched.tick_host_wait_share": (
+        {"num": ["tick_host_wait_s"], "den": ["window_s"]}, 10.0),
+    "sched.queue_wait_share": (
+        {"num": ["queue_wait_s"], "den": ["request_s"]}, 5.0)}
+
+
+def _check_counter_metric(name, metrics, window):
+    """`name`'s file reads `counter_ratio` over the engine's counters: a
+    window that holds them gives `value`, one whose numerator is 0 reads
+    0, and a parent's engine, which counts neither, reads nothing. Its
+    entry lists every serve cell."""
     import json
 
     import pytest
 
     from benchmarks import reduce, run
     from paddle_tpu.inference import paged
-    args, value = _PREFILL_ROW_METRICS[name]
+    args, value = metrics[name]
     with open(os.path.join(ROOT, "benchmarks", "metrics",
                            name + ".json")) as f:
         spec = json.load(f)
     assert (spec["reader"], spec["args"]) == ("counter_ratio", args)
     src = open(paged.__file__).read()
     for counter in args["num"] + args["den"]:
-        assert f'"{counter}": 0' in src     # a key of engine.stats
+        if counter != "window_s":
+            assert f'"{counter}": 0' in src     # a key of engine.stats
     reader = run.READERS["counter_ratio"]
     assert reader is reduce.counter_ratio
-    has = {"window": dict(_PREFILL_WINDOW), "sizes": {}}
+    has = {"window": dict(window), "sizes": {}}
     assert reader(has, **args) == pytest.approx(value)
-    none = {"window": dict(_PREFILL_WINDOW, **{args["num"][0]: 0}),
-            "sizes": {}}
+    none = {"window": dict(window, **{args["num"][0]: 0}), "sizes": {}}
     assert reader(none, **args) == 0.0
-    # a parent's engine counts neither: nothing is read, and `run.py`
-    # leaves the metric out of the line
-    parents = {"window": {"prefills": 40, "ticks": 640}, "sizes": {}}
+    # `run.py` leaves a metric that reads nothing out of the line
+    parents = {"window": {"prefills": 40, "ticks": 640, "window_s": 50.0},
+               "sizes": {}}
     assert reader(parents, **args) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == [
+        w["name"] for w in bench["workloads"] if w["name"].startswith("serve.")]
+    assert (entry["layer"], entry["moves"]) \
+        == ("scheduler", "serve_tokens_per_s")
+
+
+def _check_prefill_row_metric(name):
+    _check_counter_metric(name, _PREFILL_ROW_METRICS, _PREFILL_WINDOW)
 
 
 def test_prefill_pad_row_share_reads_the_counters_or_nothing():
@@ -133,3 +162,17 @@ def test_prefill_split_share_reads_the_counters_or_nothing():
     """Of the window's prompts, the share that met others of their
     bucket and ran at width 1."""
     _check_prefill_row_metric("sched.prefill_split_share")
+
+
+def test_tick_host_wait_share_reads_the_counter_or_nothing():
+    """Of the window, the time the ticker spent in its host phases
+    without a CPU."""
+    _check_counter_metric("sched.tick_host_wait_share", _HOST_PATH_METRICS,
+                          _HOST_PATH_WINDOW)
+
+
+def test_queue_wait_share_reads_the_counters_or_nothing():
+    """Of the time requests spent in the engine, the share before they
+    held a slot."""
+    _check_counter_metric("sched.queue_wait_share", _HOST_PATH_METRICS,
+                          _HOST_PATH_WINDOW)

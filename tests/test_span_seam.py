@@ -9,8 +9,11 @@ the trainer's step span carries the optimizer's step number; every span
 name at a call site is in the catalogue.
 """
 import glob
+import json
 import os
+import re
 import sys
+import threading
 import time
 
 import numpy as np
@@ -70,6 +73,10 @@ def _named(threads, prefix):
     that hold none are left out."""
     out = [[e for e in evs if e[0].startswith(prefix)] for evs in threads]
     return [evs for evs in out if evs]
+
+
+# the columns of a `tick_log` row, in order
+TICK_LOG = ("seq", "start", *TICK_PHASES, "live", "prefills", "host_cpu")
 
 
 def _tiny_model():
@@ -170,15 +177,16 @@ def test_engine_tick_holds_its_phases_and_counters_add_up(tmp_path):
     rows = list(eng.tick_log)[log0:]
     assert len(rows) == len(ticks)
     assert [r[0] for r in rows] == seqs
-    assert all(len(r) == 2 + len(TICK_PHASES) + 2 for r in rows)
+    assert all(len(r) == len(TICK_LOG) for r in rows)
     walls = [sum(r[2:2 + len(TICK_PHASES)]) for r in rows]
     assert sum(walls) == pytest.approx(d["tick_wall_s"], rel=0.05)
     assert d["tick_host_s"] + d["readback_s"] + d["prefill_s"] \
         == pytest.approx(d["tick_wall_s"], rel=0.05)
     assert d["tick_host_s"] > 0 and d["readback_s"] > 0
     assert eng.stats["tick_max_s"] >= max(walls) * 0.999
-    assert sum(r[-1] for r in rows) == d["prefills"]
-    assert sum(1 for r in rows if r[-2]) == d["ticks"]
+    assert sum(r[TICK_LOG.index("prefills")] for r in rows) \
+        == d["prefills"]
+    assert sum(1 for r in rows if r[TICK_LOG.index("live")]) == d["ticks"]
     # scrape-time gauges
     reg = obs.MetricsRegistry()
     eng.export_metrics(reg)
@@ -201,6 +209,126 @@ def test_idle_polls_are_no_ticks_and_the_ticker_names_its_sleep(tmp_path):
     (evs,) = _named(threads, "engine.")
     assert {e[0] for e in evs} == {"engine.idle"} and len(evs) >= 2
     assert len(eng.tick_log) == 0 and eng.stats["tick_wall_s"] == 0.0
+
+
+_HOST_PATH = ("tick_host_s", "tick_host_wait_s", "prefill_s",
+              "prefill_cpu_s", "queue_wait_s", "request_s")
+
+
+def _served(eng, prompts, new, spinners=0):
+    """Serve `prompts` to their end, with `spinners` threads spinning in
+    pure Python beside the ticker: the host path's counters'
+    differences."""
+    s0 = dict(eng.stats)
+    stop = threading.Event()
+
+    def spin():
+        n = 0
+        while not stop.is_set():
+            n += 1
+    threads = [threading.Thread(target=spin, daemon=True)
+               for _ in range(spinners)]
+    for t in threads:
+        t.start()
+    try:
+        eng.generate(prompts, max_new_tokens=new)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
+    return {k: eng.stats[k] - s0[k] for k in _HOST_PATH}
+
+
+def test_host_wait_and_prefill_cpu_lie_inside_their_walls():
+    eng = PagedKVEngine(_tiny_model(), max_slots=2, page_size=4,
+                        num_pages=24, max_pages_per_slot=8,
+                        steps_per_tick=2)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 97, size=n).astype(np.int32)
+               for n in (5, 9, 6)]
+    eng.generate(prompts[:1], max_new_tokens=2)      # compile outside
+    log0 = len(eng.tick_log)
+    d = _served(eng, prompts, 7)
+    s = eng.stats
+    # the wait is a sum of differences between two clocks, never clamped
+    # a tick: a run that hardly waits may read a hair under 0
+    assert -1e-4 <= s["tick_host_wait_s"] <= s["tick_host_s"]
+    assert 0.0 < s["prefill_cpu_s"] <= s["prefill_s"]
+    assert 0.0 < d["queue_wait_s"] <= d["request_s"]
+    rows = list(eng.tick_log)[log0:]
+    readback, cpu = TICK_LOG.index("readback"), TICK_LOG.index("host_cpu")
+    for r in rows:
+        # the host phases' CPU time: within the row's wall less readback
+        wall = sum(r[2:2 + len(TICK_PHASES)])
+        assert 0.0 <= r[cpu] <= wall - r[readback] + 1e-6
+    # and within the host wall of the same ticks, which also leaves out
+    # the prefills
+    assert sum(r[cpu] for r in rows) <= d["tick_host_s"] + 1e-6
+    reg = obs.MetricsRegistry()
+    eng.export_metrics(reg)
+    assert reg.gauge("engine.tick_host_wait_seconds").value() \
+        == s["tick_host_wait_s"]
+
+
+def test_threads_spinning_beside_the_ticker_show_as_its_wait():
+    """Two threads spinning in pure Python pass the interpreter lock
+    between them every switch interval, so whenever the ticker lets go of
+    it in a host phase it queues to take it back: its wait rises well
+    above the quiet runs', alternated three times. (One spinner alone
+    can miss: the ticker's short releases keep restarting a lone
+    waiter's switch timer, and it then takes the lock only at the
+    readback's waits, which the counter leaves out.)"""
+    eng = PagedKVEngine(_tiny_model(), max_slots=2, page_size=4,
+                        num_pages=24, max_pages_per_slot=8,
+                        steps_per_tick=2)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 97, size=n).astype(np.int32)
+               for n in (5, 9, 6, 7)]
+    _served(eng, prompts, 12)                   # compile outside
+    runs = {0: [], 2: []}
+    for _ in range(3):
+        for spinners, out in runs.items():
+            out.append(_served(eng, prompts, 12, spinners))
+    wait, host = ({n: sum(d[k] for d in out) for n, out in runs.items()}
+                  for k in ("tick_host_wait_s", "tick_host_s"))
+    for n in runs:
+        assert -1e-4 <= wait[n] <= host[n]
+    assert wait[2] >= 4 * wait[0], (wait, host)
+    assert wait[2] / host[2] >= wait[0] / host[0] + 0.25, (wait, host)
+
+
+def test_a_request_waits_for_the_slot_the_one_before_holds():
+    eng = PagedKVEngine(_tiny_model(), max_slots=1, page_size=4,
+                        num_pages=24, max_pages_per_slot=8,
+                        steps_per_tick=2)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(1, 97, size=n).astype(np.int32)
+               for n in (5, 9, 6)]
+    eng.generate(prompts[:1], max_new_tokens=2)      # compile outside
+    s0 = dict(eng.stats)
+    first, second, dropped = (eng.submit(p, 6) for p in prompts)
+    dropped.cancel()            # leaves the queue without a slot
+    while not first.done.is_set():
+        eng._step(ahead=True)
+    life = eng.stats["request_s"] - s0["request_s"]
+    wait = eng.stats["queue_wait_s"] - s0["queue_wait_s"]
+    assert wait == pytest.approx(first.admitted_at - first.queued_at)
+    assert wait < life <= time.monotonic() - first.queued_at
+    eng.run_until_idle()
+    assert dropped.admitted_at is None and eng.stats["cancelled"] == 1
+    d = {k: eng.stats[k] - s0[k] for k in _HOST_PATH}
+    # each request that held a slot counted once, the dropped one not
+    assert d["queue_wait_s"] - wait == pytest.approx(
+        second.admitted_at - second.queued_at)
+    assert d["request_s"] > life
+    # the second waited out the first's whole life, less the instant
+    # between the two submits
+    assert d["queue_wait_s"] - wait \
+        >= life - (second.queued_at - first.queued_at)
+    assert d["queue_wait_s"] <= d["request_s"]
+    assert eng.step() is False
+    assert {k: eng.stats[k] - s0[k] for k in _HOST_PATH} == d
 
 
 def test_cancel_all_ends_every_stream_in_order():
@@ -298,6 +426,13 @@ def test_span_names_at_call_sites_are_catalogued(tmp_path):
     assert {"engine.tick." + p for p in TICK_PHASES} <= seen
     for layer, covers, feeds in trace.SPANS.values():
         assert layer and covers and feeds
+    # every metric the third column names is one of the benchmark's
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        metrics = {m["name"] for m in json.load(f)["per_layer"]}
+    named = {m for _l, _c, feeds in trace.SPANS.values()
+             for m in re.findall(r"\b[a-z]+\.[a-z0-9_]+_(?:share|p50)\b",
+                                 feeds)}
+    assert named and named <= metrics, named - metrics
     # an uncatalogued or computed name is a violation of the same gate
     pkg = tmp_path / "paddle_tpu"
     (pkg / "observability").mkdir(parents=True)
