@@ -10,11 +10,12 @@ is built for thousands of rows an expert; here
 
 - the distinct experts the rows hit are listed outside the kernel (a
   scatter and a stable argsort over E flags) and ride in as a scalar-
-  prefetch operand; the grid is (tiles of f, entries of the list), and the
-  weight blocks' index maps read the list, so the pipeline fetches exactly
-  the experts hit, double-buffered, one (d, tf) + (d, tf) + (tf, d) tile a
-  step. Entries past the last hit repeat it: an unchanged block index is
-  not fetched again, and their gates are 0;
+  prefetch operand; the grid is (tiles of f, experts hit), its second
+  extent a traced value, and the weight blocks' index maps read the list,
+  so the pipeline fetches exactly the experts hit, double-buffered, one
+  (d, tf) + (d, tf) + (tf, d) tile a step, and no step computes for an
+  expert no row chose. Where no row chose a held expert (`share`) the
+  grid keeps one step, which zeroes the output under a gate of 0;
 - every row meets every listed expert (the rows are few: one small matmul a
   tile), and a row's gate for an expert it did not choose is 0. SwiGLU in
   float32, the products on the MXU in the weights' dtype;
@@ -154,27 +155,25 @@ def _call(x, wg, wu, wd, idx, gates, *, interpret, share=False):
     m = min(e, t * k)                   # the list's length
     rows = -(-t // 16) * 16             # whole sublane tiles of bf16
 
-    # the distinct experts hit, ascending, then the last one repeated
-    # (`share`: a choice of value e is held elsewhere and left out)
+    # the distinct experts hit first, ascending (`share`: a choice of
+    # value e is held elsewhere and left out)
     drop = {"mode": "drop"} if share else {}
     dense = jnp.zeros((t, e), jnp.float32).at[
         jnp.arange(t)[:, None], idx].add(gates, **drop)         # (T, E)
     hit = jnp.zeros((e,), jnp.int32).at[idx.reshape(-1)].set(1, **drop)
     n = jnp.sum(hit)
-    order = jnp.argsort(1 - hit, stable=True).astype(jnp.int32)[:m]
-    pos = jnp.arange(m, dtype=jnp.int32)
-    ids = jnp.where(pos < n, order, order[jnp.maximum(n - 1, 0)])
-    # each entry's gate column, broadcast over a lane tile; 0 past the hits
-    g = jnp.where((pos < n)[:, None], dense.T[ids], 0.0)         # (m, T)
-    g = jnp.pad(g, ((0, 0), (0, rows - t)))
+    ids = jnp.argsort(1 - hit, stable=True).astype(jnp.int32)[:m]
+    # each entry's gate column, broadcast over a lane tile: an expert no
+    # row chose has a column of 0
+    g = jnp.pad(dense.T[ids], ((0, 0), (0, rows - t)))           # (m, rows)
     g = jnp.broadcast_to(g[:, :, None], (m, rows, 128))
     xp = jnp.pad(x.astype(wg.dtype), ((0, rows - t), (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        # the tiles of f outside, the list inside: the entries past the
-        # last hit then repeat one block index and fetch nothing
-        grid=(f // tf, m),
+        # the tiles of f outside, the experts hit inside, as many as there
+        # are; one step at least, so that the output block is zeroed
+        grid=(f // tf, jnp.maximum(n, 1)),
         in_specs=[
             pl.BlockSpec((rows, d), lambda j, i, ids: (0, 0)),
             pl.BlockSpec((1, d, tf), lambda j, i, ids: (ids[i], 0, j)),

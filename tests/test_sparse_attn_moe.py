@@ -239,6 +239,29 @@ def test_few_rows_expert_kernel_equals_the_grouped_path():
     assert int(experts_hit(idx, e)) == len(np.unique(np.asarray(idx)))
 
 
+def test_few_rows_expert_kernel_runs_only_the_experts_hit():
+    """3 rows x 2 choices over 16 experts: a list of min(16, 6) = 6
+    entries of which the rows' repeated choices fill 4, so the grid runs
+    4 experts, fewer than the list holds, which holds fewer than E."""
+    from paddle_tpu.kernels.moe_experts import (experts_hit,
+                                                moe_experts_decode)
+    from paddle_tpu.nn.functional import moe as FM
+    rng = np.random.default_rng(2)
+    t, d, f, e = 3, 128, 256, 16
+    x = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.standard_normal((e, d, f)) * 0.05, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.standard_normal((e, f, d)) * 0.05, jnp.float32)
+    idx = jnp.asarray([[9, 2], [2, 14], [5, 9]], jnp.int32)
+    gates = jnp.asarray(rng.uniform(0.1, 1.0, (t, 2)), jnp.float32)
+    assert int(experts_hit(idx, e)) == 4 < t * 2 < e
+    with jax.default_matmul_precision("highest"):
+        want = FM.moe_dropless_mlp(x, wg, wu, wd, idx, gates)
+        got = moe_experts_decode(x, wg, wu, wd, idx, gates, interpret=True)
+    assert np.abs(np.asarray(got - want)).max() < TOL
+    assert np.abs(np.asarray(got)).min() > 0
+
+
 def test_expert_layer_returns_its_hit_count_and_keeps_none():
     from paddle_tpu.nn.functional import moe as FM
     from paddle_tpu.nn.layer.moe import MoEMLP

@@ -367,6 +367,15 @@ def _shapes(described, *specs):
             for shape, dt in specs]
 
 
+def _expert_grid_is_traced(lowered, listed):
+    """The few-rows expert kernel's call takes a scalar before its list of
+    `listed` experts: the grid's second extent, the number of experts hit,
+    rides in as a traced value (a static extent adds no operand)."""
+    call = re.search(r"tpu_custom_call.*", lowered.as_text()).group(0)
+    return re.search(rf": \(tensor<i32>, tensor<{listed}xi32>, ", call) \
+        is not None
+
+
 def test_few_rows_expert_kernel_compiles_at_the_cells_widths(
         as_tpu, described_v5e):
     from paddle_tpu.kernels.moe_experts import (moe_decode_problems,
@@ -382,6 +391,8 @@ def test_few_rows_expert_kernel_compiles_at_the_cells_widths(
     lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
         else fn.lower(*shapes)
     assert _kernel_names(lowered) == {"moe_experts_decode"}
+    # 8 rows x 8 choices: a list of 64 of the 128 experts, ~51 hit
+    assert _expert_grid_is_traced(lowered, 64)
     if described_v5e is not None:
         lowered.compile()       # Mosaic: VMEM under the scoped default
 
@@ -489,6 +500,8 @@ def test_few_rows_expert_kernel_at_a_share_compiles_at_the_cells_widths(
     lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
         else fn.lower(*shapes)
     assert _kernel_names(lowered) == {"moe_experts_decode"}
+    # a list of all 32 held experts, ~8 hit a decode step
+    assert _expert_grid_is_traced(lowered, 32)
     if described_v5e is not None:
         lowered.compile()       # Mosaic: VMEM under the scoped default
 
@@ -682,6 +695,8 @@ def test_few_rows_expert_kernel_compiles_at_a_blocks_rows(as_tpu,
     lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
         else fn.lower(*shapes)
     assert _kernel_names(lowered) == {"moe_experts_decode"}
+    # a list of all 128, ~99 hit a forward
+    assert _expert_grid_is_traced(lowered, 128)
     if described_v5e is not None:
         lowered.compile()
 

@@ -391,6 +391,42 @@ def test_the_few_rows_kernel_at_a_share_names_no_expert_held_elsewhere():
     assert not np.asarray(got[1]).any() and np.asarray(got[3]).any()
 
 
+# rows' choices among 64 routed experts, of which 16..31 are held: none of
+# them, one, and three of the 16 (a list of min(16, rows x 2) = 8-10)
+_HELD_HITS = {
+    "none": ([[0, 40], [63, 2], [15, 32], [5, 6]], 0),
+    "one": ([[0, 20], [20, 40], [63, 2], [20, 20]], 1),
+    "few-of-many": ([[16, 40], [31, 2], [16, 20], [63, 20], [1, 2]], 3),
+}
+
+
+@pytest.mark.parametrize("case", list(_HELD_HITS))
+def test_the_few_rows_kernel_runs_the_held_experts_hit_and_no_more(case):
+    """Interpreted, at a share: the grid's second extent is the number of
+    held experts hit. With none hit it keeps one step under a gate of 0,
+    so the output is zeros and not whatever the buffer held; with one, or
+    a few of many, the kernel equals the grouped path."""
+    choices, hits = _HELD_HITS[case]
+    idx = jnp.asarray(choices, jnp.int32)
+    key = jax.random.key(3)
+    x = jax.random.normal(key, (idx.shape[0], 128))
+    wg, wu = (0.1 * jax.random.normal(jax.random.fold_in(key, i),
+                                      (16, 128, 256)) for i in (1, 2))
+    wd = 0.1 * jax.random.normal(jax.random.fold_in(key, 3), (16, 256, 128))
+    gates = jax.random.uniform(jax.random.fold_in(key, 4), idx.shape)
+    local = held_ids(idx, 16, 16)
+    assert int(experts_hit(local, 16, share=True)) == hits
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(moe_experts_decode(x, wg, wu, wd, local, gates,
+                                            interpret=True, share=True))
+        want = np.asarray(FM.moe_dropless_mlp(x, wg, wu, wd, idx, gates,
+                                              first=16))
+    assert np.abs(got - want).max() < TOL
+    held_rows = ((np.asarray(idx) >= 16) & (np.asarray(idx) < 32)).any(-1)
+    assert not got[~held_rows].any()
+    assert hits == 0 or np.abs(got[held_rows]).max() > 10 * TOL
+
+
 # -- planted faults: the same comparison must refuse each -----------------
 
 def _window_mask_off(monkeypatch, model):
