@@ -107,6 +107,18 @@ is the one-token engine's. A prompt's whole blocks are prefilled under the
 mask by blocks; its last tokens open the slot's first block (`_Slot.open`)
 and a prefill yields no token. `paged_attention_update(block=)` is the
 op's side of it.
+
+Latent attention: a model whose config has `kv_lora_rank`
+(models/latent_attn_moe.py) caches ONE row a token a layer, its normed
+latent and its shared rotary key, in a pool of its own under the block
+table, in place of K and V (`latent_attention_update`). A decode step
+attends in the absorbed form, every head against the same rows, through
+kernels/mla_decode.py; a prefill chunk in the expanded form, the rows
+expanded into each head's keys and values a block at a time
+(kernels/mla_prefill.py on the Pallas path, `_attend_latent_expanded`
+else). What assumes K and V pages refuses such a
+model by name (`_refuse_uncarried`), as it refuses an index pool, rings
+and blocks.
 """
 from __future__ import annotations
 
@@ -126,6 +138,8 @@ import jax.numpy as jnp
 from paddle_tpu.core import compile_cache, jax_compat
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu import observability
+from paddle_tpu.kernels import mla_decode as _mla
+from paddle_tpu.kernels import mla_prefill as _mlp
 from paddle_tpu.kernels import moe_grouped as _mg
 from paddle_tpu.kernels import paged_attention as _pk
 from paddle_tpu.kernels.prefill_attention import (chunk_attention,
@@ -141,14 +155,23 @@ from paddle_tpu.inference.kvtier import HostKVTier
 from paddle_tpu.inference.prefix import PrefixCache, chain_keys
 from paddle_tpu.inference.tenancy import WeightedFairScheduler
 
-__all__ = ["PagedState", "paged_attention_update", "decode_kernel_scope",
-           "PagedKVEngine"]
+__all__ = ["PagedState", "paged_attention_update", "latent_attention_update",
+           "decode_kernel_scope", "PagedKVEngine"]
 
 
 # the float32 scores that one prefill program's whole-window attend may
 # hold (`PagedKVEngine._prefill_limit`): 16 rows x 32 heads x a 512
 # bucket x a 768-token window is 0.75 GiB
 _PREFILL_SCORE_BYTES = 2 ** 30
+
+# the tokens of one prefill program of a model with latent attention: a
+# call reads every weight once (6.3 GB at the serve cell's five layers,
+# ~7.7 ms at the HBM's peak) and re-expands the slot's context (a prompt
+# of n tokens expands about n^2 / (2 x this) latent rows a layer); at
+# 2,048 a q block of the prefill kernel expands a key block for 1,024
+# queries (2 x 512 x 128 MACs a key a head against 320 a (query, key)
+# pair), and a prompt's last call pads half a program on average
+_LATENT_PREFILL_TOKENS = 2048
 
 # What a prefill program costs by its shape, in tokens (`prefill_width`),
 # fitted once on a v5e over the dense serve cell's model (SmolLM2-1.7B in
@@ -753,6 +776,173 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
     return Tensor(out), (Tensor(kp), Tensor(vp))
 
 
+def _latent_rows(c, k_pe, width):
+    """A latent pool's rows of this call's tokens: [c | k_pe], zeros up to
+    the pool's `width` (`kernels/mla_decode.py kernel_row_width`)."""
+    row = jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+    return jnp.pad(row, ((0, 0), (0, 0), (0, width - row.shape[-1])))
+
+
+def latent_block_pages(rows, heads, s, max_pages, page_size):
+    """Pages of a slot one block of the expanded attend takes: the largest
+    power of two whose float32 scores (rows x heads x s x keys) fit
+    `_SELECT_SCORE_BYTES`. The engine counts a prefill's expanded rows by
+    the same blocks (`mla_expand_rows`)."""
+    bp = 1
+    while bp * 2 <= max_pages and rows * heads * s * bp * 2 * page_size \
+            * 4 <= _SELECT_SCORE_BYTES:
+        bp *= 2
+    return bp
+
+
+def _attend_latent_expanded(q_nope, q_pe, pool, w_uk, w_uv, state, scale):
+    """The expanded form over a latent pool: the keys go by in blocks of
+    whole pages under an online softmax, as many blocks as the longest row
+    reaches, and each block's latent rows are expanded through W_uk, W_uv
+    inside the loop, so neither the scores of the whole context nor its
+    expanded keys and values are ever held (a block's: `latent_block_pages`).
+    q_nope (b, s, H, nope), q_pe (b, s, H, rope); -> (b, s, H * v)."""
+    bt, lens, n_valid = (_val(state.block_tables), _val(state.lens),
+                         _val(state.n_valid))
+    b, s, h, _ = q_nope.shape
+    rank, rope = w_uk.shape[0], q_pe.shape[-1]
+    ps, mp = pool.shape[1], bt.shape[1]
+    bp = latent_block_pages(b, h, s, mp, ps)
+    nblk = -(-mp // bp)
+    cols = bp * ps
+    btp = jnp.pad(bt, ((0, 0), (0, nblk * bp - mp)))
+    pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    dt = q_nope.dtype
+    w_uk, w_uv = w_uk.astype(dt), w_uv.astype(dt)
+
+    def block(i, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(btp, i * bp, bp, 1)
+        rows = pool[pages].reshape(b, cols, -1).astype(dt)
+        c, k_pe = rows[..., :rank], rows[..., rank:rank + rope]
+        k_nope = jnp.einsum("bcr,rhn->bhcn", c, w_uk)
+        v = jnp.einsum("bcr,rhv->bhcv", c, w_uv)
+        sc = (jnp.einsum("bshn,bhcn->bhsc", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshr,bcr->bhsc", q_pe, k_pe,
+                           preferred_element_type=jnp.float32)) * scale
+        col = i * cols + jnp.arange(cols, dtype=jnp.int32)
+        seen = (col[None, None, :] <= pos[:, :, None])[:, None]  # (b,1,s,c)
+        sc = jnp.where(seen, sc, -1e30)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum("bhsc,bhcv->bhsv", p.astype(dt), v,
+                                       preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    reach = jnp.max(lens + jnp.maximum(n_valid, 1))         # keys in use
+    need = jnp.clip(-(-reach // cols), 1, nblk)
+    shape = (b, h, s, 1)
+    _m, l, acc = jax.lax.fori_loop(
+        0, need, block,
+        (jnp.full(shape, -1e30, jnp.float32), jnp.zeros(shape, jnp.float32),
+         jnp.zeros((b, h, s, w_uv.shape[-1]), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)
+    return jnp.moveaxis(out, 1, 2).reshape(b, s, -1).astype(dt)
+
+
+def _latent_prefill_kernel(kind, interpret, s, nope, rope, dv, rank, pool):
+    """Whether a prefill call of `s` tokens a row takes the prefill kernel
+    (kernels/mla_prefill.py) under `decode_kernel_scope(kind, interpret)`,
+    over `pool` (anything with the pool's shape and dtype); else it takes
+    `_attend_latent_expanded`."""
+    return kind == "pallas" and not _mlp.mla_prefill_problems(
+        s, nope, rope, dv, rank, pool.shape[2], pool.shape[1], pool.dtype,
+        interpret)
+
+
+def latent_expanded_rows(kind, interpret, lens, n_valid, s, *, heads, nope,
+                         rope, dv, rank, pool, max_pages):
+    """Latent rows one layer of a prefill call expands through W_kvb, by
+    the blocks of the path `latent_attention_update` takes under
+    `decode_kernel_scope(kind, interpret)`: the kernel's (each q block's
+    key blocks up to its last valid query) or `_attend_latent_expanded`'s
+    (each row's blocks of pages up to the longest row's reach)."""
+    ps = pool.shape[1]
+    if _latent_prefill_kernel(kind, interpret, s, nope, rope, dv, rank,
+                              pool):
+        return _mlp.expanded_rows(lens, n_valid, s, max_pages, ps)
+    rows = len(lens)
+    bp = latent_block_pages(rows, heads, s, max_pages, ps)
+    cols = bp * ps
+    reach = int(np.max(np.asarray(lens) + np.maximum(n_valid, 1)))
+    return rows * cols * min(max(-(-reach // cols), 1), -(-max_pages // bp))
+
+
+def latent_attention_update(q_nope, q_pe, c, k_pe, w_uk, w_uv, cache,
+                            state: PagedState, *, scale):
+    """Write this call's latent rows into the slot's pages, then attend
+    over the slot's whole paged window: the cache of a layer with latent
+    attention (`models/latent_attn_moe.py`), ONE row [c | k_pe] a token,
+    `cache` = (pool,) with pool (num_pages, page_size, width), width the
+    row's values or, where the decode kernel reads it, whole lane tiles
+    (`kernels/mla_decode.py kernel_row_width`, zeros past the row).
+
+    q_nope (b, s, H, nope), q_pe (b, s, H, rope), c (b, s, rank), k_pe
+    (b, s, rope), all position-encoded; w_uk (rank, H, nope), w_uv (rank,
+    H, v): W_kvb by heads. A decode call (s == 1) takes the absorbed form:
+    q~ = W_uk q_nope under the scope `mla_absorb`, the kernel (inside
+    `decode_kernel_scope("pallas")`) or `mla_decode_reference` over every
+    head's [q~ | q_pe] against the rows, o = õ W_uv under `mla_unabsorb`.
+    Anything longer (a prefill chunk) takes the expanded form under the
+    scope `mla_prefill`: inside `decode_kernel_scope("pallas")` the kernel
+    of `kernels/mla_prefill.py`, which copies the slot's pages a key block
+    at a time and expands each block's keys and values in VMEM, else
+    `_attend_latent_expanded` (`_latent_prefill_kernel` chooses). Returns
+    (out (b, s, H * v), the new cache)."""
+    q_nope, q_pe, c, k_pe = (_val(a) for a in (q_nope, q_pe, c, k_pe))
+    (pool,) = (_val(a) for a in cache)
+    b, s, h, _ = q_nope.shape
+    rank = c.shape[-1]
+    ps, width = pool.shape[1], pool.shape[2]
+    with jax.named_scope("kv_write"):
+        phys_f, off_f = _token_coords(state, s, ps, pool.shape[0])
+        pool = pool.at[phys_f, off_f, :].set(
+            _latent_rows(c, k_pe, width).reshape(b * s, width).astype(
+                pool.dtype), mode="drop")
+    kind, interpret = _decode_kernel_choice()
+    if s > 1:
+        with jax.named_scope("mla_prefill"):
+            if _latent_prefill_kernel(kind, interpret, s, q_nope.shape[-1],
+                                      q_pe.shape[-1], w_uv.shape[-1], rank,
+                                      pool):
+                out = _mlp.mla_prefill_attention(
+                    q_nope, q_pe, pool, _val(state.block_tables), w_uk,
+                    w_uv, _val(state.lens), _val(state.n_valid),
+                    sm_scale=scale, interpret=interpret)
+            else:
+                out = _attend_latent_expanded(q_nope, q_pe, pool, w_uk,
+                                              w_uv, state, scale)
+        return Tensor(out), (Tensor(pool),)
+    with jax.named_scope("mla_absorb"):
+        q_abs = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk.astype(
+            q_nope.dtype), preferred_element_type=jnp.float32)
+        q_cat = _latent_rows(q_abs.astype(pool.dtype), q_pe[:, 0], width)
+    # a slot with no new token reads nothing (the kernel's negative length)
+    lens = jnp.where(_val(state.n_valid) > 0, _val(state.lens), -1)
+    with jax.named_scope("paged_attn"):
+        if kind == "pallas":
+            o_abs = _mla.mla_decode_attention(
+                q_cat, pool, _val(state.block_tables), lens, rank=rank,
+                sm_scale=scale, interpret=interpret)
+        else:
+            o_abs = _mla.mla_decode_reference(
+                q_cat, pool, _val(state.block_tables), lens, rank=rank,
+                sm_scale=scale)
+    with jax.named_scope("mla_unabsorb"):
+        out = jnp.einsum("bhr,rhv->bhv", o_abs.astype(q_nope.dtype),
+                         w_uv.astype(q_nope.dtype),
+                         preferred_element_type=jnp.float32)
+    return Tensor(out.reshape(b, 1, -1).astype(q_nope.dtype)), (Tensor(pool),)
+
+
 def _last_valid_logits(lv, n_valid):
     """(bw, v): each prefill row's logits at its last valid token, from
     the model's (bw, s, v) — or from (bw, 1, v) where the model projected
@@ -974,6 +1164,19 @@ class _PageGroup:
             for grp in self.pools for a in grp)
 
 
+def _refuse_uncarried(what, uncarried, whys, extra=()):
+    """The engine's one refusal of the features that assume a cache of K
+    and V pages, for a model whose cache is something else (`what` says
+    what it keeps): `uncarried` is each such feature's (name, switched
+    on), `whys` why this cache does not carry it, in the same order, and
+    `extra` (on, reason) pairs of the model's own, named first. Raises
+    one ValueError naming every one that is on."""
+    refused = [why for on, why in extra if on] + [
+        f"{name} ({why})" for (name, on), why in zip(uncarried, whys) if on]
+    if refused:
+        raise ValueError(f"{what}; not carried by: " + "; ".join(refused))
+
+
 def _state_of(tables, lens, n_valid):
     """`PagedState` from what a program was handed as its table(s)."""
     if isinstance(tables, tuple):
@@ -1128,28 +1331,50 @@ class PagedKVEngine:
         # here, by name, so that none can drop the third in silence.
         self.index_dim = int(getattr(cfg, "index_head_dim", 0) or 0)
         self.index_topk = int(getattr(cfg, "index_topk", 0) or 0)
+        # what assumes a cache of K and V pages, each under the name it is
+        # switched on by: a model whose cache is something else refuses
+        # every one that is on, with its own reasons (`_refuse_uncarried`)
+        uncarried = (("kv_dtype='int8'", kv_dtype == "int8"),
+                     ("prefix_cache_pages", bool(int(prefix_cache_pages))),
+                     ("host_tier_bytes", bool(int(host_tier_bytes))),
+                     (f"role={role!r}", role != "both"),
+                     ("draft_model", draft_model is not None))
         if self.index_dim:
-            refused = [why for on, why in (
-                (kv_dtype == "int8", "kv_dtype='int8' (the scale planes "
-                 "take the cache's third and fourth place)"),
-                (int(prefix_cache_pages), "prefix_cache_pages (a warm "
-                 "tail would select over index keys no test holds to "
-                 "the reference yet)"),
-                (int(host_tier_bytes), "host_tier_bytes (a spilled page "
-                 "is its K and V alone)"),
-                (role != "both", f"role={role!r} (an exported page is "
-                 "its K and V alone)"),
-                (draft_model is not None, "draft_model (the verify pass "
-                 "attends over every key)")) if on]
-            if refused:
-                raise ValueError(
-                    "this model keeps an index pool beside K and V "
-                    f"(index_head_dim={self.index_dim}); not carried by: "
-                    + "; ".join(refused))
-        elif draft_model is not None and getattr(
-                draft_model.config, "index_head_dim", 0):
-            raise ValueError("a draft model with an index pool is not "
-                             "carried (its pools are K and V alone)")
+            _refuse_uncarried(
+                "this model keeps an index pool beside K and V "
+                f"(index_head_dim={self.index_dim})", uncarried, (
+                    "the scale planes take the cache's third and fourth "
+                    "place",
+                    "a warm tail would select over index keys no test "
+                    "holds to the reference yet",
+                    "a spilled page is its K and V alone",
+                    "an exported page is its K and V alone",
+                    "the verify pass attends over every key"))
+        # a model with latent attention (the config says how wide the
+        # latent is) keeps ONE pool a layer under the block table, a row
+        # [c | k_pe] a token, in place of K and V
+        # (`latent_attention_update`)
+        self.latent_rank = int(getattr(cfg, "kv_lora_rank", 0) or 0)
+        self.latent_width = (self.latent_rank + int(cfg.qk_rope_head_dim)
+                             if self.latent_rank else 0)
+        if self.latent_rank:
+            _refuse_uncarried(
+                "this model keeps one latent row a token a layer in place "
+                f"of K and V (kv_lora_rank={self.latent_rank}: "
+                f"{self.latent_width} values)", uncarried, (
+                    "no int8 latent row is built or held to the "
+                    "reference",
+                    "no test holds a warm tail over latent pages to the "
+                    "reference yet",
+                    "a spilled page is a page of K and V",
+                    "an exported page is a page of K and V",
+                    "the verify pass attends over K and V"))
+        elif not self.index_dim and draft_model is not None and (
+                getattr(draft_model.config, "index_head_dim", 0)
+                or getattr(draft_model.config, "kv_lora_rank", 0)):
+            raise ValueError("a draft model with an index pool or a latent "
+                             "pool is not carried (its pools are K and V "
+                             "alone)")
         # a model with layers that attend inside a window (the config's
         # `layer_types` names them, `sliding_window` is the window) keeps
         # those layers' K and V in rings under a SECOND page table
@@ -1167,25 +1392,24 @@ class PagedKVEngine:
                 raise ValueError(
                     f"layer_types names {len(kinds)} layers, the model "
                     f"has {cfg.num_hidden_layers}")
-            refused = [why for on, why in (
-                (self.index_dim, "an index pool (the key selection reads "
-                 "every key of a slot)"),
-                (kv_dtype == "int8", "kv_dtype='int8' (a ring's pages "
-                 "are overwritten in place; their scales only grow)"),
-                (int(prefix_cache_pages), "prefix_cache_pages (a window "
-                 "layer's page of a prefix is overwritten once the "
-                 "context has gone round the ring)"),
-                (int(host_tier_bytes), "host_tier_bytes (a spilled page "
-                 "is a page of every layer under one table)"),
-                (role != "both", f"role={role!r} (an exported page is a "
-                 "page of every layer under one table)"),
-                (draft_model is not None, "draft_model (a rejected draft "
-                 "token has already overwritten the ring's oldest)")) if on]
-            if refused:
-                raise ValueError(
-                    f"this model keeps layers {window_layers} in rings of "
-                    f"a window of {self.window} under a second page "
-                    "table; not carried by: " + "; ".join(refused))
+            _refuse_uncarried(
+                f"this model keeps layers {window_layers} in rings of a "
+                f"window of {self.window} under a second page table",
+                uncarried, (
+                    "a ring's pages are overwritten in place; their scales "
+                    "only grow",
+                    "a window layer's page of a prefix is overwritten once "
+                    "the context has gone round the ring",
+                    "a spilled page is a page of every layer under one "
+                    "table",
+                    "an exported page is a page of every layer under one "
+                    "table",
+                    "a rejected draft token has already overwritten the "
+                    "ring's oldest"),
+                extra=((self.index_dim, "an index pool (the key selection "
+                        "reads every key of a slot)"),
+                       (self.latent_rank, "a latent pool (rings hold K and "
+                        "V)")))
         # a model that generates by diffusion over blocks (the config
         # says how long a block is) has no one-token step: a tick settles
         # steps_per_tick / block_length whole blocks a slot, each by the
@@ -1208,30 +1432,26 @@ class PagedKVEngine:
                     f"prefill_chunk ({self.prefill_chunk}) must be a "
                     f"multiple of block_length ({self.block_length}): a "
                     "chunk ends where a block ends")
-            refused = [why for on, why in (
-                (self.index_dim, "an index pool (the key selection is "
-                 "causal by position)"),
-                (window_layers, "layers in rings (a window inside a block "
-                 "is not defined)"),
-                (kv_dtype == "int8", "kv_dtype='int8' (a block's K and V "
-                 "are rewritten every denoising step; a page's scales "
-                 "only grow)"),
-                (int(prefix_cache_pages), "prefix_cache_pages (a prompt's "
-                 "last partial block is not final, and no test holds a "
-                 "warm tail under the mask by blocks to the reference)"),
-                (int(host_tier_bytes), "host_tier_bytes (it spills "
-                 "prefix-cache pages)"),
-                (role != "both", f"role={role!r} (an exported page is a "
-                 "prefix-cache page)"),
-                (draft_model is not None, "draft_model (a block is "
-                 "denoised, not verified)")) if on]
-            if refused:
-                raise ValueError(
-                    f"this model generates by blocks of "
-                    f"{self.block_length} (block_length); not carried by: "
-                    + "; ".join(refused))
+            _refuse_uncarried(
+                f"this model generates by blocks of {self.block_length} "
+                "(block_length)", uncarried, (
+                    "a block's K and V are rewritten every denoising step; "
+                    "a page's scales only grow",
+                    "a prompt's last partial block is not final, and no "
+                    "test holds a warm tail under the mask by blocks to the "
+                    "reference",
+                    "it spills prefix-cache pages",
+                    "an exported page is a prefix-cache page",
+                    "a block is denoised, not verified"),
+                extra=((self.index_dim, "an index pool (the key selection "
+                        "is causal by position)"),
+                       (window_layers, "layers in rings (a window inside a "
+                        "block is not defined)"),
+                       (self.latent_rank, "a latent pool (the block's rows "
+                        "ride the decode kernel of K and V)")))
         self._cache_arity = (4 if kv_dtype == "int8"
-                             else 3 if self.index_dim else 2)
+                             else 3 if self.index_dim
+                             else 1 if self.latent_rank else 2)
 
         def make_pools(n_heads, head_dim, n_layers, num_pages=None):
             """One layer's pools (`num_pages` long: the engine's, or a
@@ -1244,8 +1464,17 @@ class PagedKVEngine:
             (num_pages, heads, page_size, width) and XLA's scatter: the
             int8 write rescales whole pages (no cell runs it, and a
             second quantising kernel is not worth growing for it), and
-            no Pallas call reads an index pool."""
+            no Pallas call reads an index pool. A latent pool is (num_pages,
+            page_size, the row's values), at whole lane tiles where the
+            decode kernel reads it (`kernel_row_width`), under XLA's
+            scatter."""
             num_pages = num_pages or self.num_pages
+            if self.latent_rank:    # never a draft's: refused above
+                width = (_mla.kernel_row_width(self.latent_width)
+                         if self.decode_kernel == "pallas"
+                         else self.latent_width)
+                return [(jnp.zeros((num_pages, self.page_size, width),
+                                   pool_dtype),) for _ in range(n_layers)]
             shape = (num_pages, n_heads, self.page_size, head_dim)
             sshape = (num_pages, n_heads)
             if self.kv_write == "pallas":
@@ -1285,7 +1514,21 @@ class PagedKVEngine:
         select_problems = (_pk.select_shape_problems(
             n_kv, hd, self.page_size, pool_dtype)
             if self.index_dim or window_layers else [])
-        if kernel == "pallas":
+        if self.latent_rank:
+            # the latent decode kernel (kernels/mla_decode.py) reads rows
+            # of whole lane tiles
+            latent_problems = _mla.mla_decode_problems(
+                cfg.num_attention_heads,
+                _mla.kernel_row_width(self.latent_width), self.latent_rank,
+                self.page_size, pool_dtype)
+            if kernel == "pallas" and latent_problems \
+                    and not self._kernel_interpret:
+                raise ValueError("mla_decode_attention: "
+                                 + "; ".join(latent_problems))
+            self.decode_kernel = ("pallas" if kernel == "pallas" or (
+                kernel is None and on_tpu and not latent_problems)
+                else "jnp")
+        elif kernel == "pallas":
             _pk.check_decode_shapes(q_heads, n_kv, hd, self.page_size,
                                     interpret=self._kernel_interpret,
                                     kv_dtype=pool_dtype)
@@ -1332,7 +1575,8 @@ class PagedKVEngine:
         # the pools are stored as rows (`_scatter_kv` chooses the same
         # way, from the scope and the pool)
         self.kv_write = ("pallas" if self.decode_kernel == "pallas"
-                         and kv_dtype != "int8" else "xla")
+                         and kv_dtype != "int8" and not self.latent_rank
+                         else "xla")
         # what a K or V page is outside the engine (a host-tier entry,
         # an exported bundle), whatever shape the pools store it in
         self._page_shape = (n_kv, self.page_size, hd)
@@ -1372,7 +1616,7 @@ class PagedKVEngine:
         # (heads and pages a grid step, grid, VMEM bytes): a count from
         # shapes, so it reads the same with and without a chip
         self.decode_plan = None
-        if self.decode_kernel == "pallas":
+        if self.decode_kernel == "pallas" and not self.latent_rank:
             self.decode_plan = _pk.decode_plan(
                 q_heads, n_kv, hd, self.page_size,
                 self.max_pages_per_slot, pool_dtype, slots=self.max_slots)
@@ -1517,6 +1761,14 @@ class PagedKVEngine:
             # for every layer would hold
             self.stats.update(decode_slot_steps=0, window_engaged_steps=0,
                               kv_tokens_held=0, kv_tokens_flat=0)
+        if self.latent_rank:
+            # latent rows the decode attention read (each live slot's
+            # keys, every layer, a step: the model's counter), rows a
+            # prefill expanded through W_kvb (every block of pages its
+            # calls looped over, padding included) and the prompt tokens
+            # of those calls (`_note_expanded`)
+            self.stats.update(mla_decode_rows=0, mla_expand_rows=0,
+                              mla_prefill_tokens=0)
         if self.block_length:
             # slot-forwards of the denoising steps and of the forwards that
             # store a settled block, positions those steps unmasked, and
@@ -2644,6 +2896,13 @@ class PagedKVEngine:
         through the chunk program in pieces of this length."""
         if self.draft_model is not None:
             return 1 << 30      # the chunk program has no draft mirror
+        if self.latent_rank:
+            # the expanded attend holds one block of scores whatever the
+            # call's length (the kernel's in VMEM, the jnp path's by
+            # `latent_block_pages`): a program of `_LATENT_PREFILL_TOKENS`
+            # tokens, all rows together
+            limit = max(8, _LATENT_PREFILL_TOKENS // rows)
+            return 1 << (limit.bit_length() - 1)
         if self._chunk_kernel and self.window:
             # through the chunk kernel a call holds no scores; what a
             # longer call costs is a longer ring (`ring_pages_for`). A
@@ -2734,6 +2993,23 @@ class PagedKVEngine:
         s["prefill_s"] += time.perf_counter() - t0[0]
         s["prefill_cpu_s"] += time.thread_time() - t0[1]
 
+    def _note_expanded(self, lens, n_valid, s):
+        """Count the latent rows one prefill call of `s` tokens a row
+        expands through W_kvb in every layer, by the blocks of the path
+        its programs take (`latent_expanded_rows`), and the call's prompt
+        tokens."""
+        if not self.latent_rank:
+            return
+        cfg = self.model.config
+        expanded = latent_expanded_rows(
+            self.decode_kernel, self._kernel_interpret, lens, n_valid, s,
+            heads=cfg.num_attention_heads, nope=cfg.qk_nope_head_dim,
+            rope=cfg.qk_rope_head_dim, dv=cfg.v_head_dim,
+            rank=self.latent_rank, pool=self.pools[0][0],
+            max_pages=self.max_pages_per_slot)
+        self.stats["mla_expand_rows"] += expanded * len(self._full.layers)
+        self.stats["mla_prefill_tokens"] += int(np.sum(n_valid))
+
     def _note_grouped(self, fn, calls):
         """Count `calls` calls of the program `fn` into the grouped
         expert kernel's counters: each call runs every kernel call its
@@ -2795,6 +3071,7 @@ class PagedKVEngine:
                         jax.tree.map(jnp.asarray, self._tables(rows)),
                         [a for kv in self.pools for a in kv])
                     self.pools = self._unflat_pools(flat)
+                    self._note_expanded(lens, nv, chunk)
                     padded += bw - int((nv > 0).sum())
                     for j, r in enumerate(part):
                         done[r] += int(nv[j])
@@ -2871,6 +3148,7 @@ class PagedKVEngine:
                     jnp.asarray(ids), jnp.asarray(lens), jnp.asarray(nv),
                     bt, [a for kv in self.pools for a in kv])
                 self.pools = self._unflat_pools(flat)
+                self._note_expanded(lens, nv, ppad)
                 if self.draft_model is not None:
                     # the draft's pools share the same block tables, so
                     # shared pages already hold the PREFIX's draft KV too

@@ -40,3 +40,7 @@ from paddle_tpu.models.block_diffusion_moe import (  # noqa: F401
     BlockDiffusionMoeConfig, BlockDiffusionMoeForCausalLM,
     BlockDiffusionMoeModel, tiny_block_diffusion_moe_config,
 )
+from paddle_tpu.models.latent_attn_moe import (  # noqa: F401
+    LatentAttnMoeConfig, LatentAttnMoeForCausalLM, LatentAttnMoeModel,
+    tiny_latent_attn_moe_config,
+)

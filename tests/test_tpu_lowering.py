@@ -218,10 +218,11 @@ def test_every_pallas_call_site_passes_a_literal_name():
                     f"{path}:{node.lineno}: pallas_call without a " \
                     f"literal name="
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 17
+    assert len(names) == len(set(names)) == 19
     assert set().union(*_NAMES.values()) \
         | {"flash_bwd_dq", "moe_experts_decode",
-           "paged_attention_prefill", "moe_grouped"} == set(names)
+           "paged_attention_prefill", "moe_grouped",
+           "mla_decode", "mla_prefill"} == set(names)
 
 
 def _trainer(mesh=None, **cfg_kw):
@@ -754,6 +755,105 @@ def test_block_diffusion_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     assert _kernel_names(lowered) == {"paged_kv_write",
                                       "paged_attention_prefill",
                                       "moe_grouped"}
+    assert "ragged_dot" not in lowered.as_text()
+
+
+# -- the decoder with latent attention, at the widths of its serve cell (32
+# -- slots, 32 heads over one latent row of 512 + 64 values a token, stored
+# -- at 640, 1,152 pages of 16)
+
+def test_the_latent_decode_kernel_compiles_at_the_cells_geometry(
+        as_tpu, described_v5e):
+    from paddle_tpu.kernels.mla_decode import (block_pages, kernel_row_width,
+                                               mla_decode_attention,
+                                               mla_decode_problems)
+    bf16, b, mp = jnp.bfloat16, 32, 1152
+    width = kernel_row_width(576)
+    assert width == 640 and block_pages(16, mp) == 32
+    assert not mla_decode_problems(32, width, 512, 16, bf16)
+    shapes = _shapes(described_v5e, ((b, 32, width), bf16),
+                     ((36865, 16, width), bf16), ((b, mp), jnp.int32),
+                     ((b,), jnp.int32))
+    fn = jax.jit(lambda q, pool, bt, lens: mla_decode_attention(
+        q, pool, bt, lens, rank=512, sm_scale=192 ** -0.5))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"mla_decode"}
+    # the block table is the call's first operand: what the benchmark's
+    # patterns find a tick program by
+    call = re.search(r"tpu_custom_call.*", lowered.as_text()).group(0)
+    assert re.search(rf"\(tensor<{b}x{mp}xi32>", call), call[:300]
+    if described_v5e is not None:
+        lowered.compile()       # Mosaic: whole lane tiles, VMEM
+
+
+def test_the_latent_prefill_kernel_compiles_at_the_cells_geometry(
+        as_tpu, described_v5e):
+    from paddle_tpu.kernels.mla_prefill import (mla_prefill_attention,
+                                                mla_prefill_problems)
+    bf16, s, mp = jnp.bfloat16, 2048, 1152
+    assert not mla_prefill_problems(s, 128, 64, 128, 512, 640, 16, bf16)
+    shapes = _shapes(described_v5e, ((1, s, 32, 128), bf16),
+                     ((1, s, 32, 64), bf16), ((36865, 16, 640), bf16),
+                     ((1, mp), jnp.int32),
+                     ((512, 32, 128), bf16), ((512, 32, 128), bf16),
+                     ((1,), jnp.int32), ((1,), jnp.int32))
+    fn = jax.jit(lambda *a: mla_prefill_attention(*a, sm_scale=192 ** -0.5))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"mla_prefill"}
+    # the grid's key extent rides in first, a traced scalar: no prefill call
+    # takes a block table first, which is what finds a tick program
+    call = re.search(r"tpu_custom_call.*", lowered.as_text()).group(0)
+    assert re.search(r": \(tensor<i32>, tensor<1xi32>, tensor<1xi32>, ",
+                     call), call[:300]
+    if described_v5e is not None:
+        lowered.compile()       # Mosaic: VMEM of a 1,024-query block
+
+
+def test_latent_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.models.latent_attn_moe import (LatentAttnMoeConfig,
+                                                   LatentAttnMoeForCausalLM)
+    from paddle_tpu.nn.layer import moe as moe_layer
+    monkeypatch.setattr(moe_layer, "on_tpu", lambda: True)
+    b, mp = 32, 1152
+    paddle_tpu.seed(0)
+    model = LatentAttnMoeForCausalLM(LatentAttnMoeConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=2, n_routed_experts=16, moe_intermediate_size=128))
+    model = paddle_tpu.amp.decorate(models=model, level="O2",
+                                    dtype="bfloat16")
+    model.eval()
+    eng = PagedKVEngine(model, max_slots=b, page_size=16, num_pages=65,
+                        max_pages_per_slot=mp, kernel=None)
+    assert eng.decode_kernel == "pallas" and eng.kv_write == "xla"
+    assert eng._prefill_limit(1) == 2048
+    pools = [a for kv in eng.pools for a in kv]
+    assert [p.shape for p in pools] == [(65, 16, 640)] * 2
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+    tick = eng._tick_fn(False)
+    rows = (z(b), z(b), z(b, dt=bool), z(b))    # tok, lens, active, limit
+    lowered = _lower_tpu(tick.func, *tick.args, rows, z(b, mp), z(b), key,
+                         np.int32(0), pools)
+    assert _kernel_names(lowered) == {"mla_decode", "moe_experts_decode"}
+    firsts = set(re.findall(r"tpu_custom_call[^\n]*?\(tensor<(\d+x\d+)xi32>",
+                            lowered.as_text()))
+    assert f"{b}x{mp}" in firsts, firsts
+    text = lowered.as_text(debug_info=True)
+    for scope in ("kv_write", "mla_absorb", "mla_unabsorb", "paged_attn",
+                  "moe", "shared_expert", "mlp"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    chunk = eng._prefill_chunk_fn(2048, 1)
+    lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 2048), z(1), z(1),
+                         z(1, mp), pools)
+    # a 2,048-row chunk takes the grouped kernel, and its attention the
+    # expanded form in the prefill kernel, under its own scope
+    assert _kernel_names(lowered) == {"moe_grouped", "mla_prefill"}
+    text = lowered.as_text(debug_info=True)
+    assert re.search(r'[/("]mla_prefill[/)"]', text)
     assert "ragged_dot" not in lowered.as_text()
 
 

@@ -15,7 +15,9 @@ of the weights). Builder `window_attn_moe`: the window mask off (a window
 layer attends over every causal key, as a full layer does), the routing
 bias in the gates (it weighs as well as chooses), the last layer's shared
 expert dropped (its down projection zero in the engine's view of the
-weights). Builder `block_diffusion_moe`: the forward that stores a settled
+weights). Builder `latent_attn_moe`: the routing's correction bias in
+the gates, the last layer's shared expert dropped. Builder
+`block_diffusion_moe`: the forward that stores a settled
 block left out (its K and V stay the last denoising step's), a prompt
 prefilled under the causal mask, the mask inside a block made causal (a
 denoising step's rows see no later row of their block), the last layer's
@@ -215,6 +217,10 @@ def main():
         "window_attn_moe": (
             {"window_mask_off": window_mask_off,
              "bias_in_the_gates": bias_in_the_gates},
+            ("shared_expert_dropped", lambda m: m.model.layers[-1]
+             .mlp.shared_expert.down_proj.weight)),
+        "latent_attn_moe": (
+            {"bias_in_the_gates": bias_in_the_gates},
             ("shared_expert_dropped", lambda m: m.model.layers[-1]
              .mlp.shared_expert.down_proj.weight)),
     }[family]
