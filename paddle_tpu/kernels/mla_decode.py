@@ -21,10 +21,16 @@ line for every slot of a decode step:
   reader finds a tick program by: `custom-call(s32[slots, pages], ...`);
 - the grid is one step a slot; a step loops over the slot's key blocks of
   `block_pages` pages, as many as its length reaches, so no block past a
-  length costs a step. The body copies the pages the slot owns, one
-  `make_async_copy` a page, into one of two VMEM windows while the
-  iteration before computes on the other (after a slot's last block, the
-  next slot's first), as `paged_attention.py` does;
+  length costs a step. A turn of the loop starts the copies of the next
+  window (this slot's next block or, after its last, the next slot's
+  first) into one of two VMEM windows, then waits for its own and
+  computes on it while those run. The starts are straight-line code, one
+  `make_async_copy` for EVERY page of the block, and the wait is one wait
+  of the window's whole size: a count of copies fixed at trace time needs
+  no scalar loop to start them and no wait a page. So a block reads pages
+  past the slot's length too: the block table names the trash page 0
+  there (the engine keeps it so), a block past the table's end copies its
+  last page again, and those columns are masked by position;
 - one dot of the H query rows [q~ | q_pe] against the window's rows
   scores every head (contracting over the whole row), one more against
   the window's first `rank` lanes accumulates the value; an online
@@ -51,11 +57,13 @@ __all__ = ["mla_decode_attention", "mla_decode_reference",
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
-# keys a block of the loop takes: an iteration's fixed cost stays small
-# against the time its copies take at the HBM's rate (512 rows of 1,152 B
-# are 0.72 us at 819 GB/s), and the two windows take 1.3 MB of VMEM at the
-# padded width of 640 lanes
-_BLOCK_TOKENS = 512
+# keys a block of the loop takes. A turn costs a fixed ~0.4 us (its
+# straight-line starts, its wait, the softmax's reductions) beside ~1 us a
+# 512 keys (their copies at 640 lanes, ~1.3 KB a row), and a block copies
+# on average half a block of trash pages a slot: 1,024 keys is the better
+# of 512 and 1,024 at the serve cell's lengths of 2k-18k (one v5e,
+# tools/mla_decode_bench.py), and the two windows take 2.6 MB of VMEM
+_BLOCK_TOKENS = 1024
 
 
 def kernel_row_width(width):
@@ -105,29 +113,33 @@ def _kernel(bt_ref, lens_ref, q_ref, pool_hbm, acc_ref, m_ref, l_ref, buf,
         slot of negative length, whose columns are all masked)."""
         return jax.lax.div(jnp.maximum(lens_ref[b], 0), t) + 1
 
-    def window(b, blk, slot, do):
-        """`do` ("start" or "wait") the copies of block `blk` of slot b's
-        pages into window `slot`: the pages its length reaches."""
-        first = blk * pages
-        last = jnp.clip(jax.lax.div(lens_ref[b], page_size), 0, mp - 1)
+    def start(b, blk, slot):
+        """Start the copies of block `blk` of slot b into window `slot`:
+        every page of the block, straight-line, a page past the block
+        table's end as its last one (columns past a slot's length are
+        masked, and the table names a page, the trash page at least,
+        wherever the slot holds none)."""
+        for p in range(pages):
+            pltpu.make_async_copy(
+                pool_hbm.at[bt_ref[b, jnp.minimum(blk * pages + p, mp - 1)]],
+                buf.at[slot, pl.ds(p * page_size, page_size)],
+                sem.at[slot]).start()
 
-        def page(p, carry):
-            row = pl.multiple_of(p * page_size, page_size)
-            getattr(pltpu.make_async_copy(
-                pool_hbm.at[bt_ref[b, first + p]],
-                buf.at[slot, pl.ds(row, page_size)], sem.at[slot]), do)()
-            return carry
-
-        jax.lax.fori_loop(0, jnp.clip(last + 1 - first, 0, pages), page, 0)
+    def wait(slot):
+        """Wait for a window's copies: one wait of the whole window's
+        size."""
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sem.at[slot]).wait()
 
     @pl.when(bi == 0)
     def _first():
-        # columns past the length get a probability of exactly 0, but 0 x
-        # what VMEM held before the call may be NaN: from here on the
-        # windows hold zeros or pool rows
+        # a masked column's probability is exactly 0, but 0 x what VMEM
+        # held before the call may be NaN. Every window is filled whole by
+        # its copies before it is read; the zeros keep that so for a
+        # window a future change copies only in part, at one store a call
         buf[...] = jnp.zeros_like(buf)
         cur_ref[0] = 0
-        window(0, 0, 0, "start")
+        start(0, 0, 0)
 
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -146,16 +158,13 @@ def _kernel(bt_ref, lens_ref, q_ref, pool_hbm, acc_ref, m_ref, l_ref, buf,
         # next slot's first; the grid runs in order, so that one finds its
         # window already on the way
         ends = j + 1 >= n
+        nxt = jnp.where(ends, bi + 1, bi)
 
-        @pl.when(jnp.logical_not(ends))
-        def _next_block():
-            window(bi, j + 1, 1 - cur, "start")
+        @pl.when(nxt < nb)
+        def _next():
+            start(nxt, jnp.where(ends, 0, j + 1), 1 - cur)
 
-        @pl.when(ends & (bi + 1 < nb))
-        def _next_slot():
-            window(bi + 1, 0, 1 - cur, "start")
-
-        window(bi, j, cur, "wait")
+        wait(cur)
         cur_ref[0] = 1 - cur
         kv = buf[cur]                                # (t, width)
         s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),

@@ -188,18 +188,32 @@ def test_the_absorbed_decode_is_the_expanded_form():
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("lens", [[0, 7, 8, 511], [512, -1, 300, 1023]],
-                         ids=["block-edges", "a-slot-idle"])
-def test_the_decode_kernel_interpreted_is_its_jnp_twin(lens):
-    """The Pallas kernel over blocks of 32 pages (512 keys), interpreted,
-    against `mla_decode_reference`: lengths at a page's and a block's
-    edges, and a slot of negative length, which reads nothing."""
+@pytest.mark.parametrize(
+    "lens, mp, trash",
+    [([0, 7, 8, 1023], 128, False), ([1024, -1, 300, 2047], 128, False),
+     ([100, 1500, -1, 2000], 128, True), ([0, 1040, 1279, 300], 80, False),
+     ([1023, 1024, 1025, -1], 128, False)],
+    ids=["block-edges", "a-slot-idle", "the-trash-page-past-a-length",
+         "a-table-not-whole-blocks", "around-a-block-edge"])
+def test_the_decode_kernel_interpreted_is_its_jnp_twin(lens, mp, trash):
+    """The Pallas kernel over blocks of 64 pages (1,024 keys),
+    interpreted, against `mla_decode_reference`: lengths at a page's and a
+    block's edges, and a slot of negative length, which reads nothing. A
+    window copies every page of its block: past a slot's last page the
+    table names the trash page 0 (here full of large finite values, which
+    the mask must keep out), and a block past the table's end (80 pages,
+    so block 1 holds 16) copies its last page again."""
     rng = np.random.default_rng(1)
-    b, h, rank, width, mp = len(lens), 8, 128, 256, 64
-    assert mla.block_pages(16, mp) == 32
+    b, h, rank, width = len(lens), 8, 128, 256
+    assert mla.block_pages(16, mp) == 64
     pool = jnp.asarray(rng.standard_normal((b * mp + 1, 16, width)),
                        jnp.float32)
-    bt = jnp.asarray(rng.permutation(b * mp).reshape(b, mp) + 1, jnp.int32)
+    bt = rng.permutation(b * mp).reshape(b, mp) + 1
+    if trash:
+        pool = pool.at[0].set(1e30)
+        held = np.maximum(np.asarray(lens), 0)[:, None] // 16
+        bt = np.where(np.arange(mp)[None, :] > held, 0, bt)
+    bt = jnp.asarray(bt, jnp.int32)
     q = jnp.asarray(rng.standard_normal((b, h, width)), jnp.float32)
     lens = jnp.asarray(lens, jnp.int32)
     got = mla.mla_decode_attention(q, pool, bt, lens, rank=rank,

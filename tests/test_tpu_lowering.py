@@ -769,7 +769,7 @@ def test_the_latent_decode_kernel_compiles_at_the_cells_geometry(
                                                mla_decode_problems)
     bf16, b, mp = jnp.bfloat16, 32, 1152
     width = kernel_row_width(576)
-    assert width == 640 and block_pages(16, mp) == 32
+    assert width == 640 and block_pages(16, mp) == 64
     assert not mla_decode_problems(32, width, 512, 16, bf16)
     shapes = _shapes(described_v5e, ((b, 32, width), bf16),
                      ((36865, 16, width), bf16), ((b, mp), jnp.int32),
