@@ -119,6 +119,18 @@ expanded into each head's keys and values a block at a time
 else). What assumes K and V pages refuses such a
 model by name (`_refuse_uncarried`), as it refuses an index pool, rings
 and blocks.
+
+Layers with a state a slot: a model whose `config.layer_types` names
+"mamba" layers (models/ssm_hybrid.py) keeps each such layer's conv window
+and SSM state in the engine's state cache (`_SlotState`), one row a slot
+beside the page groups, which the programs take with the pools: a tick's
+rows are the slots, a prefill's name theirs in `PagedState.slots`
+(`slot_state_read` / `slot_state_write`; a prompt's first program reads
+zeros, a padding row writes nothing). Layers that hold nothing of their
+own (`_HOLD_NOTHING`: a cross layer, which reads another layer's pages,
+and a gated memory unit) ride no table. A prefill's scan is the Pallas
+`ssm_scan` on the Pallas path (`selective_scan`); what assumes that the
+pages hold a slot's whole context refuses such a model by name.
 """
 from __future__ import annotations
 
@@ -142,6 +154,7 @@ from paddle_tpu.kernels import mla_decode as _mla
 from paddle_tpu.kernels import mla_prefill as _mlp
 from paddle_tpu.kernels import moe_grouped as _mg
 from paddle_tpu.kernels import paged_attention as _pk
+from paddle_tpu.kernels import ssm_scan as _ssm
 from paddle_tpu.kernels.prefill_attention import (chunk_attention,
                                                   chunk_attention_problems)
 from paddle_tpu.nn.functional.key_selection import index_scores, select_top
@@ -156,7 +169,9 @@ from paddle_tpu.inference.prefix import PrefixCache, chain_keys
 from paddle_tpu.inference.tenancy import WeightedFairScheduler
 
 __all__ = ["PagedState", "paged_attention_update", "latent_attention_update",
-           "decode_kernel_scope", "PagedKVEngine"]
+           "paged_kv_store", "paged_attention_read", "slot_state_read",
+           "slot_state_write", "selective_scan", "decode_kernel_scope",
+           "PagedKVEngine"]
 
 
 # the float32 scores that one prefill program's whole-window attend may
@@ -172,6 +187,14 @@ _PREFILL_SCORE_BYTES = 2 ** 30
 # queries (2 x 512 x 128 MACs a key a head against 320 a (query, key)
 # pair), and a prompt's last call pads half a program on average
 _LATENT_PREFILL_TOKENS = 2048
+
+# the tokens of one prefill program of a model with a state a slot (Mamba
+# layers): the state rides from one program to the next, so the length is
+# the work's. At 1,024 a program's matmuls (2 x 3.3 G weights x 1,024, 35 ms
+# at the bf16 peak for the serve cell's 3.8 B model) already take 3.7 times
+# the read of its weights (9.4 ms), so a longer one saves little, and the
+# window layers' rings grow with it (`ring_pages_for`)
+_STATE_PREFILL_TOKENS = 1024
 
 # What a prefill program costs by its shape, in tokens (`prefill_width`),
 # fitted once on a v5e over the dense serve cell's model (SmolLM2-1.7B in
@@ -240,11 +263,16 @@ class PagedState(NamedTuple):
         in physical page ring_tables[i, (p // page_size) % ring_pages]
         of THOSE layers' pools, a ring that is never grown and whose
         pages are overwritten in place.
+    slots: (b,) int32, or None where the rows are the engine's slots in
+        order (a decode tick) — the slot whose fixed-size state (a Mamba
+        layer's, `slot_state_read`) each row reads and writes; a row
+        that is padding names one past the last slot, so its write drops.
     """
     block_tables: jnp.ndarray
     lens: jnp.ndarray
     n_valid: jnp.ndarray
     ring_tables: jnp.ndarray | None = None
+    slots: jnp.ndarray | None = None
 
 
 def ring_pages_for(window, tokens, page_size):
@@ -383,7 +411,7 @@ def _scatter_kv(kp, vp, k, v, state: PagedState, k_scale=None,
 
 
 def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
-                  v_scale=None, hk=None, block=0):
+                  v_scale=None, hk=None, block=0, scale=None):
     """jnp fallback attend: gather each slot's page window and run a
     dense masked softmax in f32. GQA folds query heads into a head-
     group axis (reshape + einsum) instead of jnp.repeat-ing K/V —
@@ -392,8 +420,9 @@ def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
     q: (b, s, hq, d). `hk`: the pools' kv heads, where they are stored
     as rows and do not say (the gathered window is seen by heads and
     tokens, never the pool). `block`: causal by blocks of `block`
-    positions, a query sees every key of its own block. Returns
-    (b, s, hq*d) in q.dtype.
+    positions, a query sees every key of its own block. `scale`: the
+    scores' factor, 1 / sqrt(d) by default. Returns (b, s, hq*d) in
+    q.dtype.
     """
     bt, lens = _val(state.block_tables), _val(state.lens)
     b, s, hq, d = q.shape
@@ -424,14 +453,15 @@ def _attend_pages(q, kp, vp, state: PagedState, k_scale=None,
     if hq != hk:
         g = hq // hk
         qg = qt.reshape(b, hk, g, s, d)
-        scores = jnp.einsum("bhgsd,bhcd->bhgsc", qg,
-                            ks) / math.sqrt(d)
+        scores = jnp.einsum("bhgsd,bhcd->bhgsc", qg, ks)
+        scores = scores / math.sqrt(d) if scale is None else scores * scale
         scores = jnp.where(mask[:, :, None], scores, -1e9)
         p = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bhgsc,bhcd->bhgsd", p, vs)
         out = out.reshape(b, hq, s, d)
     else:
-        scores = jnp.einsum("bhsd,bhcd->bhsc", qt, ks) / math.sqrt(d)
+        scores = jnp.einsum("bhsd,bhcd->bhsc", qt, ks)
+        scores = scores / math.sqrt(d) if scale is None else scores * scale
         scores = jnp.where(mask, scores, -1e9)
         p = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bhsc,bhcd->bhsd", p, vs)
@@ -467,7 +497,8 @@ def _select_keys(qi, w, ip, state: PagedState, topk):
         return select_top(scores, causal, topk)
 
 
-def _attend_selected(q, kp, vp, state: PagedState, select, hk=None):
+def _attend_selected(q, kp, vp, state: PagedState, select, hk=None,
+                     scale=None):
     """Attend over the selected keys only: `select` (b, s, L) bool over
     the columns of each slot's page window (causality is in it). The keys
     go by in blocks of whole pages under an online softmax, as many blocks
@@ -475,8 +506,8 @@ def _attend_selected(q, kp, vp, state: PagedState, select, hk=None):
     one block's scores (`_SELECT_SCORE_BYTES`, from the shapes) and a
     prefill chunk does not pay for the window's empty end.
 
-    q: (b, s, hq, d); `hk` as in `_attend_pages`. Returns (b, s, hq*d)
-    in q.dtype."""
+    q: (b, s, hq, d); `hk` and `scale` as in `_attend_pages`. Returns
+    (b, s, hq*d) in q.dtype."""
     bt, lens, n_valid = (_val(state.block_tables), _val(state.lens),
                          _val(state.n_valid))
     b, s, hq, d = q.shape
@@ -493,7 +524,7 @@ def _attend_selected(q, kp, vp, state: PagedState, select, hk=None):
     btp = jnp.pad(bt, ((0, 0), (0, nblk * bp - mp)))
     sel = jnp.pad(select, ((0, 0), (0, 0), (0, nblk * cols - mp * ps)))
     qg = jnp.moveaxis(q.reshape(b, s, hk, g, d), 1, 3)     # (b,hk,g,s,d)
-    scale = 1.0 / math.sqrt(d)
+    scale = scale or 1.0 / math.sqrt(d)
 
     def block(i, carry):
         m, l, acc = carry
@@ -599,7 +630,7 @@ def _ring_view(state: PagedState, s, window, page_size):
     return table, lens - first * page_size, seen
 
 
-def _attend_window(q, k, v, cache, state: PagedState, window):
+def _attend_window(q, k, v, cache, state: PagedState, window, scale=None):
     """The ring form of `paged_attention_update`: this call's k and v go
     into the ring's pages, over what they held a ring's length ago, and
     each token attends over the `window` newest positions up to its own
@@ -627,7 +658,7 @@ def _attend_window(q, k, v, cache, state: PagedState, window):
         if kind == "pallas" and s == 1:
             out = _pk.paged_decode_attention(
                 q[:, 0], kp, vp, table, at, interpret=interpret,
-                select=seen[:, 0], kv_heads=hk)
+                select=seen[:, 0], kv_heads=hk, sm_scale=scale)
             out = out[:, None].reshape(b, s, hq * d).astype(q.dtype)
         elif _chunk_kernel_takes(q, hk):
             # the view's columns are positions in order from its first
@@ -635,11 +666,12 @@ def _attend_window(q, k, v, cache, state: PagedState, window):
             lens = _val(state.lens)
             out = chunk_attention(
                 q, _gathered(kp, table, hk, d), _gathered(vp, table, hk, d),
-                lens, lens - at, window=window, interpret=interpret)
+                lens, lens - at, window=window, sm_scale=scale,
+                interpret=interpret)
         else:
             out = _attend_selected(
                 q, kp, vp, PagedState(table, at, _val(state.n_valid)),
-                seen, hk)
+                seen, hk, scale)
     return Tensor(out), (Tensor(kp), Tensor(vp))
 
 
@@ -661,7 +693,7 @@ def _unfold_rows(out, s, hk):
 
 
 def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
-                           window=None, block=None):
+                           window=None, block=None, scale=None):
     """Write this call's k/v into the slot's pages, then attend over the
     slot's whole paged window. One code path serves BOTH phases of the
     reference contract (block_multi_head_attention_kernel.cu's prefill
@@ -679,7 +711,8 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
     `_attend_indexed`).
     `window`: this layer attends over the newest `window` positions alone
     and its pools ride `state.ring_tables`, not the block table
-    (`_attend_window`).
+    (`_attend_window`); `scale` is then the scores' factor (1 / sqrt(d)
+    by default).
     `block`: attention is causal by blocks of `block` positions (generation
     by diffusion over blocks): a query sees every key up to the last
     position of its own block, later rows of this call included. A call
@@ -706,7 +739,10 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
         raise ValueError("paged_attention_update(block=) takes a plain "
                          "(k_pool, v_pool) cache under one block table")
     if window:
-        return _attend_window(q, k, v, cache, state, window)
+        return _attend_window(q, k, v, cache, state, window, scale)
+    if scale is not None:
+        raise ValueError("paged_attention_update(scale=) is a window "
+                         "layer's: other calls score at 1 / sqrt(d)")
     if len(cache) == 3:
         if index is None:
             raise ValueError(
@@ -774,6 +810,88 @@ def paged_attention_update(q, k, v, cache, state: PagedState, index=None,
         return Tensor(out), (Tensor(kp), Tensor(vp),
                              Tensor(k_scale), Tensor(v_scale))
     return Tensor(out), (Tensor(kp), Tensor(vp))
+
+
+def paged_kv_store(k, v, cache, state: PagedState):
+    """Write this call's k/v (b, s, hk, d) into the slot's pages under the
+    block table, and attend nothing: the write of a layer whose queries
+    run at fewer positions than its keys (`paged_attention_read`).
+    Returns the new (k_pool, v_pool)."""
+    if len(cache) != 2:
+        raise ValueError("paged_kv_store takes a plain (k_pool, v_pool) "
+                         "cache")
+    with jax.named_scope("kv_write"):
+        kp, vp, _ks, _vs = _scatter_kv(_val(cache[0]), _val(cache[1]),
+                                       _val(k), _val(v), state)
+    return Tensor(kp), Tensor(vp)
+
+
+def paged_attention_read(q, cache, state: PagedState, pos, hk,
+                         scale=None):
+    """One query a row over pages another call wrote, under the block
+    table: q (b, hq, d) at position `pos` (b,) attends over the keys 0 ..
+    pos of the pools' `hk` heads and writes nothing, through the decode
+    kernel inside `decode_kernel_scope("pallas")` (its length is `pos`),
+    the jnp attend else. `scale`: the scores' factor, 1 / sqrt(d) by
+    default. Returns (b, hq * d) in q.dtype."""
+    kp, vp = _val(cache[0]), _val(cache[1])
+    q, pos = _val(q), _val(pos)
+    b, hq, d = q.shape
+    bt = _val(state.block_tables)
+    kind, interpret = _decode_kernel_choice()
+    with jax.named_scope("paged_attn"):
+        if kind == "pallas":
+            out = _pk.paged_decode_attention(
+                q, kp, vp, bt, pos, interpret=interpret, kv_heads=hk,
+                sm_scale=scale)
+            return out.reshape(b, hq * d).astype(q.dtype)
+        out = _attend_pages(q[:, None], kp, vp, PagedState(bt, pos, pos),
+                            hk=hk, scale=scale)
+    return out[:, 0]
+
+
+def slot_state_read(cache, state: PagedState):
+    """A layer's fixed-size state a slot (a Mamba layer's conv window and
+    SSM state: `cache`, each (slots, ...)), as this call's rows: a tick's
+    rows are the slots, a prefill's rows read `state.slots`, and a row
+    that starts a prompt (lens 0) starts from zeros."""
+    arrays = [_val(a) for a in cache]
+    if state.slots is None:
+        return arrays
+    slots = _val(state.slots)
+    fresh = _val(state.lens) == 0
+    return [jnp.where(fresh.reshape((-1,) + (1,) * (a.ndim - 1)), 0,
+                      a[jnp.clip(slots, 0, a.shape[0] - 1)])
+            for a in arrays]
+
+
+def slot_state_write(cache, rows, state: PagedState):
+    """The inverse of `slot_state_read`: a tick's rows ARE the new state
+    (a dead row's step leaves its own as it was: the model's), a
+    prefill's rows go back to their slots, a padding row's write drops.
+    Returns the new cache."""
+    if state.slots is None:
+        return tuple(Tensor(r.astype(_val(a).dtype))
+                     for a, r in zip(cache, rows))
+    slots = _val(state.slots)
+    return tuple(Tensor(_val(a).at[slots].set(r.astype(_val(a).dtype),
+                                              mode="drop"))
+                 for a, r in zip(cache, rows))
+
+
+def selective_scan(u, delta, bt, ct, at, d, state0):
+    """A Mamba layer's selective scan over this call's tokens
+    (kernels/ssm_scan.py has the shapes): the Pallas `ssm_scan` inside
+    `decode_kernel_scope("pallas")` for a many-token call whose shapes it
+    takes, the `lax.scan` else (a decode step's one token). -> (y (b, s,
+    E) float32, the final state (b, N, E) float32)."""
+    kind, interpret = _decode_kernel_choice()
+    _b, s, e = u.shape
+    if kind == "pallas" and s > 1 and not _ssm.ssm_scan_problems(
+            s, e, at.shape[0], interpret):
+        return _ssm.ssm_scan(u, delta, bt, ct, at, d, state0,
+                             interpret=interpret)
+    return _ssm.ssm_scan_reference(u, delta, bt, ct, at, d, state0)
 
 
 def _latent_rows(c, k_pe, width):
@@ -1164,6 +1282,36 @@ class _PageGroup:
             for grp in self.pools for a in grp)
 
 
+class _SlotState:
+    """The fixed-size state a slot keeps in the model's layers that carry
+    one (a Mamba layer's conv window and SSM state), beside the page
+    groups: which layers hold it and their arrays, one tuple a layer, each
+    array (slots, ...). Nothing is handed out: a slot's row is its own
+    from admission to retirement, and a prefill that starts a prompt reads
+    it as zeros (`slot_state_read`), so a retired slot's state needs no
+    clearing."""
+    __slots__ = ("layers", "slots", "pools")
+
+    def __init__(self, layers, slots, shapes):
+        self.layers = list(layers)
+        self.slots = int(slots)
+        self.pools = [tuple(jnp.zeros((self.slots,) + tuple(shape), dtype)
+                            for shape, dtype in shapes)
+                      for _ in self.layers]
+
+    def bytes_per_slot(self):
+        """HBM bytes a slot's state pins in these layers, from the real
+        buffers."""
+        return sum(a.size * a.dtype.itemsize // self.slots
+                   for grp in self.pools for a in grp)
+
+
+# the kinds of layer (`config.layer_types`) that hold nothing of their own:
+# a cross layer reads another layer's pages, a gated memory unit the
+# memory of its own step
+_HOLD_NOTHING = ("cross_attention", "gmu")
+
+
 def _refuse_uncarried(what, uncarried, whys, extra=()):
     """The engine's one refusal of the features that assume a cache of K
     and V pages, for a model whose cache is something else (`what` says
@@ -1178,9 +1326,10 @@ def _refuse_uncarried(what, uncarried, whys, extra=()):
 
 
 def _state_of(tables, lens, n_valid):
-    """`PagedState` from what a program was handed as its table(s)."""
+    """`PagedState` from what a program was handed as its table(s): the
+    block table, or a tuple (it, the ring table[, the rows' slots])."""
     if isinstance(tables, tuple):
-        return PagedState(tables[0], lens, n_valid, tables[1])
+        return PagedState(tables[0], lens, n_valid, *tables[1:])
     return PagedState(tables, lens, n_valid)
 
 
@@ -1311,9 +1460,14 @@ class PagedKVEngine:
         # chunks of that limit for a longer one.
         self.prefill_chunk = (int(prefill_chunk) if prefill_chunk
                               else None)
-        n_kv = getattr(cfg, "num_key_value_heads", None) \
+        # what a cache holds a token: the model's kv heads, or where it
+        # stores them otherwise (paired heads of differential attention as
+        # one of twice the width) what its config says it stores
+        n_kv = getattr(cfg, "cache_kv_heads", None) \
+            or getattr(cfg, "num_key_value_heads", None) \
             or cfg.num_attention_heads
-        hd = getattr(cfg, "head_dim", None) \
+        hd = getattr(cfg, "cache_head_dim", None) \
+            or getattr(cfg, "head_dim", None) \
             or cfg.hidden_size // cfg.num_attention_heads
         if dtype is None:
             p = next(iter(model.parameters()))
@@ -1375,6 +1529,40 @@ class PagedKVEngine:
             raise ValueError("a draft model with an index pool or a latent "
                              "pool is not carried (its pools are K and V "
                              "alone)")
+        # a model with layers that carry a fixed-size state a slot (the
+        # config's `layer_types` names them "mamba": a conv window and an
+        # SSM state) keeps it in the engine's state cache (`_SlotState`),
+        # beside the pages, and layers that hold nothing of their own
+        # (`_HOLD_NOTHING`) ride no table. What assumes that a page holds
+        # all a slot's context refuses here, by name.
+        kinds = list(getattr(cfg, "layer_types", None) or [])
+        state_layers = [i for i, kind in enumerate(kinds) if kind == "mamba"]
+        # the state cache: a slot's last K - 1 pre-conv rows in the pools'
+        # dtype and its SSM state (N, E) in float32, a Mamba layer
+        self._state = None
+        if state_layers:
+            _refuse_uncarried(
+                f"this model keeps a fixed-size state a slot in layers "
+                f"{state_layers} (a Mamba layer's conv window and SSM "
+                "state)", uncarried, (
+                    "no int8 K or V beside a state is held to the "
+                    "reference",
+                    "a warm tail would start from a state no page holds (a "
+                    "page's end keeps no snapshot of it)",
+                    "a spilled page carries no state",
+                    "an exported page carries no state",
+                    "a rejected draft token has already moved the state"),
+                extra=((self.index_dim, "an index pool (the key selection "
+                        "reads every key of a slot)"),
+                       (self.latent_rank, "a latent pool"),
+                       (int(getattr(cfg, "block_length", 0) or 0),
+                        "a block length (a denoising step rewrites its "
+                        "block, and a state cannot be rewound)")))
+            e, n = int(cfg.mamba_d_inner), int(cfg.mamba_d_state)
+            self._state = _SlotState(
+                state_layers, self.max_slots,
+                (((int(cfg.mamba_d_conv) - 1, e), pool_dtype),
+                 ((n, e), jnp.float32)))
         # a model with layers that attend inside a window (the config's
         # `layer_types` names them, `sliding_window` is the window) keeps
         # those layers' K and V in rings under a SECOND page table
@@ -1382,7 +1570,6 @@ class PagedKVEngine:
         # layers that keep every token. What assumes one table refuses
         # here, by name.
         window = int(getattr(cfg, "sliding_window", 0) or 0)
-        kinds = list(getattr(cfg, "layer_types", None) or [])
         window_layers = [i for i, kind in enumerate(kinds)
                          if kind == "sliding_attention"] if window else []
         # the window of the layers in rings; 0: no layer is
@@ -1585,7 +1772,8 @@ class PagedKVEngine:
         # slot, each long enough for the longest write of one call
         self._full = _PageGroup(
             [i for i in range(cfg.num_hidden_layers)
-             if i not in window_layers],
+             if i not in window_layers and i not in state_layers
+             and (not kinds or kinds[i] not in _HOLD_NOTHING)],
             self.num_pages, self.max_pages_per_slot, self.max_slots)
         self._ring = None
         # whether a prefill's attention goes through the Pallas chunk
@@ -1769,6 +1957,14 @@ class PagedKVEngine:
             # of those calls (`_note_expanded`)
             self.stats.update(mla_decode_rows=0, mla_expand_rows=0,
                               mla_prefill_tokens=0)
+        if self._state is not None:
+            # valid prompt tokens a prefill call ran through the selective
+            # scan (in each Mamba layer), the calls, and the prompt
+            # positions at which the cross-decoder did not run (a call runs
+            # it at each row's last token alone): `_note_state`
+            self.stats.update(ssm_scan_tokens=0, ssm_scan_calls=0)
+            if any(kind == "cross_attention" for kind in kinds):
+                self.stats.update(cross_skipped_tokens=0)
         if self.block_length:
             # slot-forwards of the denoising steps and of the forwards that
             # store a settled block, positions those steps unmasked, and
@@ -1795,16 +1991,17 @@ class PagedKVEngine:
 
     def kv_bytes_per_slot(self):
         """HBM bytes one fully-grown slot pins across every layer's KV
-        pools (int8 scale planes and draft-model pools included),
-        computed from the REAL buffer dtypes — so `kv_dtype` is honored
-        end-to-end instead of assuming f32/bf16 element sizes."""
+        pools (int8 scale planes and draft-model pools included) and its
+        state cache, computed from the REAL buffer dtypes — so `kv_dtype`
+        is honored end-to-end instead of assuming f32/bf16 element
+        sizes."""
         per_page = 0
         for grp in self.draft_pools or []:
             for arr in grp:
                 per_page += (arr.size * arr.dtype.itemsize
                              // self.num_pages)
         return per_page * self.max_pages_per_slot \
-            + sum(grp.bytes_per_slot() for grp in self._groups)
+            + sum(grp.bytes_per_slot() for grp in self._holders)
 
     # the table of the layers that keep every token, under the names the
     # scheduler has always used for it
@@ -1822,22 +2019,35 @@ class PagedKVEngine:
             else [self._full, self._ring]
 
     @property
+    def _holders(self):
+        """The page groups and the state cache: what holds a layer's
+        cache."""
+        return self._groups + ([self._state] if self._state else [])
+
+    @property
     def pools(self):
-        """Every layer's pools in the model's order, as the programs take
-        them; each lives in its group."""
-        if self._ring is None:
+        """Every layer's cache that holds one, in the model's order, as
+        the programs take them; each lives in its group or the state
+        cache."""
+        holders = self._holders
+        if len(holders) == 1:
             return self._full.pools
-        merged = [None] * sum(len(g.layers) for g in self._groups)
-        for grp in self._groups:
-            for i, pool in zip(grp.layers, grp.pools):
-                merged[i] = pool
-        return merged
+        held = sorted(((i, pool) for h in holders
+                       for i, pool in zip(h.layers, h.pools)),
+                      key=lambda pair: pair[0])
+        return [pool for _i, pool in held]
 
     @pools.setter
     def pools(self, pools):
-        for grp in self._groups:
-            grp.pools = pools if self._ring is None or pools is None \
-                else [pools[i] for i in grp.layers]
+        holders = self._holders
+        if len(holders) == 1 or pools is None:
+            for h in holders:
+                h.pools = pools
+            return
+        order = sorted(i for h in holders for i in h.layers)
+        at = dict(zip(order, pools))
+        for h in holders:
+            h.pools = [at[i] for i in h.layers]
 
     def page_groups(self):
         """What each page table holds, as the engine built it: the layers
@@ -1854,7 +2064,9 @@ class PagedKVEngine:
     def _tables(self, rows=None):
         """The block table as the host holds it now, a copy: one array,
         or with window layers (the table, the ring table). `rows`: of
-        these slots only, one row each, None for a row left empty."""
+        these slots only, one row each, None for a row left empty; with a
+        state cache then (the table, the ring table or None, each row's
+        slot, one past the last for an empty row)."""
         def cut(bt):
             if rows is None:
                 return bt.copy()
@@ -1863,6 +2075,11 @@ class PagedKVEngine:
                 if idx is not None:
                     out[r] = bt[idx]
             return out
+        if rows is not None and self._state is not None:
+            return (cut(self._full.bt),
+                    None if self._ring is None else cut(self._ring.bt),
+                    np.asarray([self.max_slots if idx is None else idx
+                                for idx in rows], np.int32))
         if self._ring is None:
             return cut(self._full.bt)
         return cut(self._full.bt), cut(self._ring.bt)
@@ -2896,6 +3113,12 @@ class PagedKVEngine:
         through the chunk program in pieces of this length."""
         if self.draft_model is not None:
             return 1 << 30      # the chunk program has no draft mirror
+        if self._state is not None:
+            # a state is carried from one program to the next whatever
+            # their length, so a program is sized by its work: at
+            # `_STATE_PREFILL_TOKENS` tokens, all rows together
+            limit = max(8, _STATE_PREFILL_TOKENS // rows)
+            return 1 << (limit.bit_length() - 1)
         if self.latent_rank:
             # the expanded attend holds one block of scores whatever the
             # call's length (the kernel's in VMEM, the jnp path's by
@@ -3010,6 +3233,20 @@ class PagedKVEngine:
         self.stats["mla_expand_rows"] += expanded * len(self._full.layers)
         self.stats["mla_prefill_tokens"] += int(np.sum(n_valid))
 
+    def _note_state(self, n_valid):
+        """Count one prefill call of a model with a state a slot: its
+        valid prompt tokens, all of which went through the scan, and the
+        positions of them at which the cross-decoder did not run (it runs
+        at each row's last)."""
+        if self._state is None:
+            return
+        s = self.stats
+        s["ssm_scan_tokens"] += int(np.sum(n_valid))
+        s["ssm_scan_calls"] += 1
+        if "cross_skipped_tokens" in s:
+            s["cross_skipped_tokens"] += int(np.sum(np.maximum(n_valid - 1,
+                                                               0)))
+
     def _note_grouped(self, fn, calls):
         """Count `calls` calls of the program `fn` into the grouped
         expert kernel's counters: each call runs every kernel call its
@@ -3072,6 +3309,7 @@ class PagedKVEngine:
                         [a for kv in self.pools for a in kv])
                     self.pools = self._unflat_pools(flat)
                     self._note_expanded(lens, nv, chunk)
+                    self._note_state(nv)
                     padded += bw - int((nv > 0).sum())
                     for j, r in enumerate(part):
                         done[r] += int(nv[j])
@@ -3149,6 +3387,7 @@ class PagedKVEngine:
                     bt, [a for kv in self.pools for a in kv])
                 self.pools = self._unflat_pools(flat)
                 self._note_expanded(lens, nv, ppad)
+                self._note_state(nv)
                 if self.draft_model is not None:
                     # the draft's pools share the same block tables, so
                     # shared pages already hold the PREFIX's draft KV too

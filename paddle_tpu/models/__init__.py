@@ -44,3 +44,7 @@ from paddle_tpu.models.latent_attn_moe import (  # noqa: F401
     LatentAttnMoeConfig, LatentAttnMoeForCausalLM, LatentAttnMoeModel,
     tiny_latent_attn_moe_config,
 )
+from paddle_tpu.models.ssm_hybrid import (  # noqa: F401
+    SsmHybridConfig, SsmHybridForCausalLM, SsmHybridModel,
+    tiny_ssm_hybrid_config,
+)

@@ -218,11 +218,11 @@ def test_every_pallas_call_site_passes_a_literal_name():
                     f"{path}:{node.lineno}: pallas_call without a " \
                     f"literal name="
                 names.append(kw["name"].value)
-    assert len(names) == len(set(names)) == 19
+    assert len(names) == len(set(names)) == 20
     assert set().union(*_NAMES.values()) \
         | {"flash_bwd_dq", "moe_experts_decode",
            "paged_attention_prefill", "moe_grouped",
-           "mla_decode", "mla_prefill"} == set(names)
+           "mla_decode", "mla_prefill", "ssm_scan"} == set(names)
 
 
 def _trainer(mesh=None, **cfg_kw):
@@ -855,6 +855,80 @@ def test_latent_engine_programs_lower_for_tpu(as_tpu, monkeypatch):
     text = lowered.as_text(debug_info=True)
     assert re.search(r'[/("]mla_prefill[/)"]', text)
     assert "ragged_dot" not in lowered.as_text()
+
+
+# -- the decoder-hybrid-decoder with Mamba layers, at the widths of its
+# -- serve cell (32 slots, 40 query rows over 10 kv pairs cached as heads
+# -- of 128, 768 pages of 16, 5,120 Mamba channels of 16 states)
+
+def test_the_scan_kernel_compiles_at_the_cells_geometry(as_tpu,
+                                                         described_v5e):
+    from paddle_tpu.kernels.ssm_scan import ssm_scan, ssm_scan_problems
+    f32, s, e, n = jnp.float32, 1024, 5120, 16
+    assert not ssm_scan_problems(s, e, n)
+    shapes = _shapes(described_v5e, ((1, s, e), f32), ((1, s, e), f32),
+                     ((1, n, s), f32), ((1, n, s), f32), ((n, e), f32),
+                     ((e,), f32), ((1, n, e), f32))
+    fn = jax.jit(lambda *a: ssm_scan(*a))
+    lowered = _lower_tpu(fn, *shapes) if described_v5e is None \
+        else fn.lower(*shapes)
+    assert _kernel_names(lowered) == {"ssm_scan"}
+    if described_v5e is not None:
+        lowered.compile()       # Mosaic: the token loop's registers, VMEM
+
+
+def test_ssm_hybrid_engine_programs_lower_for_tpu(as_tpu):
+    import paddle_tpu
+    from paddle_tpu.inference import PagedKVEngine
+    from paddle_tpu.models.ssm_hybrid import (SsmHybridForCausalLM,
+                                              tiny_ssm_hybrid_config)
+    b, mp = 32, 768
+    paddle_tpu.seed(0)
+    cfg = tiny_ssm_hybrid_config(vocab_size=512, hidden_size=256,
+                                 intermediate_size=512, num_attention_heads=4,
+                                 num_key_value_heads=2, sliding_window=512,
+                                 mamba_d_state=16)
+    model = paddle_tpu.amp.decorate(models=SsmHybridForCausalLM(cfg),
+                                    level="O2", dtype="bfloat16")
+    model.eval()
+    eng = PagedKVEngine(model, max_slots=b, page_size=16, num_pages=65,
+                        max_pages_per_slot=mp, kernel=None)
+    assert eng.decode_kernel == "pallas" and eng.kv_write == "pallas"
+    assert eng._chunk_kernel and eng._prefill_limit(1) == 1024
+    # a ring: the window's 33 pages at any offset and a program's 64
+    assert eng._ring.pages_per_slot == 97
+    assert eng._state.layers == [0, 2, 4] and eng._full.layers == [5]
+    pools = [a for kv in eng.pools for a in kv]
+    z = lambda *s, dt=np.int32: np.zeros(s, dt)         # noqa: E731
+    key = np.asarray(jax.random.key_data(jax.random.key(0)))
+    tick = eng._tick_fn(False)
+    rows = (z(b), z(b), z(b, dt=bool), z(b))    # tok, lens, active, limit
+    lowered = _lower_tpu(tick.func, *tick.args, rows, (z(b, mp), z(b, 97)),
+                         z(b), key, np.int32(0), pools)
+    # decode's scan and conv stay in jax.numpy inside the tick
+    assert _kernel_names(lowered) == {"paged_attention_decode",
+                                      "paged_kv_write"}
+    # the tick-finding patterns: the full layer's and the cross layer's
+    # calls take the [slots, max_pages_per_slot] block table first, a
+    # window layer's the 33 pages of its view
+    firsts = set(re.findall(r"tpu_custom_call[^\n]*?\(tensor<(\d+x\d+)xi32>",
+                            lowered.as_text()))
+    assert {f"{b}x{mp}", f"{b}x33"} <= firsts, firsts
+    text = lowered.as_text(debug_info=True)
+    for scope in ("ssm", "gmu", "cross_attn", "window", "kv_write",
+                  "paged_attn", "in_proj", "mlp", "sample"):
+        assert re.search(rf'[/("]{scope}[/)"]', text), scope
+    chunk = eng._prefill_chunk_fn(1024, 1)
+    lowered = _lower_tpu(chunk.func, *chunk.args, z(1, 1024), z(1), z(1),
+                         (z(1, mp), z(1, 97), z(1)), pools)
+    # the chunk's scan is the Pallas kernel; its window layers attend
+    # through the chunk kernel; the full layer and the cross layer at the
+    # last token alone, through the decode kernel
+    assert _kernel_names(lowered) == {"ssm_scan", "paged_attention_prefill",
+                                      "paged_attention_decode",
+                                      "paged_kv_write"}
+    text = lowered.as_text(debug_info=True)
+    assert re.search(r'[/("]ssm[/)"]', text)
 
 
 # -- no pool is copied: the serve cells' programs compiled for the chip ----

@@ -16,7 +16,10 @@ layer attends over every causal key, as a full layer does), the routing
 bias in the gates (it weighs as well as chooses), the last layer's shared
 expert dropped (its down projection zero in the engine's view of the
 weights). Builder `latent_attn_moe`: the routing's correction bias in
-the gates, the last layer's shared expert dropped. Builder
+the gates, the last layer's shared expert dropped. Builder `ssm_hybrid`:
+the Mamba layers' state reset at every prefill program's start (a long
+prompt's later programs start from zeros), the last gated memory unit
+dropped (its W_2 zero in the engine's view of the weights). Builder
 `block_diffusion_moe`: the forward that stores a settled
 block left out (its K and V stay the last denoising step's), a prompt
 prefilled under the causal mask, the mask inside a block made causal (a
@@ -184,6 +187,20 @@ def main():
             return real(self, ids, lens, rows_live, bt, flat)
         return patched(PagedKVEngine, "_block_forward", unstored)
 
+    def state_reset():
+        """A prefill program reads every Mamba layer's state as zeros, as
+        a prompt's first does: a prompt longer than one program loses
+        what its earlier programs carried."""
+        real = paged.slot_state_read
+
+        def fresh(cache, state):
+            rows = real(cache, state)
+            if state.slots is None:
+                return rows
+            calls["state_reset_at_the_program_boundary"] += 1
+            return [jnp.zeros_like(r) for r in rows]
+        return patched(paged, "slot_state_read", fresh)
+
     def causal_where(name, rows_hit):
         """`paged_attention_update` loses its `block=` for the calls whose
         rows a slot `rows_hit` takes: those attend causally by position."""
@@ -223,6 +240,11 @@ def main():
             {"bias_in_the_gates": bias_in_the_gates},
             ("shared_expert_dropped", lambda m: m.model.layers[-1]
              .mlp.shared_expert.down_proj.weight)),
+        "ssm_hybrid": (
+            {"state_reset_at_the_program_boundary": state_reset},
+            ("last_memory_unit_dropped", lambda m: next(
+                layer for layer in reversed(m.model.layers)
+                if layer.kind == "gmu").mixer.out_proj.weight)),
     }[family]
 
     def engine(model):
